@@ -616,11 +616,13 @@ class ParallaxConfig:
     # under this name on every batch so the feed structure stays
     # signature-stable.
     bucket_mask_feed: str = "w"
-    # Directory for JAX's persistent compilation cache: repeated
-    # launches of the same program skip XLA entirely (compiles become
-    # disk reads). Process-global; keyed by HLO + compile environment,
-    # so a stale cache can only miss, never corrupt. None = leave the
-    # process setting alone.
+    # A fixed directory for JAX's persistent compilation cache:
+    # repeated launches of the same program skip XLA entirely
+    # (compiles become disk reads). Process-global; keyed by HLO +
+    # compile environment, so a stale cache can only miss, never
+    # corrupt. Honoured only when JAX_COMPILATION_CACHE_DIR is unset
+    # (a cache placed from outside wins); None = <checkout>/.jax_cache
+    # (compile/cache.ensure_persistent_cache decides).
     compilation_cache_dir: Optional[str] = None
     # When True, ``run()`` materializes every fetch to a host value
     # before returning (the pre-async blocking behavior). Default False:

@@ -23,9 +23,10 @@ measured. Three cooperating parts drive those compiles to the minimum:
   * :mod:`~parallax_tpu.compile.cache` — executable/engine caching: the
     session keeps built engines keyed by ``(num_partitions,
     batch-signature)`` so the partition search reuses the measured
-    winner instead of rebuilding it, and
-    ``Config(compilation_cache_dir=...)`` wires JAX's persistent
-    compilation cache so repeated launches skip XLA entirely.
+    winner instead of rebuilding it, and ``ensure_persistent_cache``
+    places JAX's persistent compilation cache (where
+    ``JAX_COMPILATION_CACHE_DIR`` says, else a fixed path) so repeated
+    launches skip XLA entirely.
 
 Everything reports through the obs layer: ``engine.compile_seconds``
 (histogram), ``engine.executable_cache.{hits,misses}`` and
@@ -37,10 +38,10 @@ Everything reports through the obs layer: ``engine.compile_seconds``
 from parallax_tpu.compile.bucketing import (batch_signature, bucket_batch,
                                             resolve_buckets)
 from parallax_tpu.compile.cache import (EngineCache,
-                                        enable_persistent_cache)
+                                        ensure_persistent_cache)
 from parallax_tpu.compile.warmup import aot_warmup
 
 __all__ = [
     "batch_signature", "bucket_batch", "resolve_buckets",
-    "EngineCache", "enable_persistent_cache", "aot_warmup",
+    "EngineCache", "ensure_persistent_cache", "aot_warmup",
 ]

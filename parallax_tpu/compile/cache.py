@@ -16,41 +16,76 @@ Two cache layers with different lifetimes:
   a dictionary lookup plus a state reshard, zero XLA work.
 
 * JAX's persistent compilation cache (on-disk, cross-process):
-  ``Config(compilation_cache_dir=...)`` wires it for the session, so a
-  relaunched job (same model, same toolchain) skips XLA entirely —
-  compiles become disk reads. Keyed by HLO + compile environment: a
-  stale cache can only miss, never corrupt.
+  ``ensure_persistent_cache`` is the ONE place that decides where it
+  lives, and every entry point (``ParallaxSession``, ``ServeSession``,
+  ``chip_smoke.py``, ``bench.py``, ``tests/conftest.py``) goes through
+  it, so a relaunched job (same model, same toolchain) skips XLA
+  entirely — compiles become disk reads. Keyed by HLO + compile
+  environment: a stale cache can only miss, never corrupt.
 """
 
 from __future__ import annotations
 
+import os
 from typing import Dict, Optional, Tuple
+
+import jax
 
 from parallax_tpu.common.lib import parallax_log
 from parallax_tpu.obs import metrics as obs_metrics
 
+# <checkout>/.jax_cache (listed in .gitignore). A fixed path: the
+# directory is part of how a cache is found again, so one made from
+# tempfile, a pid or the time would never hit.
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-def enable_persistent_cache(cache_dir: str,
-                            min_compile_secs: float = 0.0) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
 
-    Process-global (the cache is a backend property). Returns False —
-    with a warning, never an exception — on toolchains without the
-    config knobs, so a session on an old jax still runs, just
-    uncached.
+# the directory the first call settled on; decided once per process so
+# a later session cannot switch the cache back on after the pipeline
+# engines' guard (core/engine._guard_persistent_cache_for_pipeline)
+# turned it off
+_decided_dir: Optional[str] = None
+
+
+def ensure_persistent_cache(explicit_dir: Optional[str] = None) -> str:
+    """Turn JAX's persistent compilation cache on and return the
+    directory it uses. Process-global (the cache is a backend
+    property): the first call decides, later calls return its answer.
+
+    * ``JAX_COMPILATION_CACHE_DIR`` set: the cache was placed from
+      outside (a machine that keeps it between runs). jax reads the
+      variable itself and this program sets NO directory in code —
+      not from ``explicit_dir``, not a default.
+    * unset: ``explicit_dir`` (a user's fixed path,
+      ``Config.compilation_cache_dir``) when given, else
+      ``CHECKOUT_CACHE_DIR``.
+
+    Every executable is cached (threshold 0 s), so a second run of
+    the same program adds no entries — jax's default threshold of 1 s
+    would re-decide borderline compiles run by run. Like the
+    directory, a threshold exported in the environment
+    (``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``) is left alone.
     """
-    import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    global _decided_dir
+    if _decided_dir is not None:
+        if explicit_dir and explicit_dir != _decided_dir:
+            parallax_log.warning(
+                "compilation_cache_dir=%s ignored: this process's "
+                "persistent compilation cache is already at %s",
+                explicit_dir, _decided_dir)
+        return _decided_dir
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_secs))
-        parallax_log.info("persistent compilation cache at %s", cache_dir)
-        return True
-    except Exception as e:  # older jax without the knobs
-        parallax_log.warning(
-            "compilation_cache_dir=%s has no effect on this jax "
-            "build (%s); compiles will not persist", cache_dir, e)
-        return False
+                          0.0)
+    _decided_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not _decided_dir:
+        _decided_dir = explicit_dir or CHECKOUT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", _decided_dir)
+    parallax_log.info("persistent compilation cache at %s",
+                      _decided_dir)
+    return _decided_dir
 
 
 class EngineCache:

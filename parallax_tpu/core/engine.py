@@ -970,21 +970,19 @@ class Engine:
         never a device execution."""
         if self._step_costs is not None:
             return self._step_costs
-        from parallax_tpu.common import compat
         costs: Dict[str, float] = {}
         try:
             if self._executables:
-                costs = compat.cost_analysis(
-                    next(iter(self._executables.values())))
+                # {} when the backend reports nothing
+                costs = dict(next(iter(
+                    self._executables.values())).cost_analysis() or {})
             elif not cheap_only:
                 state_shapes = jax.eval_shape(
                     self._init_jit,
                     jax.ShapeDtypeStruct((), jnp.int32))
                 lowered = self._step_jit.lower(state_shapes,
                                                self._batch_shapes)
-                # compat owns the list-vs-dict normalization (Lowered
-                # exposes the same cost_analysis() surface)
-                costs = compat.cost_analysis(lowered)
+                costs = dict(lowered.cost_analysis() or {})
             else:
                 return {}
         except Exception as e:  # never fail training for forensics

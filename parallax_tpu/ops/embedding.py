@@ -57,7 +57,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from parallax_tpu.core.mesh import AXIS_REPL, AXIS_SHARD, num_devices
-from parallax_tpu.common import compat
 
 
 class SliceCapture:
@@ -383,7 +382,7 @@ def _overflow_flag(ids, vocab, cap, mesh):
     def local(ids_local):
         return _distinct_count_overflows(ids_local.reshape(-1), vocab,
                                          cap)
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=P((AXIS_REPL, AXIS_SHARD)),
         out_specs=P(),
@@ -443,7 +442,7 @@ def _sharded_lookup(table, ids, mesh, dedup_capacity: Optional[int] = None,
     # static — disabling it for exactly this case changes no numerics;
     # out_specs still declares the true layout.
     check = not (guarded and dedup_capacity is not None)
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(AXIS_SHARD, None), P((AXIS_REPL, AXIS_SHARD)), P()),
         out_specs=P((AXIS_REPL, AXIS_SHARD)),
@@ -570,7 +569,7 @@ def _manual_bwd(mesh, dedup_capacity, guarded, average, sparse_repl,
     # sparse_repl output is invariant over 'repl' BY CONSTRUCTION (every
     # device scatters the same full-mesh gather), which the static vma
     # checker can't see — hence check_vma=False on that variant only
-    grad_table = compat.shard_map(
+    grad_table = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P((AXIS_REPL, AXIS_SHARD)), P((AXIS_REPL, AXIS_SHARD)),
                   P()),

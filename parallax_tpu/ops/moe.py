@@ -31,7 +31,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from parallax_tpu.core.mesh import AXIS_REPL, AXIS_SHARD
-from parallax_tpu.common import compat
 
 
 class MoEOut(NamedTuple):
@@ -141,7 +140,7 @@ def switch_moe(tokens: jax.Array,          # [B, D] (batch sharded dim 0)
         drop_ct = jnp.sum(1.0 - keep.astype(jnp.float32))
         return combined, drop_ct.reshape(1)
 
-    out, drop_ct = compat.shard_map(
+    out, drop_ct = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P((AXIS_REPL, AXIS_SHARD), None),
                   P((AXIS_REPL, AXIS_SHARD), None),

@@ -24,7 +24,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from parallax_tpu.common import compat
 import numpy as np
 from jax.experimental import pallas as pl
 
@@ -102,7 +101,7 @@ def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying the caller's varying-mesh-axes set —
     required when the kernels run inside a shard_map (the ring
     attention block path); a plain struct elsewhere."""
-    vma = getattr(compat.typeof(like), "vma", None)
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)

@@ -114,10 +114,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from parallax_tpu.common import compat
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from parallax_tpu.ops.pallas_attention import _sds
 
 
 def _split_w(w, w_proj):
@@ -266,8 +267,18 @@ def _lstm_kernel_res(xw_ref, wh_ref, wp_ref, out_ref, gates_ref,
     cseq_ref[0] = c.astype(cseq_ref.dtype)
 
 
+def _compiler_params(vmem_limit):
+    """Mosaic's scoped-VMEM cap for one kernel: the byte count of the
+    fit that chose its tile (`_fwd_vmem_bytes`/`_bwd_vmem_bytes`), so
+    the tile and the limit can never disagree. None (direct callers,
+    interpret mode) keeps the compiler's default."""
+    if vmem_limit is None:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=int(vmem_limit))
+
+
 def _forward(x_seq, w, b, w_proj, batch_tile: int, interpret: bool,
-             save_residuals: bool = False):
+             save_residuals: bool = False, vmem_limit=None):
     T, B, _ = x_seq.shape
     H = w.shape[1] // 4
     P = w_proj.shape[1]
@@ -277,6 +288,7 @@ def _forward(x_seq, w, b, w_proj, batch_tile: int, interpret: bool,
     while B % bt:
         bt -= 1
     grid = (B // bt, T)
+    params = _compiler_params(vmem_limit)
     in_specs = [
         pl.BlockSpec((1, bt, 4 * H), lambda i, t: (t, i, 0)),
         pl.BlockSpec(w_h.shape, lambda i, t: (0, 0)),
@@ -292,8 +304,9 @@ def _forward(x_seq, w, b, w_proj, batch_tile: int, interpret: bool,
             grid=grid,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, bt, P), lambda i, t: (t, i, 0)),
-            out_shape=jax.ShapeDtypeStruct((T, B, P), x_seq.dtype),
+            out_shape=_sds((T, B, P), x_seq.dtype, xw),
             scratch_shapes=scratch,
+            compiler_params=params,
             interpret=interpret,
         )(xw, w_h, w_proj)
     return pl.pallas_call(
@@ -305,12 +318,16 @@ def _forward(x_seq, w, b, w_proj, batch_tile: int, interpret: bool,
             pl.BlockSpec((1, bt, 4 * H), lambda i, t: (t, i, 0)),
             pl.BlockSpec((1, bt, H), lambda i, t: (t, i, 0)),
         ],
+        # under the mesh wrap (shard_map, VMA checker on for compiled
+        # kernels) the outputs vary over the batch axes exactly as the
+        # streamed input does
         out_shape=[
-            jax.ShapeDtypeStruct((T, B, P), x_seq.dtype),
-            jax.ShapeDtypeStruct((T, B, 4 * H), x_seq.dtype),
-            jax.ShapeDtypeStruct((T, B, H), x_seq.dtype),
+            _sds((T, B, P), x_seq.dtype, xw),
+            _sds((T, B, 4 * H), x_seq.dtype, xw),
+            _sds((T, B, H), x_seq.dtype, xw),
         ],
         scratch_shapes=scratch,
+        compiler_params=params,
         interpret=interpret,
     )(xw, w_h, w_proj)
 
@@ -457,7 +474,8 @@ def _bwd_scan_path(x_seq, w, b, w_proj, gates, cseq, hs, g):
 
 
 def _bwd_kernel_path(x_seq, w, b, w_proj, gates, cseq, hs, g,
-                     bwd_batch_tile: int, interpret: bool):
+                     bwd_batch_tile: int, interpret: bool,
+                     vmem_limit=None):
     """The kernel backward: the time-reversed pallas recurrence streams
     d_xw / dh_total out, then every weight gradient is ONE batched
     fp32-accumulating XLA matmul — the mirror image of the forward's
@@ -490,13 +508,14 @@ def _bwd_kernel_path(x_seq, w, b, w_proj, gates, cseq, hs, g,
             pl.BlockSpec((1, bt, P), rev),             # dh_total
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((T, B, 4 * H), x_seq.dtype),
-            jax.ShapeDtypeStruct((T, B, P), f32),
+            _sds((T, B, 4 * H), x_seq.dtype, gates),
+            _sds((T, B, P), f32, gates),
         ],
         scratch_shapes=[
             pltpu.VMEM((bt, H), f32),                  # dc carry
             pltpu.VMEM((bt, P), f32),                  # dh carry
         ],
+        compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
     )(g, gates, cseq, cseq, w_h, w_proj)
     return _bwd_epilogue(x_seq, w, b, w_proj, gates, cseq, hs, dxw,
@@ -527,89 +546,121 @@ def _bwd_recompute(x_seq, w, b, w_proj, g):
             db.astype(b.dtype), dwp.astype(w_proj.dtype))
 
 
+# fwd_plan (static): (batch tile, vmem limit) from the forward fit.
 # bwd_mode (static): None -> recompute-XLA (no residuals saved);
 # "scan" -> residual backward via the XLA reversed scan;
-# ("kernel", bt) -> the time-reversed pallas kernel at batch tile bt
+# ("kernel", bt, limit) -> the time-reversed pallas kernel at batch
+# tile bt under its own fit's vmem limit
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _lstm_scan_pallas(x_seq, w, b, w_proj, batch_tile,
+def _lstm_scan_pallas(x_seq, w, b, w_proj, fwd_plan,
                       bwd_mode, interpret):
-    return _forward(x_seq, w, b, w_proj, batch_tile, interpret)
+    bt, limit = fwd_plan
+    return _forward(x_seq, w, b, w_proj, bt, interpret,
+                    vmem_limit=limit)
 
 
-def _fwd(x_seq, w, b, w_proj, batch_tile, bwd_mode, interpret):
+def _fwd(x_seq, w, b, w_proj, fwd_plan, bwd_mode, interpret):
+    bt, limit = fwd_plan
     if bwd_mode is None:
         # recompute backward: save no residuals (the primal inputs are
         # enough to re-run the reference scan)
-        out = _forward(x_seq, w, b, w_proj, batch_tile, interpret)
+        out = _forward(x_seq, w, b, w_proj, bt, interpret,
+                       vmem_limit=limit)
         return out, (x_seq, w, b, w_proj, None, None, None)
-    out, gates, cseq = _forward(x_seq, w, b, w_proj, batch_tile,
-                                interpret, save_residuals=True)
+    out, gates, cseq = _forward(x_seq, w, b, w_proj, bt, interpret,
+                                save_residuals=True, vmem_limit=limit)
     return out, (x_seq, w, b, w_proj, gates, cseq, out)
 
 
-def _bwd(batch_tile, bwd_mode, interpret, res, g):
+def _bwd(fwd_plan, bwd_mode, interpret, res, g):
     x_seq, w, b, w_proj, gates, cseq, hs = res
     if gates is None:
         return _bwd_recompute(x_seq, w, b, w_proj, g)
     if bwd_mode == "scan":
         return _bwd_scan_path(x_seq, w, b, w_proj, gates, cseq, hs, g)
+    _, bwd_bt, bwd_limit = bwd_mode
     return _bwd_kernel_path(x_seq, w, b, w_proj, gates, cseq, hs, g,
-                            bwd_mode[1], interpret)
+                            bwd_bt, interpret, vmem_limit=bwd_limit)
 
 
 _lstm_scan_pallas.defvjp(_fwd, _bwd)
 
 
+# -- the VMEM fit: one model chooses the tile AND the compiler's limit ------
+# What Mosaic allocates per BlockSpec, read off the compiler's own
+# "Scoped allocation with size ..." messages on a v5e target (jax
+# 0.9.0 / libtpu 0.0.34, PR 22): a block whose index map is constant
+# over the grid (w_h, w_proj) gets ONE buffer; every block whose index
+# moves with the grid gets TWO (the pipeline prefetches step t+1 while
+# step t computes); scratch is allocated as declared. On top of the
+# buffers the body materializes fp32 [bt, 4H] gate tiles.
+
+_VMEM_BUDGET = 64 * 1024 * 1024     # half a v5e TensorCore's 128 MiB
+
+
+def _vmem_budget() -> int:
+    return int(os.environ.get("PARALLAX_LSTM_VMEM_BUDGET", _VMEM_BUDGET))
+
+
+def _fwd_vmem_bytes(bt, H, P, wsz, xsz, residuals: bool) -> int:
+    """Bytes the FORWARD kernel holds in VMEM at batch tile ``bt``."""
+    resident = (P * 4 * H + H * P) * wsz               # w_h + w_proj, x1
+    streamed = bt * 4 * H * xsz + bt * P * xsz         # xw in, out
+    if residuals:
+        streamed += bt * 4 * H * xsz + bt * H * xsz    # gate acts, c traj
+    scratch = bt * H * 4 + bt * P * 4                  # fp32 c + h carry
+    body = 2 * bt * 4 * H * 4                          # gates pre/post act
+    return resident + 2 * streamed + scratch + body
+
+
+def _bwd_vmem_bytes(bt, H, P, wsz, xsz) -> int:
+    """Bytes the BACKWARD kernel holds in VMEM at batch tile ``bt``.
+    The cotangent ``g`` is sized at the output dtype: JAX types a
+    cotangent by its primal, so it arrives as hs's dtype (x_seq's)."""
+    resident = (P * 4 * H + H * P) * wsz               # w_h + w_proj, x1
+    streamed = (bt * P * xsz                           # g
+                + bt * 4 * H * xsz                     # gate acts
+                + 2 * bt * H * xsz                     # c_t + c_{t-1}
+                + bt * 4 * H * xsz                     # d_xw out
+                + bt * P * 4)                          # dh_total out
+    scratch = bt * H * 4 + bt * P * 4                  # fp32 dc + dh carry
+    body = 2 * bt * 4 * H * 4                          # gates + d_gates
+    return resident + 2 * streamed + scratch + body
+
+
+def _largest_fitting_tile(batch_tile, B, vmem_bytes, budget):
+    """(bt, bytes) for the largest divisor ``bt <= batch_tile`` of B
+    with ``vmem_bytes(bt) <= budget``, or None when even bt=1 cannot
+    fit. ``bytes`` is what the kernel is then compiled under
+    (``vmem_limit_bytes``)."""
+    for bt in range(min(batch_tile, B), 0, -1):
+        if B % bt == 0:
+            need = vmem_bytes(bt)
+            if need <= budget:
+                return bt, need
+    return None
+
+
 def _vmem_fit_batch_tile(batch_tile, B, H, P, w_dtype, x_dtype, budget,
                          *, residuals: bool = False):
-    """Largest bt <= batch_tile whose FORWARD resident set fits the
-    budget, or None. Resident: w_h + w_proj blocks (constant index ->
-    kept), the fp32 carry scratch, and double-buffered xw/out
-    streaming tiles (both stored in the compute dtype); with
-    ``residuals`` (the under-differentiation forward) also the
-    double-buffered gate-activation and c-trajectory output tiles."""
+    """FORWARD fit -> (bt, vmem bytes) or None (-> refusal)."""
     wsz = jnp.dtype(w_dtype).itemsize
     xsz = jnp.dtype(x_dtype).itemsize
-    fixed = P * 4 * H * wsz + H * P * wsz              # w_h + w_proj
-    bt = min(batch_tile, B)
-    while bt >= 1:
-        if B % bt == 0:
-            per_b = (bt * H * 4 + bt * P * 4           # c + h scratch
-                     + 2 * bt * 4 * H * xsz            # xw blocks
-                     + 2 * bt * P * xsz)               # out blocks
-            if residuals:
-                per_b += (2 * bt * 4 * H * xsz         # gate-act blocks
-                          + 2 * bt * H * xsz)          # c-traj blocks
-            if fixed + per_b <= budget:
-                return bt
-        bt -= 1
-    return None
+    return _largest_fitting_tile(
+        batch_tile, B,
+        lambda bt: _fwd_vmem_bytes(bt, H, P, wsz, xsz, residuals),
+        budget)
 
 
 def _vmem_fit_batch_tile_bwd(batch_tile, B, H, P, w_dtype, x_dtype,
                              budget):
-    """Largest bt whose BACKWARD resident set fits, or None (-> the
-    recompute-XLA fallback). Resident: w_h + w_proj, the fp32 (dc, dh)
-    carry scratch, and double-buffered streams — g (sized fp32: the
-    cotangent dtype is unknown at forward-trace time, so the fit is
-    conservative), gate activations, c read twice (c_t and c_{t-1}
-    windows), d_xw out (compute dtype) and dh_total out (fp32)."""
+    """BACKWARD fit -> (bt, vmem bytes) or None (-> the residual-scan
+    executor under ``bwd_impl='auto'``)."""
     wsz = jnp.dtype(w_dtype).itemsize
     xsz = jnp.dtype(x_dtype).itemsize
-    fixed = P * 4 * H * wsz + H * P * wsz              # w_h + w_proj
-    bt = min(batch_tile, B)
-    while bt >= 1:
-        if B % bt == 0:
-            per_b = (bt * H * 4 + bt * P * 4           # dc + dh scratch
-                     + 2 * bt * P * 4                  # g blocks (fp32)
-                     + 2 * bt * 4 * H * xsz            # gate-act blocks
-                     + 2 * 2 * bt * H * xsz            # c + c_prev
-                     + 2 * bt * 4 * H * xsz            # d_xw blocks
-                     + 2 * bt * P * 4)                 # dh_total blocks
-            if fixed + per_b <= budget:
-                return bt
-        bt -= 1
-    return None
+    return _largest_fitting_tile(
+        batch_tile, B,
+        lambda bt: _bwd_vmem_bytes(bt, H, P, wsz, xsz), budget)
 
 
 # -- trace records for the cost model ---------------------------------------
@@ -751,8 +802,7 @@ def lstm_scan(x_seq, w, b, w_proj, *, impl: str = "xla",
     T, B, E = x_seq.shape
     H = w.shape[1] // 4
     P = w_proj.shape[1]
-    budget = int(os.environ.get("PARALLAX_LSTM_VMEM_BUDGET",
-                                12 * 1024 * 1024))
+    budget = _vmem_budget()
     # refuse sizes that cannot compile on hardware instead of failing
     # deep inside Mosaic; only the RECURRENT matrix must be resident
     # (batch size is divided across devices by the shard_map wrap below,
@@ -763,6 +813,8 @@ def lstm_scan(x_seq, w, b, w_proj, *, impl: str = "xla",
                 else tuple(batch_axes))
         n_shards = int(np.prod([mesh.shape[a] for a in axes]))
     B_dev = max(1, B // n_shards)
+    # interpret mode runs any size at the requested tile, no limit
+    any_size = (min(batch_tile, B_dev), None)
     # backward mode first: whether residuals are saved decides the
     # forward's own tile fit. 'auto' picks the pallas kernel when its
     # resident set fits a real TensorCore run, and the XLA
@@ -773,13 +825,11 @@ def lstm_scan(x_seq, w, b, w_proj, *, impl: str = "xla",
     elif bwd_impl == "scan":
         bwd_mode = "scan"
     else:
-        bwd_bt = _vmem_fit_batch_tile_bwd(batch_tile, B_dev, H, P,
-                                          w.dtype, x_seq.dtype, budget)
+        bwd_fit = _vmem_fit_batch_tile_bwd(batch_tile, B_dev, H, P,
+                                           w.dtype, x_seq.dtype, budget)
         if bwd_impl == "kernel":
-            if bwd_bt is None:
-                if interpret:
-                    bwd_bt = min(batch_tile, B_dev)    # interpret: any
-                else:
+            if bwd_fit is None:
+                if not interpret:
                     wh_bytes = P * 4 * H * jnp.dtype(w.dtype).itemsize
                     raise ValueError(
                         f"pallas lstm backward: resident set "
@@ -788,45 +838,60 @@ def lstm_scan(x_seq, w, b, w_proj, *, impl: str = "xla",
                         f"{budget / 1e6:.0f} MB VMEM budget at every "
                         f"batch tile — use bwd_impl='scan' (the "
                         f"residual fallback) or 'recompute'")
-            bwd_mode = ("kernel", int(bwd_bt))
-        elif interpret or bwd_bt is None:              # auto
+                bwd_fit = any_size
+            bwd_mode = ("kernel", *bwd_fit)
+        elif interpret or bwd_fit is None:             # auto
             bwd_mode = "scan"
         else:
-            bwd_mode = ("kernel", int(bwd_bt))
-    bt = _vmem_fit_batch_tile(batch_tile, B_dev, H, P,
-                              w.dtype, x_seq.dtype, budget,
-                              residuals=bwd_mode is not None)
-    if bt is None and bwd_mode is not None and bwd_impl == "auto":
+            bwd_mode = ("kernel", *bwd_fit)
+    fwd_fit = _vmem_fit_batch_tile(batch_tile, B_dev, H, P,
+                                   w.dtype, x_seq.dtype, budget,
+                                   residuals=bwd_mode is not None)
+    if fwd_fit is None and bwd_mode is not None and bwd_impl == "auto":
         # the residual streams are what broke the forward fit: drop to
         # the recompute backward rather than refusing outright
         bwd_mode = None
-        bt = _vmem_fit_batch_tile(batch_tile, B_dev, H, P,
-                                  w.dtype, x_seq.dtype, budget)
-    if not interpret and bt is None:
+        fwd_fit = _vmem_fit_batch_tile(batch_tile, B_dev, H, P,
+                                       w.dtype, x_seq.dtype, budget)
+    if not interpret and fwd_fit is None:
         wh_bytes = P * 4 * H * jnp.dtype(w.dtype).itemsize
         raise ValueError(
             f"pallas lstm: resident set (recurrent matrix "
             f"{wh_bytes / 1e6:.1f} MB + proj + carry) exceeds the "
             f"{budget / 1e6:.0f} MB VMEM budget at every batch tile — "
             f"use impl='xla' (or a smaller hidden/projection size)")
-    if bt is None:
-        bt = min(batch_tile, B_dev)                    # interpret: any
+    if fwd_fit is None:
+        fwd_fit = any_size
     bwd_name = ("recompute" if bwd_mode is None
                 else "scan" if bwd_mode == "scan" else "kernel")
     _record_call(mesh, T, B, E, H, P, x_seq.dtype, w.dtype, n_shards,
                  bwd_name)
 
     def run(x_seq, w, b, w_proj):
-        return _lstm_scan_pallas(x_seq, w, b, w_proj, int(bt),
+        return _lstm_scan_pallas(x_seq, w, b, w_proj, fwd_fit,
                                  bwd_mode, bool(interpret))
 
     if mesh is None or batch_axes is None:
         return run(x_seq, w, b, w_proj)
     from jax.sharding import PartitionSpec as P_
-    return compat.shard_map(
-        run, mesh=mesh,
+
+    def run_checked(x_seq, w, b, w_proj):
+        # Compiled kernels run with the VMA checker on, and a
+        # custom_vjp must return cotangents typed like its primals:
+        # each device's dW is device-varying, the replicated weights
+        # are not. Mark the weights varying on the way in — the
+        # transpose of that cast IS the gradient psum over the batch
+        # axes (first trace for a TensorCore, PR 22: "the varying
+        # manual axes do not match").
+        w, b, w_proj = (jax.lax.pcast(a, axes, to="varying")
+                        for a in (w, b, w_proj))
+        return run(x_seq, w, b, w_proj)
+
+    return jax.shard_map(
+        # pallas interpret mode trips the VMA checker (see
+        # ops/ring_attention.py — jax's own suggested workaround), and
+        # no pcast may be emitted with the checker off
+        run if interpret else run_checked, mesh=mesh,
         in_specs=(P_(None, batch_axes, None), P_(), P_(), P_()),
         out_specs=P_(None, batch_axes, None),
-        # pallas interpret mode trips the VMA checker (see
-        # ops/ring_attention.py — jax's own suggested workaround)
         check_vma=not interpret)(x_seq, w, b, w_proj)

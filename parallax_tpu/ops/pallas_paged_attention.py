@@ -157,22 +157,24 @@ def paged_gather(pool_layer, pages):
 
 def _paged_attn_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                        m_ref, l_ref, acc_ref, *, page_size: int,
-                       pool_pages: int, num_heads: int,
+                       pool_pages: int, num_heads: int, num_queries: int,
                        sqrt_hd: float):
     """One (slot, page-step) program. Refs:
 
     * ``pages_ref [S, P]`` / ``pos_ref [S, G]`` — scalar prefetch
       (SMEM); the page table also drives the K/V index maps.
-    * ``q_ref [1, G, D]`` — the slot's queries, VMEM-resident across
-      the page sweep (constant index map).
+    * ``q_ref [1, Gp, D]`` — the slot's queries padded to a whole
+      sublane tile (``_padded_queries``), VMEM-resident across the
+      page sweep (constant index map). Rows ``>= G`` are padding: they
+      see no position, accumulate nothing and are sliced off outside.
     * ``k_ref``/``v_ref [1, page_size, D]`` — THE streamed block: the
       index map fetched page ``pages[s, p]`` (clipped).
-    * ``o_ref [1, G, D]`` — written at the last page step.
-    * scratch: ``m_ref``/``l_ref [num_heads, G, _LANES]`` f32 and
-      ``acc_ref [G, D]`` f32, persisting across the page sweep.
+    * ``o_ref [1, Gp, D]`` — written at the last page step.
+    * scratch: ``m_ref``/``l_ref [num_heads, Gp, _LANES]`` f32 and
+      ``acc_ref [Gp, D]`` f32, persisting across the page sweep.
     """
     s, p = pl.program_id(0), pl.program_id(1)
-    G = q_ref.shape[1]
+    Gp = q_ref.shape[1]
     D = q_ref.shape[2]
     hd = D // num_heads
 
@@ -184,63 +186,80 @@ def _paged_attn_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     page_id = pages_ref[s, p]
     live = page_id < pool_pages
-    q = q_ref[0]                                           # [G, D]
+    q = q_ref[0]                                           # [Gp, D]
     k = k_ref[0]                                           # [ps, D]
     v = v_ref[0].astype(jnp.float32)
 
-    # shared masks for this page step: causal frontier per query row
-    # (2D iota; per-row SMEM scalars enter via a static-G unroll) and
-    # the in-kernel sentinel kill
+    # shared mask for this page step, built as whole int32 tiles: the
+    # causal frontier per query row (per-row SMEM scalars selected in
+    # by a row iota over the static real rows; padding rows keep -1 and
+    # so see nothing) and the in-kernel sentinel kill (a dead page
+    # moves the frontier to -1 for every row)
+    row = jax.lax.broadcasted_iota(jnp.int32, (Gp, page_size), 0)
     tok = p * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)                      # [1, ps]
-    causal = jnp.concatenate([tok <= pos_ref[s, g] for g in range(G)],
-                             axis=0)                       # [G, ps]
-    visible = causal & live
+        jnp.int32, (Gp, page_size), 1)
+    frontier = jnp.full((Gp, page_size), -1, jnp.int32)
+    for g in range(num_queries):
+        frontier = jnp.where(row == g, pos_ref[s, g], frontier)
+    frontier = jnp.where(live, frontier, -1)
+    visible = tok <= frontier                              # [Gp, ps]
 
     # column->head map for the head-masked full-width dots
-    col_head = jax.lax.broadcasted_iota(jnp.int32, (G, D), 1) // hd
+    col_head = jax.lax.broadcasted_iota(jnp.int32, (Gp, D), 1) // hd
 
-    acc = acc_ref[...]                                     # [G, D] f32
-    contrib = jnp.zeros((G, D), jnp.float32)
-    alpha_full = jnp.zeros((G, D), jnp.float32)
+    acc = acc_ref[...]                                     # [Gp, D] f32
+    contrib = jnp.zeros((Gp, D), jnp.float32)
+    alpha_full = jnp.zeros((Gp, D), jnp.float32)
     for h in range(num_heads):
-        q_h = jnp.where(col_head == h, q, 0)               # [G, D]
+        head_cols = col_head == h
+        q_h = jnp.where(head_cols, q, jnp.zeros_like(q))   # [Gp, D]
         # scale AFTER the f32 dot (divide, matching the reference's
         # ``scores / sqrt(hd)`` rounding) — scaling q in the compute
         # dtype would inject ~2^-9 relative score noise under bf16,
         # an order of magnitude past the online-softmax drift
         s_h = jax.lax.dot_general(
             q_h, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) / sqrt_hd  # [G, ps]
+            preferred_element_type=jnp.float32) / sqrt_hd  # [Gp, ps]
         s_h = jnp.where(visible, s_h, _NEG_INF)
-        m_prev = m_ref[h]                                  # [G, LANES]
+        m_prev = m_ref[h]                                  # [Gp, LANES]
         l_prev = l_ref[h]
-        m_cur = jnp.max(s_h, axis=-1, keepdims=True)       # [G, 1]
-        m_new = jnp.maximum(m_prev, m_cur)                 # [G, LANES]
+        m_cur = jnp.max(s_h, axis=-1, keepdims=True)       # [Gp, 1]
+        m_new = jnp.maximum(m_prev, m_cur)                 # [Gp, LANES]
         alpha = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
         p_h = jnp.exp(s_h - m_new[:, :1])
-        p_h = jnp.where(s_h > _NEG_INF / 2, p_h, 0.0)
+        p_h = jnp.where(visible, p_h, 0.0)
         m_ref[h] = m_new
         l_ref[h] = l_prev * alpha + jnp.sum(p_h, axis=-1,
                                             keepdims=True)
         pv = jax.lax.dot_general(
             p_h, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [G, D]
-        head_cols = col_head == h
-        contrib = contrib + jnp.where(head_cols, pv, 0)
-        alpha_full = alpha_full + jnp.where(head_cols, alpha[:, :1], 0)
+            preferred_element_type=jnp.float32)            # [Gp, D]
+        contrib = contrib + jnp.where(head_cols, pv, 0.0)
+        alpha_full = alpha_full + jnp.where(head_cols, alpha[:, :1],
+                                            0.0)
     acc_ref[...] = acc * alpha_full + contrib
 
     @pl.when(p == pl.num_programs(1) - 1)
     def _finalize():
-        l_full = jnp.zeros((G, D), jnp.float32)
+        l_full = jnp.zeros((Gp, D), jnp.float32)
         for h in range(num_heads):
             l_full = l_full + jnp.where(col_head == h, l_ref[h][:, :1],
-                                        0)
+                                        0.0)
         # a fully-masked query (zero live visible positions) has l == 0
         # and acc == 0: emit exactly 0, never NaN (module docstring)
         o_ref[0] = (acc_ref[...]
                     / jnp.maximum(l_full, 1e-30)).astype(o_ref.dtype)
+
+
+def _padded_queries(G: int, itemsize: int) -> int:
+    """Query rows per program: ``G`` rounded up to one whole sublane
+    tile of the compute dtype (8 rows of 32-bit, 16 of 16-bit). Mosaic
+    refuses the sub-tile shapes — at G=1 ``Not implemented: Sublane
+    broadcast``, at G=3 an internal ``native_vreg_ty`` assert (first
+    compile for a TensorCore, PR 22) — and the MXU pads to the tile
+    anyway, so the padding rows cost no extra passes."""
+    tile = 8 * max(1, 4 // itemsize)
+    return -(-G // tile) * tile
 
 
 def _kernel_call(q, k_pool, v_pool, pages, pos, num_heads: int,
@@ -249,9 +268,10 @@ def _kernel_call(q, k_pool, v_pool, pages, pos, num_heads: int,
     pool = k_pool.shape[0]
     P = pages.shape[1]
     hd = D // num_heads
+    Gp = _padded_queries(G, jnp.dtype(q.dtype).itemsize)
     kernel = functools.partial(
         _paged_attn_kernel, page_size=page_size, pool_pages=pool,
-        num_heads=num_heads, sqrt_hd=float(np.sqrt(hd)))
+        num_heads=num_heads, num_queries=G, sqrt_hd=float(np.sqrt(hd)))
 
     def kv_map(s, p, pages_ref, pos_ref):
         # sentinel entries clip to the LAST live-clipped index
@@ -263,24 +283,27 @@ def _kernel_call(q, k_pool, v_pool, pages, pos, num_heads: int,
         num_scalar_prefetch=2,
         grid=(S, P),
         in_specs=[
-            pl.BlockSpec((1, G, D), lambda s, p, pages, pos: (s, 0, 0)),
+            pl.BlockSpec((1, Gp, D), lambda s, p, pages, pos: (s, 0, 0)),
             pl.BlockSpec((1, page_size, D), kv_map),
             pl.BlockSpec((1, page_size, D), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, G, D),
+        out_specs=pl.BlockSpec((1, Gp, D),
                                lambda s, p, pages, pos: (s, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((num_heads, G, _LANES), jnp.float32),   # m
-            pltpu.VMEM((num_heads, G, _LANES), jnp.float32),   # l
-            pltpu.VMEM((G, D), jnp.float32),                   # acc
+            pltpu.VMEM((num_heads, Gp, _LANES), jnp.float32),  # m
+            pltpu.VMEM((num_heads, Gp, _LANES), jnp.float32),  # l
+            pltpu.VMEM((Gp, D), jnp.float32),                  # acc
         ],
     )
-    return pl.pallas_call(
+    q_pad = jnp.pad(q, ((0, 0), (0, Gp - G), (0, 0)))
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, Gp, D), q.dtype),
         interpret=interpret,
-    )(pages.astype(jnp.int32), pos.astype(jnp.int32), q, k_pool, v_pool)
+    )(pages.astype(jnp.int32), pos.astype(jnp.int32), q_pad, k_pool,
+      v_pool)
+    return out[:, :G]
 
 
 # -- the einsum reference ---------------------------------------------------
@@ -324,13 +347,16 @@ def _einsum_reference(q, k_pool, v_pool, pages, pos, num_heads: int,
 
 def _vmem_fit(G: int, D: int, page_size: int, num_heads: int,
               itemsize: int, budget: int) -> bool:
-    """Whether one program's resident set fits: q + out blocks, the
-    double-buffered K/V page streams, and the f32 (m, l, acc)
-    scratch."""
-    resident = (2 * G * D * itemsize                # q + out blocks
-                + 2 * 2 * page_size * D * itemsize  # k, v double-buffered
-                + 2 * num_heads * G * _LANES * 4    # m, l
-                + G * D * 4)                        # acc
+    """Whether one program's resident set fits, counted as the
+    BlockSpecs allocate it: q and out blocks at the padded query
+    width, double-buffered like the K/V page streams (their block
+    index moves with the slot), and the f32 (m, l, acc) scratch with
+    the ``_LANES`` dim padded out to a whole 128-lane tile."""
+    Gp = _padded_queries(G, itemsize)
+    resident = (2 * 2 * Gp * D * itemsize           # q + out blocks
+                + 2 * 2 * page_size * D * itemsize  # k, v page streams
+                + 2 * num_heads * Gp * 128 * 4      # m, l
+                + Gp * D * 4)                       # acc
     return resident <= budget
 
 

@@ -56,7 +56,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from parallax_tpu.core.mesh import AXIS_REPL, pipeline_axis
-from parallax_tpu.common import compat
 from parallax_tpu.common.lib import parallax_log
 
 
@@ -186,9 +185,9 @@ def pipeline_apply(stage_fn: Callable,
             return stage_fn(pv, xx)
 
         act0 = jnp.zeros_like(xm[0])
-        outs0 = compat.pcast(
+        outs0 = jax.lax.pcast(
             jnp.zeros_like(xm), (stage_axis,), to="varying")
-        act0 = compat.pcast(act0, (stage_axis,), to="varying")
+        act0 = jax.lax.pcast(act0, (stage_axis,), to="varying")
 
         def tick(carry, t):
             act, outs = carry
@@ -227,7 +226,7 @@ def pipeline_apply(stage_fn: Callable,
     spec_params = jax.tree.map(
         lambda p: P(*((stage_axis,) + (None,) * (p.ndim - 1))),
         stage_params)
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_params, P(AXIS_REPL)),
         out_specs=P(AXIS_REPL),
@@ -337,12 +336,12 @@ def pipeline_value_and_grad(stage_fn: Callable,
         # those axes inserted by the transpose — a per-tick collective,
         # and a double-count with the one reduction we do at the end.
         my_params = jax.tree.map(
-            lambda p: compat.pcast(p, data_axes, to="varying"),
+            lambda p: jax.lax.pcast(p, data_axes, to="varying"),
             my_params)
 
         def vary_all(a):
             for ax in mesh.axis_names:
-                a = compat.pcast(a, (ax,), to="varying")
+                a = jax.lax.pcast(a, (ax,), to="varying")
             return a
 
         head_v = jax.tree.map(vary_all, head_local)
@@ -451,7 +450,7 @@ def pipeline_value_and_grad(stage_fn: Callable,
         stage_params)
     head_specs = jax.tree.map(lambda _: P(), head_params)
     y_specs = jax.tree.map(lambda _: P(AXIS_REPL), y)
-    loss, g_stage, g_head, g_x = compat.shard_map(
+    loss, g_stage, g_head, g_x = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_params, head_specs, P(AXIS_REPL), y_specs),
         out_specs=(P(), spec_params, head_specs, P(AXIS_REPL)),

@@ -34,7 +34,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from parallax_tpu.common import compat
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -130,7 +129,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         def pvary(x):
             if flash_interpret:
                 return x
-            return compat.pcast(x, vary, to="varying")
+            return jax.lax.pcast(x, vary, to="varying")
 
         m0 = pvary(jnp.full((B, H, Tq), _NEG_INF, jnp.float32))
         l0 = pvary(jnp.zeros((B, H, Tq), jnp.float32))
@@ -356,14 +355,10 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         m, l, o = accumulate(k_l, v_l, n - 1, m, l, o)
         return normalize(l, o)
 
-    # without the VMA system the legacy rep checker cannot be told the
-    # scan carry is device-varying (no pcast) and rejects the cond over
-    # ring steps — run it unchecked there, as jax itself advises
-    return compat.shard_map(local, mesh=mesh,
+    return jax.shard_map(local, mesh=mesh,
                          in_specs=(spec, spec, spec),
                          out_specs=spec,
-                         check_vma=(not flash_interpret
-                                    and compat.HAS_VMA))(q, k, v)
+                         check_vma=not flash_interpret)(q, k, v)
 
 
 def full_attention_reference(q, k, v, causal=False, scale=None):

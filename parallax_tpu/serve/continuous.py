@@ -173,9 +173,15 @@ class ContinuousScheduler:
                  queue: RequestQueue,
                  name: str = "parallax-serve-decode",
                  on_deadline_breach=None, replica_id=None,
-                 faults=None, on_fatal=None, on_error=None):
+                 faults=None, on_fatal=None, on_error=None, *,
+                 state_sharding):
         self._program = program
         self._params = params
+        # where the decode state (KV caches, page pool) lives: the
+        # owning session's mesh. ``init_state`` builds on jax's default
+        # device, so without this every replica's fresh pool sat on
+        # device 0 until its first dispatch moved it.
+        self._state_sharding = state_sharding
         self._sc = serve_config
         self._queue = queue
         self.metrics = metrics
@@ -295,10 +301,15 @@ class ContinuousScheduler:
         self._stop = threading.Event()
         self._kick = threading.Event()
         self._warm()
-        self._state = program.init_state(params, self._S)
+        self._state = self._fresh_state()
         self._thread = threading.Thread(target=self._loop, name=name,
                                         daemon=True)
         self._thread.start()
+
+    def _fresh_state(self):
+        return jax.device_put(
+            self._program.init_state(self._params, self._S),
+            self._state_sharding)
 
     # -- insert dispatch ---------------------------------------------------
 
@@ -324,7 +335,7 @@ class ContinuousScheduler:
         prog, params = self._program, self._params
         t0 = time.perf_counter()
         with trace.span("serve.warmup_compile", mode="decode"):
-            state = prog.init_state(params, self._S)
+            state = self._fresh_state()
             feed = prog.prepare_feed(prog.example_feed())
             if self._chunks > 1:
                 carry = feed

@@ -43,7 +43,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from parallax_tpu.common.config import ParallaxConfig
 from parallax_tpu.common.lib import parallax_log
-from parallax_tpu.compile import bucketing
+from parallax_tpu.compile import bucketing, cache as compile_cache
 from parallax_tpu.core import engine as engine_lib, mesh as mesh_lib
 from parallax_tpu.obs import _state as obs_state
 from parallax_tpu.obs import metrics as obs_metrics, reqtrace, trace
@@ -96,6 +96,8 @@ class ServeSession:
                 "(continuous decode)")
         self._config = config or ParallaxConfig()
         sc = self._config.serve_config
+        compile_cache.ensure_persistent_cache(
+            self._config.compilation_cache_dir)
         self.mesh = mesh if mesh is not None else mesh_lib.build_mesh(
             num_partitions=num_partitions)
         self.metrics = metrics if metrics is not None \
@@ -147,7 +149,8 @@ class ServeSession:
                 program, self._params, sc, self.metrics, self._queue,
                 on_deadline_breach=self._on_deadline_breach,
                 replica_id=replica_id, faults=faults,
-                on_fatal=on_fatal, on_error=on_error)
+                on_fatal=on_fatal, on_error=on_error,
+                state_sharding=NamedSharding(self.mesh, P()))
             self._batcher = None
             return
         self._scheduler = None

@@ -11,6 +11,7 @@ the budget is 2e-2 relative-to-peak (measured ~5e-3 at the flagship
 weight shape)."""
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -134,20 +135,82 @@ class TestFlagshipSize:
     """VERDICT r4 item 2: the kernel must serve the FLAGSHIP recurrence
     — bf16 gate matrix [E+P, 4H] = [1024, 8192] (16.8 MB). The r5
     design hoists the input projection and keeps only w_h [512, 8192]
-    (8.4 MB) resident, so the flagship fits the 12 MB VMEM budget."""
+    (8.4 MB) resident, so the flagship fits the VMEM budget with the
+    whole per-chip batch in one tile."""
 
     FE, FH, FP = 512, 2048, 512                     # flagship dims
 
     def test_vmem_fit_passes_flagship_bf16(self):
-        bt = pallas_lstm._vmem_fit_batch_tile(
+        bt, need = pallas_lstm._vmem_fit_batch_tile(
+            128, 128, self.FH, self.FP,
+            jnp.bfloat16, jnp.bfloat16, pallas_lstm._VMEM_BUDGET)
+        # the whole per-chip batch in one tile: the MXU sees 128 rows
+        assert bt == 128 and need <= pallas_lstm._VMEM_BUDGET
+        # a tighter budget shrinks the tile instead of refusing
+        bt12, need12 = pallas_lstm._vmem_fit_batch_tile(
             128, 128, self.FH, self.FP,
             jnp.bfloat16, jnp.bfloat16, 12 * 1024 * 1024)
-        assert bt is not None and 128 % bt == 0
+        assert 128 % bt12 == 0 and bt12 < 128
+        assert need12 <= 12 * 1024 * 1024
         # and the guard still refuses when the RESIDENT set alone
         # (recurrent matrix at 4x the hidden) cannot fit
         assert pallas_lstm._vmem_fit_batch_tile(
             128, 128, 4 * self.FH, 4 * self.FP,
-            jnp.bfloat16, jnp.bfloat16, 12 * 1024 * 1024) is None
+            jnp.bfloat16, jnp.bfloat16, pallas_lstm._VMEM_BUDGET) is None
+
+    @staticmethod
+    def _allocated_bytes(eqn):
+        """VMEM a traced pallas_call asks Mosaic for, counted the way
+        the compiler allocates it (read off its scoped-allocation
+        messages, PR 22): ONE buffer for a block whose index map is
+        constant over the grid, TWO for a block that moves, scratch as
+        declared."""
+        gm = eqn.params["grid_mapping"]
+        total = 0
+        for bm in gm.block_mappings:
+            nbytes = bm.array_aval.dtype.itemsize * int(np.prod(
+                [getattr(d, "block_size", d) for d in bm.block_shape]))
+            constant = all(isinstance(v, jax.extend.core.Literal)
+                           for v in bm.index_map_jaxpr.jaxpr.outvars)
+            total += nbytes * (1 if constant else 2)
+        kernel_args = eqn.params["jaxpr"].invars
+        for v in kernel_args[len(kernel_args) - gm.num_scratch_operands:]:
+            total += v.aval.dtype.itemsize * int(np.prod(v.aval.shape))
+        return total
+
+    def test_fit_model_counts_buffers_as_blockspecs_allocate(self):
+        """The fit that picks the tile, the BlockSpecs the kernels
+        declare, and the vmem_limit_bytes Mosaic compiles under are
+        ONE number: for the forward-with-residuals and the backward
+        program at the flagship shape, limit == buffers (as allocated)
+        + the fit's fp32 body-tile term. Trace only, nothing compiles."""
+        T_, B_ = 2, 128
+        args = (jax.ShapeDtypeStruct((T_, B_, self.FE), jnp.bfloat16),
+                jax.ShapeDtypeStruct((self.FE + self.FP, 4 * self.FH),
+                                     jnp.bfloat16),
+                jax.ShapeDtypeStruct((4 * self.FH,), jnp.bfloat16),
+                jax.ShapeDtypeStruct((self.FH, self.FP), jnp.bfloat16))
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(pallas_lstm.lstm_scan(
+                *a, impl="pallas", bwd_impl="kernel",
+                interpret=False).astype(jnp.float32)),
+            argnums=(0, 1, 2, 3)))(*args)
+        calls = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 2                 # fwd+residuals, bwd
+        want = (pallas_lstm._fwd_vmem_bytes(128, self.FH, self.FP, 2, 2,
+                                            residuals=True),
+                pallas_lstm._bwd_vmem_bytes(128, self.FH, self.FP, 2, 2))
+        body = 2 * 128 * 4 * self.FH * 4       # two fp32 [bt, 4H] tiles
+        for eqn, need in zip(calls, want):
+            limit = eqn.params["compiler_params"][
+                "mosaic_tpu"].vmem_limit_bytes
+            assert limit == need
+            assert self._allocated_bytes(eqn) + body == need
+        # the weights really are the single-buffered blocks: 10 MiB of
+        # w_h + w_proj counted once, not twice
+        assert want[0] < 2 * (self.FP * 4 * self.FH
+                              + self.FH * self.FP) * 2 + 20 * 2 ** 20
 
     def test_flagship_weight_shape_parity(self, rng):
         """Parity at the flagship WEIGHT shape (what gates compilation;
@@ -300,10 +363,14 @@ class TestBackwardKernel:
             return rec["bwd"]
 
         assert probe() == "kernel"           # default budget: fits
-        # between the residual-saving forward's bt=1 resident set
-        # (10,571,776 B) and the backward kernel's (10,586,112 B):
-        # only the backward fit fails
-        monkeypatch.setenv("PARALLAX_LSTM_VMEM_BUDGET", "10576000")
+        # between the residual-saving forward's bt=1 resident set and
+        # the backward kernel's: only the backward fit fails
+        fwd1 = pallas_lstm._fwd_vmem_bytes(1, FH, FP, 2, 2,
+                                           residuals=True)
+        bwd1 = pallas_lstm._bwd_vmem_bytes(1, FH, FP, 2, 2)
+        assert fwd1 < bwd1
+        monkeypatch.setenv("PARALLAX_LSTM_VMEM_BUDGET",
+                           str((fwd1 + bwd1) // 2))
         assert probe() == "scan"
 
     def test_bwd_env_override_forces_recompute(self, args,

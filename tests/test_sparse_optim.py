@@ -54,9 +54,7 @@ def test_update_cost_is_lower():
         s = tx.init(p)
         c = jax.jit(step, donate_argnums=(0, 1)).lower(
             p, s, jnp.zeros((big_v, big_d))).compile()
-        # compat: some jax releases wrap the analysis dict in a list
-        from parallax_tpu.common import compat
-        return compat.cost_analysis(c)["flops"]
+        return c.cost_analysis()["flops"]
 
     dense_flops = run(optax.adagrad(lr))
     sparse_flops = run(row_sparse_adagrad(lr, max_touched_rows=k))
