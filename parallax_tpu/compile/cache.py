@@ -21,12 +21,16 @@ Two cache layers with different lifetimes:
   ``chip_smoke.py``, ``bench.py``, ``tests/conftest.py``) goes through
   it, so a relaunched job (same model, same toolchain) skips XLA
   entirely — compiles become disk reads. Keyed by HLO + compile
-  environment: a stale cache can only miss, never corrupt.
+  environment, and the HLO's metadata (named scopes, source lines
+  relative to the checkout) is part of the key: a stale cache can only
+  miss, never corrupt — not the program and not the names that
+  ``Engine.layer_index()`` reads off the executable.
 """
 
 from __future__ import annotations
 
 import os
+import re
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -34,12 +38,12 @@ import jax
 from parallax_tpu.common.lib import parallax_log
 from parallax_tpu.obs import metrics as obs_metrics
 
+CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 # <checkout>/.jax_cache (listed in .gitignore). A fixed path: the
 # directory is part of how a cache is found again, so one made from
 # tempfile, a pid or the time would never hit.
-CHECKOUT_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), ".jax_cache")
+CHECKOUT_CACHE_DIR = os.path.join(CHECKOUT_ROOT, ".jax_cache")
 
 
 # the directory the first call settled on; decided once per process so
@@ -67,6 +71,18 @@ def ensure_persistent_cache(explicit_dir: Optional[str] = None) -> str:
     would re-decide borderline compiles run by run. Like the
     directory, a threshold exported in the environment
     (``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``) is left alone.
+
+    The key holds the HLO's metadata. By default jax strips it
+    (``strip-debuginfo``), and a program that differs from a cached
+    one only in a ``jax.named_scope`` or a kernel's name is then handed
+    the OLD executable, whose ``as_text()`` carries the old names:
+    everything that reads layers off the executable
+    (``Engine.layer_index()``) would read the previous source's. With
+    the metadata in, the names are this source's. Source paths lose
+    everything up to the checkout's root first, so two checkouts of one
+    commit still share entries. The price: an edit that moves a source
+    line under a jitted function compiles it once more at the next
+    start; a relaunch of unchanged code still hits.
     """
     global _decided_dir
     if _decided_dir is not None:
@@ -79,6 +95,10 @@ def ensure_persistent_cache(explicit_dir: Optional[str] = None) -> str:
     if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(CHECKOUT_ROOT + os.sep))
     _decided_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not _decided_dir:
         _decided_dir = explicit_dir or CHECKOUT_CACHE_DIR

@@ -56,7 +56,7 @@ from parallax_tpu.common.lib import parallax_log
 from parallax_tpu.compile import bucketing, warmup as warmup_lib
 from parallax_tpu.core import classify, mesh as mesh_lib, specs as specs_lib
 from parallax_tpu.obs import _state as obs_state, \
-    metrics as obs_metrics, numwatch, trace
+    metrics as obs_metrics, numwatch, trace, xprof
 from parallax_tpu.ops import embedding
 
 
@@ -351,6 +351,10 @@ class Engine:
         # (warmup()); step() dispatches to these before falling back to
         # the jit cache
         self._executables: Dict[Tuple, Any] = {}
+        # the one that ran the last step, and its layer index once
+        # somebody asked (layer_index())
+        self._last_executable = None
+        self._layer_index: Optional[Tuple[Any, Dict[str, Any]]] = None
         self._exec_hits = self.metrics.counter(
             "engine.executable_cache.hits")
         self._exec_misses = self.metrics.counter(
@@ -675,19 +679,22 @@ class Engine:
                 pending = jax.tree.map(
                     lambda b, g: jax.lax.dynamic_update_index_in_dim(
                         b, g, slot, axis=0), state.pending_grads, grads)
-            updates, opt_state = tx.update(
-                apply_grads, state.opt_state, state.params)
-            if slice_resolved:
-                # don't route slice tables through apply_updates: their
-                # masked update is zero, but table + 0 still costs a
-                # full [V, D] buffer write per step
-                params = jax.tree_util.tree_map_with_path(
-                    lambda kp, p, u: (
-                        p if classify._pathname(kp) in slice_resolved
-                        else optax.apply_updates(p, u)),
-                    state.params, updates)
-            else:
-                params = optax.apply_updates(state.params, updates)
+            # the two update layers carry their names into the compiled
+            # step (obs/xprof.LAYER_SCOPES; layer_index() reads them)
+            with jax.named_scope("dense_update"):
+                updates, opt_state = tx.update(
+                    apply_grads, state.opt_state, state.params)
+                if slice_resolved:
+                    # don't route slice tables through apply_updates:
+                    # their masked update is zero, but table + 0 still
+                    # costs a full [V, D] buffer write per step
+                    params = jax.tree_util.tree_map_with_path(
+                        lambda kp, p, u: (
+                            p if classify._pathname(kp) in slice_resolved
+                            else optax.apply_updates(p, u)),
+                        state.params, updates)
+                else:
+                    params = optax.apply_updates(state.params, updates)
             slice_state = state.slice_state
             if slice_resolved:
                 # scatter-only table updates from the captured slices
@@ -698,20 +705,22 @@ class Engine:
                                                    ids_list, gdeltas):
                     per_path.setdefault(path, []).append((ids, dd))
                 slice_state = dict(slice_state)
-                for path, items in per_path.items():
-                    upd = slice_resolved[path]
-                    ids_cat = jnp.concatenate(
-                        [i.reshape(-1) for i, _ in items])
-                    drows_cat = jnp.concatenate(
-                        [d.reshape(-1, d.shape[-1]) for _, d in items])
-                    table = _get_path(params, path)
-                    new_table, new_acc = upd.update(
-                        table, slice_state[path], ids_cat, drows_cat,
-                        average=avg)
-                    params = _set_path(params, path, new_table)
-                    slice_state[path] = _constrain_like_table(
-                        new_acc, table,
-                        _get_path(param_shardings, path))
+                with jax.named_scope("table_update"):
+                    for path, items in per_path.items():
+                        upd = slice_resolved[path]
+                        ids_cat = jnp.concatenate(
+                            [i.reshape(-1) for i, _ in items])
+                        drows_cat = jnp.concatenate(
+                            [d.reshape(-1, d.shape[-1])
+                             for _, d in items])
+                        table = _get_path(params, path)
+                        new_table, new_acc = upd.update(
+                            table, slice_state[path], ids_cat,
+                            drows_cat, average=avg)
+                        params = _set_path(params, path, new_table)
+                        slice_state[path] = _constrain_like_table(
+                            new_acc, table,
+                            _get_path(param_shardings, path))
             params = jax.lax.with_sharding_constraint(params,
                                                       param_shardings)
             new_state = state.replace(step=state.step + 1, params=params,
@@ -770,6 +779,11 @@ class Engine:
     # -- public ops --------------------------------------------------------
 
     def init_state(self, seed: int = 0) -> TrainState:
+        seed = int(seed)
+        if not -2**31 <= seed < 2**31:
+            # the jitted initialiser takes 32 signed bits and raises
+            # OverflowError past them; seeds inside them are unchanged
+            seed %= 2**31
         with trace.span("engine.init_state"), self.mesh:
             return self._init_jit(seed)
 
@@ -795,6 +809,7 @@ class Engine:
                 try:
                     new_state, outputs = exe(state, batch)
                     self._exec_hits.inc()
+                    self._last_executable = exe
                 except (TypeError, ValueError) as e:
                     # input rejection (shape/dtype/pytree/sharding
                     # drift, e.g. a shape-changing feed_transform) —
@@ -913,10 +928,42 @@ class Engine:
                 len(self._traced_signatures) - 1,
                 [(n, s) for n, s, _ in sig])
 
+    def layer_index(self) -> Optional[Dict[str, Any]]:
+        """Which layer each instruction of the compiled step belongs
+        to: ``{"module": the program's name as a device trace prints
+        it, "layers": {instruction name: one of xprof.LAYER_SCOPES or
+        None}, "scopes_found": the layers that own an instruction,
+        "hlo_index": xprof.build_hlo_index of the text (what
+        ``xprof.attribute`` joins on)}``, read off the AOT executable
+        that ran the last step (before any step: the first one
+        ``warmup()`` compiled). None where there is no AOT executable:
+        nothing is lowered or compiled for a read. Built on the first
+        call for an executable and kept; never on the step path, and it
+        still answers after ``close()``."""
+        exe = self._last_executable
+        if exe is None and self._executables:
+            exe = next(iter(self._executables.values()))
+        if exe is None:
+            return None
+        if self._layer_index is None or self._layer_index[0] is not exe:
+            text = exe.as_text()
+            hlo_index = xprof.build_hlo_index(text)
+            layers = {name: xprof.layer_of(meta)
+                      for name, meta in hlo_index.items()}
+            found = set(layers.values())
+            self._layer_index = (exe, {
+                "module": xprof.module_name(text),
+                "layers": layers,
+                "scopes_found": [s for s in xprof.LAYER_SCOPES
+                                 if s in found],
+                "hlo_index": hlo_index})
+        return self._layer_index[1]
+
     def close(self):
         """Restore process-global settings this engine changed
         (jax_debug_nans is process-wide; don't leak it into later
-        sessions)."""
+        sessions). Drops nothing: the executables, and with them
+        ``layer_index()``, outlive it."""
         if self._debug_nans_was is not None:
             jax.config.update("jax_debug_nans", self._debug_nans_was)
             self._debug_nans_was = None

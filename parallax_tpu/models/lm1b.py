@@ -161,8 +161,11 @@ def build_model(cfg: LM1BConfig, full_softmax: bool = False) -> Model:
 
         c0 = jnp.zeros((B, H), cfg.compute_dtype)
         h0 = jnp.zeros((B, P), cfg.compute_dtype)
-        (_, _), hs = jax.lax.scan(cell, (c0, h0), x_seq,
-                                  unroll=max(1, cfg.lstm_scan_unroll))
+        # the same layer name the pallas path carries
+        # (obs/xprof.LAYER_SCOPES)
+        with jax.named_scope("lstm"):
+            (_, _), hs = jax.lax.scan(cell, (c0, h0), x_seq,
+                                      unroll=max(1, cfg.lstm_scan_unroll))
         return hs
 
     def loss_fn(params, batch, rng):

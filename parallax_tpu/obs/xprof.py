@@ -17,12 +17,14 @@ closes that gap. It has two halves:
   where no tracked device op ran — is always reported, never hidden:
   ``coverage`` is the fraction the per-op account explains.
 * **HLO metadata joins**: ``build_hlo_index`` parses a compiled
-  module's HLO text (``metadata={op_name=... source_file=...}``) so
-  trace op names (``fusion.3``, ``dot.1``) map back to model-source
-  layers, and the dense-vs-sparse variable split — the paper's core
-  axis — falls out of the source file that emitted the op
-  (``ops/embedding.py`` / ``ops/sparse_optim.py`` /
-  ``ops/sampled_softmax.py`` are the sparse path).
+  module's HLO text (``metadata={op_name="jit(train_step)/
+  transpose(jvp(sampled_softmax))/dot_general" ...}``) so trace op
+  names (``fusion.3``, ``dot.1``) map back to the layer whose
+  ``jax.named_scope`` emitted them (``LAYER_SCOPES``; the device trace
+  itself carries no ``op_name``, the compiled text does), and the
+  dense-vs-sparse variable split — the paper's core axis — falls out
+  of the layer (``SPARSE_LAYERS``). The index of the step that runs is
+  the engine's to hand over (``Engine.layer_index()``).
 
 The capture side is owned by ``profiler.ProfileHook`` (windowed
 on-demand capture, ``session.profile_steps(n)``); the session exports
@@ -57,10 +59,18 @@ _COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
                      "all-to-all", "collective-permute",
                      "collective-broadcast")
 
-# source files whose ops are the sparse (row-sharded table) path — the
-# paper's dense-vs-sparse variable split, measured per op
-SPARSE_SOURCES = ("embedding.py", "sparse_optim.py",
-                  "sampled_softmax.py")
+# The layers of the train step, declared once: the names of the
+# ``jax.named_scope``s around ops/embedding.embedding_lookup,
+# ops/pallas_lstm.lstm_scan (and models/lm1b's lax.scan branch),
+# ops/sampled_softmax.sampled_softmax_loss, and core/engine.train_step's
+# dense (clip + optimizer + apply) and table (ops/sparse_optim row
+# scatter) updates. ``layer_of`` reads them back off a compiled
+# instruction's ``op_name``.
+LAYER_SCOPES = ("embedding", "lstm", "sampled_softmax", "dense_update",
+                "table_update")
+# the row-sharded table path — the paper's sparse side of the
+# dense-vs-sparse variable split
+SPARSE_LAYERS = ("embedding", "sampled_softmax", "table_update")
 
 
 def categorize(name: str) -> Tuple[str, Optional[str]]:
@@ -371,12 +381,19 @@ def attribute(trace: Dict, steps: Optional[int] = None,
 
 # -- HLO metadata joins ------------------------------------------------------
 
-# "%name = type opcode(...) ..., metadata={...}"; names may carry
-# dots, dashes and digits. The computation header lines ("%fused_
-# computation (param: ...)") don't match — they have no " = ".
+# "[ROOT] %name = type opcode(...) ..., metadata={...}". The type may
+# be a tuple with spaces in it (a Pallas kernel's custom call returns
+# one), so the opcode is the first lower-case word that opens a
+# parenthesis after the " = " (shapes and layouts open theirs after
+# ``T``, ``S`` or a bracket). The computation header lines
+# ("%fused_computation (param: ...)") don't match — they have no
+# " = ".
 _HLO_INSTR_RE = re.compile(
-    r"%?([\w.\-]+)\s*=\s*\S+\s+([\w\-]+)\(")
-_HLO_META_RE = re.compile(r"metadata=\{([^}]*)\}")
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s+(.*)$")
+_HLO_OPCODE_RE = re.compile(r"(?:^|[\s)}\]])([a-z][a-z0-9\-]*)\(")
+# not the ``kernel_metadata={}`` inside a custom call's
+# ``frontend_attributes``
+_HLO_META_RE = re.compile(r"(?<![\w])metadata=\{([^}]*)\}")
 _META_FIELD_RE = re.compile(r'(\w+)=(?:"([^"]*)"|(\S+))')
 
 
@@ -384,42 +401,46 @@ def build_hlo_index(hlo_text: str) -> Dict[str, Dict[str, Any]]:
     """{instruction name: {opcode, op_name, source_file,
     source_line}} from optimized-HLO text (``compiled.as_text()``).
     Trace op events are named by these instructions, so this is the
-    join key back to model source. Pure string parsing; instructions
-    without metadata still index (opcode only)."""
+    join key back to the layers' scopes. Pure string parsing;
+    instructions without metadata still index (opcode only)."""
     out: Dict[str, Dict[str, Any]] = {}
     for line in hlo_text.splitlines():
-        m = _HLO_INSTR_RE.search(line)
+        m = _HLO_INSTR_RE.match(line)
         if not m:
             continue
-        name, opcode = m.group(1), m.group(2)
-        entry: Dict[str, Any] = {"opcode": opcode}
+        op = _HLO_OPCODE_RE.search(m.group(2))
+        if not op:
+            continue
+        entry: Dict[str, Any] = {"opcode": op.group(1)}
         meta = _HLO_META_RE.search(line)
         if meta:
             for fm in _META_FIELD_RE.finditer(meta.group(1)):
                 key = fm.group(1)
                 if key in ("op_name", "source_file", "source_line"):
                     entry[key] = fm.group(2) or fm.group(3)
-        out[name] = entry
+        out[m.group(1)] = entry
     return out
 
 
+# one part of an op_name's scope path, AD's wrappers around it
+_SCOPE_PART_RE = re.compile(r"^(?:(?:jvp|transpose)\()*([^()]*)\)*$")
+
+
 def layer_of(meta: Optional[Dict[str, Any]]) -> Optional[str]:
-    """A readable model-layer label from one index entry: the
-    ``op_name`` scope path with ``jit(...)`` wrappers stripped and the
-    trailing primitive dropped (``jit(step)/jit(main)/lstm_0/dot`` ->
-    ``lstm_0``); falls back to the source file basename."""
+    """The layer one index entry belongs to: the INNERMOST part of its
+    ``op_name`` scope path that is one of ``LAYER_SCOPES``, with AD's
+    ``jvp(...)`` / ``transpose(...)`` wrappers taken off each part
+    (``jit(train_step)/transpose(jvp(sampled_softmax))/jvp(embedding)/
+    gather`` -> ``embedding``: the sampled softmax fetches its
+    candidate rows through the embedding lookup). None when no part is
+    a declared layer."""
     if not meta:
         return None
-    op_name = meta.get("op_name") or ""
-    parts = [p for p in op_name.split("/")
-             if p and not p.startswith("jit(")
-             and not p.startswith("transpose(")]
-    if len(parts) > 1:
-        return "/".join(parts[:-1])
-    src = meta.get("source_file")
-    if src:
-        return os.path.basename(src)
-    return parts[0] if parts else None
+    for part in reversed((meta.get("op_name") or "").split("/")):
+        m = _SCOPE_PART_RE.match(part)
+        if m and m.group(1) in LAYER_SCOPES:
+            return m.group(1)
+    return None
 
 
 def direction_of(meta: Optional[Dict[str, Any]]) -> Optional[str]:
@@ -439,42 +460,24 @@ def direction_of(meta: Optional[Dict[str, Any]]) -> Optional[str]:
     return "forward"
 
 
-def sparse_split(meta: Optional[Dict[str, Any]],
-                 sparse_sources: Sequence[str] = SPARSE_SOURCES
-                 ) -> Optional[str]:
-    """``"sparse"`` when the op's source file is on the row-sharded
-    table path (ops/embedding.py & co.), ``"dense"`` for any other
-    known source, None when the metadata carries no source at all."""
-    if not meta:
+def sparse_split(meta: Optional[Dict[str, Any]]) -> Optional[str]:
+    """``"sparse"`` when the op's layer is on the row-sharded table
+    path (``SPARSE_LAYERS``), ``"dense"`` for any other declared
+    layer, None for an op under no layer's scope."""
+    layer = layer_of(meta)
+    if layer is None:
         return None
-    src = meta.get("source_file")
-    if not src:
-        return None
-    base = os.path.basename(src)
-    return "sparse" if base in tuple(sparse_sources) else "dense"
+    return "sparse" if layer in SPARSE_LAYERS else "dense"
 
 
-def engine_hlo_index(engine) -> Optional[Dict[str, Dict[str, Any]]]:
-    """The compiled step's HLO index off a live engine: prefers an
-    AOT executable (warmup/preflight), falls back to a host-side
-    lower+compile; None when no text is reachable (layer mapping then
-    reports ``(unmapped)`` — visible, not wrong)."""
-    try:
-        if getattr(engine, "_executables", None):
-            compiled = next(iter(engine._executables.values()))
-            return build_hlo_index(compiled.as_text())
-    except Exception:
-        pass
-    try:
-        import jax
-        import jax.numpy as jnp
-        state_shapes = jax.eval_shape(
-            engine._init_jit, jax.ShapeDtypeStruct((), jnp.int32))
-        lowered = engine._step_jit.lower(state_shapes,
-                                         engine._batch_shapes)
-        return build_hlo_index(lowered.compile().as_text())
-    except Exception:
-        return None
+_HLO_MODULE_RE = re.compile(r"^HloModule\s+([^\s,]+)", re.M)
+
+
+def module_name(hlo_text: str) -> Optional[str]:
+    """The compiled module's name (``jit_train_step``), as the device
+    trace's line of program runs prints it."""
+    m = _HLO_MODULE_RE.search(hlo_text)
+    return m.group(1) if m else None
 
 
 # -- trace loading -----------------------------------------------------------
@@ -507,8 +510,8 @@ def load_trace(path_or_dir: str) -> Tuple[Dict, str]:
 
 
 __all__ = [
-    "Attribution", "CATEGORIES", "SPARSE_SOURCES", "attribute",
-    "build_hlo_index", "categorize", "device_op_events",
-    "direction_of", "engine_hlo_index", "find_trace_file", "layer_of",
-    "load_trace", "merge_intervals", "sparse_split",
+    "Attribution", "CATEGORIES", "LAYER_SCOPES", "SPARSE_LAYERS",
+    "attribute", "build_hlo_index", "categorize", "device_op_events",
+    "direction_of", "find_trace_file", "layer_of", "load_trace",
+    "merge_intervals", "module_name", "sparse_split",
 ]

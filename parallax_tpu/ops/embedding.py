@@ -208,6 +208,13 @@ def embedding_lookup(table: jax.Array, ids: jax.Array,
     sharded) this is a plain gather — the replicated/dense path, equivalent
     to the reference's MPI mode where every replica holds the full variable.
     """
+    # the layer's name on every op it emits, forward and backward
+    # (obs/xprof.LAYER_SCOPES)
+    with jax.named_scope("embedding"):
+        return _lookup(table, ids, sharded)
+
+
+def _lookup(table, ids, sharded):
     ctx = _CTX.get()
     # slices mode: this table's gradient flows through the injected
     # delta, not through AD on the table (see SliceCapture)

@@ -308,6 +308,7 @@ def _forward(x_seq, w, b, w_proj, batch_tile: int, interpret: bool,
             scratch_shapes=scratch,
             compiler_params=params,
             interpret=interpret,
+            name="lstm_fwd",
         )(xw, w_h, w_proj)
     return pl.pallas_call(
         _lstm_kernel_res,
@@ -329,6 +330,7 @@ def _forward(x_seq, w, b, w_proj, batch_tile: int, interpret: bool,
         scratch_shapes=scratch,
         compiler_params=params,
         interpret=interpret,
+        name="lstm_fwd_res",
     )(xw, w_h, w_proj)
 
 
@@ -517,6 +519,7 @@ def _bwd_kernel_path(x_seq, w, b, w_proj, gates, cseq, hs, g,
         ],
         compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
+        name="lstm_bwd",
     )(g, gates, cseq, cseq, w_h, w_proj)
     return _bwd_epilogue(x_seq, w, b, w_proj, gates, cseq, hs, dxw,
                          dhtot)
@@ -788,6 +791,15 @@ def lstm_scan(x_seq, w, b, w_proj, *, impl: str = "xla",
     + ``batch_axes`` (the mesh axes B is sharded over) and the kernel
     runs per-device under shard_map (weights replicated in, gradients
     psum'd by the transpose), keeping the batch sharding intact."""
+    # the layer's name on the hoisted product, both kernels and the
+    # backward's weight products (obs/xprof.LAYER_SCOPES)
+    with jax.named_scope("lstm"):
+        return _scan(x_seq, w, b, w_proj, impl, batch_tile, bwd_impl,
+                     interpret, mesh, batch_axes)
+
+
+def _scan(x_seq, w, b, w_proj, impl, batch_tile, bwd_impl, interpret,
+          mesh, batch_axes):
     if impl not in ("xla", "pallas"):
         raise ValueError(f"unknown lstm impl {impl!r}")
     if impl == "xla":

@@ -76,6 +76,17 @@ def sampled_softmax_loss(
     runs with ``matmul_dtype`` inputs and float32 accumulation (softmax
     corrections, logsumexp and the loss stay float32 throughout).
     """
+    # the layer's name on every op it emits, forward and backward
+    # (obs/xprof.LAYER_SCOPES); the candidate rows' lookups name
+    # themselves "embedding" inside it
+    with jax.named_scope("sampled_softmax"):
+        return _loss(softmax_w, softmax_b, hidden, labels, rng,
+                     num_samples, vocab_size, remove_accidental_hits,
+                     matmul_dtype)
+
+
+def _loss(softmax_w, softmax_b, hidden, labels, rng, num_samples,
+          vocab_size, remove_accidental_hits, matmul_dtype):
     n = hidden.shape[0]
     samples = log_uniform_candidates(rng, num_samples, vocab_size)
 

@@ -1001,6 +1001,14 @@ class ParallaxSession:
     def engine(self):
         return self._engine
 
+    def layer_index(self) -> Optional[Dict[str, Any]]:
+        """``Engine.layer_index()`` of the live engine: which layer
+        (obs/xprof.LAYER_SCOPES) each instruction of the compiled step
+        belongs to. None before the engine exists or without an AOT
+        executable (``warmup()``); still answers after ``close()``."""
+        return (self._engine.layer_index()
+                if self._engine is not None else None)
+
     @property
     def plan(self) -> Optional[Plan]:
         """The full configuration the live engine was built for (mesh
@@ -1077,10 +1085,10 @@ class ParallaxSession:
         path, steps = pending
         try:
             trace_doc, tpath = xprof.load_trace(path)
-            idx = (xprof.engine_hlo_index(self._engine)
-                   if self._engine is not None else None)
-            attrib = xprof.attribute(trace_doc, steps=steps,
-                                     hlo_index=idx, source=tpath)
+            layer_idx = self.layer_index()
+            attrib = xprof.attribute(
+                trace_doc, steps=steps, source=tpath,
+                hlo_index=layer_idx and layer_idx["hlo_index"])
             self._profile_attrib = attrib.as_dict()
             self._emit_profile_lanes(attrib)
             parallax_log.info(

@@ -1,0 +1,292 @@
+"""The layers' names inside the compiled step: the five
+``jax.named_scope``s of ``obs/xprof.LAYER_SCOPES`` as
+``Engine.layer_index()`` reads them back off the executable, the one
+rule for reading a scope (``xprof.layer_of`` / ``sparse_split``), and
+the compile cache's key, which must hold the names
+(``compile/cache.ensure_persistent_cache``) or a cached executable
+answers with the previous source's."""
+
+import json
+import os
+import re
+import subprocess
+import sys
+import textwrap
+
+import numpy as np
+import pytest
+
+import parallax_tpu as parallax
+from parallax_tpu.models import lm1b
+from parallax_tpu.obs import xprof
+
+NDEV = 8
+
+
+def _session(**cfg_kw):
+    cfg = lm1b.tiny_config(num_partitions=NDEV,
+                           sparse_grad_mode="slices", **cfg_kw)
+    sess, *_ = parallax.parallel_run(
+        lm1b.build_model(cfg),
+        parallax_config=parallax.Config(
+            run_option="HYBRID", search_partitions=False,
+            sparse_grad_mode="slices", shape_buckets="auto"))
+    batch = lm1b.make_batch(np.random.default_rng(0), 2 * NDEV, 6,
+                            cfg.vocab_size)
+    return cfg, sess, batch
+
+
+@pytest.fixture(scope="module")
+def warmed():
+    """Tiny LM1B through ``parallel_run`` on the eight virtual devices,
+    slices on, warmed up, one step run, CLOSED: ``(cfg, session, the
+    index it gave before close, the executable's text)``."""
+    cfg, sess, batch = _session(lstm_impl="pallas", keep_prob=0.9)
+    sess.warmup(feed_dict=batch)
+    sess.run("loss", feed_dict=batch)
+    index = sess.layer_index()
+    text = next(iter(sess.engine._executables.values())).as_text()
+    sess.close()
+    return cfg, sess, index, text
+
+
+def _ops_of(index, layer_part):
+    return {name: meta for name, meta in index["hlo_index"].items()
+            if layer_part in (meta.get("op_name") or "")}
+
+
+def test_every_declared_scope_is_found(warmed):
+    _, _, index, _ = warmed
+    assert index["scopes_found"] == list(xprof.LAYER_SCOPES)
+    assert index["module"] == "jit_train_step"
+    assert set(index["layers"]) == set(index["hlo_index"])
+    assert set(index["layers"].values()) <= set(xprof.LAYER_SCOPES) | {None}
+
+
+def test_table_scatter_maps_to_table_update(warmed):
+    cfg, _, index, text = warmed
+    rows = cfg.padded_vocab // NDEV
+    table = re.compile(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = "
+        + re.escape(f"f32[{rows},{cfg.emb_dim}]") + r"\S* scatter\(",
+        re.M)
+    scatters = table.findall(text)
+    assert scatters, "no scatter into a table's shard in the step"
+    for name in scatters:
+        assert index["hlo_index"][name]["opcode"] == "scatter"
+        assert index["layers"][name] == "table_update", name
+    # and no scatter of the step is anybody else's but the lookups'
+    # gradient rows
+    others = {index["layers"][n] for n, m in index["hlo_index"].items()
+              if m["opcode"] == "scatter"} - {"table_update"}
+    assert others <= {"embedding"}, others
+
+
+def test_backward_rule_inherits_its_layer(warmed):
+    _, _, index, _ = warmed
+    bwd = {n: m for n, m in
+           _ops_of(index, "transpose(jvp(sampled_softmax))/").items()
+           if "embedding" not in m["op_name"]}
+    assert bwd
+    assert {index["layers"][n] for n in bwd} == {"sampled_softmax"}
+    for n, m in _ops_of(index, "transpose(jvp(lstm))/").items():
+        assert index["layers"][n] == "lstm", m
+
+
+def test_candidate_rows_are_embeddings_innermost_wins(warmed):
+    _, _, index, _ = warmed
+    inner = {n: m for n, m in _ops_of(index, "sampled_softmax").items()
+             if re.search(r"sampled_softmax\)*/(.*/)?embedding/",
+                          m["op_name"])}
+    assert any(m["opcode"] == "gather" for m in inner.values()), \
+        sorted(m["op_name"] for m in inner.values())[:5]
+    assert {index["layers"][n] for n in inner} == {"embedding"}
+
+
+def test_exchange_under_shard_map_is_scoped(warmed):
+    """Every collective of the step but the loss's mean sits under a
+    layer: the lookups' exchange is ``embedding``'s."""
+    _, _, index, _ = warmed
+    collectives = {n: m for n, m in index["hlo_index"].items()
+                   if m["opcode"] in ("all-gather", "reduce-scatter",
+                                      "all-to-all", "collective-permute")}
+    assert collectives
+    bare = {n: m.get("op_name") for n, m in collectives.items()
+            if index["layers"][n] is None}
+    assert not bare, bare
+    assert any(index["layers"][n] == "embedding"
+               and "shard_map" in m["op_name"]
+               for n, m in collectives.items())
+
+
+def test_index_outlives_close_and_is_built_once(warmed):
+    _, sess, index, _ = warmed
+    assert sess.layer_index() is index
+    assert sess.engine.layer_index() is index
+
+
+def test_no_aot_executable_no_index_and_no_compile():
+    """Nothing is lowered or compiled for a read: without ``warmup()``
+    there is no AOT executable and the answer is None."""
+    _, sess, batch = _session()
+    try:
+        assert sess.layer_index() is None       # no engine yet
+        sess.run("loss", feed_dict=batch)
+        before = sess.metrics_snapshot().get("engine.recompiles", 0)
+        assert sess.layer_index() is None
+        assert sess.metrics_snapshot().get("engine.recompiles", 0) \
+            == before
+        assert not sess.engine._executables
+    finally:
+        sess.close()
+
+
+def test_seed_past_32_signed_bits_builds_a_state():
+    """Found on the chip (PR 25): the benchmark's driver passes seeds a
+    little over 2**31, and the jitted initialiser overflowed on them."""
+    cfg = lm1b.tiny_config(num_partitions=NDEV, sparse_grad_mode="slices")
+    batch = lm1b.make_batch(np.random.default_rng(0), 2 * NDEV, 6,
+                            cfg.vocab_size)
+    losses = []
+    for seed in (2**31 + 7, 7, 8):
+        sess, *_ = parallax.parallel_run(
+            lm1b.build_model(cfg), seed=seed,
+            parallax_config=parallax.Config(
+                run_option="HYBRID", search_partitions=False,
+                sparse_grad_mode="slices"))
+        losses.append(float(sess.run("loss", feed_dict=batch)))
+        sess.close()
+    assert np.isfinite(losses).all()
+    assert losses[0] == losses[1] != losses[2]    # folded, not dropped
+
+
+def test_lax_scan_branch_carries_the_lstm_scope():
+    """``lstm_impl="xla"`` takes models/lm1b's ``lax.scan``: the same
+    layer name as the kernels' path."""
+    _, sess, batch = _session(lstm_impl="xla")
+    try:
+        sess.warmup(feed_dict=batch)
+        index = sess.layer_index()
+        assert "lstm" in index["scopes_found"]
+        whiles = [n for n, m in index["hlo_index"].items()
+                  if m["opcode"] == "while"
+                  and index["layers"][n] == "lstm"]
+        assert whiles
+    finally:
+        sess.close()
+
+
+@pytest.mark.parametrize("op_name,layer,split", [
+    ("jit(train_step)/jvp(lstm)/dot_general", "lstm", "dense"),
+    ("jit(train_step)/transpose(jvp(lstm))/lstm_bwd/pallas_call",
+     "lstm", "dense"),
+    ("jit(train_step)/transpose(jvp(sampled_softmax))/dot_general",
+     "sampled_softmax", "sparse"),
+    # innermost wins: the candidate rows go through embedding_lookup
+    ("jit(train_step)/jvp(sampled_softmax)/embedding/shard_map/all_gather",
+     "embedding", "sparse"),
+    ("jit(train_step)/transpose(jvp(sampled_softmax))/jvp(embedding)/"
+     "shard_map/jit(_take)/scatter-add", "embedding", "sparse"),
+    ("jit(train_step)/table_update/jit(_unique_sorted_mask)/sort",
+     "table_update", "sparse"),
+    ("jit(train_step)/dense_update/mul", "dense_update", "dense"),
+    # a primitive or a user's scope that merely contains a layer's name
+    ("jit(train_step)/jvp(my_lstm_block)/dot_general", None, None),
+    ("jit(train_step)/model/embedding_norm/mul", None, None),
+    ("jit(train_step)/jvp()/reduce_sum", None, None),
+    ("jit(train_step)/jvp()/shard_map/psum", None, None),
+    ("", None, None),
+])
+def test_layer_of_and_sparse_split_read_one_rule(op_name, layer, split):
+    meta = {"opcode": "fusion", "op_name": op_name,
+            # a file name decides nothing any more
+            "source_file": "/x/parallax_tpu/ops/embedding.py"}
+    assert xprof.layer_of(meta) == layer
+    assert xprof.sparse_split(meta) == split
+
+
+def test_no_metadata_is_no_layer():
+    assert xprof.layer_of(None) is None
+    assert xprof.layer_of({"opcode": "copy"}) is None
+    assert xprof.sparse_split({"opcode": "copy"}) is None
+    assert set(xprof.SPARSE_LAYERS) < set(xprof.LAYER_SCOPES)
+
+
+def test_tuple_typed_kernel_call_indexes():
+    """A Pallas kernel's custom call returns a tuple, whose type has
+    spaces in it, and carries ``kernel_metadata={}`` before its
+    ``metadata``: both used to lose the instruction."""
+    line = ('  %lstm_bwd.1 = (bf16[20,128,8192]{2,1,0:T(8,128)(2,1)S(1)}, '
+            'f32[20,128,512]{2,1,0:T(8,128)S(1)}) custom-call(%a, %b), '
+            'custom_call_target="tpu_custom_call", '
+            'frontend_attributes={kernel_metadata={}}, '
+            'metadata={op_name="jit(f)/transpose(jvp(lstm))/lstm_bwd/'
+            'pallas_call" stack_frame_id=4}')
+    idx = xprof.build_hlo_index(line)
+    assert idx["lstm_bwd.1"]["opcode"] == "custom-call"
+    assert xprof.layer_of(idx["lstm_bwd.1"]) == "lstm"
+
+
+# -- the compile cache's key holds the names ------------------------------
+
+_SCOPED_PROGRAM = textwrap.dedent("""
+    import json, os, sys
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import jax, jax.numpy as jnp
+    jax.config.update("jax_platforms", "cpu")
+    from parallax_tpu.compile.cache import ensure_persistent_cache
+    cache_dir = ensure_persistent_cache()
+
+    def f(x):
+        with jax.named_scope(sys.argv[1]):
+            return jnp.sin(x) @ x
+
+    text = jax.jit(f).lower(
+        jax.ShapeDtypeStruct((8, 8), jnp.float32)).compile().as_text()
+    print(json.dumps({
+        "scopes": [s for s in ("layerA", "layerB") if s in text],
+        "entries": len(os.listdir(cache_dir))}))
+    """)
+
+
+def _checkout(tmp_path, name):
+    """A directory that looks like a checkout of this commit: the
+    package beside a program."""
+    root = tmp_path / name
+    root.mkdir()
+    os.symlink(os.path.dirname(os.path.abspath(parallax.__file__)),
+               root / "parallax_tpu")
+    (root / "program.py").write_text(_SCOPED_PROGRAM)
+    return root
+
+
+def _run_scoped(root, scope, cache_dir):
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(cache_dir))
+    # cache everything, as a session outside this suite does
+    env.pop("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", None)
+    proc = subprocess.run(
+        [sys.executable, str(root / "program.py"), scope], env=env,
+        cwd=str(root), capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_cached_executable_carries_this_sources_names(tmp_path):
+    """Two processes, one cache directory, one computation under two
+    scope names: the second reads ITS name off the executable (jax's
+    default key strips the names and hands back the first's), a rerun
+    adds no entry, and a second checkout directory of the same source
+    shares the entries."""
+    cache_dir = tmp_path / "cache"
+    cache_dir.mkdir()
+    here = _checkout(tmp_path, "here")
+    first = _run_scoped(here, "layerA", cache_dir)
+    assert first["scopes"] == ["layerA"] and first["entries"] > 0
+    second = _run_scoped(here, "layerB", cache_dir)
+    assert second["scopes"] == ["layerB"]
+    assert second["entries"] > first["entries"]
+    again = _run_scoped(here, "layerB", cache_dir)
+    assert again == second
+    elsewhere = _run_scoped(_checkout(tmp_path, "elsewhere"), "layerB",
+                            cache_dir)
+    assert elsewhere == second

@@ -153,8 +153,8 @@ _HLO_TEXT = """\
 HloModule jit_step
 
 ENTRY %main.10 (Arg_0.1: f32[8]) -> f32[8] {
-  %dot.1 = f32[8]{0} dot(f32[8]{0} %Arg_0.1, f32[8]{0} %Arg_0.1), metadata={op_name="jit(step)/jit(main)/model/lstm_0/dot_general" source_file="/repo/parallax_tpu/models/lm1b.py" source_line=42}
-  %all-gather.1 = f32[8]{0} all-gather(f32[8]{0} %dot.1), metadata={op_name="jit(step)/jit(main)/emb/all_gather" source_file="/repo/parallax_tpu/ops/embedding.py" source_line=100}
+  %dot.1 = f32[8]{0} dot(f32[8]{0} %Arg_0.1, f32[8]{0} %Arg_0.1), metadata={op_name="jit(step)/jit(main)/jvp(lstm)/dot_general" source_file="/repo/parallax_tpu/models/lm1b.py" source_line=42}
+  %all-gather.1 = f32[8]{0} all-gather(f32[8]{0} %dot.1), metadata={op_name="jit(step)/jit(main)/transpose(jvp(sampled_softmax))/embedding/shard_map/all_gather" source_file="/repo/parallax_tpu/ops/embedding.py" source_line=100}
   ROOT %add.2 = f32[8]{0} add(f32[8]{0} %dot.1, f32[8]{0} %all-gather.1)
 }
 """
@@ -171,26 +171,34 @@ class TestHloIndex:
         assert "op_name" not in idx["add.2"]
 
     def test_layer_mapping_strips_jit_wrappers(self):
+        """The layer is the innermost declared scope of the op_name
+        (xprof.LAYER_SCOPES), AD's wrappers taken off; a path with no
+        declared scope is no layer, whatever file emitted it."""
         idx = xprof.build_hlo_index(_HLO_TEXT)
-        assert xprof.layer_of(idx["dot.1"]) == "model/lstm_0"
-        assert xprof.layer_of(idx["all-gather.1"]) == "emb"
+        assert xprof.layer_of(idx["dot.1"]) == "lstm"
+        assert xprof.layer_of(idx["all-gather.1"]) == "embedding"
+        assert xprof.layer_of(idx["add.2"]) is None
         assert xprof.layer_of(None) is None
 
     def test_dense_sparse_split_by_source(self):
+        """The split follows the layer (xprof.SPARSE_LAYERS), not the
+        source file: jax 0.9's compiled text names no file."""
         idx = xprof.build_hlo_index(_HLO_TEXT)
         assert xprof.sparse_split(idx["all-gather.1"]) == "sparse"
         assert xprof.sparse_split(idx["dot.1"]) == "dense"
         assert xprof.sparse_split(idx["add.2"]) is None
+        assert xprof.sparse_split(
+            {"opcode": "dot", "source_file": "x/ops/embedding.py",
+             "op_name": "jit(s)/model/emb/dot"}) is None
 
     def test_attribution_joins_index(self):
         idx = {"dot.1": {"opcode": "dot",
-                         "op_name": "jit(s)/jit(main)/layer_a/dot",
-                         "source_file": "x/models/lm1b.py"}}
+                         "op_name": "jit(s)/jit(main)/lstm/dot"}}
         a = xprof.attribute(_golden(), steps=2, hlo_index=idx)
         ops = {r["op"]: r for r in a.top_ops}
-        assert ops["dot.1"]["layer"] == "layer_a"
+        assert ops["dot.1"]["layer"] == "lstm"
         assert ops["dot.1"]["split"] == "dense"
-        assert a.layers["layer_a"] == pytest.approx(0.080, abs=1e-6)
+        assert a.layers["lstm"] == pytest.approx(0.080, abs=1e-6)
         # unmapped ops stay visible, never silently dropped
         assert a.dense_sparse["dense_self_ms"] == \
             pytest.approx(0.080, abs=1e-6)

@@ -22,14 +22,14 @@ TRACED_STEPS = 8
 
 
 def run_trace(outdir: str):
-    """Returns the compiled step's HLO index (obs/xprof) so the
-    summary can join trace op names back to model scopes — the
-    layer / dense-sparse / fwd-bwd attribution rows."""
+    """Returns the compiled step's HLO index (the session's
+    ``layer_index()``) so the summary can join trace op names back to
+    the layers' scopes — the layer / dense-sparse / fwd-bwd
+    attribution rows."""
     import jax
     import numpy as np
     import parallax_tpu as parallax
     from parallax_tpu.models import lm1b
-    from parallax_tpu.obs import xprof
 
     n_chips = jax.device_count()
     platform = jax.devices()[0].platform
@@ -55,6 +55,8 @@ def run_trace(outdir: str):
     rng = np.random.default_rng(0)
     batches = [lm1b.make_batch(rng, bs, T, cfg.vocab_size)
                for _ in range(4)]
+    # the AOT executable is what layer_index() reads its names off
+    sess.warmup(feed_dict=batches[0], batch_sizes=[bs])
     for i in range(5):
         sess.run("loss", feed_dict=batches[i % 4])
     jax.block_until_ready(sess.state.params)
@@ -69,9 +71,9 @@ def run_trace(outdir: str):
     print(f"# step time (untraced): "
           f"{(time.perf_counter() - t0) / 10 * 1e3:.1f} ms "
           f"({platform}, bs={bs}, T={T}, lstm_impl={lstm_impl})")
-    hlo_index = xprof.engine_hlo_index(sess.engine)
+    layer_index = sess.layer_index()
     sess.close()
-    return hlo_index
+    return layer_index and layer_index["hlo_index"]
 
 
 def summarize(outdir: str, top: int = 25, hlo_index=None) -> None:
@@ -109,10 +111,10 @@ def summarize(outdir: str, top: int = 25, hlo_index=None) -> None:
           + "  ".join(f"{k.replace('_self_ms', '')} "
                       f"{v:.2f} ms ({v / total:.0%})"
                       for k, v in fb.items()))
-    lstm_layers = {k: v for k, v in attrib.layers.items()
-                   if "lstm" in k.lower()}
-    for layer, v in lstm_layers.items():
-        print(f"# lstm layer  {layer:<40} {v:9.2f} ms")
+    # own time by layer (obs/xprof.LAYER_SCOPES; "(unmapped)" is the
+    # glue under no layer's scope)
+    for layer, v in attrib.layers.items():
+        print(f"# layer       {layer:<40} {v:9.2f} ms")
     width = max((len(r["op"]) for r in attrib.top_ops), default=10)
     for r in attrib.top_ops:
         print(f"{r['op'][:90]:<{min(width, 90)}}  "
