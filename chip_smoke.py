@@ -9,7 +9,8 @@ calls, at the full width of the models (weights random from a seed):
    emb 512 / hidden 2048 / proj 512, 8,192 samples, bf16 compute)
    through ``parallax.parallel_run`` -> ``sess.warmup`` ->
    ``sess.run_iter``: hybrid plan, slices sparse grads, the Pallas LSTM
-   forward and backward.
+   forward and backward, and on one chip the in-place row kernel of
+   the two wide tables' update (``ops/sparse_optim``).
 2. **serve** — NMT at its default widths (vocab 32,000, dim 512, 8
    heads, 6 layers, max_len 128) behind ``NMTDecodeProgram`` (paged KV,
    chunked prefill) -> one ``ServeSession`` per device, each on its own
@@ -70,6 +71,7 @@ def _sizes(rehearsal: bool, n: int) -> dict:
                         pool_pages=1024),
                    dict(S=8, D=512, num_heads=8, page_size=16, P=8,
                         pool_pages=64)],
+            table=dict(V=793470, D=512, B=128, T=20, samples=8192),
             ring=(2, 2048, 8, 64))
     return dict(
         lm1b=dict(vocab_size=1000, emb_dim=32, hidden_dim=64,
@@ -82,6 +84,7 @@ def _sizes(rehearsal: bool, n: int) -> dict:
         flash=[(1, 32, 2, 16)], lstm=[(3, 8, 16, 32, 16)],
         paged=[dict(S=2, D=32, num_heads=2, page_size=16, P=2,
                     pool_pages=4)],
+        table=dict(V=1006, D=128, B=4, T=4, samples=32),
         ring=(1, 16 * n, 2, 16))
 
 
@@ -123,7 +126,7 @@ def phase_train(sz: dict, rehearsal: bool) -> dict:
 
     import parallax_tpu as parallax
     from parallax_tpu.models import lm1b
-    from parallax_tpu.ops import pallas_lstm
+    from parallax_tpu.ops import pallas_lstm, sparse_optim
 
     n = jax.device_count()
     B, T = sz["B"], sz["T"]
@@ -167,6 +170,7 @@ def phase_train(sz: dict, rehearsal: bool) -> dict:
         gc.collect()
 
     pallas_lstm.reset_trace_records()
+    sparse_optim.reset_trace_records()
     sess = session("pallas")
     try:
         t0 = time.perf_counter()
@@ -229,6 +233,29 @@ def phase_train(sz: dict, rehearsal: bool) -> dict:
                 f"{out['step_mosaic_calls']} Mosaic custom call(s); "
                 "the LSTM forward and backward kernels are two")
         out["lstm_fwd"] = "interpret" if rehearsal else "kernel"
+
+        # which executor updated each table's rows: the in-place kernel
+        # where a lane-aligned f32 table sits whole on the one chip
+        out["table_update"] = {r["table"]: r["executor"]
+                               for r in sparse_optim.trace_records()}
+        wide = "kernel" if n == 1 and not rehearsal else "xla"
+        want_rows = {"emb": wide, "softmax_w": wide, "softmax_b": "xla"}
+        if out["table_update"] != want_rows:
+            raise AssertionError(
+                f"table update executors {out['table_update']}, want "
+                f"{want_rows}")
+        table = f"f32[{cfg.padded_vocab // n},{cfg.emb_dim}]"
+        out["step_table_copies"] = sum(
+            1 for line in step_text.splitlines()
+            if " copy(" in line and f"= {table}" in line)
+        if wide == "kernel" and (out["step_table_copies"]
+                                 or out["step_mosaic_calls"] < 4):
+            raise AssertionError(
+                f"compiled step copies a {table} table "
+                f"{out['step_table_copies']} time(s) and holds "
+                f"{out['step_mosaic_calls']} Mosaic call(s); the row "
+                "kernels update two tables in place next to the LSTM's "
+                "two")
 
         # same seed, same params, same batches, same dropout and sample
         # draws; only the recurrence's executor differs. The losses
@@ -624,6 +651,83 @@ def _paged_checks(rows, geo, executor):
                executor)
 
 
+def _table_checks(rows, geo, executor):
+    """SliceAdagrad's in-place row kernel against its scatter path on a
+    table and accumulator at a cell's shapes, for the ids of an ``emb``
+    update (a batch's tokens) and of a ``softmax_w`` update (its labels
+    and the sampled candidates), the table's last row among them.
+    Compared on the device: the touched rows in units in the last
+    place of their values, every other row bit for bit with what
+    went in."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from parallax_tpu.models import lm1b
+    from parallax_tpu.ops import sparse_optim as so
+    from parallax_tpu.ops.sampled_softmax import log_uniform_candidates
+
+    V, D = geo["V"], geo["D"]
+    sl = so.SliceAdagrad(0.2)
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    param = jax.random.normal(ks[0], (V, D), jnp.float32)
+    acc = jax.random.uniform(ks[1], (V, D), jnp.float32, 0.1, 2.0)
+    batch = lm1b.make_batch(np.random.default_rng(6), geo["B"], geo["T"],
+                            V)
+    cands = np.asarray(log_uniform_candidates(ks[2], geo["samples"], V))
+    id_lists = {"emb": batch["x"].reshape(-1),
+                "softmax_w": np.concatenate([batch["y"].reshape(-1),
+                                             cands])}
+
+    def bits(x):
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+    def ulps(got, want, was):
+        # in units in the last place of the larger of what the row held
+        # and what it holds now: where the step nearly cancels the
+        # value, the result's own last place would be far finer than
+        # anything that was added
+        size = jnp.maximum(jnp.abs(want), jnp.abs(was))
+        return jnp.abs(got - want) / (jnp.nextafter(size, jnp.inf) - size)
+
+    @jax.jit
+    def combine(ids, drows):
+        return so._combine_slices(ids, drows, V, jnp.float32, False)
+
+    @jax.jit
+    def compare(param, acc, uids, gsum):
+        want = sl._scatter_rows(param, acc, uids, gsum)
+        got = sl._kernel_rows(param, acc, uids, gsum)
+        touched = jnp.zeros((V, 1), bool).at[uids].set(True, mode="drop")
+        off = jnp.maximum(*(
+            jnp.max(jnp.where(touched, ulps(g, w, x), 0.0))
+            for g, w, x in zip(got, want, (param, acc))))
+        changed = sum(jnp.sum((bits(g) != bits(x)) & ~touched)
+                      for g, x in zip(got, (param, acc)))
+        moved = jnp.sum(bits(got[0]) != bits(param))
+        return off, changed, moved, jnp.sum(uids < V)
+
+    for name, ids in id_lists.items():
+        ids = jnp.asarray(np.append(ids[:-1], V - 1), jnp.int32)
+        drows = jax.random.normal(jax.random.PRNGKey(ids.shape[0]),
+                                  (ids.shape[0], D), jnp.float32) * 0.05
+        uids, gsum = combine(ids, drows)
+        if executor == "kernel":
+            calls = _mosaic_calls(sl._kernel_rows, param, acc, uids, gsum)
+            if calls != 1:
+                raise AssertionError(
+                    f"row update of {name} lowered to {calls} Mosaic "
+                    "call(s)")
+        off, changed, moved, live = (int(x) for x in compare(
+            param, acc, uids, gsum))
+        if changed or not moved:
+            raise AssertionError(
+                f"row update of {name}: {changed} element(s) changed "
+                f"outside the {live} touched rows, {moved} inside")
+        _check(rows, f"adagrad_rows_{name}_ulp",
+               (V, D, int(ids.shape[0]), live), off, 2, executor)
+
+
 def _ring_check(rows, shape, executor):
     """One causal ring-attention pass with the flash kernels as block
     core and zig-zag placement, sequence split over every device,
@@ -670,6 +774,7 @@ def phase_kernels(sz: dict, rehearsal: bool) -> dict:
         _lstm_checks(rows, shape, executor)
     for geo in sz["paged"]:
         _paged_checks(rows, geo, executor)
+    _table_checks(rows, sz["table"], executor)
     if jax.device_count() > 1:
         _ring_check(rows, sz["ring"], executor)
     out = {"seconds_with_compiles": round(time.perf_counter() - t0, 1),
@@ -771,6 +876,7 @@ def main(argv=None) -> int:
         "executors": {
             "lstm_fwd": phases["train"].get("lstm_fwd"),
             "lstm_bwd": phases["train"].get("lstm_bwd"),
+            "table_update": phases["train"].get("table_update"),
             "paged_decode": phases["serve"].get("paged_impl"),
             "kernels_phase": sorted({r["executor"]
                                      for r in kernel_rows}),

@@ -57,7 +57,7 @@ from parallax_tpu.compile import bucketing, warmup as warmup_lib
 from parallax_tpu.core import classify, mesh as mesh_lib, specs as specs_lib
 from parallax_tpu.obs import _state as obs_state, \
     metrics as obs_metrics, numwatch, trace, xprof
-from parallax_tpu.ops import embedding
+from parallax_tpu.ops import embedding, sparse_optim
 
 
 class Model:
@@ -714,9 +714,12 @@ class Engine:
                             [d.reshape(-1, d.shape[-1])
                              for _, d in items])
                         table = _get_path(params, path)
-                        new_table, new_acc = upd.update(
-                            table, slice_state[path], ids_cat,
-                            drows_cat, average=avg)
+                        # the updater picks its executor by the table's
+                        # placement, which a traced array does not show
+                        with sparse_optim.table_update_scope(path, mesh):
+                            new_table, new_acc = upd.update(
+                                table, slice_state[path], ids_cat,
+                                drows_cat, average=avg)
                         params = _set_path(params, path, new_table)
                         slice_state[path] = _constrain_like_table(
                             new_acc, table,

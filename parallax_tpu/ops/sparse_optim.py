@@ -5,16 +5,17 @@ The reference applies sparse gradients with scatter-only kernels
 :71-77): only the rows a step touched are read and written, so a 793k-row
 table doesn't pay a full [V, D] optimizer pass per step.
 
-TPU-native equivalent: the gradient w.r.t. a looked-up table arrives as a
-dense scatter-add cotangent, but only ``max_touched_rows`` of its rows can
-be nonzero (bounded by the step's id count — a static quantity). This
-transformation finds those rows with ``top_k`` on row activity and updates
-accumulator and parameters by scatter, which XLA lowers in place on
-donated TPU buffers. Adagrad's untouched-row update is a mathematical
-no-op (accumulator += 0, step -= 0), so the trajectory is bit-for-bit the
-dense one whenever the bound holds.
+Two families live here.
 
-Use per-table via ``optax.multi_transform``::
+``row_sparse_adagrad`` (an optax transformation): the gradient w.r.t. a
+looked-up table arrives as a dense scatter-add cotangent, but only
+``max_touched_rows`` of its rows can be nonzero (bounded by the step's
+id count — a static quantity). It finds those rows with ``top_k`` on row
+activity and updates accumulator and parameters by scatter, which XLA
+lowers in place on donated TPU buffers. Adagrad's untouched-row update
+is a mathematical no-op (accumulator += 0, step -= 0), so the trajectory
+is bit-for-bit the dense one whenever the bound holds. Use per-table
+via ``optax.multi_transform``::
 
     tx = optax.multi_transform(
         {"table": row_sparse_adagrad(0.1, max_touched_rows=4096),
@@ -25,12 +26,40 @@ Use per-table via ``optax.multi_transform``::
 (e.g. batch·seq_len ids + num_samples candidates); if it doesn't, the
 lowest-activity touched rows are silently skipped that step — choose the
 bound from static batch shapes, never guess.
+
+The slice updaters (``SliceAdagrad``, ``SliceAdam``; the engine's
+"slices" mode) never see a dense cotangent: they take a step's
+(ids, row gradients), combine duplicate ids (``_combine_slices``) into
+``uids`` — the distinct ids first, sorted ascending, then the sentinel
+``V`` in every slot left over — and ``gsum``, and update those rows.
+
+**Which executor updates a SliceAdagrad table's rows** is read off the
+table, with no option: the in-place kernel ``adagrad_rows`` where the
+backend is a TPU, the engine's mesh (``table_update_scope``) holds one
+device so the table is whole on it, parameter and accumulator are
+float32 and the row width is a multiple of 128 lanes; the gather and
+two scatters over every slot (``_scatter_rows``) everywhere else: a
+[V, 1] bias table, a bf16 table, the CPU, a table sharded over a mesh,
+a call outside the engine's scope. Both do the same arithmetic in the
+same order. ``trace_records()`` says which one each table got.
+
+**What the kernel relies on:** ``uids[:n_valid]`` is sorted, free of
+duplicates and below ``V - V % 8``, and ``gsum[i]`` belongs to
+``uids[i]``. It walks those ``n_valid`` slots only — the price of a step
+follows its distinct rows, not its slot count — and moves aligned
+groups of 8 rows (the (8, 128) HBM tile; Mosaic refuses less), so the
+up to 7 untouched neighbours of a touched row are rewritten with the
+bits they had, and the at most ``V % 8`` rows of a partial last group
+are left to ``_scatter_rows`` on 8 slots.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
-from typing import NamedTuple
+import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -156,12 +185,45 @@ class SliceAdagrad:
         outside [0, V) are dropped (zero-row parity with the sharded
         lookup's sentinel handling).
         """
-        V = param.shape[0]
+        V, D = param.shape
         uids, gsum = _combine_slices(ids, drows, V, jnp.float32, average,
                                      self.grad_scale)
+        scope = _TABLE_SCOPE.get()
+        executor = _row_executor(param, acc, scope.mesh,
+                                 jax.default_backend())
+        _TRACE_RECORDS[(scope.path, uids.shape[0], D)] = executor
+        rows = self._kernel_rows if executor == "kernel" else \
+            self._scatter_rows
+        return rows(param, acc, uids, gsum)
+
+    def _kernel_rows(self, param, acc, uids, gsum):
+        """The live slots' rows read and written once, in place, by
+        ``adagrad_rows``. It moves whole groups of 8 rows, so it takes
+        the ids below the last multiple of 8 (a prefix: ``uids`` is
+        sorted); the at most V % 8 rows past it go through the scatter
+        on 8 slots cut from the lists where the kernel stopped."""
+        V, D = param.shape
+        v_groups = V - V % _GROUP
+        n_valid = jnp.sum(uids < v_groups, dtype=jnp.int32)
+        param, acc = adagrad_rows(param, acc, uids, n_valid, gsum,
+                                  self.learning_rate, self.eps)
+        if v_groups == V:
+            return param, acc
+        n_tail = min(_GROUP, uids.shape[0])
+        start = jnp.minimum(n_valid, uids.shape[0] - n_tail)
+        uids = jax.lax.dynamic_slice(uids, (start,), (n_tail,))
+        uids = jnp.where(uids >= v_groups, uids, V)
+        gsum = jax.lax.dynamic_slice(gsum, (start, 0), (n_tail, D))
+        return self._scatter_rows(param, acc, uids, gsum)
+
+    def _scatter_rows(self, param, acc, uids, gsum):
+        """Gather, update and scatter every slot of (uids, gsum); the
+        sentinel slots (id V) are read as 0 and dropped."""
         # NOTE: deliberately NO unique_indices/indices_are_sorted hints:
-        # measured on v5e, the hinted scatter lowers ~3x SLOWER than the
-        # plain one for these shapes (bench 291k -> 90k words/sec/chip)
+        # on a v5e the hinted gather and scatters take 11.5-12.1 ms a
+        # table at the LM1B cells' shapes whatever the slot count (a
+        # pass over the whole [V, D] table) against 0.65-4.2 ms plain
+        # (PERF.md section 6, PR 26: the row path alone, not a cell)
         acc_rows = acc.at[uids, :].get(mode="fill", fill_value=0.0)
         acc_rows = acc_rows + gsum * gsum
         inv_rt = jnp.where(acc_rows > 0,
@@ -226,6 +288,204 @@ def _combine_slices(ids, drows, V, dtype, average, grad_scale=1.0):
             cnt > 0, 1.0 / jnp.maximum(cnt, 1.0), 0.0
         )[:, None].astype(gsum.dtype)
     return uids, gsum
+
+
+# ---------------------------------------------------------------------------
+# The in-place row update: SliceAdagrad's executor for a table that is
+# f32, lane-aligned and whole on one TPU (see the module docstring).
+# ---------------------------------------------------------------------------
+
+class _TableScope(NamedTuple):
+    path: Optional[str]
+    mesh: object            # a jax Mesh, or None: placement unknown
+
+
+_TABLE_SCOPE: contextvars.ContextVar = contextvars.ContextVar(
+    "parallax_table_update_scope", default=_TableScope(None, None))
+
+
+@contextlib.contextmanager
+def table_update_scope(path: str, mesh):
+    """Engine-installed around one table's ``update``: the table's
+    parameter path (for the executor record) and the mesh the step is
+    compiled for (a traced table does not show its placement)."""
+    token = _TABLE_SCOPE.set(_TableScope(path, mesh))
+    try:
+        yield
+    finally:
+        _TABLE_SCOPE.reset(token)
+
+
+def _row_executor(param, acc, mesh, backend: str) -> str:
+    """'kernel' for a table the in-place kernel can serve, else 'xla'
+    (the module docstring's rule)."""
+    whole_on_one_tpu = (backend == "tpu" and mesh is not None
+                        and mesh.size == 1)
+    if (whole_on_one_tpu and param.dtype == jnp.float32
+            and acc.dtype == jnp.float32 and param.shape[0] >= _GROUP
+            and param.shape[1] % 128 == 0):
+        return "kernel"
+    return "xla"
+
+
+# Which executor each table's row update got, noted at trace time like
+# ops/pallas_lstm's records: chip_smoke.py and the tests read it. One
+# entry a (table, slots, width), so a process's tables bound it.
+_TRACE_RECORDS: dict = {}
+
+
+def trace_records():
+    """One ``{"table": path, "rows": slots, "dim": D, "executor":
+    "kernel" | "xla"}`` per distinct SliceAdagrad update traced since
+    the last reset, in trace order (``table`` is None outside the
+    engine's scope)."""
+    return [{"table": path, "rows": rows, "dim": dim, "executor": ex}
+            for (path, rows, dim), ex in _TRACE_RECORDS.items()]
+
+
+def reset_trace_records():
+    _TRACE_RECORDS.clear()
+
+
+# HBM tiles f32 as (8, 128): Mosaic refuses a DMA of fewer than 8 rows,
+# so the unit of transfer is the aligned group of 8 rows.
+_GROUP = 8
+# ids a grid step, and so the most groups of 8 rows in VMEM at once, for
+# rows of up to 512 lanes (64 / 128 / 256 timed within 2 % of each other
+# on a v5e; PERF.md, PR 26); wider rows take fewer, so that the three
+# buffers stay at 6 MiB
+_BLOCK_ROWS = 128
+
+
+def _block_rows(dim: int) -> int:
+    return max(_GROUP, _BLOCK_ROWS * 512 // max(dim, 512) // _GROUP * _GROUP)
+
+
+def _adagrad_rows_kernel(uids_ref, nv_ref, g_ref, p_in, a_in, p_out, a_out,
+                         pbuf, abuf, gbuf, group_of_slot, sem, *, lr, eps,
+                         rows):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    del p_in, a_in                      # aliased to p_out / a_out
+    base = pl.program_id(0) * rows
+    nv = nv_ref[0]
+
+    @pl.when(base < nv)
+    def _():
+        gbuf[...] = jnp.zeros_like(gbuf)
+
+        tables = ((p_out, pbuf), (a_out, abuf))
+
+        def rows8(group):
+            return pl.ds(pl.multiple_of(group * _GROUP, _GROUP), _GROUP)
+
+        def reads(slot, group):
+            return [pltpu.make_async_copy(hbm.at[rows8(group)], buf.at[slot],
+                                          sem.at[k])
+                    for k, (hbm, buf) in enumerate(tables)]
+
+        def writes(slot, group):
+            return [pltpu.make_async_copy(buf.at[slot], hbm.at[rows8(group)],
+                                          sem.at[2 + k])
+                    for k, (hbm, buf) in enumerate(tables)]
+
+        # one walk over the block's sorted ids on the scalar core: a new
+        # group of 8 rows takes the next slot and starts its two reads;
+        # the id's gradient row goes to its sublane of that slot
+        def start_in(r, carry):
+            slot, prev = carry
+            i = uids_ref[base + r]
+            group = i // _GROUP
+            new = group != prev
+            slot = slot + new.astype(jnp.int32)
+
+            @pl.when(new)
+            def _():
+                group_of_slot[slot] = group
+                for dma in reads(slot, group):
+                    dma.start()
+            gbuf[slot, pl.ds(i % _GROUP, 1), :] = g_ref[pl.ds(r, 1), :]
+            return slot, group
+        last_slot, _ = jax.lax.fori_loop(
+            0, jnp.minimum(rows, nv - base), start_in,
+            (jnp.int32(-1), jnp.int32(-1)))
+        n_slots = last_slot + 1
+
+        def wait_in(s, c):
+            for dma in reads(s, jnp.int32(0)):
+                dma.wait()
+            return c
+        jax.lax.fori_loop(0, n_slots, wait_in, 0)
+
+        # SliceAdagrad's arithmetic in its order; a group's untouched
+        # rows see g = 0 and keep their bits (acc + 0, param + -0.0)
+        g = gbuf[...]
+        acc = abuf[...] + g * g
+        inv_rt = jnp.where(acc > 0, jax.lax.rsqrt(acc + eps), 0.0)
+        abuf[...] = acc
+        pbuf[...] = pbuf[...] + (inv_rt * g) * jnp.float32(-lr)
+
+        def start_out(s, c):
+            for dma in writes(s, group_of_slot[s]):
+                dma.start()
+            return c
+        jax.lax.fori_loop(0, n_slots, start_out, 0)
+
+        # drained before the next block reads: a group whose ids straddle
+        # two blocks is read back with this block's update in it
+        def wait_out(s, c):
+            for dma in writes(s, jnp.int32(0)):
+                dma.wait()
+            return c
+        jax.lax.fori_loop(0, n_slots, wait_out, 0)
+
+
+def adagrad_rows(param, acc, uids, n_valid, gsum, lr, eps, *,
+                 block_rows=None, interpret=None):
+    """Adagrad on the rows ``uids[:n_valid]`` of (param, acc) f32[V, D],
+    each row read and written once, in place (the outputs alias the
+    inputs). Relies on: ``uids[:n_valid]`` sorted ascending, free of
+    duplicates and below ``V - V % 8``; ``gsum[i]`` is row ``uids[i]``'s
+    combined gradient. Slots at or past ``n_valid`` cost one empty grid
+    step each and no transfer. The rows that share an aligned group of
+    8 with a live row are rewritten with the bits they had."""
+    # imported where a kernel is traced: `import parallax_tpu` stays
+    # free of pallas (0.9 s) for programs that run no kernel
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    D = param.shape[1]
+    rows = block_rows or _block_rows(D)
+
+    def g_map(b, uids_ref, nv_ref):
+        # dead blocks re-use the last live block: nothing is fetched
+        del uids_ref
+        last = jnp.maximum((nv_ref[0] + rows - 1) // rows - 1, 0)
+        return jnp.minimum(b, last), 0
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((rows, _GROUP, D), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_adagrad_rows_kernel, lr=lr, eps=eps, rows=rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(pl.cdiv(uids.shape[0], rows),),
+            in_specs=[pl.BlockSpec((rows, D), g_map), hbm, hbm],
+            out_specs=[hbm, hbm],
+            scratch_shapes=[buf, buf, buf,
+                            pltpu.SMEM((rows,), jnp.int32),
+                            pltpu.SemaphoreType.DMA((4,))]),
+        out_shape=[jax.ShapeDtypeStruct(param.shape, param.dtype),
+                   jax.ShapeDtypeStruct(acc.shape, acc.dtype)],
+        input_output_aliases={3: 0, 4: 1},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=48 * 1024 * 1024),
+        name="adagrad_rows",
+        interpret=interpret,
+    )(uids, jnp.reshape(n_valid, (1,)).astype(jnp.int32), gsum, param, acc)
 
 
 class SliceAdamState(NamedTuple):

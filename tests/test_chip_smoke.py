@@ -98,3 +98,20 @@ def test_default_sizes_are_the_full_widths():
     assert sz["page_size"] >= 16                   # a whole bf16 tile
     assert (20, 128, 512, 2048, 512) in sz["lstm"]  # the flagship cell
     assert sz["requests"] >= 24                    # "a few dozen"
+
+
+def test_rehearsal_covers_the_row_update_kernel():
+    """The kernels phase's newest rows at the rehearsal's sizes: the
+    in-place row update (interpreted here) against the scatter path,
+    on a table whose last group of 8 rows is partial."""
+    geo = chip_smoke._sizes(rehearsal=True, n=1)["table"]
+    assert geo["V"] % 8 and geo["D"] % 128 == 0
+    rows = []
+    chip_smoke._table_checks(rows, geo, "interpret")
+    assert [r["kernel"] for r in rows] == ["adagrad_rows_emb_ulp",
+                                           "adagrad_rows_softmax_w_ulp"]
+    assert all(r["ok"] and r["tol"] == 2 for r in rows)
+    full = chip_smoke._sizes(rehearsal=False, n=1)["table"]
+    assert (full["V"], full["D"]) == (793470, 512)     # the cells' tables
+    assert full["B"] * full["T"] == 2560               # emb's slots
+    assert full["B"] * full["T"] + full["samples"] == 10752

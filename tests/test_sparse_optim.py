@@ -2,12 +2,15 @@
 adagrad parity with the reference's SparseApplyAdagrad semantics
 (reference graph_transform_lib.py:71-77)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
 
+from parallax_tpu.ops import sparse_optim as so
 from parallax_tpu.ops.sparse_optim import (collect_overflow_steps,
                                            row_sparse_adagrad)
 
@@ -115,3 +118,199 @@ def test_lm1b_wiring_trajectory_unchanged(rng):
     np.testing.assert_allclose(losses_sparse, losses_dense, rtol=1e-5)
     np.testing.assert_allclose(emb_sparse, emb_dense, rtol=1e-5,
                                atol=1e-7)
+
+
+# ---------------------------------------------------------------------------
+# SliceAdagrad's in-place row kernel (interpret mode here; compiled by
+# Mosaic and compared at the cells' shapes in chip_smoke.py's kernels
+# phase). The test steers the choice, the program has no option for it.
+# ---------------------------------------------------------------------------
+
+KD = 128          # one lane tile: the narrowest table the kernel takes
+
+
+def _through_kernel(monkeypatch):
+    """Route SliceAdagrad.update through the kernel (interpreted off the
+    chip), in blocks of 16 ids so that a few dozen ids span blocks."""
+    monkeypatch.setattr(so, "_row_executor", lambda *a: "kernel")
+    monkeypatch.setattr(so, "adagrad_rows", functools.partial(
+        so.adagrad_rows, block_rows=16))
+
+
+def _ids_duplicates(rng, V, cap):
+    return rng.choice(V // 4, size=cap)
+
+
+def _ids_out_of_range(rng, V, cap):
+    ids = rng.choice(V, size=cap)
+    ids[::5] = -1
+    ids[1::7] = V
+    return ids
+
+
+def _ids_none_live(rng, V, cap):
+    return np.where(np.arange(cap) % 2 == 0, -1, V)
+
+
+def _ids_all_live(rng, V, cap):
+    return rng.choice(V - V % 8, size=cap, replace=False)
+
+
+def _ids_split_group(rng, V, cap):
+    # consecutive ids from 4 on: with 16 ids a block, rows 16-19 close
+    # block 0 and rows 20-23 of the same group of 8 open block 1
+    return 4 + np.arange(cap)
+
+
+def _ids_last_partial_group(rng, V, cap):
+    ids = rng.choice(V, size=cap)
+    ids[:3] = [V - 1, V - 2, V - V % 8 - 1]
+    return ids
+
+
+ROW_KERNEL_CASES = {
+    # name: (V, slots, ids, average)
+    "duplicates": (1000, 96, _ids_duplicates, False),
+    "out_of_range_ids": (1000, 96, _ids_out_of_range, False),
+    "no_live_row": (1000, 32, _ids_none_live, False),
+    "every_slot_live": (1000, 64, _ids_all_live, False),
+    "slots_not_a_multiple_of_the_block": (1000, 41, _ids_duplicates, False),
+    "group_split_between_blocks": (1000, 80, _ids_split_group, False),
+    "last_partial_group": (1006, 96, _ids_last_partial_group, False),
+    "partial_group_fewer_than_8_slots": (1006, 5,
+                                         _ids_last_partial_group, False),
+    "average": (1000, 96, _ids_duplicates, True),
+}
+
+
+def _table(rng, V):
+    p = jnp.asarray(rng.standard_normal((V, KD)).astype(np.float32))
+    a = jnp.asarray(rng.uniform(0.1, 2.0, (V, KD)).astype(np.float32))
+    return p, a
+
+
+@pytest.mark.parametrize("case", sorted(ROW_KERNEL_CASES))
+def test_row_kernel_matches_scatter_path(rng, monkeypatch, case):
+    V, cap, make_ids, average = ROW_KERNEL_CASES[case]
+    ids = np.asarray(make_ids(rng, V, cap), np.int32)
+    drows = jnp.asarray(rng.standard_normal((cap, KD)).astype(np.float32))
+    p, a = _table(rng, V)
+    sl = so.SliceAdagrad(0.2)
+    want_p, want_a = sl.update(p, a, jnp.asarray(ids), drows,
+                               average=average)
+    _through_kernel(monkeypatch)
+    got_p, got_a = sl.update(p, a, jnp.asarray(ids), drows,
+                             average=average)
+    np.testing.assert_allclose(got_p, want_p, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(got_a, want_a, rtol=1e-6, atol=1e-6)
+    live = np.unique(ids[(ids >= 0) & (ids < V)])
+    untouched = np.setdiff1d(np.arange(V), live)
+    # the neighbours in a touched group of 8 are rewritten: same bits
+    np.testing.assert_array_equal(np.asarray(got_p)[untouched],
+                                  np.asarray(p)[untouched])
+    np.testing.assert_array_equal(np.asarray(got_a)[untouched],
+                                  np.asarray(a)[untouched])
+    if live.size:
+        assert not np.array_equal(np.asarray(got_p)[live],
+                                  np.asarray(p)[live])
+
+
+def test_row_kernel_default_block_and_direct_call(rng):
+    """The kernel's own contract at its shipped block size: sorted,
+    duplicate-free ids, ``n_valid`` of them live, the rest ignored
+    whatever they hold."""
+    V, cap, n_valid = 4008, 300, 170
+    uids = np.full((cap,), V, np.int32)
+    uids[:n_valid] = np.sort(rng.choice(V, size=n_valid, replace=False))
+    gsum = jnp.asarray(rng.standard_normal((cap, KD)).astype(np.float32))
+    p, a = _table(rng, V)
+    got_p, got_a = so.adagrad_rows(p, a, jnp.asarray(uids),
+                                   jnp.int32(n_valid), gsum, 0.2, 1e-7)
+    live = jnp.asarray(np.where(np.arange(cap) < n_valid, uids, V))
+    want_p, want_a = so.SliceAdagrad(0.2)._scatter_rows(p, a, live, gsum)
+    np.testing.assert_allclose(got_p, want_p, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(got_a, want_a, rtol=1e-6, atol=1e-6)
+
+
+def test_row_kernel_trajectory_matches_optax(rng, monkeypatch):
+    """Five steps through the kernel against dense optax.adagrad."""
+    _through_kernel(monkeypatch)
+    V, cap, lr = 1006, 48, 0.3
+    sl = so.SliceAdagrad(lr, initial_accumulator_value=0.1)
+    tx = optax.adagrad(lr, initial_accumulator_value=0.1)
+    p_k = p_d = jnp.asarray(rng.standard_normal((V, KD)).astype(np.float32))
+    acc, st = sl.init(p_k), tx.init(p_d)
+    touched = set()
+    for _ in range(5):
+        ids = rng.choice(V, size=cap).astype(np.int32)
+        ids[0] = V - 1
+        drows = rng.standard_normal((cap, KD)).astype(np.float32)
+        g = np.zeros((V, KD), np.float32)
+        np.add.at(g, ids, drows)
+        touched |= set(ids.tolist())
+        p_k, acc = sl.update(p_k, acc, jnp.asarray(ids), jnp.asarray(drows))
+        u, st = tx.update(jnp.asarray(g), st, p_d)
+        p_d = optax.apply_updates(p_d, u)
+    rows = np.asarray(sorted(touched))
+    np.testing.assert_allclose(np.asarray(p_k)[rows], np.asarray(p_d)[rows],
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(acc)[rows],
+                               np.asarray(st[0].sum_of_squares)[rows],
+                               rtol=1e-5, atol=1e-6)
+    rest = np.setdiff1d(np.arange(V), rows)
+    np.testing.assert_array_equal(np.asarray(p_k)[rest],
+                                  np.asarray(p_d)[rest])
+
+
+def _mesh(n):
+    from jax.sharding import Mesh
+    return Mesh(np.asarray(jax.devices()[:n]).reshape(1, n),
+                ("repl", "shard"))
+
+
+def _sds(shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+EXECUTOR_CASES = {
+    # name: (param, acc, devices in the mesh (None: no scope), backend)
+    "one_lane": (_sds((64, 1)), _sds((64, 1)), 1, "tpu"),
+    "eight_lanes": (_sds((64, 8)), _sds((64, 8)), 1, "tpu"),
+    "bf16_table": (_sds((64, 128), jnp.bfloat16), _sds((64, 128)), 1,
+                   "tpu"),
+    "cpu_backend": (_sds((64, 128)), _sds((64, 128)), 1, "cpu"),
+    "sharded_over_8": (_sds((64, 128)), _sds((64, 128)), 8, "tpu"),
+    "placement_unknown": (_sds((64, 128)), _sds((64, 128)), None, "tpu"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EXECUTOR_CASES))
+def test_row_executor_choice_keeps_the_scatter_path(case):
+    """Every table the kernel is not for records "xla": by the rule,
+    and by what a traced update on this backend notes."""
+    param, acc, n_dev, backend = EXECUTOR_CASES[case]
+    mesh = _mesh(n_dev) if n_dev else None
+    assert so._row_executor(param, acc, mesh, backend) == "xla"
+    # the one table that differs from these only in what they lack
+    assert so._row_executor(_sds((64, 128)), _sds((64, 128)), _mesh(1),
+                            "tpu") == "kernel"
+    so.reset_trace_records()
+    V, D = param.shape
+    p = jnp.zeros((V, D), param.dtype)
+    a = jnp.full((V, D), 0.1, acc.dtype)
+    if n_dev and n_dev > 1:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        p = jax.device_put(p, NamedSharding(mesh, P("shard", None)))
+        a = jax.device_put(a, p.sharding)
+    ids, drows = jnp.arange(8, dtype=jnp.int32), jnp.ones((8, D))
+
+    def step(p, a):
+        if mesh is None:
+            return so.SliceAdagrad(0.1).update(p, a, ids, drows)
+        with so.table_update_scope("t", mesh):
+            return so.SliceAdagrad(0.1).update(p, a, ids, drows)
+    jax.jit(step)(p, a)
+    assert so.trace_records() == [
+        {"table": "t" if mesh is not None else None, "rows": 8, "dim": D,
+         "executor": "xla"}]
+    so.reset_trace_records()

@@ -13,8 +13,11 @@ paged kernel lowered here for five PRs and was refused there) and the
 numbers the kernels produce — is ``chip_smoke.py``'s kernels phase.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import pytest
 
 from parallax_tpu.ops import pallas_lstm
 from parallax_tpu.ops.pallas_attention import (flash_attention,
@@ -162,6 +165,34 @@ def test_pallas_lstm_recompute_fallback_lowers_for_tpu():
     text = _export_tpu(fwd_bwd, *args)
     assert text.count("tpu_custom_call") == 1, text.count(
         "tpu_custom_call")
+
+
+@pytest.mark.parametrize("slots", [2560, 10752, 18432])
+def test_adagrad_rows_lowers_for_tpu(slots):
+    """ISSUE 26: SliceAdagrad's in-place row update at the benchmark
+    cells' shapes (f32[793470, 512] table and accumulator left in HBM
+    and aliased to the outputs, the id list scalar-prefetched, manual
+    DMAs of 8-row groups). The table's 793,470 rows are not a multiple
+    of 8, so the partial last group goes through the scatter beside
+    the ONE custom call."""
+    from parallax_tpu.ops import sparse_optim as so
+
+    V, D = 793470, 512
+    table = jax.ShapeDtypeStruct((V, D), jnp.float32)
+    sl = so.SliceAdagrad(0.2)
+
+    def rows(param, acc, uids, gsum):
+        with pytest.MonkeyPatch.context() as m:
+            # off the chip the kernel would interpret itself
+            m.setattr(so, "adagrad_rows", functools.partial(
+                so.adagrad_rows, interpret=False))
+            return sl._kernel_rows(param, acc, uids, gsum)
+    text = _export_tpu(rows, table, table,
+                       jax.ShapeDtypeStruct((slots,), jnp.int32),
+                       jax.ShapeDtypeStruct((slots, D), jnp.float32))
+    assert text.count("tpu_custom_call") == 1, text.count(
+        "tpu_custom_call")
+    assert "output_operand_aliases" in text      # in place: no [V, D] copy
 
 
 def test_paged_attention_kernel_lowers_for_tpu():
