@@ -76,6 +76,8 @@ class Model:
     * ``optimizer`` — an optax GradientTransformation (default: sgd(0.01)).
     * ``sparse_params`` / ``dense_params`` — path-string overrides for the
       automatic classifier (classify.py).
+    * ``gauges`` — which of ``loss_fn``'s scalar metrics the session
+      shows as polled gauges, and under which names.
     """
 
     def __init__(self, init_fn: Callable, loss_fn: Callable,
@@ -87,9 +89,21 @@ class Model:
                  param_specs: Optional[Dict[str, Any]] = None,
                  slice_updaters: Optional[Dict[str, Any]] = None,
                  value_and_grad_fn: Optional[Callable] = None,
-                 pipeline_info: Optional[Dict[str, Any]] = None):
+                 pipeline_info: Optional[Dict[str, Any]] = None,
+                 gauges: Optional[Dict[str, Any]] = None):
         self.init_fn = init_fn
         self.loss_fn = loss_fn
+        # gauge name -> the scalar entry of ``loss_fn``'s metrics it
+        # shows, or ``(entry, "max")`` for the largest value any step
+        # gave (a rare event must not hide between two polls).
+        # ``session.metrics_snapshot()`` refreshes them from the last
+        # dispatched step's outputs; nothing is read in the step loop.
+        self.gauges = {
+            name: (spec, "last") if isinstance(spec, str) else tuple(spec)
+            for name, spec in (gauges or {}).items()}
+        for name, (_, mode) in self.gauges.items():
+            if mode not in ("last", "max"):
+                raise ValueError(f"gauge {name!r}: unknown mode {mode!r}")
         # Pipeline capability record (ISSUE 18): a model that can run
         # its layer stack through ops/pipeline declares the schedule
         # here ({"schedule", "microbatches", "virtual_stages",
@@ -931,6 +945,19 @@ class Engine:
                 len(self._traced_signatures) - 1,
                 [(n, s) for n, s, _ in sig])
 
+    def _step_executable(self):
+        exe = self._last_executable
+        if exe is None and self._executables:
+            exe = next(iter(self._executables.values()))
+        return exe
+
+    def executable_text(self) -> Optional[str]:
+        """The optimized HLO text of the AOT executable ``layer_index()``
+        reads (shapes and all), or None where there is none. Nothing is
+        lowered or compiled for a read."""
+        exe = self._step_executable()
+        return exe.as_text() if exe is not None else None
+
     def layer_index(self) -> Optional[Dict[str, Any]]:
         """Which layer each instruction of the compiled step belongs
         to: ``{"module": the program's name as a device trace prints
@@ -943,9 +970,7 @@ class Engine:
         nothing is lowered or compiled for a read. Built on the first
         call for an executable and kept; never on the step path, and it
         still answers after ``close()``."""
-        exe = self._last_executable
-        if exe is None and self._executables:
-            exe = next(iter(self._executables.values()))
+        exe = self._step_executable()
         if exe is None:
             return None
         if self._layer_index is None or self._layer_index[0] is not exe:

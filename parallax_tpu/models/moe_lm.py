@@ -2,9 +2,18 @@
 
 Expert parallelism is a TPU-native extension beyond the reference
 (SURVEY.md §2.5 lists EP as absent). Every block's MLP is a top-1 switch
-MoE (ops/moe.py): expert weights shard over the 'shard' mesh axis via
-Model.param_specs overrides, tokens dispatch/combine with all_to_all,
-and the router's load-balancing auxiliary loss joins the objective.
+MoE (``ops/moe.switch_moe``): expert weights shard over the 'shard' mesh
+axis via Model.param_specs overrides, tokens dispatch/combine with
+all_to_all, and the router's load-balancing auxiliary loss joins the
+objective.
+
+Which entry of ``ops/moe`` drops: this model's, ``switch_moe``. Under a
+mesh it DROPS the (token, choice) slots past ``capacity_factor`` (and
+reports the share as ``moe_dropped``); on one device it takes the dense
+path, which drops nothing but computes EVERY expert for EVERY token, so
+it is for small sizes only. The dropless layer, ``ops/moe.routed_experts``
+(work in proportion to the rows routed to the experts a chip holds,
+gated experts), is ``models/keye_vl2``'s.
 """
 
 from __future__ import annotations

@@ -59,14 +59,26 @@ _COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
                      "all-to-all", "collective-permute",
                      "collective-broadcast")
 
-# The layers of the train step, declared once: the names of the
+# The layers of the train steps, declared once: the names of the
 # ``jax.named_scope``s around ops/embedding.embedding_lookup,
 # ops/pallas_lstm.lstm_scan (and models/lm1b's lax.scan branch),
 # ops/sampled_softmax.sampled_softmax_loss, and core/engine.train_step's
 # dense (clip + optimizer + apply) and table (ops/sparse_optim row
-# scatter) updates. ``layer_of`` reads them back off a compiled
-# instruction's ``op_name``.
-LAYER_SCOPES = ("embedding", "lstm", "sampled_softmax", "dense_update",
+# scatter) updates; and of models/keye_vl2's block: ``attention``
+# (``_layer``: projections, norms, RoPE, the attention over the selected
+# keys, the output product), ``indexer`` (``_layer``'s three indexer
+# projections and ops/sparse_attention._chunk's scores, selection and
+# loss; the inner scope, so it wins inside ``attention``), ``moe``
+# (``_layer``: norm, router, grouping, the experts' products, the
+# combine, the auxiliary loss) and ``lm_head`` (``loss_fn``: final
+# norm, head, cross-entropy), with ``layer_scan`` around ``loss_fn``'s
+# ``lax.scan`` over the blocks for what the scan itself costs (a
+# layer's weights cut out of the stack, its kept arrays and gradients
+# written into theirs, the loop; the outermost, so a block's own names
+# win). A step holds the scopes of its own model only. ``layer_of``
+# reads them back off a compiled instruction's ``op_name``.
+LAYER_SCOPES = ("embedding", "lstm", "sampled_softmax", "layer_scan",
+                "attention", "indexer", "moe", "lm_head", "dense_update",
                 "table_update")
 # the row-sharded table path — the paper's sparse side of the
 # dense-vs-sparse variable split

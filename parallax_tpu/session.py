@@ -293,6 +293,9 @@ class ParallaxSession:
         self._profile_pending: Optional[tuple] = None
         self._profile_attrib: Optional[Dict[str, Any]] = None
         self._last_outputs: Dict[str, Any] = {}
+        # the running maxima, on the device, of the step outputs the
+        # model declares as "max" gauges (Model.gauges): a poll reads them
+        self._gauge_max: Dict[str, Any] = {}
         # Host-side mirror of state.step: reading the device value every
         # run() would block on the previous step and kill async dispatch.
         self._host_step = 0
@@ -947,6 +950,15 @@ class ParallaxSession:
         self.memwatch.sample(step)
         self._profile.after_step(step)
         self._last_outputs = outputs
+        for gauge, (entry, mode) in self._model.gauges.items():
+            value = outputs.get(entry)
+            if mode == "max" and value is not None:
+                # one such step must not hide between two polls: the
+                # maximum is kept on the device, dispatched and not read
+                seen = self._gauge_max.get(gauge)
+                self._gauge_max[gauge] = (
+                    value if seen is None
+                    else jax.numpy.maximum(seen, value))
         if self.numerics is not None:
             # cache the batch BEFORE recovery looks at the outputs: if
             # this step trips, provenance sweeps exactly these feeds
@@ -1254,6 +1266,17 @@ class ParallaxSession:
             # reading live opt_state can race step donation; the stale
             # gauge value is better than killing a monitoring thread
             pass
+        # the step outputs the model declares as gauges (Model.gauges),
+        # as of the last dispatched step: reading them waits for that
+        # step here, never in the step loop
+        for gauge, (entry, mode) in self._model.gauges.items():
+            value = (self._gauge_max.get(gauge) if mode == "max"
+                     else self._last_outputs.get(entry))
+            if value is not None:
+                try:
+                    self.metrics.gauge(gauge).set(float(value))
+                except Exception:
+                    pass    # a donated buffer: keep the stale value
         for dev, stats in device_memory_stats().items():
             for key in ("bytes_in_use", "peak_bytes_in_use"):
                 if key in stats:

@@ -21,6 +21,11 @@ from parallax_tpu.models import lm1b
 from parallax_tpu.obs import xprof
 
 NDEV = 8
+# the scopes each model's step declares, in LAYER_SCOPES' order
+LM1B_SCOPES = ["embedding", "lstm", "sampled_softmax", "dense_update",
+               "table_update"]
+KEYE_SCOPES = ["embedding", "layer_scan", "attention", "indexer", "moe",
+               "lm_head", "dense_update", "table_update"]
 
 
 def _session(**cfg_kw):
@@ -57,10 +62,45 @@ def _ops_of(index, layer_part):
 
 def test_every_declared_scope_is_found(warmed):
     _, _, index, _ = warmed
-    assert index["scopes_found"] == list(xprof.LAYER_SCOPES)
+    # the LM1B step's own scopes, and no other model's
+    assert index["scopes_found"] == LM1B_SCOPES
+    assert [s for s in xprof.LAYER_SCOPES if s in LM1B_SCOPES] \
+        == LM1B_SCOPES
     assert index["module"] == "jit_train_step"
     assert set(index["layers"]) == set(index["hlo_index"])
     assert set(index["layers"].values()) <= set(xprof.LAYER_SCOPES) | {None}
+
+
+def test_every_declared_scope_is_found_in_the_keye_step():
+    """The twin case: the Keye-VL-2.0 language model's step holds its
+    eight scopes; the indexer's operations, traced inside the
+    attention's scope, go by their own, and a block's by theirs inside
+    the scan's (innermost wins)."""
+    from parallax_tpu.models import keye_vl2
+    cfg = keye_vl2.tiny_config()
+    sess, *_ = parallax.parallel_run(
+        keye_vl2.build_model(cfg),
+        parallax_config=parallax.Config(
+            run_option="HYBRID", search_partitions=False,
+            sparse_grad_mode="slices", shape_buckets=[8]))
+    batch = keye_vl2.make_batch(np.random.default_rng(0), 8, cfg.seq_len,
+                                cfg.vocab_size)
+    sess.warmup(feed_dict=batch)
+    index = sess.layer_index()
+    sess.close()
+    assert index["scopes_found"] == KEYE_SCOPES
+    assert [s for s in xprof.LAYER_SCOPES if s in KEYE_SCOPES] == KEYE_SCOPES
+    assert set(LM1B_SCOPES) | set(KEYE_SCOPES) == set(xprof.LAYER_SCOPES)
+    inner = {n: m for n, m in index["hlo_index"].items()
+             if re.search(r"attention\)*/(.*/)?indexer", m.get("op_name", ""))}
+    assert inner
+    assert {index["layers"][n] for n in inner} == {"indexer"}
+    # the scan's own operations: under its scope and no block's
+    scan = [m["op_name"] for n, m in index["hlo_index"].items()
+            if index["layers"][n] == "layer_scan"]
+    assert scan
+    assert not any(re.search(r"/(attention|indexer|moe)\)*(/|$)", o)
+                   for o in scan)
 
 
 def test_table_scatter_maps_to_table_update(warmed):

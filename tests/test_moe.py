@@ -144,3 +144,189 @@ def test_bad_top_k_rejected(tokens, weights):
         moe.switch_moe(tokens, router, w1, w2, None, top_k=0)
     with pytest.raises(ValueError, match="top_k"):
         moe.switch_moe(tokens, router, w1, w2, None, top_k=E + 1)
+
+
+# ---- routed_experts: the dropless layer of a chip's share ----------------
+
+RD, RF, RE = 16, 24, 8
+
+
+def _routed_weights(rng, held=RE):
+    def n(*shape, scale=0.2):
+        return jnp.asarray(rng.standard_normal(shape).astype(np.float32)
+                           ) * scale
+    return n(RD, RE, scale=0.7), n(held, RD, RF), n(held, RD, RF), \
+        n(held, RF, RD)
+
+
+def _routed_plain(tokens, router, w_gate, w_up, w_down, k, first=0):
+    """Every held expert for every token, then the token's own gates."""
+    probs = jax.nn.softmax(tokens @ router, -1)
+    top_p, top_i = jax.lax.top_k(probs, k)
+    gates = top_p / top_p.sum(-1, keepdims=True)
+    act = jax.nn.silu(jnp.einsum("nd,edf->nef", tokens, w_gate)) \
+        * jnp.einsum("nd,edf->nef", tokens, w_up)
+    each = jnp.einsum("nef,efd->ned", act, w_down)
+    weight = (jax.nn.one_hot(top_i - first, w_gate.shape[0])
+              * gates[..., None]).sum(1)
+    return jnp.einsum("ned,ne->nd", each, weight)
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_routed_experts_match_every_expert_for_every_token(tokens, rng, k):
+    router, wg, wu, wd = _routed_weights(rng)
+    got = moe.routed_experts(tokens, router, wg, wu, wd, top_k=k)
+    want = _routed_plain(tokens, router, wg, wu, wd, k)
+    np.testing.assert_allclose(np.asarray(got.out), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+    assert float(got.dropped) == 0.0
+    assert float(got.rows_here) == B * k      # all experts held here
+
+
+def test_the_shares_of_a_layer_add_up_to_the_whole(tokens, rng):
+    """Four chips holding two experts each: their parts sum to what one
+    chip holding all eight computes; the router is counted once."""
+    router, wg, wu, wd = _routed_weights(rng)
+    whole = moe.routed_experts(tokens, router, wg, wu, wd, top_k=3)
+    parts = [moe.routed_experts(tokens, router, wg[f:f + 2], wu[f:f + 2],
+                                wd[f:f + 2], top_k=3, first_expert=f)
+             for f in range(0, RE, 2)]
+    np.testing.assert_allclose(
+        np.asarray(sum(p.out for p in parts)), np.asarray(whole.out),
+        rtol=2e-5, atol=2e-6)
+    assert sum(float(p.rows_here) for p in parts) == B * 3
+    assert all(float(p.aux_loss) == float(whole.aux_loss) for p in parts)
+    # a traced first_expert (a shard's index) gives the same part
+    traced = jax.jit(lambda f: moe.routed_experts(
+        tokens, router, wg[2:4], wu[2:4], wd[2:4], top_k=3,
+        first_expert=f).out)(jnp.int32(2))
+    np.testing.assert_allclose(np.asarray(traced), np.asarray(parts[1].out),
+                               rtol=1e-6, atol=1e-7)
+
+
+def test_routed_experts_drop_nothing_under_a_skewed_router(tokens, rng):
+    """A router that sends every token to the same two experts: both
+    are computed for all B tokens (switch_moe's capacity would drop)."""
+    router, wg, wu, wd = _routed_weights(rng)
+    bias_tokens = jnp.concatenate([tokens, jnp.ones((B, 1))], axis=1)
+    skew = jnp.zeros((RD + 1, RE)).at[RD, 1].set(30.0).at[RD, 5].set(29.0)
+    pad = lambda w: jnp.concatenate(
+        [w, jnp.zeros((w.shape[0], 1, w.shape[2]))], axis=1)
+    wd_p = jnp.concatenate([wd, jnp.zeros((RE, RF, 1))], axis=2)
+    got = moe.routed_experts(bias_tokens, skew, pad(wg), pad(wu), wd_p,
+                             top_k=2)
+    want = _routed_plain(bias_tokens, skew, pad(wg), pad(wu), wd_p, 2)
+    np.testing.assert_allclose(np.asarray(got.out), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+    assert float(got.dropped) == 0.0
+    assert float(got.rows_here) == 2 * B
+    assert float(got.load_max_over_mean) == pytest.approx(RE / 2)
+    # the chip that holds neither of the two computes nothing, drops nothing
+    idle = moe.routed_experts(bias_tokens, skew, pad(wg)[2:4], pad(wu)[2:4],
+                              wd_p[2:4], top_k=2, first_expert=2)
+    assert float(idle.rows_here) == 0.0 and float(idle.dropped) == 0.0
+    assert float(jnp.abs(idle.out).max()) == 0.0
+
+
+def test_routed_experts_gradients_match(tokens, rng):
+    router, wg, wu, wd = _routed_weights(rng, held=4)
+
+    def loss(fn):
+        def f(t, r, a, b, c):
+            return jnp.sum(fn(t, r, a, b, c) ** 2)
+        return jax.grad(f, argnums=(0, 1, 2, 3, 4))(tokens, router, wg, wu,
+                                                    wd)
+
+    got = loss(lambda t, r, a, b, c: moe.routed_experts(
+        t, r, a, b, c, top_k=3, first_expert=2).out)
+    want = loss(lambda t, r, a, b, c: _routed_plain(t, r, a, b, c, 3, 2))
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-5)
+
+
+def test_routed_experts_kernel_path_interpreted(rng):
+    """The megablox kernel the TPU runs, interpreted here, against
+    XLA's ragged dot, rows of absent experts included."""
+    toks = jnp.asarray(rng.standard_normal((64, RD)).astype(np.float32))
+    router, wg, wu, wd = _routed_weights(rng, held=4)
+    args = (toks, router, wg, wu, wd)
+    ref = moe.routed_experts(*args, top_k=2, first_expert=4,
+                             impl="ragged_dot")
+    got = moe.routed_experts(*args, top_k=2, first_expert=4,
+                             impl="gmm_interpret")
+    np.testing.assert_allclose(np.asarray(got.out), np.asarray(ref.out),
+                               rtol=2e-5, atol=2e-6)
+    assert 0 < float(got.rows_here) < 128      # 128 rows: one tile
+
+
+def test_routed_experts_reject_bad_arguments(tokens, rng):
+    router, wg, wu, wd = _routed_weights(rng)
+    with pytest.raises(ValueError, match="top_k"):
+        moe.routed_experts(tokens, router, wg, wu, wd, top_k=RE + 1)
+    with pytest.raises(ValueError, match="impl"):
+        moe.routed_experts(tokens, router, wg, wu, wd, top_k=2, impl="x")
+
+
+@pytest.mark.parametrize("skewed", [False, True])
+def test_routed_experts_rows_past_the_fast_part(rng, skewed):
+    """1,024 tokens top-2 on one held expert of eight: a balanced
+    router's share is 256 rows and the fast part 512. A router skewed
+    onto the held expert sends it all 1,024, which the second part
+    takes: the result and the gradients are the plain ones either way,
+    and nothing drops."""
+    n = 1024
+    toks = jnp.asarray(rng.standard_normal((n, RD)).astype(np.float32))
+    router, wg, wu, wd = _routed_weights(rng, held=1)
+    if skewed:
+        toks = toks.at[:, 0].set(4.0)
+        router = router.at[0, 3].set(9.0)
+    args = (toks, router, wg, wu, wd)
+    got = moe.routed_experts(*args, top_k=2, first_expert=3)
+    want = _routed_plain(*args, 2, 3)
+    np.testing.assert_allclose(np.asarray(got.out), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+    assert float(got.dropped) == 0.0
+    assert (float(got.rows_here) > 512) == skewed
+    grads = jax.grad(lambda *a: jnp.sum(moe.routed_experts(
+        *a, top_k=2, first_expert=3).out ** 2), argnums=(0, 1, 2, 4))(*args)
+    plain = jax.grad(lambda *a: jnp.sum(_routed_plain(*a, 2, 3) ** 2),
+                     argnums=(0, 1, 2, 4))(*args)
+    for g, w in zip(grads, plain):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=5e-4, atol=5e-5)
+
+
+def test_fast_rows_and_the_two_parts_cover_every_row(rng):
+    """The first part's size, and that the two parts' group sizes add
+    up to every held expert's rows wherever the split falls."""
+    assert moe.fast_rows(8192, 8, 16, 128) == 16384
+    assert moe.fast_rows(1024, 2, 1, 8) == 512
+    assert moe.fast_rows(64, 2, 4, 8) == 128          # all of them
+    sizes = jnp.asarray(rng.integers(0, 300, 6), jnp.int32)
+    ends = jnp.cumsum(sizes)
+    for fast in (0, 128, 512, 2048):
+        a = moe._part_sizes(sizes, ends, 0, fast)
+        b = moe._part_sizes(sizes, ends, fast, 2048 - fast)
+        np.testing.assert_array_equal(np.asarray(a + b), np.asarray(sizes))
+
+
+def test_dropped_counts_rows_that_no_part_covered(rng, monkeypatch):
+    """``dropped`` is read off the parts' own group sizes and the
+    second part's predicate: a split that loses rows shows in it."""
+    n = 1024
+    toks = jnp.asarray(rng.standard_normal((n, RD)).astype(np.float32))
+    toks = toks.at[:, 0].set(4.0)
+    router, wg, wu, wd = _routed_weights(rng, held=1)
+    router = router.at[0, 3].set(9.0)
+    args = (toks, router, wg, wu, wd)
+    sound = moe.routed_experts(*args, top_k=2, first_expert=3)
+    assert float(sound.rows_here) > 512 and float(sound.dropped) == 0.0
+    # each part leaves the last 128 of its rows out: the first part's
+    # [384, 512) are live and lost, the second's lie past the live rows
+    whole = moe._part_sizes
+    monkeypatch.setattr(
+        moe, "_part_sizes",
+        lambda sizes, ends, lo, n: whole(sizes, ends, lo, max(n - 128, 0)))
+    lossy = moe.routed_experts(*args, top_k=2, first_expert=3)
+    assert float(lossy.dropped) == 128.0

@@ -1,0 +1,183 @@
+"""ops/sparse_attention: the exact top-k selection (ties, short rows),
+GQA without repeated keys, the dense limit, the indexer's loss and who
+receives which gradient, against straightforward jax.numpy."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from parallax_tpu.ops import sparse_attention as sa
+
+B, T, HQ, HKV, D, HI, DI = 2, 24, 4, 2, 8, 2, 4
+
+
+def _inputs(seed=0, t=T):
+    rng = np.random.default_rng(seed)
+
+    def n(*shape):
+        return jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+
+    return (n(B, t, HQ, D), n(B, t, HKV, D), n(B, t, HKV, D),
+            n(B, t, HI, DI), n(B, t, DI), n(B, t, HI))
+
+
+def _plain(q, k, v, qi, ki, wi, topk):
+    """Dense [T, T] scores, lax.top_k, K and V repeated per query head."""
+    t = q.shape[1]
+    z = jnp.einsum("bqjd,bsd->bqjs", qi, ki)
+    scores = jnp.sum(wi[..., None] * jax.nn.relu(z), 2) \
+        * (HI ** -0.5 * DI ** -0.5)
+    scores = jnp.where(scores == 0, 0.0, scores)
+    causal = jnp.tril(jnp.ones((t, t), bool))[None]
+    _, idx = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf), min(topk, t))
+    sel = jnp.zeros((B, t, t), bool).at[
+        jnp.arange(B)[:, None, None], jnp.arange(t)[None, :, None],
+        idx].set(True) & causal
+    kr, vr = (jnp.repeat(a, HQ // HKV, axis=2) for a in (k, v))
+    logits = jnp.einsum("bqhd,bshd->bhqs", q, kr) * D ** -0.5
+    probs = jax.nn.softmax(jnp.where(sel[:, None], logits, -1e30), -1)
+    out = jnp.einsum("bhqs,bshd->bqhd", probs, vr)
+    target = jax.lax.stop_gradient(probs.sum(1) / HQ)
+    log_q = jax.nn.log_softmax(jnp.where(sel, scores, -1e30), -1)
+    kl = jnp.sum(jnp.where(sel & (target > 0), target * (
+        jnp.log(jnp.maximum(target, 1e-37)) - log_q), 0.0))
+    return out, kl, sel
+
+
+@pytest.mark.parametrize("topk,q_chunk,band", [(5, 4, 2), (5, 8, 4),
+                                               (7, 24, 1), (1, 4, 3)])
+def test_matches_plain_attention(topk, q_chunk, band):
+    args = _inputs()
+    got = sa.sparse_attention(*args, topk=topk, q_chunk=q_chunk,
+                              chunks_per_band=band, return_selection=True)
+    out, kl, sel = _plain(*args, topk)
+    np.testing.assert_array_equal(np.asarray(got.selection), np.asarray(sel))
+    np.testing.assert_allclose(np.asarray(got.out), np.asarray(out),
+                               rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(float(got.indexer_loss), float(kl), rtol=1e-4)
+    assert float(got.selected) == float(sel.sum())
+    assert float(got.causal) == B * T * (T + 1) / 2
+
+
+def test_selection_is_exact_under_ties_and_for_short_rows():
+    """Rows of equal scores take the LOWEST keys, as lax.top_k does; a
+    query with fewer than topk causal keys takes them all."""
+    scores = jnp.zeros((1, 12, 12))
+    scores = scores.at[0, 9, :].set(jnp.asarray(
+        [1., 3, 3, 3, 0, 3, 3, -0.0, 0, 0, 9, 9]))
+    causal = jnp.tril(jnp.ones((12, 12), bool))[None]
+    sel = np.asarray(sa.select_topk(scores, causal, 4))
+    for t in range(12):
+        want = np.zeros(12, bool)
+        want[:min(t + 1, 4)] = True
+        if t == 9:
+            want = np.zeros(12, bool)
+            want[[1, 2, 3, 5]] = True
+        np.testing.assert_array_equal(sel[0, t], want, err_msg=str(t))
+    # negative scores and a score of -0.0 order as numbers do
+    row = jnp.asarray([[[-2., -1, -0.0, 0, -3, 5]]])
+    got = np.asarray(sa.select_topk(row, jnp.ones((1, 1, 6), bool), 3))
+    np.testing.assert_array_equal(got[0, 0], [0, 0, 1, 1, 0, 1])
+
+
+def test_zero_indexer_selects_the_first_keys():
+    q, k, v, qi, ki, wi = _inputs()
+    got = sa.sparse_attention(q, k, v, qi * 0, ki, wi, topk=3, q_chunk=8,
+                              return_selection=True)
+    sel = np.asarray(got.selection)
+    for t in range(T):
+        assert sel[0, t, :min(t + 1, 3)].all() and sel[0, t].sum() \
+            == min(t + 1, 3)
+
+
+def test_topk_of_the_sequence_is_dense_attention():
+    q, k, v, qi, ki, wi = _inputs()
+    got = sa.sparse_attention(q, k, v, qi, ki, wi, topk=T, q_chunk=8)
+    kr, vr = (jnp.repeat(a, HQ // HKV, axis=2) for a in (k, v))
+    logits = jnp.einsum("bqhd,bshd->bhqs", q, kr) * D ** -0.5
+    causal = jnp.tril(jnp.ones((T, T), bool))
+    dense = jnp.einsum("bhqs,bshd->bqhd", jax.nn.softmax(
+        jnp.where(causal, logits, -1e30), -1), vr)
+    np.testing.assert_allclose(np.asarray(got.out), np.asarray(dense),
+                               rtol=2e-5, atol=2e-6)
+    assert float(got.selected) == float(got.causal)
+
+
+def test_query_heads_read_their_own_group():
+    """Changing one key/value head moves only its group's query heads."""
+    q, k, v, qi, ki, wi = _inputs()
+    base = sa.sparse_attention(q, k, v, qi, ki, wi, topk=6, q_chunk=8).out
+    moved = sa.sparse_attention(q, k.at[:, :, 1].add(1.0),
+                                v.at[:, :, 1].add(1.0), qi, ki, wi,
+                                topk=6, q_chunk=8).out
+    group = HQ // HKV
+    np.testing.assert_array_equal(np.asarray(moved[:, :, :group]),
+                                  np.asarray(base[:, :, :group]))
+    assert not np.allclose(np.asarray(moved[:, :, group:]),
+                           np.asarray(base[:, :, group:]))
+
+
+def test_gradients_match_and_stay_on_their_side():
+    """q, k, v learn from the output only; the indexer from its loss
+    only (the selection is a constant of both)."""
+    args = _inputs()
+
+    def both(fn):
+        def f(*a):
+            out, kl = fn(*a)[:2]
+            return jnp.sum(out ** 2) + 0.7 * kl
+        return jax.grad(f, argnums=tuple(range(6)))(*args)
+
+    got = both(lambda *a: sa.sparse_attention(*a, topk=5, q_chunk=8,
+                                              chunks_per_band=2))
+    want = both(lambda *a: _plain(*a, 5))
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-5)
+    out_only = jax.grad(lambda *a: jnp.sum(sa.sparse_attention(
+        *a, topk=5, q_chunk=8).out ** 2), argnums=(3, 4, 5))(*args)
+    assert all(float(jnp.abs(g).max()) == 0.0 for g in out_only)
+    loss_only = jax.grad(lambda *a: sa.sparse_attention(
+        *a, topk=5, q_chunk=8).indexer_loss, argnums=(0, 1, 2))(*args)
+    assert all(float(jnp.abs(g).max()) == 0.0 for g in loss_only)
+
+
+def test_shapes_that_do_not_divide_are_refused():
+    q, k, v, qi, ki, wi = _inputs()
+    with pytest.raises(ValueError, match="multiple"):
+        sa.sparse_attention(q, k, v, qi, ki, wi, topk=4, q_chunk=7)
+    with pytest.raises(ValueError, match="group"):
+        sa.sparse_attention(q[:, :, :3], k, v, qi, ki, wi, topk=4,
+                            q_chunk=8)
+
+
+@pytest.mark.parametrize("topk,band", [(5, 2), (24, 3)])
+def test_kernels_interpreted_match_the_xla_executor(topk, band, monkeypatch):
+    """The three Mosaic kernels the TPU runs, interpreted here, against
+    the einsum executor: output, loss and every gradient; key tiles of
+    8, so that a chunk walks several and skips those above its
+    diagonal."""
+    monkeypatch.setattr(sa, "_KEY_TILE", 8)
+    args = _inputs(seed=3)
+
+    def run(impl):
+        def f(*a):
+            o = sa.sparse_attention(*a, topk=topk, q_chunk=8,
+                                    chunks_per_band=band, impl=impl)
+            return jnp.sum(o.out ** 2) + 0.7 * o.indexer_loss, o
+        (_, o), grads = jax.value_and_grad(
+            f, argnums=tuple(range(6)), has_aux=True)(*args)
+        return o, grads
+
+    want, want_grads = run("xla")
+    got, got_grads = run("kernel_interpret")
+    np.testing.assert_allclose(np.asarray(got.out), np.asarray(want.out),
+                               rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(float(got.indexer_loss),
+                               float(want.indexer_loss), rtol=1e-5)
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-5)
+    with pytest.raises(ValueError, match="impl"):
+        sa.sparse_attention(*args, topk=4, q_chunk=8, impl="mosaic")

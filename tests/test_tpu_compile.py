@@ -1,0 +1,91 @@
+"""The Mosaic kernels of the Keye-VL-2.0 step, compiled at the
+published widths by the TPU's own compiler against a described v5e (no
+chip is attached, nothing runs): what interpret mode cannot show: a
+block that is not aligned to the tiling, more VMEM than a kernel may
+take, a transposed product Mosaic refuses.
+
+The topology is described inside a fixture of this one file, never at
+import (the on-chip-measurement guide, section 2): every xdist worker
+collects the same tests, and only the worker that is handed this file
+loads the TPU's library.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here, or it is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+
+
+def _kernels(compiled):
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+def test_sparse_attention_kernels_compile_at_published_widths(one_chip):
+    """One 512-query chunk of 32 query heads on 4 key/value heads of 128
+    against 2,048 keys: forward, the heads' summed probabilities, and
+    the backward kernel."""
+    from parallax_tpu.ops import sparse_attention as sa
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, qi, ki, wi, start):
+        out, kl, _, _ = sa._chunk(q, k, v, qi, ki, wi, start,
+                                  jnp.int32(2048), "kernel")
+        return jnp.sum(out.astype(jnp.float32)) + kl
+
+    compiled = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4, 5)),
+        sds((1, 4, 8, 512, 128)), sds((1, 4, 2048, 128)),
+        sds((1, 4, 2048, 128)), sds((1, 512, 16, 64)), sds((1, 2048, 64)),
+        sds((1, 512, 16)), sds((), jnp.int32))
+    # forward, the summed probabilities (for the loss and again for its
+    # gradient), backward
+    assert _kernels(compiled) == 4
+    for name in ("sparse_attn_fwd", "sparse_attn_bwd", "sparse_attn_probs"):
+        assert name in compiled.as_text()
+
+
+def test_routed_experts_kernels_compile_at_published_widths(one_chip):
+    """2,048 tokens of width 2048 through 16 held experts of width 768,
+    top-8 of 128: three grouped products forward and six backward, for
+    the fast part of the rows and again for the part behind the
+    ``cond``."""
+    from parallax_tpu.ops import moe
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(tokens, router, w_gate, w_up, w_down):
+        return jnp.sum(moe.routed_experts(
+            tokens, router, w_gate, w_up, w_down, top_k=8,
+            impl="gmm").out.astype(jnp.float32))
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 2, 3, 4)),
+        sds((2048, 2048), jnp.bfloat16), sds((2048, 128), jnp.float32),
+        sds((16, 2048, 768), jnp.float32), sds((16, 2048, 768), jnp.float32),
+        sds((16, 768, 2048), jnp.float32))
+    assert _kernels(compiled) == 18
+    # no product over tokens x experts held
+    assert "[2048,16,768]" not in compiled.as_text()
