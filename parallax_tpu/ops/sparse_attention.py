@@ -39,22 +39,31 @@ counts over the chunk), and ties at the threshold go to the lowest keys
 by a running count, which is what ``jax.lax.top_k`` returns. A chunk's backward
 pass is written out (``_chunk_bwd``): the forward keeps the chunk's
 selection, output and logsumexp (named ``sparse_attn_chunk`` for a
-caller's checkpoint policy) and the backward computes the scores again,
-so that the indexer's per-head products of one chunk at a time exist.
+caller's checkpoint policy) and the backward computes the scores again
+(one fusion down to ``[q_chunk, keys]``, as in the forward), takes the
+loss's gradient with respect to them on arrays of that shape, and only
+then goes back through the per-head products.
 
-The attention over a chunk's selection has two executors, chosen as
-``ops/pallas_lstm`` chooses its own (``impl``; by the backend when not
-given). ``"kernel"`` (the TPU): three Mosaic kernels that stream the
-keys through VMEM tile by tile under the chunk's selection mask, the
-``[queries, keys]`` logits of a head never leaving the core:
-``sparse_attn_fwd`` (online softmax; output and per-row logsumexp),
-``sparse_attn_bwd`` (one pass over the key tiles gives dk and dv of the
-tile and accumulates dq, the eight query heads of a group looping inside
-the kernel over one fetch of their key/value head and of the mask) and
-``sparse_attn_probs`` (the heads' probabilities summed, the indexer's
-target). Key tiles that lie wholly above the chunk's diagonal are
-neither fetched nor computed. ``"xla"`` (elsewhere, and the reference
-for the kernels' tests): einsum, softmax, einsum.
+Two executors, chosen as ``ops/pallas_lstm`` chooses its own (``impl``;
+by the backend when not given), serve the attention over a chunk's
+selection and the scores' backward alike. ``"kernel"`` (the TPU): four
+Mosaic kernels that stream the keys through VMEM tile by tile, the
+``[queries, keys]`` logits of a head never leaving the core. Three
+attend under the chunk's selection mask: ``sparse_attn_fwd`` (online
+softmax; output and per-row logsumexp), ``sparse_attn_bwd`` (one pass
+over the key tiles gives dk and dv of the tile and accumulates dq, the
+eight query heads of a group looping inside the kernel over one fetch
+of their key/value head and of the mask) and ``sparse_attn_probs`` (the
+heads' probabilities summed, the indexer's target). The fourth,
+``indexer_bwd``, is the gradient of ``indexer_scores``: for a key tile
+and each indexer head in turn the products ``z [q_chunk, tile]`` again,
+what ``relu`` and the head's weight let through of the scores'
+cotangent, and its three sums (``dqi`` accumulated over the tiles,
+``dki`` of the tile, ``dwi``); a chunk's per-head products ``[q_chunk,
+Hi, keys]`` never reach HBM. Key tiles that lie wholly above the
+chunk's diagonal are neither fetched nor computed. ``"xla"`` (elsewhere,
+and the reference for the kernels' tests): einsum, softmax, einsum, and
+``jax.vjp(indexer_scores)``.
 """
 
 from __future__ import annotations
@@ -168,6 +177,12 @@ def _kernel_operands(q, sel, q_start):
 def _last_tile(start_ref, C: int, tk: int):
     """The last key tile a chunk starting at ``start_ref[0]`` can see."""
     return (start_ref[0] + C - 1) // tk
+
+
+def _seen(kt, start_ref, C: int, tk: int):
+    """The key tile a grid step fetches: a tile past the chunk's last
+    is not fetched, its index folds onto the last needed one."""
+    return jnp.minimum(kt, _last_tile(start_ref, C, tk))
 
 
 def _tile(Tk: int) -> int:
@@ -302,7 +317,7 @@ def _specs(q, k, tk: int, grid_order: str):
         return lambda b, kt, g, start: fn(b, g, kt, start)
 
     def seen(kt, start):
-        return jnp.minimum(kt, _last_tile(start, C, tk))
+        return _seen(kt, start, C, tk)
 
     return {
         "q": pl.BlockSpec((1, 1, R, C, D), ix(lambda b, g, kt, s:
@@ -381,6 +396,106 @@ def _probs_call(q, k, mask, start, lse, interpret):
         [], (start, q, k, mask, lse), interpret)[0] / (Hkv * R)
 
 
+def _indexer_bwd_kernel(start_ref, qi_ref, ki_ref, wi_ref, ds_ref, dqi_ref,
+                        dki_ref, dwi_ref, dqi_sc, dwi_sc, *, tk: int,
+                        scale: float):
+    """One key tile of ``indexer_scores``' gradient: for each head the
+    products ``z [C, tk]`` again, what their ``relu`` and the head's
+    weight let through of ``ds``, and the three sums it feeds. Nothing
+    of ``[C, Hi, tk]`` leaves the core."""
+    Hi, C = qi_ref.shape[1], qi_ref.shape[2]
+    lanes = dwi_sc.shape[2]
+    kt = pl.program_id(1)
+    needed = kt <= _last_tile(start_ref, C, tk)
+
+    @pl.when(kt == 0)
+    def _():
+        dqi_sc[...] = jnp.zeros(dqi_sc.shape, jnp.float32)
+        dwi_sc[...] = jnp.zeros(dwi_sc.shape, jnp.float32)
+
+    @pl.when(needed)
+    def _():
+        ki = ki_ref[0]                                           # [tk, Di]
+        g = ds_ref[0] * jnp.float32(scale)                       # [C, tk]
+        wi = wi_ref[0].astype(jnp.float32)                       # [C, Hi]
+        dki = jnp.zeros(dki_ref.shape[1:], jnp.float32)
+        for j in range(Hi):
+            qi = qi_ref[0, j]                                    # [C, Di]
+            z = jax.lax.dot_general(
+                qi, ki, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            # dwi's sum over the keys: lane tile onto lane tile here,
+            # across the lanes once a chunk
+            p = jnp.maximum(z, 0.0) * g
+            dwi_sc[j] = dwi_sc[j] + sum(
+                p[:, i:i + lanes] for i in range(0, tk, lanes))
+            dz = jnp.where(z > 0, wi[:, j:j + 1] * g, 0.0).astype(qi.dtype)
+            dki = dki + jax.lax.dot_general(
+                dz, qi, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dqi_sc[j] = dqi_sc[j] + jax.lax.dot_general(
+                dz, ki, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        dki_ref[0] = dki.astype(dki_ref.dtype)
+
+    @pl.when(jnp.logical_not(needed))
+    def _():
+        dki_ref[0] = jnp.zeros(dki_ref.shape[1:], dki_ref.dtype)
+
+    @pl.when(kt == pl.num_programs(1) - 1)
+    def _():
+        dqi_ref[0] = dqi_sc[...].astype(dqi_ref.dtype)
+        head = jax.lax.broadcasted_iota(jnp.int32, (C, Hi), 1)
+        dwi = jnp.zeros((C, Hi), jnp.float32)
+        for j in range(Hi):
+            dwi = jnp.where(head == j, jnp.sum(dwi_sc[j], axis=1,
+                                               keepdims=True), dwi)
+        dwi_ref[0] = dwi.astype(dwi_ref.dtype)
+
+
+def _indexer_bwd_call(qi, ki, wi, ds, start, interpret):
+    """``(dqi, dki, dwi)`` of ``indexer_scores(qi, ki, wi)`` under the
+    cotangent ``ds [B, C, Tk]`` (float32, zero wherever the chunk's
+    queries see no key), by the kernel ``indexer_bwd``: the key tiles
+    stream through VMEM as in ``sparse_attn_bwd``, the heads loop inside
+    over one fetch of the tile. ``start``: the chunk's first position as
+    ``_kernel_operands`` hands it to every kernel."""
+    from jax.experimental.pallas import tpu as pltpu
+    B, C, Hi, Di = qi.shape
+    Tk = ki.shape[1]
+    tk = _tile(Tk)
+
+    dqi, dki, dwi = pl.pallas_call(
+        functools.partial(_indexer_bwd_kernel, tk=tk,
+                          scale=Hi ** -0.5 * Di ** -0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, Tk // tk),
+            in_specs=[
+                pl.BlockSpec((1, Hi, C, Di), lambda b, kt, s: (b, 0, 0, 0)),
+                pl.BlockSpec((1, tk, Di), lambda b, kt, s:
+                             (b, _seen(kt, s, C, tk), 0)),
+                pl.BlockSpec((1, C, Hi), lambda b, kt, s: (b, 0, 0)),
+                pl.BlockSpec((1, C, tk), lambda b, kt, s:
+                             (b, 0, _seen(kt, s, C, tk)))],
+            out_specs=[
+                pl.BlockSpec((1, Hi, C, Di), lambda b, kt, s: (b, 0, 0, 0)),
+                pl.BlockSpec((1, tk, Di), lambda b, kt, s: (b, kt, 0)),
+                pl.BlockSpec((1, C, Hi), lambda b, kt, s: (b, 0, 0))],
+            scratch_shapes=[pltpu.VMEM((Hi, C, Di), jnp.float32),
+                            pltpu.VMEM((Hi, C, min(tk, 128)), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((B, Hi, C, Di), qi.dtype),
+                   jax.ShapeDtypeStruct(ki.shape, ki.dtype),
+                   jax.ShapeDtypeStruct(wi.shape, wi.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="indexer_bwd", interpret=interpret)(
+            start, jnp.swapaxes(qi, 1, 2), ki, wi, ds)
+    # head-major inside the kernel (a head's queries are one tile of
+    # rows); the transpositions are [C, Hi, Di], not [C, Hi, keys]
+    return jnp.swapaxes(dqi, 1, 2), dki, dwi
+
+
 def _selection(qi, ki, wi, q_start, topk):
     """``(scores [B, C, Tk] f32, causal, sel)`` of a chunk whose first
     query stands at ``q_start``."""
@@ -413,7 +528,8 @@ def _chunk(q, k, v, qi, ki, wi, q_start, topk, impl: str):
     selection, the output and the rows' logsumexp, and computes the
     scores and the heads' summed probabilities again, so that neither
     the per-head logits nor the indexer's per-head products of a chunk
-    outlive the pass that made them."""
+    outlive the pass that made them (under ``"kernel"`` they are never
+    in memory at all)."""
     return _chunk_fwd(q, k, v, qi, ki, wi, q_start, topk, impl)[0]
 
 
@@ -467,10 +583,23 @@ def _chunk_bwd(impl, res, cotangents):
         dq = _scaled(dqs)
         target = _probs_call(qs, k, mask, start, lse, interpret)
     with jax.named_scope("indexer"):
-        _, pull = jax.vjp(
-            lambda qi, ki, wi: _indexer_loss(indexer_scores(qi, ki, wi), sel,
-                                             target), qi, ki, wi)
-        dqi, dki, dwi = pull(d_kl)
+        # the scores again, and the way back through the chunk's
+        # per-head products
+        if impl == "xla":
+            scores, back = jax.vjp(indexer_scores, qi, ki, wi)
+        else:
+            scores = indexer_scores(qi, ki, wi)
+
+            def back(ds):
+                # a score of zero is written, not computed
+                # (indexer_scores)
+                return _indexer_bwd_call(
+                    qi, ki, wi, jnp.where(scores == 0, 0.0, ds), start,
+                    interpret)
+        # between them the loss's side, on [C, keys] arrays alone: what
+        # the loss asks of the scores (zero off the selection)
+        _, pull = jax.vjp(lambda s: _indexer_loss(s, sel, target), scores)
+        dqi, dki, dwi = back(pull(d_kl)[0])
     return dq, dk, dv, dqi, dki, dwi, None, None
 
 

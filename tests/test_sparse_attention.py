@@ -181,3 +181,37 @@ def test_kernels_interpreted_match_the_xla_executor(topk, band, monkeypatch):
                                    rtol=2e-4, atol=2e-5)
     with pytest.raises(ValueError, match="impl"):
         sa.sparse_attention(*args, topk=4, q_chunk=8, impl="mosaic")
+
+
+@pytest.mark.parametrize("q_start,dtype,rtol,atol", [
+    (0, jnp.float32, 1e-5, 1e-5), (8, jnp.float32, 1e-5, 1e-5),
+    (0, jnp.bfloat16, 2e-2, 2e-2), (8, jnp.bfloat16, 2e-2, 2e-2)])
+def test_indexer_bwd_kernel_matches_the_vjp_of_the_scores(
+        q_start, dtype, rtol, atol, monkeypatch):
+    """The kernel ``indexer_bwd``, interpreted, against
+    ``jax.vjp(indexer_scores)`` for one chunk of 8 queries over 24 keys
+    in tiles of 8, under a ``ds`` that is zero off a random causal
+    selection: a chunk at 0 sees one tile, one at 8 two, and the tiles
+    above the diagonal get ``dki`` rows of exact zeros."""
+    monkeypatch.setattr(sa, "_KEY_TILE", 8)
+    C, hi, di = 8, 3, 4
+    rng = np.random.default_rng(11 + q_start)
+
+    def n(*shape):
+        return jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+
+    qi, ki, wi = (n(B, C, hi, di).astype(dtype), n(B, T, di).astype(dtype),
+                  n(B, C, hi).astype(dtype))
+    causal = np.arange(T)[None] <= q_start + np.arange(C)[:, None]
+    ds = jnp.where(causal & (rng.random((B, C, T)) < 0.6), n(B, C, T), 0.0)
+    want = jax.vjp(sa.indexer_scores, qi, ki, wi)[1](ds)
+    got = sa._indexer_bwd_call(qi, ki, wi, ds,
+                                jnp.full((1,), q_start, jnp.int32), True)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32),
+                                   rtol=rtol, atol=atol)
+    above = q_start + C
+    assert float(jnp.abs(got[1][:, :above].astype(jnp.float32)).max()) > 0
+    assert float(jnp.abs(got[1][:, above:].astype(jnp.float32)).max()) == 0.0

@@ -10,6 +10,8 @@ collects the same tests, and only the worker that is handed this file
 loads the TPU's library.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -40,10 +42,26 @@ def _kernels(compiled):
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _outside_fusions(text, shape):
+    """The instructions of shape ``shape`` that stand outside every
+    fused computation: what a fusion or a custom call writes to or reads
+    from memory (an instruction inside a fusion lives in registers)."""
+    fused = set(re.findall(r"\bfusion\(.*?calls=%([\w.\-]+)", text))
+    hits, name = [], None
+    for line in text.splitlines():
+        start = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if start:
+            name = start.group(1)
+        elif name not in fused and shape in line:
+            hits.append(line.strip())
+    return hits
+
+
 def test_sparse_attention_kernels_compile_at_published_widths(one_chip):
     """One 512-query chunk of 32 query heads on 4 key/value heads of 128
-    against 2,048 keys: forward, the heads' summed probabilities, and
-    the backward kernel."""
+    against 2,048 keys: forward, the heads' summed probabilities, the
+    backward kernel, and the indexer's own backward kernel, which keeps
+    the chunk's per-head products ``[512, 16, keys]`` out of memory."""
     from parallax_tpu.ops import sparse_attention as sa
 
     def sds(shape, dtype=jnp.bfloat16):
@@ -60,10 +78,15 @@ def test_sparse_attention_kernels_compile_at_published_widths(one_chip):
         sds((1, 4, 2048, 128)), sds((1, 512, 16, 64)), sds((1, 2048, 64)),
         sds((1, 512, 16)), sds((), jnp.int32))
     # forward, the summed probabilities (for the loss and again for its
-    # gradient), backward
-    assert _kernels(compiled) == 4
-    for name in ("sparse_attn_fwd", "sparse_attn_bwd", "sparse_attn_probs"):
+    # gradient), backward, the indexer's backward
+    assert _kernels(compiled) == 5
+    for name in ("sparse_attn_fwd", "sparse_attn_bwd", "sparse_attn_probs",
+                 "indexer_bwd"):
         assert name in compiled.as_text()
+    # the forward's scores are one fusion down to [512, keys]; nothing
+    # else may hold a head's products of the chunk
+    assert "[512,16,2048]" in compiled.as_text()
+    assert _outside_fusions(compiled.as_text(), "[512,16,2048]") == []
 
 
 def test_routed_experts_kernels_compile_at_published_widths(one_chip):
