@@ -680,7 +680,7 @@ class ParallaxConfig:
     # paged-attn kernel vs einsum) and exports rel-error / argmax-flip
     # gauges. Each sweep runs both executors on the dispatch thread —
     # whole milliseconds, not micros — so the default 0 keeps it out
-    # of the training loop; tools/bench run sentinels explicitly.
+    # of the training loop; tests run the sentinels explicitly.
     numerics_drift_interval: int = 0
     # Override the PARALLAX logger level for this run (default: leave
     # the env-var/import-time level alone). E.g. "DEBUG", "WARNING".

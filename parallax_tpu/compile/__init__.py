@@ -31,8 +31,8 @@ measured. Three cooperating parts drive those compiles to the minimum:
 Everything reports through the obs layer: ``engine.compile_seconds``
 (histogram), ``engine.executable_cache.{hits,misses}`` and
 ``session.engine_cache.{hits,misses}`` (counters), all carried by
-``registry.snapshot()`` and stamped into the BENCH JSON
-(``ParallaxSession.compile_stats()``).
+``registry.snapshot()`` and reported whole by
+``ParallaxSession.compile_stats()``.
 """
 
 from parallax_tpu.compile.bucketing import (batch_signature, bucket_batch,

@@ -18,7 +18,7 @@ Two cache layers with different lifetimes:
 * JAX's persistent compilation cache (on-disk, cross-process):
   ``ensure_persistent_cache`` is the ONE place that decides where it
   lives, and every entry point (``ParallaxSession``, ``ServeSession``,
-  ``chip_smoke.py``, ``bench.py``, ``tests/conftest.py``) goes through
+  ``chip_smoke.py``, ``benchmark/``, ``tests/conftest.py``) goes through
   it, so a relaunched job (same model, same toolchain) skips XLA
   entirely — compiles become disk reads. Keyed by HLO + compile
   environment, and the HLO's metadata (named scopes, source lines

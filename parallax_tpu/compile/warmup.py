@@ -8,7 +8,7 @@ dispatches a ready executable instead of stalling the loop on a full
 XLA compile. The resulting executables are held by the engine and
 dispatched by shape signature (``Engine.step``); per-signature compile
 wall-time lands in the ``engine.compile_seconds`` histogram and in
-``Engine.warmup_seconds`` (stamped into the BENCH JSON by
+``Engine.warmup_seconds`` (both reported by
 ``ParallaxSession.compile_stats``).
 
 Lowering needs concrete input layouts: the live ``TrainState`` carries

@@ -270,10 +270,6 @@ def _pin_lstm_replicated(model: Model) -> Model:
     return model
 
 
-def build_full_softmax_model(cfg: LM1BConfig) -> Model:
-    return build_model(cfg, full_softmax=True)
-
-
 def make_batch(rng: np.random.Generator, batch_size: int, num_steps: int,
                vocab_size: int):
     """Synthetic Zipf-ish batch with the reference driver's feed keys."""

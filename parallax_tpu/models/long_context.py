@@ -77,8 +77,8 @@ class LongContextConfig:
     # zig-zag sequence placement in ring mode: balances the causal
     # workload across the ring (each device holds a low block and its
     # mirrored high block; ops/ring_attention.py computes maskless
-    # half-tiles for foreign blocks — ~2x attention wall-clock at large
-    # rings, perf/zigzag_balance.json). The permute happens in-graph, so
+    # half-tiles for foreign blocks — about half the tiles on the worst
+    # device at large rings, by count). The permute happens in-graph, so
     # feeds stay natural-order. None (default) = AUTO: zigzag whenever
     # the sequence length divides 2*ring (its only extra requirement),
     # contiguous otherwise; True/False forces.

@@ -37,7 +37,7 @@ reports goodput across attempts — the artifact the chaos guard
 
 This module is also the single owner of per-step goodput math:
 :func:`step_goodput` is the window account that used to live on
-``StepTimeline.goodput()`` (which now delegates here), so bench keys
+``StepTimeline.goodput()`` (which now delegates here), so its keys
 keep their meaning while run-lifetime and per-step views can never
 disagree on the arithmetic.
 
@@ -67,7 +67,7 @@ _STEP_RING = 1024
 def step_goodput(timeline) -> Dict:
     """The per-step goodput account over a StepTimeline's rolling
     window: per-phase mean milliseconds and fraction of mean wall
-    time, plus MFU. JSON-ready (bench.py, flight dumps). One owner of
+    time, plus MFU. JSON-ready (flight dumps, tests). One owner of
     this math — ``StepTimeline.goodput()`` is a thin delegate."""
     from parallax_tpu.obs.timeline import COMPONENTS
     rows = timeline.rows()

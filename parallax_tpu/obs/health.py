@@ -17,7 +17,7 @@ finished (``is_ready``), so the async pipeline's dispatch thread never
 blocks on monitoring. ``report()`` / session close drain the rest.
 
 Everything lands in the session's MetricsRegistry (``health.*``), so
-one snapshot carries it (bench.py, the JSONL sink).
+one snapshot carries it (``metrics_snapshot``, the JSONL sink).
 """
 
 from __future__ import annotations

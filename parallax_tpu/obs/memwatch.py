@@ -89,7 +89,7 @@ def compiled_step_memory(engine) -> Optional[Dict[str, Any]]:
     ``"warmup"``. Without one, pays a single host-side compile against
     the engine's real shardings (init compiled for its output
     shardings, the step lowered against sharded abstract state +
-    placed-batch avals — the tools/memory_report.py recipe) and hands
+    placed-batch avals — what the first real step would lower) and hands
     the executable to the engine's AOT table so the very next step of
     that signature dispatches it instead of recompiling: the preflight
     compile is the compile the trial would have paid anyway, just
@@ -329,7 +329,7 @@ class MemWatch:
 
     def live_peak_bytes(self) -> Optional[int]:
         """High-water bytes-in-use across every sample so far (the
-        runtime-measured evidence layer of tools/memory_report.py);
+        runtime-measured side beside the compiled ``peak_bytes``);
         None when the backend never reported."""
         with self._lock:
             return self._live_peak or None

@@ -4,8 +4,8 @@ One registry per session gathers every runtime signal — the async
 pipeline's dispatch-gap / H2D-bytes / blocked-on-device (PipelineStats,
 migrated here from profiler.py), steps/sec, sparse-overflow counts,
 engine recompiles, health-monitor outputs — behind a single
-``snapshot()`` that is JSON-ready (bench.py stamps it into the BENCH
-line) and an optional periodic JSONL sink
+``snapshot()`` that is JSON-ready (``session.metrics_snapshot()``, the
+flight dumps) and an optional periodic JSONL sink
 (``Config.metrics_path`` / ``metrics_interval_s``) for scraping live
 runs.
 
@@ -314,7 +314,7 @@ class PipelineStats:
     (``pipeline.*``) so one ``registry.snapshot()`` carries them next to
     engine / health metrics.
 
-    ``summary()`` keeps the pre-migration shape (bench.py JSON,
+    ``summary()`` keeps the pre-migration shape (test_obs,
     test_async_pipeline) and adds p50/p95.
     """
 
@@ -376,7 +376,7 @@ class PipelineStats:
                 "max_ms": round(snap["max"], 3)}
 
     def summary(self) -> Dict:
-        """Snapshot over the rolling window, JSON-ready (bench.py)."""
+        """Snapshot over the rolling window, JSON-ready."""
         h2d = self._h2d.snapshot()
         sps = self.steps_per_sec()
         return {

@@ -638,7 +638,7 @@ def paged_attn_drift_pair(seed: int = 0,
 def default_sentinels(registry: Optional[MetricsRegistry] = None,
                       perturb: float = 0.0) -> List[DriftSentinel]:
     """The two built-in executor A/Bs (names are the gauge keys the
-    bench/regression gates pin)."""
+    tests and alerts read)."""
     return [
         DriftSentinel("lstm_bwd", lstm_drift_pair(perturb=perturb),
                       registry=registry, rel_err_tol=1e-3),

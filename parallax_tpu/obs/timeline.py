@@ -28,7 +28,7 @@ dispatch thread:
 With the compiled step's XLA ``cost_analysis`` FLOPs and the chip's
 published peak (``common/flops.py``) attached via :meth:`set_flops`,
 each row also carries per-step **MFU** and :meth:`goodput` returns the
-account bench.py / the flight recorder stamp: the fraction of wall time
+account the flight recorder stamps: the fraction of wall time
 each phase consumed over the rolling window.
 
 The ring doubles as the flight recorder's step log (obs/flightrec.py):
@@ -223,7 +223,7 @@ class StepTimeline:
     def goodput(self) -> Dict:
         """The goodput account over the rolling window: per-phase
         mean milliseconds and fraction of mean wall time, plus MFU.
-        JSON-ready (bench.py, flight dumps). Thin delegate: the math
+        JSON-ready (flight dumps, tests). Thin delegate: the math
         lives in obs/goodput.py (:func:`~parallax_tpu.obs.goodput.
         step_goodput`), the single owner of goodput arithmetic, so the
         per-step window and the run-lifetime ledger can never

@@ -461,7 +461,7 @@ def direction_of(meta: Optional[Dict[str, Any]]) -> Optional[str]:
     whole backward pass lives under it), ``"forward"`` for any other
     op_name'd op, None when the metadata carries no op_name at all.
     The join key for the training-step fwd/bwd attribution row
-    (tools/profile_lm1b.py, ISSUE 14)."""
+    (``attribute()``'s ``by_direction``, ISSUE 14)."""
     if not meta:
         return None
     op_name = meta.get("op_name") or ""

@@ -750,7 +750,7 @@ def kernel_hbm_bytes(T, B, E, H, P, x_itemsize, w_itemsize, *,
 def scan_hbm_bytes(T, B, E, H, P, x_itemsize, w_itemsize, *,
                    training=True):
     """The XLA-scan alternative's analytic bytes for the same shapes —
-    the T x weight re-fetch story the kernel removes (docs/bench): the
+    the T x weight re-fetch story the kernel removes (README): the
     scan body re-reads the full [E+P, 4H] gate matrix and w_proj every
     timestep, forward and (training) again in the transposed backward
     plus the recompute-fallback's extra forward."""

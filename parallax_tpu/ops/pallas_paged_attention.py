@@ -77,11 +77,11 @@ host-side, and neither can leak into kept tokens: overshoot positions
 are write-dropped, so the caches other queries read never contain
 them.
 
-Like every Pallas ratio in this repo, measured CPU numbers price the
-interpreter emulation, not the TPU memory system — the analytic
-``kernel_hbm_bytes`` / ``gather_hbm_bytes`` table is the hardware
-claim and ``tools/bench_paged_attn.py`` stamps the interpret-tax
-witness in-artifact.
+A CPU run prices the Pallas interpreter, not the TPU memory system,
+and is no statement about speed. The analytic ``kernel_hbm_bytes`` /
+``gather_hbm_bytes`` table is counted from shapes, not measured; on
+the chip the kernel was timed by hand only (0.3 % of its roofline,
+``PERF.md`` section 6) and no benchmark cell runs it yet.
 """
 
 from __future__ import annotations
@@ -103,7 +103,7 @@ _LANES = 8      # lane-broadcast width for per-row scalars (see
                 # ops/pallas_attention._LANES: (8, lanes) blocks satisfy
                 # Mosaic's equal-dims clause at 1/16 the 128-lane cost)
 
-# The flagship decode shape the lowering gate and the analytic bench
+# The flagship decode shape the lowering gate and the analytic byte
 # table price: continuous serving of the transformer NMT flagship
 # (D=512, 8 heads) with a 2048-position cap paged at 128 tokens/page,
 # 64 slots, spec-decode verify width 3 (spec_tokens=2 + bonus).

@@ -293,7 +293,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             # rotation wall-clock is one HALF tile on every device
             # (vs a full tile on the worst device under the contiguous
             # skip), so attention wall time drops ~2x at large n —
-            # the measured decision artifact is perf/zigzag_balance.
+            # counted in tiles; no chip run has timed it (ROADMAP A9).
             h = Tq // 2
             m, l, o = accumulate(k_loc, v_loc, 0, m0, l0, o0)
 

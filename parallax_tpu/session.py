@@ -26,7 +26,7 @@ steps and the partition search keep the old blocking semantics so their
 wall-times cover real device work; ``ParallaxConfig.eager_fetch=True``
 restores them everywhere. ``pipeline_stats`` (profiler.PipelineStats)
 records dispatch-gap / H2D-bytes / blocked-on-device per step so the
-overlap is measurable (bench.py) rather than assumed.
+overlap is measurable (``benchmark/``) rather than assumed.
 
 The session also owns the per-step hooks the reference installs in the
 patched run: checkpoint triggers (chief-only hooks, lib.py:38-56), profile
@@ -1032,7 +1032,7 @@ class ParallaxSession:
         settled (candidates enumerated / pruned / trialed, per-trial
         predicted-vs-measured ms, the winner's ratio, search wall
         seconds — see ``tune.MeshSearch.summary``), else None. Also a
-        flight-recorder provider and the bench ``tune`` block."""
+        flight-recorder provider."""
         return self._tune_result
 
     # -- plan observatory (obs/xprof + obs/memwatch, ISSUE 13) ------------
@@ -1258,7 +1258,7 @@ class ParallaxSession:
         steps-per-sec), engine builds + recompiles, health counters when
         enabled — with the polled gauges (sparse overflow, device
         memory) refreshed first. Safe to call from a monitoring thread
-        while training is live (bench.py stamps this into BENCH JSON)."""
+        while training is live (``benchmark/`` reads it after a window)."""
         try:
             self.metrics.gauge("sparse.overflow_steps").set(
                 self.sparse_overflow_steps())
@@ -1365,7 +1365,7 @@ class ParallaxSession:
         einsum) and return the check results; gauges land as
         ``numerics.drift.<name>.*``. Runs whole milliseconds of kernel
         work — the in-loop cadence is ``numerics_drift_interval`` (off
-        by default); this method is the explicit/bench entry point.
+        by default); this method is the explicit entry point.
         None when the numerics observatory is off."""
         if self.numerics is None:
             return None
@@ -1658,7 +1658,7 @@ class ParallaxSession:
 
     def _goodput_for_dump(self) -> Dict:
         # cheap-only: a crash dump must not re-trace the model; with
-        # warmup() used (the bench path) the AOT executable makes this
+        # warmup() used (the usual path) the AOT executable makes this
         # free, otherwise MFU just stays null in the artifact
         self._ensure_flops(cheap_only=True)
         return self.timeline.goodput()
@@ -1795,8 +1795,8 @@ class ParallaxSession:
         return t
 
     def compile_stats(self) -> Dict[str, Any]:
-        """JSON-ready compile/caching report (bench.py stamps this into
-        the BENCH line): declared bucket sizes, per-bucket AOT compile
+        """JSON-ready compile/caching report (the tuner's and the
+        cache's tests read it): declared bucket sizes, per-bucket AOT compile
         seconds, and the executable-/engine-cache hit and miss
         counters."""
         eng = self._engine
@@ -1903,7 +1903,7 @@ class ParallaxSession:
                 # the full decision record — candidates, per-trial
                 # predicted-vs-measured, the winner's ratio — goes to
                 # the flight recorder (provider + one-shot artifact)
-                # and to bench via tune_summary()
+                # and to callers via tune_summary()
                 self._tune_result = self._search.summary()
                 parallax_log.info(
                     "mesh search done: winner %s (%s)",

@@ -40,7 +40,7 @@ constants — on the CPU rig (unknown peak) the model falls back to
 nominal TPU-class constants, so predictions are *ranking* devices, not
 wall-clock oracles, and every predicted-vs-measured ratio downstream is
 CPU-relative until captured on hardware. The per-term breakdown rides
-into the flight-recorder/bench artifacts so each tuner decision stays
+into the flight-recorder artifacts so each tuner decision stays
 explainable either way.
 """
 
@@ -244,7 +244,7 @@ class CostInputs:
 @dataclasses.dataclass
 class PlanCost:
     """Predicted step time for one plan, with the per-term breakdown
-    that makes the decision explainable (flight recorder / bench)."""
+    that makes the decision explainable (flight recorder)."""
 
     plan: Plan
     total_s: float

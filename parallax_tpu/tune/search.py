@@ -26,7 +26,7 @@ can emit is a plan a driver has run.
 The settled winner is stamped with its predicted-vs-measured ratio
 (CPU-relative until captured on hardware — the model's constants are
 nominal off-TPU) and the whole decision record lands in the flight
-recorder and the bench ``tune`` block.
+recorder and ``session.tune_summary()``.
 """
 
 from __future__ import annotations
@@ -176,7 +176,7 @@ class MeshSearch:
     3. per measured trial the session calls :meth:`report(plan,
        mean_step_time)` -> the next candidate, or None when done;
     4. :meth:`best_plan` is the measured argmin; :meth:`summary` is
-       the full decision record (bench/flight artifacts).
+       the full decision record (flight artifacts).
     """
 
     def __init__(self, num_devices: int, tune_config,
@@ -414,7 +414,7 @@ class MeshSearch:
                 "predicted_over_measured": (
                     round(pc.total_s / m, 6) if pc and m else None),
                 # None on a 2-D winner; a pp>1 winner carries its
-                # priced bubble so the bench tune block can gate it
+                # priced bubble so a reader of the summary can check it
                 "bubble_fraction": (
                     (pc.pipeline or {}).get("bubble_fraction")
                     if pc else None),

@@ -439,8 +439,8 @@ def test_flagship_wire_ratio_gate():
     FLAGSHIP sparse path must stay under 2% of the same-dtype dense
     all-reduce, recomputed from the engine's trace-time accounting — a
     lookup regression (lost dedup, widened planes, an extra dense
-    cotangent) can't land silently. The committed artifact
-    (perf/WIRE_BYTES_r04.json) records 1.3%."""
+    cotangent) can't land silently. Counted from shapes at the flagship
+    widths, the ratio is 1.3%."""
     import os as _os
     import sys
     sys.path.insert(0, _os.path.join(_os.path.dirname(__file__), ".."))

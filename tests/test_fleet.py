@@ -751,38 +751,6 @@ class TestAnomalyRebaseline:
             fleet.close()
 
 
-# -- fleet secondary regression gates ---------------------------------------
-
-
-class TestFleetSecondaryGates:
-    @staticmethod
-    def _doc(recovery=60.0, blackout=40.0):
-        return {"bench_version": 3, "value": 1000.0,
-                "serve": {"fleet": {
-                    "failover_recovery_ms": recovery,
-                    "hotswap_blackout_ms": blackout}}}
-
-    def _run(self, cur, prev):
-        from tools.check_regression import compare_secondary
-        return {r["gate"]: r for r in compare_secondary(cur, prev)}
-
-    def test_recovery_regression_fails(self):
-        res = self._run(self._doc(recovery=200.0),
-                        self._doc(recovery=60.0))
-        assert res["serve.fleet.failover_recovery_ms"]["status"] \
-            == "regression"
-        assert res["serve.fleet.hotswap_blackout_ms"]["status"] == "ok"
-
-    def test_missing_fleet_block_skips(self):
-        cur, prev = self._doc(), self._doc()
-        del prev["serve"]["fleet"]
-        res = self._run(cur, prev)
-        assert res["serve.fleet.failover_recovery_ms"]["status"] \
-            == "skipped"
-        assert res["serve.fleet.hotswap_blackout_ms"]["status"] \
-            == "skipped"
-
-
 # -- decode failover token identity (paged KV, in-process) ------------------
 
 

@@ -1,12 +1,10 @@
 """Training forensics (ISSUE 5): step-time attribution timeline,
 flight recorder dump triggers (crash / non-finite loss / serve SLO
-breach / explicit), anomaly + straggler detection, the bench
-regression gate, and the device-peak-FLOPs table under a TPU stub."""
+breach / explicit), anomaly + straggler detection, and the
+device-peak-FLOPs table under a TPU stub."""
 
 import glob
 import json
-import os
-import sys
 import time
 
 import numpy as np
@@ -22,8 +20,6 @@ from parallax_tpu.obs.anomaly import AnomalyMonitor
 from parallax_tpu.obs.flightrec import FlightRecorder
 from parallax_tpu.obs.metrics import MetricsRegistry
 from parallax_tpu.obs.timeline import StepTimeline
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def _simple_session(**cfg_kw):
@@ -482,152 +478,7 @@ class TestServeSLOBreachDump:
             serve.close()
 
 
-# -- regression gate (tools/check_regression.py) ---------------------------
-
-
-def _bench_block(value=4000.0, version=2, sha="abc123", **kw):
-    block = {"metric": "lm1b_words_per_sec_per_chip", "value": value,
-             "unit": "words/sec/chip", "platform": "cpu", "n_chips": 8,
-             "bench_version": version,
-             "harness": {"bench_sha256": sha, "steps_measured": 30}}
-    block.update(kw)
-    return block
-
-
-class TestRegressionGate:
-    def _compare(self, cur, prev, **kw):
-        from tools.check_regression import compare
-        return compare(cur, prev, **kw)
-
-    def test_unchanged_rerun_passes(self):
-        r = self._compare(_bench_block(4000.0), _bench_block(4010.0))
-        assert r["status"] == "ok"
-        assert r["harness_verified"] is True
-
-    def test_catches_injected_2x_slowdown(self):
-        """Acceptance: a 2x step-time slowdown (headline halves)
-        between harness-compatible rounds FAILS the gate."""
-        r = self._compare(_bench_block(2000.0), _bench_block(4000.0))
-        assert r["status"] == "regression"
-        assert r["ratio"] == pytest.approx(0.5)
-
-    def test_regression_note_explains(self):
-        r = self._compare(
-            _bench_block(2000.0, regression_note="vocab doubled"),
-            _bench_block(4000.0))
-        assert r["status"] == "explained"
-
-    def test_version_bump_needs_ab_block(self):
-        cur = _bench_block(2000.0, version=3)
-        prev = _bench_block(4000.0, version=2)
-        r = self._compare(cur, prev)
-        assert r["status"] == "not_comparable"
-        assert "ab_vs_prev_harness" in r["why"]
-        # A/B shows the move is methodology: same build under prev
-        # params holds the old number -> explained
-        cur["ab_vs_prev_harness"] = {"value_under_prev_params": 3900.0}
-        r = self._compare(cur, prev)
-        assert r["status"] == "explained"
-        assert r["ab_ratio"] == pytest.approx(0.975)
-
-    def test_version_bump_cannot_amnesty_a_build_regression(self):
-        """The gate judges the A/B's apples-to-apples ratio: a build
-        that regressed 2x cannot hide behind a bench_version bump."""
-        cur = _bench_block(2000.0, version=3)
-        prev = _bench_block(4000.0, version=2)
-        cur["ab_vs_prev_harness"] = {"value_under_prev_params": 2000.0}
-        r = self._compare(cur, prev)
-        assert r["status"] == "regression"
-        assert r["ab_ratio"] == pytest.approx(0.5)
-        cur["regression_note"] = "accepted: bf16 accumulate change"
-        assert self._compare(cur, prev)["status"] == "explained"
-
-    def test_harness_edit_within_version_not_comparable(self):
-        r = self._compare(_bench_block(2000.0, sha="NEW"),
-                          _bench_block(4000.0, sha="OLD"))
-        assert r["status"] == "not_comparable"
-
-    def test_platform_or_chips_mismatch_not_comparable(self):
-        r = self._compare(_bench_block(8000.0, platform="tpu"),
-                          _bench_block(4000.0))
-        assert r["status"] == "not_comparable"
-
-    def test_failed_round_never_gates(self):
-        r = self._compare(_bench_block(0.0, error="worker exited"),
-                          _bench_block(4000.0))
-        assert r["status"] == "no_data"
-
-    def test_suspicious_rise_flagged_but_passes(self):
-        r = self._compare(_bench_block(9000.0), _bench_block(4000.0))
-        assert r["status"] == "suspicious_rise"
-
-    def test_main_on_wrapped_artifacts(self, tmp_path):
-        """End to end through the CLI against driver-format files:
-        unchanged rerun exits 0, injected 2x slowdown exits 1."""
-        from tools.check_regression import main
-        prev = tmp_path / "BENCH_r05.json"
-        cur = tmp_path / "BENCH_r06.json"
-        prev.write_text(json.dumps(
-            {"n": 5, "rc": 0, "parsed": _bench_block(4000.0)}))
-        cur.write_text(json.dumps(
-            {"n": 6, "rc": 0, "parsed": _bench_block(3900.0)}))
-        assert main([str(cur), str(prev)]) == 0
-        cur.write_text(json.dumps(
-            {"n": 6, "rc": 0, "parsed": _bench_block(2000.0)}))
-        assert main([str(cur), str(prev)]) == 1
-
-    def test_discovery_orders_by_round_number(self, tmp_path):
-        from tools.check_regression import discover_rounds
-        for n in (2, 10, 9):
-            (tmp_path / f"BENCH_r{n:02d}.json").write_text("{}")
-        cur, prev = discover_rounds(str(tmp_path))
-        assert cur.endswith("BENCH_r10.json")
-        assert prev.endswith("BENCH_r09.json")
-
-    def test_wrapper_truncation_recovers_harness_from_tail(
-            self, tmp_path):
-        """ISSUE 7 satellite: the r05 driver wrapper truncated the
-        parsed block (no ``harness``), which made the r5->r6 gate
-        report not_comparable for want of an A/B replay. load_block
-        must backfill missing top-level keys from the raw result line
-        in the wrapper's stdout tail — parsed values win on
-        conflict — so a wrapped artifact round-trips whole."""
-        from tools.bench_artifacts import load_block
-        full = _bench_block(4000.0)
-        full["harness"] = {"bench_sha256": "abc123", "batch_size": 128,
-                          "steps_measured": 20}
-        full["serve"] = {"qps": 55.0}
-        truncated = {k: v for k, v in full.items()
-                     if k not in ("harness", "serve")}
-        truncated["value"] = 4001.0  # parsed wins on conflict
-        p = tmp_path / "BENCH_r09.json"
-        p.write_text(json.dumps({
-            "n": 9, "rc": 0,
-            "tail": ("PARALLAX INFO: noise\n" + json.dumps(full)
-                     + "\n"),
-            "parsed": truncated}))
-        blk = load_block(str(p))
-        assert blk["harness"] == full["harness"]
-        assert blk["serve"] == full["serve"]
-        assert blk["value"] == 4001.0
-        # an untruncated wrapper round-trips to itself
-        p2 = tmp_path / "BENCH_r10.json"
-        p2.write_text(json.dumps({
-            "n": 10, "rc": 0, "tail": json.dumps(full),
-            "parsed": full}))
-        assert load_block(str(p2)) == full
-        # a tail whose result line measured a DIFFERENT metric never
-        # backfills (recovering someone else's harness would be worse
-        # than recovering nothing)
-        other = dict(full, metric="other_metric")
-        p3 = tmp_path / "BENCH_r11.json"
-        p3.write_text(json.dumps({
-            "n": 11, "rc": 0, "tail": json.dumps(other),
-            "parsed": truncated}))
-        assert "harness" not in load_block(str(p3))
-
-
-# -- device peak FLOPs under a TPU stub (VERDICT r5 item 5) ---------------
+# -- device peak FLOPs under a TPU stub -----------------------------------
 
 
 class TestDevicePeakFlops:
@@ -651,71 +502,3 @@ class TestDevicePeakFlops:
             flops_lib.device_peak_flops("tpu", "")
         # off the TPU the same kind is no error: there is no peak
         assert flops_lib.device_peak_flops("cpu", "TPU v99") is None
-
-    def test_mfu_nonnull_the_moment_platform_is_tpu(self):
-        """bench.py's exact computation under a v5e stub: a non-null
-        MFU lands without any TPU-side special-casing."""
-        from parallax_tpu.models import lm1b
-        cfg = lm1b.tiny_config(num_partitions=8)
-        fpw = flops_lib.lm1b_matmul_flops_per_word(cfg)
-        peak = flops_lib.device_peak_flops("tpu", "TPU v5e")
-        value = flops_lib.mfu(fpw, 1e6, peak)
-        assert value is not None and 0 < value < 1
-        assert flops_lib.mfu(fpw, 1e6, None) is None  # CPU: null
-
-
-# -- bench harness A/B decision (VERDICT r5 item 6) ------------------------
-
-
-class TestBenchHarnessAB:
-    def test_needs_ab_only_on_version_bump_with_harness(self):
-        import bench
-        prev = {"bench_version": bench.BENCH_VERSION - 1,
-                "harness": {"batch_size": 128}}
-        assert bench._needs_harness_ab(prev)
-        assert not bench._needs_harness_ab(
-            {"bench_version": bench.BENCH_VERSION,
-             "harness": {"batch_size": 128}})
-        assert not bench._needs_harness_ab(
-            {"bench_version": bench.BENCH_VERSION - 1})  # no harness
-        assert not bench._needs_harness_ab(None)
-
-    def test_load_prev_round_unwraps_driver_format(self, tmp_path):
-        import bench
-        (tmp_path / "BENCH_r04.json").write_text(json.dumps(
-            {"parsed": {"value": 1.0, "bench_version": 1}}))
-        (tmp_path / "BENCH_r05.json").write_text(json.dumps(
-            {"parsed": {"value": 2.0, "bench_version": 2}}))
-        prev = bench._load_prev_round(str(tmp_path))
-        assert prev == {"value": 2.0, "bench_version": 2}
-        assert bench._load_prev_round(str(tmp_path / "none")) is None
-
-
-# -- bench_resnet tracking number (VERDICT r5 item 5) ----------------------
-
-
-class TestResnetVsPrev:
-    def _result(self, **kw):
-        base = {"value": 0.1, "platform": "cpu", "n_chips": 8,
-                "model": "resnet50_v1.5", "image_size": 224,
-                "classes": 1000, "per_chip_batch": 2}
-        base.update(kw)
-        return base
-
-    def test_comparable_rounds_track(self):
-        from tools.bench_resnet import vs_prev
-        ratio, why = vs_prev(self._result(value=0.05),
-                             self._result(value=0.1))
-        assert ratio == pytest.approx(0.5)  # the 2x regression shows
-        assert why == "comparable"
-
-    def test_shape_or_platform_change_never_fakes_a_ratio(self):
-        from tools.bench_resnet import vs_prev
-        ratio, why = vs_prev(self._result(),
-                             self._result(image_size=64))
-        assert ratio is None and "image_size" in why
-        ratio, why = vs_prev(self._result(),
-                             self._result(platform="tpu"))
-        assert ratio is None
-        assert vs_prev(self._result(), None)[0] is None
-        assert vs_prev(self._result(), self._result(value=0))[0] is None

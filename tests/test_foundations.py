@@ -101,54 +101,6 @@ class TestIsLocalHost:
         assert is_local_host(socket.gethostname())
 
 
-class TestBenchOneProcess:
-    """``python bench.py`` is the process that holds the device: no
-    parent/worker split, and every child it starts is pinned to the
-    CPU (a child reaching for the chip its parent owns fails or
-    hangs)."""
-
-    @pytest.fixture
-    def bench_src(self):
-        import os
-        path = os.path.join(os.path.dirname(__file__), "..", "bench.py")
-        with open(path) as f:
-            return f.read()
-
-    def test_no_parent_worker_split(self, bench_src):
-        import ast
-        tree = ast.parse(bench_src)
-        names = {n.name for n in ast.walk(tree)
-                 if isinstance(n, ast.FunctionDef)}
-        assert "main" in names
-        assert not names & {"worker_main", "_probe_backend", "_cpu_env",
-                            "_log_probe"}
-        assert "PARALLAX_BENCH_WORKER" not in bench_src
-        # the entry point calls main() directly: nothing re-executes
-        # this file
-        assert "__file__)]" not in bench_src
-
-    def test_every_child_gets_the_cpu(self, bench_src, monkeypatch):
-        import ast
-        import bench
-        for platforms in ("tpu", "", None):
-            if platforms is None:
-                monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-            else:
-                monkeypatch.setenv("JAX_PLATFORMS", platforms)
-            assert bench._child_env()["JAX_PLATFORMS"] == "cpu"
-        spawns = [n for n in ast.walk(ast.parse(bench_src))
-                  if isinstance(n, ast.Call)
-                  and isinstance(n.func, ast.Attribute)
-                  and isinstance(n.func.value, ast.Name)
-                  and n.func.value.id == "subprocess"
-                  and n.func.attr in ("run", "Popen", "call",
-                                      "check_call", "check_output")]
-        assert spawns, "bench.py is expected to start its CPU children"
-        for call in spawns:
-            env = {k.arg: k.value for k in call.keywords}.get("env")
-            assert env is not None and ast.unparse(env) == "_child_env()"
-
-
 class TestShardAPI:
     def test_mod_filter_semantics(self):
         # reference shard.py:69-87: elem index % num_shards == shard_id

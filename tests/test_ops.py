@@ -231,7 +231,7 @@ class TestGoodputLedger:
         for s in range(4):
             tl.record_step(s, 0.0, 1e-3, 1e-4, 1e-4, 1e-4, 5e-4, 0.0)
         # single owner of the math: the method and the function agree
-        # key for key (bench.py's goodput keys keep their meaning)
+        # key for key
         assert tl.goodput() == step_goodput(tl)
         assert tl.goodput()["steps"] == 4
         assert "phase_frac" in tl.goodput()

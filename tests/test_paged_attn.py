@@ -23,8 +23,7 @@ Five layers of coverage over ``ops/pallas_paged_attention``:
 * the serve-level guard (tools/check_paged_attn_serve.py, subprocess):
   kernel-executor session == einsum-executor session token for token
   over the full paged+chunked+speculative rig with zero serve-time
-  compiles and zero leaked pages — and the regression-gate rows for
-  the bench ``attn`` block.
+  compiles and zero leaked pages.
 """
 
 import os
@@ -405,48 +404,3 @@ def test_paged_attn_serve_guard():
     assert result["token_mismatches_churn"] == 0
     assert result["kernel"]["compiles"] == 0
     assert result["kernel"]["pages_in_use_after_close"] == 0
-
-
-# -- regression-gate secondary rows (tools/check_regression.py) --------------
-
-
-class TestAttnSecondaryGates:
-    @staticmethod
-    def _doc(kernel_ms=30.0, ratio=90.0, note=None):
-        d = {"bench_version": 3, "value": 4000.0,
-             "attn": {"step_ms": {"kernel": kernel_ms,
-                                  "einsum": 0.4},
-                      "kernel_over_einsum": ratio}}
-        if note:
-            d["regression_note"] = note
-        return d
-
-    def _rows(self, cur, prev):
-        from tools.check_regression import compare_secondary
-        return [r for r in compare_secondary(cur, prev)
-                if r["gate"].startswith("attn.")]
-
-    def test_within_bounds_is_ok(self):
-        rows = self._rows(self._doc(), self._doc(kernel_ms=29.0,
-                                                 ratio=88.0))
-        assert rows and all(r["status"] == "ok" for r in rows)
-
-    def test_kernel_slowdown_fails(self):
-        rows = self._rows(self._doc(kernel_ms=60.0),
-                          self._doc(kernel_ms=30.0))
-        assert any(r["gate"] == "attn.step_ms.kernel"
-                   and r["status"] == "regression" for r in rows)
-
-    def test_ratio_drift_fails_both_directions(self):
-        up = self._rows(self._doc(ratio=140.0), self._doc(ratio=90.0))
-        assert any(r["gate"] == "attn.kernel_over_einsum"
-                   and r["status"] == "regression" for r in up)
-        down = self._rows(self._doc(ratio=40.0), self._doc(ratio=90.0))
-        assert any(r["gate"] == "attn.kernel_over_einsum"
-                   and r["status"] == "regression" for r in down)
-
-    def test_missing_block_skips(self):
-        cur = self._doc()
-        prev = {"bench_version": 3, "value": 4000.0}
-        rows = self._rows(cur, prev)
-        assert rows and all(r["status"] == "skipped" for r in rows)

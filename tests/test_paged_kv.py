@@ -234,9 +234,15 @@ class TestChunkedPrefill:
         carry = feed
         for k in range(chunked.num_prefill_chunks):
             carry = chunked.prefill_chunk(params, carry, k)
-        for key in ("ck", "cv", "src_valid"):
-            np.testing.assert_array_equal(np.asarray(rs[key]),
-                                          np.asarray(carry[key]))
+        np.testing.assert_array_equal(np.asarray(rs["src_valid"]),
+                                      np.asarray(carry["src_valid"]))
+        # whole and chunked prefill are two differently fused float32
+        # programs, so their sums associate differently: equal to a few
+        # ulps, not to the bit (observed 3.6e-7 on values of order 1)
+        for key in ("ck", "cv"):
+            np.testing.assert_allclose(np.asarray(rs[key]),
+                                       np.asarray(carry[key]),
+                                       rtol=0, atol=2e-6)
 
     def test_chunk_layer_validation(self):
         cfg = nmt_cfg()
@@ -410,8 +416,8 @@ class TestSpeculativeDecode:
             self, rng):
         """draft == target: every proposal verifies, so each iteration
         emits spec_tokens + 1 tokens — decode_steps must come in well
-        under total tokens (the tokens/sec multiplier, measured rather
-        than asserted in tools/nmt_decode_timing.py)."""
+        under total tokens (the count that would multiply tokens/sec;
+        the rate itself is not measured here)."""
         cfg = nmt_cfg()
         params = _nmt_params(cfg)
         prog = NMTDecodeProgram(cfg, max_src_len=8, max_len=12,
@@ -437,68 +443,6 @@ class TestSpeculativeDecode:
         finally:
             sess.close()
         _assert_greedy_identical(params, cfg, srcs, caps, outs)
-
-
-# -- regression-gate secondary blocks (tools/check_regression.py) -----------
-
-
-class TestSecondaryGates:
-    @staticmethod
-    def _doc(qps=100.0, tps=500.0, ttft=20.0, cached=50.0, note=None):
-        d = {"bench_version": 3, "value": 4000.0,
-             "serve": {"qps": qps, "latency_ms": {"p50": 10.0},
-                       "continuous": {"tokens_per_sec_best": tps,
-                                      "ttft_ms_p50_at_8x": ttft}},
-             "decode": {"rows": [{"cached_ms": 10.0},
-                                 {"cached_ms": cached}],
-                        "spec_vs_plain": {"tokens_per_sec_spec": 300.0},
-                        "paged_vs_dense": {"paged_step_ms": 5.0}}}
-        if note:
-            d["regression_note"] = note
-        return d
-
-    def _run(self, cur, prev):
-        from tools.check_regression import compare_secondary
-        return {r["gate"]: r for r in compare_secondary(cur, prev)}
-
-    def test_within_bounds_is_ok(self):
-        res = self._run(self._doc(), self._doc(qps=95.0, tps=520.0))
-        assert res["serve.qps"]["status"] == "ok"
-        assert res["serve.continuous.tokens_per_sec_best"]["status"] \
-            == "ok"
-
-    def test_tokens_per_sec_drop_fails(self):
-        res = self._run(self._doc(tps=300.0), self._doc(tps=500.0))
-        assert res["serve.continuous.tokens_per_sec_best"]["status"] \
-            == "regression"
-
-    def test_ttft_rise_fails_lower_is_better(self):
-        res = self._run(self._doc(ttft=40.0), self._doc(ttft=20.0))
-        assert res["serve.continuous.ttft_ms_p50_at_8x"]["status"] \
-            == "regression"
-        # and a ttft DROP is an improvement, not a regression
-        res = self._run(self._doc(ttft=10.0), self._doc(ttft=20.0))
-        assert res["serve.continuous.ttft_ms_p50_at_8x"]["status"] \
-            == "ok"
-
-    def test_decode_row_gated_from_the_end(self):
-        res = self._run(self._doc(cached=90.0), self._doc(cached=50.0))
-        assert res["decode.rows.-1.cached_ms"]["status"] == "regression"
-
-    def test_missing_block_skips_not_fails(self):
-        cur = self._doc()
-        prev = self._doc()
-        del prev["serve"]["continuous"]
-        res = self._run(cur, prev)
-        assert res["serve.continuous.tokens_per_sec_best"]["status"] \
-            == "skipped"
-        assert res["serve.qps"]["status"] == "ok"
-
-    def test_regression_note_explains(self):
-        res = self._run(self._doc(tps=300.0, note="rig moved"),
-                        self._doc(tps=500.0))
-        assert res["serve.continuous.tokens_per_sec_best"]["status"] \
-            == "explained"
 
 
 # -- the signature-set contract ---------------------------------------------

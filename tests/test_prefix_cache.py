@@ -759,40 +759,3 @@ def test_prefix_reuse_guard():
         0.8 * result["ttft_ms_p50_cold_nosharing"]
     assert result["token_mismatches"] == 0
     assert result["tenant_isolation"]["b_hits_delta"] == 0
-
-
-# -- regression-gate secondary blocks (tools/check_regression.py) -----------
-
-
-class TestPrefixSecondaryGates:
-    @staticmethod
-    def _doc(warm=2.0, hit=0.8, note=None):
-        d = {"bench_version": 3, "value": 4000.0,
-             "serve": {"prefix": {"ttft_ms_p50_warm": warm,
-                                  "hit_rate": hit}}}
-        if note:
-            d["regression_note"] = note
-        return d
-
-    def _run(self, cur, prev):
-        from tools.check_regression import compare_secondary
-        return {r["gate"]: r for r in compare_secondary(cur, prev)}
-
-    def test_warm_ttft_rise_fails(self):
-        res = self._run(self._doc(warm=4.0), self._doc(warm=2.0))
-        assert res["serve.prefix.ttft_ms_p50_warm"]["status"] \
-            == "regression"
-        res = self._run(self._doc(warm=1.0), self._doc(warm=2.0))
-        assert res["serve.prefix.ttft_ms_p50_warm"]["status"] == "ok"
-
-    def test_hit_rate_drop_fails(self):
-        res = self._run(self._doc(hit=0.3), self._doc(hit=0.8))
-        assert res["serve.prefix.hit_rate"]["status"] == "regression"
-        res = self._run(self._doc(hit=0.85), self._doc(hit=0.8))
-        assert res["serve.prefix.hit_rate"]["status"] == "ok"
-
-    def test_missing_block_skips(self):
-        prev = self._doc()
-        del prev["serve"]["prefix"]
-        res = self._run(self._doc(), prev)
-        assert res["serve.prefix.hit_rate"]["status"] == "skipped"

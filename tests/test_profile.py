@@ -553,38 +553,6 @@ def test_session_preflight_refusal_in_tune_decision(rng, tmp_path,
         sess.close()
 
 
-# -- secondary gates (bench) ------------------------------------------------
-
-def test_profile_secondary_gates_two_sided():
-    from tools.check_regression import SECONDARY_GATES, \
-        compare_secondary
-    paths = [g for g, _ in SECONDARY_GATES]
-    assert "profile.attribution_coverage" in paths
-    assert paths.count(
-        "profile.calibration.wire_predicted_over_measured") == 2
-
-    def artifact(cov, wire):
-        return {"profile": {
-            "attribution_coverage": cov,
-            "calibration":
-                {"wire_predicted_over_measured": wire}}}
-
-    gates = [g for g in SECONDARY_GATES if g[0].startswith("profile.")]
-    # coverage drop fails; calibration drift fails in BOTH directions
-    rows = compare_secondary(artifact(0.5, 1.0),
-                             artifact(0.99, 1.0), gates=gates)
-    assert [r["status"] for r in rows] == ["regression", "ok", "ok"]
-    rows = compare_secondary(artifact(0.99, 3.0),
-                             artifact(0.99, 1.0), gates=gates)
-    assert "regression" in [r["status"] for r in rows]
-    rows = compare_secondary(artifact(0.99, 0.3),
-                             artifact(0.99, 1.0), gates=gates)
-    assert "regression" in [r["status"] for r in rows]
-    # missing block skips, never fails
-    rows = compare_secondary({}, artifact(0.99, 1.0), gates=gates)
-    assert {r["status"] for r in rows} == {"skipped"}
-
-
 # -- session profile window (in-process) ------------------------------------
 
 def test_session_profile_window_and_gauges(tmp_path):

@@ -315,7 +315,7 @@ class TestEnumeration:
     def test_bounded_space_accounting_stays_consistent(self):
         """min_tp > 1 keeps AR's canonical tp=1 plan: the enumerated
         count must still cover every scored plan (the decision record
-        lands in flight/bench artifacts — 'recorded, never silent')."""
+        lands in flight artifacts — 'recorded, never silent')."""
         ms = MeshSearch(8, parallax.TuneConfig(
             run_options=("AR", "SHARD"), min_tp=2), Plan(1, 8, "SHARD"))
         ms.begin(_inputs())

@@ -46,9 +46,7 @@ The XLA-compile witness is paused around ``push_weights`` itself (a
 program; the zero-recompile claim is about SERVING dispatches, which
 ``serve.recompiles`` covers end to end and the witness re-arms for).
 
-bench.py stamps the ``bench`` sub-dict as the ``serve.fleet`` block;
-tools/check_regression.py gates ``failover_recovery_ms`` and
-``hotswap_blackout_ms`` between harness-compatible rounds. All
+The ``bench`` sub-dict is the summary tests/test_fleet.py reads. All
 numbers are CPU runs: not measured on the chip.
 """
 

@@ -231,15 +231,6 @@ def measure(steps: int = STEPS) -> dict:
             ("ops", "rollback_discarded")),
     }
 
-    result["bench"] = {
-        "steps": steps,
-        "clean_goodput_fraction": result["clean"]["goodput_fraction"],
-        "clean_badput_s": a1.get("badput_s"),
-        "clean_wall_rel_err": result["clean"]["wall_rel_err"],
-        "resume_wall_rel_err": result["sigkill"]["wall_rel_err"],
-        "restore_replay_s": result["sigkill"]["restore_replay_s"],
-        "rollback_discarded_s": result["nan"]["rollback_discarded_s"],
-    }
     return result
 
 

@@ -22,8 +22,7 @@ Run directly::
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python tools/check_profile_attrib.py
 
-or via tier-1 (tests/test_profile.py subprocess guard). bench.py runs
-it as the ``profile`` block's child; the JSON it prints is the block.
+or via tier-1 (tests/test_profile.py subprocess guard).
 """
 
 from __future__ import annotations
@@ -67,8 +66,7 @@ def _model():
 
 
 def measure(steps: int = 6, warm: int = 4) -> dict:
-    """One profiled window end to end; returns the JSON-ready report
-    (the bench ``profile`` block)."""
+    """One profiled window end to end; returns the JSON-ready report."""
     import jax
     import numpy as np
 

@@ -38,7 +38,6 @@ state + cursor agree on every loss bit):
   final checkpoint at its current step before dying with the
   standard SIGTERM status.
 
-bench.py stamps the ``bench`` sub-dict as the ``ckpt.faults`` block.
 All numbers are CPU runs: not measured on the chip.
 """
 
@@ -352,18 +351,6 @@ def measure(steps: int = STEPS) -> dict:
         "flight_classes": _flight_classes(fl4),
     }
 
-    c = result
-    result["bench"] = {
-        "steps": steps,
-        "sigkill_resume_seconds": c["sigkill"]["resume_seconds"],
-        "torn_fallback_resume_seconds": c["torn"]["resume_seconds"],
-        "nan_recovery_seconds": c["nan"]["seconds"],
-        "loss_mismatches": (len(c["sigkill"]["loss_mismatches"])
-                            + len(c["torn"]["loss_mismatches"])),
-        "nan_rollbacks": c["nan"]["rollbacks"],
-        "preemption_final_ckpt": bool(
-            c["preemption"]["final_checkpoint_steps"]),
-    }
     return result
 
 

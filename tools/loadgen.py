@@ -6,21 +6,18 @@ submits again — the standard saturating-load shape) over a caller-
 supplied feed generator, and reports per-request outcomes (latency,
 time-to-first-token, emitted tokens) alongside the session's own
 ``serve.*`` metrics. Used by ``tools/check_serve_slo.py`` (the tier-1
-SLO contract), the BENCH "serve" section (bench.py), and runnable
+SLO contract) and the other ``tools/check_*.py`` rigs, and runnable
 directly::
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python tools/loadgen.py
 
 which serves a small MLP scorer under a mixed-length load and prints
-one JSON report.
-
-**Concurrency sweep** (ISSUE 6): ``--mode decode --sweep 8,16,32,64``
-brings up one continuous-decode session per offered concurrency level
-(paged KV + chunked prefill + speculative decoding by default) and
-stamps tokens/sec and TTFT per level — the 8x-64x-concurrency claim as
-one artifact, not prose. ``sweep_decode()`` is the API bench.py stamps
-into the ``serve.continuous`` block.
+one JSON report (``--mode decode`` serves the tiny-NMT continuous-decode
+rig: paged KV + chunked prefill + speculative decoding by default).
+Off the TPU its rates and latencies are the CPU rig's: they check
+behaviour and counts, and are no statement about speed
+(``benchmark/`` makes those).
 """
 
 from __future__ import annotations
@@ -145,7 +142,7 @@ def demo_session(max_batch: int = 8, length_buckets=(16, 32),
                  dim: int = 384, layers: int = 4, max_queue: int = 128,
                  max_wait_ms: float = 2.0, default_deadline_ms=None):
     """A small-MLP one-shot scorer behind a ServeSession — the shared
-    rig of the CLI, the SLO tool and the bench serve section. Returns
+    rig of the CLI and the SLO tool. Returns
     ``(session, make_feed)``."""
     import jax
     import numpy as np
@@ -256,45 +253,6 @@ def mixed_regime_feed(Ts: int = 8, vocab: int = 256,
     return make_feed, max_new_tokens
 
 
-def demo_disagg_rig(slots: int = 4, T: int = 12, Ts: int = 8,
-                    model_dim: int = 32, num_layers: int = 2,
-                    vocab: int = 64, page_size: int = 4,
-                    max_queue: int = 4096):
-    """The disaggregation A/B fixture (bench ``serve.disagg`` block
-    and tests): a paged f32 tiny-NMT decode program plus a replica
-    factory with the prefix cache ON (the import surface). Every
-    replica shares ONE program instance, so the colocated arm, the
-    prefill pool and the decode pool all ride the same jit caches.
-    Build the colocated arm as ``ServeFleet(make_replica, ...)`` and
-    the disaggregated arm as ``DisaggFleet(make_replica,
-    make_replica, ...)`` over the same :func:`mixed_regime_feed`
-    stream (feed key ``"src"``). Returns ``make_replica``."""
-    import jax
-    import jax.numpy as jnp
-
-    import parallax_tpu as parallax
-    from parallax_tpu.models import nmt
-    from parallax_tpu.serve import NMTDecodeProgram, ServeSession
-
-    cfg = nmt.tiny_config(vocab_size=vocab, model_dim=model_dim,
-                          num_heads=4, mlp_dim=2 * model_dim,
-                          num_layers=num_layers, max_len=max(T, Ts),
-                          num_partitions=1,
-                          compute_dtype=jnp.float32)
-    params = nmt.build_model(cfg).init_fn(jax.random.PRNGKey(0))
-    prog = NMTDecodeProgram(cfg, max_src_len=Ts, max_len=T,
-                            page_size=page_size,
-                            pool_pages=slots * (T // page_size))
-    pcfg = parallax.Config(serve_config=parallax.ServeConfig(
-        max_batch=slots, max_queue=max_queue, prefix_cache=True))
-
-    def make_replica(rid, **serve_kw):
-        return ServeSession(program=prog, params=params, config=pcfg,
-                            **serve_kw)
-
-    return make_replica
-
-
 def demo_decode_session(slots: int = 16, T: int = 16, Ts: int = 8,
                         page_size: int = 4, pool_pages=None,
                         prefill_chunk_layers=1, spec_tokens: int = 2,
@@ -312,8 +270,7 @@ def demo_decode_session(slots: int = 16, T: int = 16, Ts: int = 8,
     quotas, SLO classes) off by default. Returns ``(session,
     make_feed)``; ``make_feed`` produces mixed-length sources.
     ``paged=False`` / ``speculative=False`` select the dense / plain
-    ablations (the A/B rigs of tools/nmt_decode_timing.py and the
-    sweep)."""
+    ablations (the A/B rigs of the ``tools/check_*.py`` guards)."""
     import jax
     import numpy as np
 
@@ -373,8 +330,7 @@ def demo_decode_fleet(replicas: int = 2, slots: int = 4, T: int = 12,
                       fleet_config=None, faults=None, flight=None,
                       anomaly=None, metrics=None):
     """A replicated tiny-NMT continuous-decode :class:`ServeFleet` —
-    the chaos-harness rig (tools/check_fleet_faults.py) and the bench
-    ``serve.fleet`` block.
+    the chaos-harness rig (tools/check_fleet_faults.py).
 
     Every replica is a full ServeSession (own scheduler thread, own
     queue) on its own submesh when the device count splits
@@ -450,56 +406,6 @@ def demo_decode_fleet(replicas: int = 2, slots: int = 4, T: int = 12,
     return fleet, make_feed, params, cfg
 
 
-def sweep_decode(levels=(8, 16, 32, 64), requests_per_level=None,
-                 result_timeout_s: float = 300.0, **session_kw) -> list:
-    """The concurrency sweep: one fresh continuous-decode session per
-    offered level (slots == offered closed-loop clients), tokens/sec
-    and TTFT stamped per level. Sessions are rebuilt per level so
-    every row starts from a cold queue and clean metrics; warmup
-    compiles happen at construction, OUTSIDE the measured window."""
-    from tools import serve_report
-
-    rows = []
-    for level in levels:
-        n_req = requests_per_level or max(2 * level, 16)
-        sess, make_feed = demo_decode_session(slots=level, **session_kw)
-        try:
-            rep = run_load(sess, make_feed, n_req, concurrency=level,
-                           result_timeout_s=result_timeout_s)
-            stats = sess.stats()
-            records = sess.request_records()
-        finally:
-            sess.close()
-        # trace-derived attribution (ISSUE 12): per-phase TTFT shares
-        # and the per-percentile dominant-cause report for this level
-        attribution = serve_report.analyze(records)
-        rows.append({
-            "offered_concurrency": level,
-            "slots": level,
-            "requests": n_req,
-            "completed": rep["completed"],
-            "failed": rep["failed"],
-            "tokens": rep["tokens"],
-            "tokens_per_sec": rep["tokens_per_sec"],
-            "ttft_ms": rep["ttft_ms"],
-            "latency_ms": rep["latency_ms"],
-            "qps": rep["qps"],
-            "recompiles": stats.get("serve.recompiles", 0),
-            "kv_pages_in_use_after": stats.get("serve.kv_pages_in_use"),
-            "kv_refill_deferred": stats.get("serve.kv_refill_deferred",
-                                            0),
-            "spec_accept_rate": stats.get("serve.spec_accept_rate"),
-            "decode_steps": stats.get("serve.decode_steps"),
-            "ttft_decomp": serve_report.ttft_shares(records),
-            "deadline_miss_budget_consumed":
-                serve_report.deadline_miss_budget_consumed(records),
-            "attribution": attribution,
-        })
-        print(f"# sweep level {level}: {rep['tokens_per_sec']} tok/s, "
-              f"ttft p50 {rep['ttft_ms']['p50']}ms", flush=True)
-    return rows
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--requests", type=int, default=64)
@@ -507,9 +413,6 @@ def main(argv=None) -> int:
     ap.add_argument("--deadline-ms", type=float, default=None)
     ap.add_argument("--mode", choices=("oneshot", "decode"),
                     default="oneshot")
-    ap.add_argument("--sweep", type=str, default=None,
-                    help="comma-separated offered-concurrency levels; "
-                         "decode mode only (e.g. 8,16,32,64)")
     ap.add_argument("--prefix-share", type=float, default=None,
                     help="decode mode: fraction of requests drawing "
                          "their source from a deterministic shared "
@@ -522,16 +425,6 @@ def main(argv=None) -> int:
                          "short-decode vs short-prefill/long-decode "
                          "mix with per-request decode budgets")
     args = ap.parse_args(argv)
-    if args.sweep:
-        if args.prefix_share is not None:
-            ap.error("--prefix-share is not wired into --sweep; the "
-                     "sweep prices raw concurrency (run --mode decode "
-                     "--prefix-share for the shared-prefix rig, or "
-                     "tools/check_prefix_reuse.py for the full A/B)")
-        levels = tuple(int(x) for x in args.sweep.split(","))
-        rows = sweep_decode(levels=levels)
-        print(json.dumps({"sweep": rows}, indent=2, default=str))
-        return 0 if all(r["failed"] == 0 for r in rows) else 1
     if args.mixed_regime and args.prefix_share is not None:
         ap.error("--mixed-regime and --prefix-share are separate "
                  "traffic shapes; pick one")

@@ -34,7 +34,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 def flagship_accounting(n_chips: int = 8, batch_per_chip: int = 128,
                         num_steps: int = 20, table_dtype: str = "float32",
                         dedup_capacity=None):
-    """Build the bench's flagship engine (793,470-vocab LM1B, HYBRID,
+    """Build the flagship engine (793,470-vocab LM1B, HYBRID,
     slices mode) and return its wire-bytes accounting from an abstract
     trace of one training step.
 
@@ -197,10 +197,9 @@ def pipeline_plan_section(pipeline: dict, num_devices: int = 8,
 
 
 def _demo_pipeline_record():
-    """The pipeline capability record of the tiny pipeline LM the rest
-    of the tooling (bench tune block, mesh_search_driver pp pool)
-    exercises — so --pipeline reports the same plan pool they
-    measure."""
+    """The pipeline capability record of the tiny pipeline LM that
+    tests/mesh_search_driver.py's pp pool exercises — so --pipeline
+    reports the same plan pool it measures."""
     from parallax_tpu.models import long_context as lc
     cfg = lc.tiny_config(parallelism="pipeline", num_layers=8,
                          num_microbatches=4)
