@@ -192,9 +192,34 @@ def _tile(Tk: int) -> int:
     return tk
 
 
+def _state_lanes(tk: int) -> int:
+    """How many lanes hold a row's running maximum and sum in
+    ``sparse_attn_fwd``: one lane tile where the key tile is made of
+    them, the key tile's own width where it is not."""
+    return 128 if tk % 128 == 0 else tk
+
+
+def _over_lanes(x, n: int):
+    """``x [rows, lanes]``, whose lanes all hold their row's one value,
+    over ``n`` lanes: lane tiles side by side, no broadcast out of one
+    lane."""
+    lanes = x.shape[1]
+    if n <= lanes:
+        return x[:, :n]
+    if n % lanes == 0:
+        return jnp.tile(x, (1, n // lanes))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _fwd_kernel(start_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                 m_sc, l_sc, acc_sc, *, tk: int):
-    R, C = q_ref.shape[2], q_ref.shape[3]
+    """The online softmax's state of a row lies across the lanes: the
+    running maximum ``m_sc [R, C, lanes]`` the same in every lane (what
+    a reduction over the keys leaves, so ``s - m`` and ``alpha * acc``
+    are lane tile against lane tile), the running sum ``l_sc`` as one
+    partial sum a lane, summed across the lanes once a chunk."""
+    R, C, D = q_ref.shape[2], q_ref.shape[3], q_ref.shape[4]
+    lanes = m_sc.shape[2]
     kt = pl.program_id(2)
 
     @pl.when(kt == 0)
@@ -211,26 +236,27 @@ def _fwd_kernel(start_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
             s = jnp.where(keep, jax.lax.dot_general(
                 q_ref[0, 0, r], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32), _NEG)
-            m_prev = m_sc[r]                                     # [C, 1]
+            m_prev = m_sc[r]                                     # [C, lanes]
             m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             # a row that has met no selected key yet (m_next = _NEG)
             # gathers exp(0) here; the first real key's alpha = exp(_NEG
             # - m) = 0 wipes it, and every row has its own key to meet
-            p = jnp.exp(s - m_next)
+            p = jnp.exp(s - _over_lanes(m_next, tk))
             alpha = jnp.exp(m_prev - m_next)
-            l_sc[r] = alpha * l_sc[r] + jnp.sum(p, axis=1, keepdims=True)
-            acc_sc[r] = alpha * acc_sc[r] + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            l_sc[r] = alpha * l_sc[r] + sum(
+                p[:, i:i + lanes] for i in range(0, tk, lanes))
+            acc_sc[r] = _over_lanes(alpha, D) * acc_sc[r] \
+                + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
             m_sc[r] = m_next
 
     @pl.when(kt == pl.num_programs(2) - 1)
     def _():
         for r in range(R):
-            l = jnp.maximum(l_sc[r], 1e-30)
+            l = jnp.maximum(jnp.sum(l_sc[r], axis=1, keepdims=True), 1e-30)
             o_ref[0, 0, r] = (acc_sc[r] / l).astype(o_ref.dtype)
-            lse_ref[0, 0, r] = jnp.broadcast_to(m_sc[r] + jnp.log(l),
-                                                (C, _LANES))
+            lse_ref[0, 0, r] = _over_lanes(m_sc[r], _LANES) + jnp.log(l)
 
 
 def _bwd_kernel(start_ref, q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
@@ -361,13 +387,14 @@ def _call(kernel, name, q, k, grid_order, in_keys, out_keys, out_shapes,
 def _fwd_call(q, k, v, mask, start, interpret):
     from jax.experimental.pallas import tpu as pltpu
     B, Hkv, R, C, D = q.shape
+    lanes = _state_lanes(_tile(k.shape[2]))
     return _call(
         _fwd_kernel, "sparse_attn_fwd", q, k, "bgk",
         ("q", "kv", "kv", "mask"), ("q", "row"),
         [jax.ShapeDtypeStruct(q.shape, q.dtype),
          jax.ShapeDtypeStruct((B, Hkv, R, C, _LANES), jnp.float32)],
-        [pltpu.VMEM((R, C, 1), jnp.float32),
-         pltpu.VMEM((R, C, 1), jnp.float32),
+        [pltpu.VMEM((R, C, lanes), jnp.float32),
+         pltpu.VMEM((R, C, lanes), jnp.float32),
          pltpu.VMEM((R, C, D), jnp.float32)],
         (start, q, k, v, mask), interpret)
 

@@ -215,3 +215,95 @@ def test_indexer_bwd_kernel_matches_the_vjp_of_the_scores(
     above = q_start + C
     assert float(jnp.abs(got[1][:, :above].astype(jnp.float32)).max()) > 0
     assert float(jnp.abs(got[1][:, above:].astype(jnp.float32)).max()) == 0.0
+
+
+def _check_fwd_kernel(q, k, v, sel, q_start, rtol, atol):
+    """``sparse_attn_fwd`` interpreted against the einsum executor's
+    output and the logsumexp over the selection; returns the scaled
+    logits."""
+    qs, mask, start = sa._kernel_operands(q, sel, jnp.int32(q_start))
+    out, lse = sa._fwd_call(qs, k, v, mask, start, True)
+    assert out.dtype == q.dtype and out.shape == q.shape
+    assert lse.dtype == jnp.float32 \
+        and lse.shape == q.shape[:-1] + (sa._LANES,)
+    want, _ = sa._attend_xla(q, k, v, sel)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=rtol, atol=atol)
+    logits = jnp.einsum("bgrqd,bgsd->bgrqs", qs, k,
+                        preferred_element_type=jnp.float32)
+    want_lse = jax.nn.logsumexp(
+        jnp.where(sel[:, None, None], logits, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(np.asarray(lse[..., 0]), np.asarray(want_lse),
+                               rtol=1e-5, atol=1e-5)
+    # every lane holds the row's one value
+    np.testing.assert_array_equal(
+        np.asarray(lse), np.broadcast_to(np.asarray(lse[..., :1]), lse.shape))
+    return logits
+
+
+@pytest.mark.parametrize("dtype,rtol,atol", [(jnp.float32, 1e-5, 1e-5),
+                                             (jnp.bfloat16, 2e-2, 2e-2)])
+@pytest.mark.parametrize("q_start", [0, 8, 16])
+def test_fwd_kernel_keeps_its_running_state_exactly(q_start, dtype, rtol,
+                                                    atol, monkeypatch):
+    """The kernel ``sparse_attn_fwd``, interpreted, for one chunk of 8
+    queries over 24 keys in tiles of 8 (a chunk at 0 walks one tile, at
+    8 two, at 16 three), against the einsum executor's output and the
+    logsumexp over the selection. Row 0's logits rise with the key, so
+    its maximum moves in every tile; row 1's fall, so it never moves
+    again; row 2 meets its first selected key in the second tile it
+    walks, row 3 only in its own (the last), row 4 reads its own key
+    alone; the rest select at random. The state lies across the lanes:
+    every lane of ``lse`` holds the row's one value."""
+    monkeypatch.setattr(sa, "_KEY_TILE", 8)
+    C, R = 8, 3
+    rng = np.random.default_rng(5 + q_start)
+
+    def n(*shape):
+        return rng.standard_normal(shape).astype(np.float32)
+
+    q, k, v = n(B, HKV, R, C, D), n(B, HKV, T, D), n(B, HKV, T, D)
+    # rows 0 and 1 look along one direction, along which the keys grow
+    u = np.zeros(D, np.float32)
+    u[0] = 4.0
+    q[:, :, :, 0], q[:, :, :, 1] = u, -u
+    k[..., 0] = 0.5 * (1 + np.arange(T))
+    t = q_start + np.arange(C)[:, None]
+    s = np.arange(T)[None]
+    sel = (s <= t) & (rng.random((B, C, T)) < 0.5)
+    sel[:, :2] = s <= t[:2]
+    sel[:, 2] = (s >= 8) & (s <= t[2])
+    sel[:, 3] = (s >= q_start) & (s <= t[3])
+    sel[:, 4] = False
+    sel |= s == t                       # every row has its own key
+    sel = jnp.asarray(sel)
+    q, k, v = (jnp.asarray(a).astype(dtype) for a in (q, k, v))
+
+    logits = _check_fwd_kernel(q, k, v, sel, q_start, rtol, atol)
+    # the cases the state must get right are in the selection
+    first = np.argmax(np.asarray(sel), axis=-1)             # [B, C]
+    if q_start >= 8:
+        assert (first[:, 2] == 8).all() and (first[:, 3] == q_start).all()
+    rising = np.asarray(logits[0, 0, 0, 0], np.float32)[:q_start + 1]
+    assert (np.diff(rising) > 0).all()
+
+
+@pytest.mark.parametrize("keys", [24, 192, 256])
+def test_fwd_kernel_state_follows_the_key_tile(keys):
+    """The state's width follows the key tile: one tile of 24 or of 192
+    keys holds it over its own width, one of 256 over 128 lanes (the
+    row sum as two lane tiles added, the maximum laid twice side by
+    side), as the tiles of 512 on the chip do."""
+    C, R = 8, 2
+    rng = np.random.default_rng(keys)
+    q, k, v = (jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+               for shape in ((B, HKV, R, C, D), (B, HKV, keys, D),
+                             (B, HKV, keys, D)))
+    q_start = keys - C
+    t = q_start + np.arange(C)[:, None]
+    s = np.arange(keys)[None]
+    sel = jnp.asarray(((s <= t) & (rng.random((B, C, keys)) < 0.4))
+                      | (s == t))
+    assert sa._state_lanes(sa._tile(keys)) == (128 if keys == 256 else keys)
+    _check_fwd_kernel(q, k, v, sel, q_start, 1e-5, 1e-5)

@@ -89,6 +89,34 @@ def test_sparse_attention_kernels_compile_at_published_widths(one_chip):
     assert _outside_fusions(compiled.as_text(), "[512,16,2048]") == []
 
 
+def test_sparse_attn_fwd_alone_against_the_longest_band(one_chip):
+    """The forward kernel as a chunk calls it (the queries scaled, the
+    selection cast to int8 before it) against 8,192 keys, the longest
+    band and the largest scratch: still ONE Mosaic call named
+    ``sparse_attn_fwd`` that stands outside every fusion, where
+    ``benchmark/lib/kernel_calls`` finds it (a ``kCustom`` fusion
+    wrapped around the call would hide it from that reader)."""
+    from parallax_tpu.ops import sparse_attention as sa
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fwd(q, k, v, sel, q_start):
+        qs, mask, start = sa._kernel_operands(q, sel, q_start)
+        return sa._fwd_call(qs, k, v, mask, start, False)
+
+    compiled = _compile(
+        fwd, sds((1, 4, 8, 512, 128)), sds((1, 4, 8192, 128)),
+        sds((1, 4, 8192, 128)), sds((1, 512, 8192), jnp.bool_),
+        sds((), jnp.int32))
+    text = compiled.as_text()
+    assert _kernels(compiled) == 1
+    calls = _outside_fusions(text, 'custom_call_target="tpu_custom_call"')
+    assert len(calls) == 1 and "sparse_attn_fwd" in calls[0]
+    # the rows' logsumexp leaves in the format the other kernels read
+    assert "f32[1,4,8,512,8]" in calls[0]
+
+
 def test_routed_experts_kernels_compile_at_published_widths(one_chip):
     """2,048 tokens of width 2048 through 16 held experts of width 768,
     top-8 of 128: three grouped products forward and six backward, for
