@@ -9,9 +9,9 @@ QK-norm and RoPE in three position streams (``mrope_section``), read
 through a learned top-k key selection (``sa_config``: an indexer of 16
 heads x 64 with one shared key head picks ``topk`` 2048 keys a query;
 ``ops/sparse_attention.py``); RMSNorm, a router over all 128 experts,
-top-8 with renormalised gates, and the SwiGLU experts this chip holds
-(``ops/moe.routed_experts``: ``experts_held`` experts from
-``first_expert`` on, dropless). Untied head. The vision tower is not
+top-8 with renormalised gates (``ops/moe.linear_router``), and the
+SwiGLU experts this chip holds (``ops/moe.routed_experts``:
+``experts_held`` experts from ``first_expert`` on, dropless). Untied head. The vision tower is not
 built: the published config gives none of its sizes, so batches are
 text and carry one position for all three streams (a batch may bring
 its own ``pos [B, T, 3]``).
@@ -174,16 +174,17 @@ def _layer(cfg: KeyeVL2Config, p, h, pos, impls=(None, None),
 
     with jax.named_scope("moe"):
         y = rms_norm(h, p["ln2"], eps).reshape(B * T, D)
+        route = moe_ops.linear_router(y, p["router"], cfg.experts_per_token)
         moe = moe_ops.routed_experts(
-            y, p["router"], p["w_gate"], p["w_up"], p["w_down"],
-            top_k=cfg.experts_per_token, first_expert=cfg.first_expert,
-            impl=impls[1], return_choice=collect)
+            y, route.choice, route.gate, p["w_gate"], p["w_up"],
+            p["w_down"], num_experts=cfg.num_experts,
+            first_expert=cfg.first_expert, impl=impls[1])
         h = h + moe.out.reshape(B, T, D)
-    scalars = {"indexer_loss": attn.indexer_loss, "aux_loss": moe.aux_loss,
+    scalars = {"indexer_loss": attn.indexer_loss, "aux_loss": route.aux_loss,
                "selected": attn.selected, "causal": attn.causal,
                "moe_dropped": moe.dropped, "moe_rows_here": moe.rows_here,
                "moe_load_max_over_mean": moe.load_max_over_mean}
-    extra = ({"selection": attn.selection, "expert_choice": moe.choice}
+    extra = ({"selection": attn.selection, "expert_choice": route.choice}
              if collect else None)
     return h, scalars, extra
 

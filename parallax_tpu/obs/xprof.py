@@ -78,8 +78,8 @@ _COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
 # win). A step holds the scopes of its own model only. ``layer_of``
 # reads them back off a compiled instruction's ``op_name``.
 LAYER_SCOPES = ("embedding", "lstm", "sampled_softmax", "layer_scan",
-                "attention", "indexer", "moe", "lm_head", "dense_update",
-                "table_update")
+                "attention", "indexer", "cca_mix", "moe", "router",
+                "lm_head", "dense_update", "table_update")
 # the row-sharded table path — the paper's sparse side of the
 # dense-vs-sparse variable split
 SPARSE_LAYERS = ("embedding", "sampled_softmax", "table_update")

@@ -12,17 +12,21 @@ token** and masks: exact and dropless, but E times the work, so small
 sizes only. Two-matrix ReLU experts, top-1 / top-2.
 
 ``routed_experts`` — the dropless layer of a chip that holds a range of
-the experts: the router runs over all ``E`` experts, the top-k gates are
-renormalised, and the chip computes the part of the result its own
-``[first_expert, first_expert + held)`` experts give, for exactly the
-rows routed to them, grouped by expert (a grouped matrix product:
-``megablox.gmm`` on the TPU, ``lax.ragged_dot`` elsewhere). **It never
-drops**: its buffers hold the worst case (every choice of every token),
-the products visit only the rows routed here. Three-matrix gated (SwiGLU)
-experts. What the absent experts would add is left out; on one device
-``first_expert`` is an argument, under expert parallelism it follows the
-shard index (``jax.lax.axis_index``), and the exchange that would bring
-other chips' tokens here is not part of it.
+the experts. **Routing is the caller's**: it hands over, for every
+token, the ``k`` experts it chose among all ``E`` and the gate of each
+(``linear_router`` is the usual one: one matrix, softmax, top-k,
+renormalised gates, the load-balance loss; ``models/zaya`` brings an
+MLP's ``argmax(p + bias)`` with the unrenormalised ``p`` as its gate).
+The chip computes the part of the result its own ``[first_expert,
+first_expert + held)`` experts give, for exactly the rows routed to
+them, grouped by expert (a grouped matrix product: ``megablox.gmm`` on
+the TPU, ``lax.ragged_dot`` elsewhere). **It never drops**: its buffers
+hold the worst case (every choice of every token), the products visit
+only the rows routed here. Three-matrix gated (SwiGLU) experts. What
+the absent experts would add is left out; on one device ``first_expert``
+is an argument, under expert parallelism it follows the shard index
+(``jax.lax.axis_index``), and the exchange that would bring other chips'
+tokens here is not part of it.
 
 ``switch_moe`` layout:
   * expert weights: [E, D, F] sharded P('shard', None, None) — each
@@ -200,12 +204,17 @@ def _expert_compute_dense(tokens, top_idx, gates, w1, w2):
 
 class RoutedOut(NamedTuple):
     out: jax.Array          # [B, D]: the held experts' part of the result
-    aux_loss: jax.Array     # scalar load-balance loss over all E experts
     dropped: jax.Array      # (token, choice) rows routed here that no
                             # part's grouped products covered (must be 0)
     rows_here: jax.Array    # rows routed to the held experts
     load_max_over_mean: jax.Array   # fullest held expert over their mean
-    choice: Optional[jax.Array] = None      # int [B, k] top-k, on request
+
+
+class LinearRoute(NamedTuple):
+    choice: jax.Array       # int [B, k]: the top-k experts of all E
+    gate: jax.Array         # float32 [B, k]: their probabilities,
+                            # renormalised to one
+    aux_loss: jax.Array     # scalar load-balance loss over all E experts
 
 
 # megablox tiles (rows, contraction, columns): the largest of these that
@@ -392,41 +401,57 @@ def _rows_if_bwd(lo, n, k, impl, res, g):
 _rows_if.defvjp(_rows_if_fwd, _rows_if_bwd)
 
 
+def linear_router(tokens: jax.Array,         # [B, D]
+                  router_w: jax.Array,       # [D, E]: all E experts
+                  top_k: int) -> LinearRoute:
+    """The one-matrix router: ``softmax(tokens @ router_w)`` over all
+    ``E`` experts in float32, its top-k, their probabilities
+    renormalised to one, and the load-balance loss."""
+    E = router_w.shape[1]
+    k = int(top_k)
+    if not 1 <= k <= E:
+        raise ValueError(f"top_k={k} must be in [1, {E}]")
+    probs = jax.nn.softmax(tokens.astype(jnp.float32)
+                           @ router_w.astype(jnp.float32), axis=-1)
+    top_probs, top_idx = jax.lax.top_k(probs, k)               # [B, k]
+    gates = top_probs / jnp.sum(top_probs, axis=-1, keepdims=True)
+    return LinearRoute(top_idx, gates, load_balance_loss(probs, top_idx))
+
+
 def routed_experts(tokens: jax.Array,        # [B, D]
-                   router_w: jax.Array,      # [D, E]: all E experts
+                   choice: jax.Array,        # int [B, k] among all E
+                   gate: jax.Array,          # [B, k]
                    w_gate: jax.Array,        # [held, D, F]
                    w_up: jax.Array,          # [held, D, F]
                    w_down: jax.Array,        # [held, F, D]
-                   top_k: int,
+                   num_experts: int,
                    first_expert=0,
-                   impl: Optional[str] = None,
-                   return_choice: bool = False) -> RoutedOut:
-    """Top-k routed gated experts without capacity and without drops,
-    for the experts ``[first_expert, first_expert + held)`` this chip
-    holds: ``sum_{e in top-k(t), e held} g_e * w_down[e] (silu(w_gate[e]
-    y_t) * w_up[e] y_t)`` with ``g`` the router's top-k probabilities
-    renormalised to one. The router's softmax, the gates and the
-    auxiliary loss are float32 over all ``E`` experts; the experts
-    compute in ``tokens.dtype``.
+                   impl: Optional[str] = None) -> RoutedOut:
+    """Routed gated experts without capacity and without drops, for the
+    experts ``[first_expert, first_expert + held)`` this chip holds,
+    under the caller's routing: ``sum_{c < k, choice[t, c] held}
+    gate[t, c] * w_down[e] (silu(w_gate[e] y_t) * w_up[e] y_t)`` with
+    ``e = choice[t, c]``. The gates are taken as they come (float32,
+    differentiable); ``choice`` carries no gradient. The experts compute
+    in ``tokens.dtype``.
 
     The (token, choice) rows are sorted by held expert, the rows of
     absent experts last, and are taken in two parts: the first
     ``_FAST_ROWS_FACTOR`` times a balanced router's share of the rows
-    always, the remainder only in a step whose rows reach into it.
-    ``dropped`` counts the rows routed here that neither part covered,
-    off the parts' own group sizes and the predicate the second part
-    ran under: 0 unless the split loses rows. In each part the three
-    products run as grouped matrix products over the rows routed here
-    (``impl``: ``"gmm"`` the megablox kernel, the default on a TPU;
+    (``fast_rows``, which is why ``num_experts`` is asked for) always,
+    the remainder only in a step whose rows reach into it. ``dropped``
+    counts the rows routed here that neither part covered, off the
+    parts' own group sizes and the predicate the second part ran under:
+    0 unless the split loses rows. In each part the three products run
+    as grouped matrix products over the rows routed here (``impl``:
+    ``"gmm"`` the megablox kernel, the default on a TPU;
     ``"ragged_dot"`` XLA's, the default elsewhere; ``"gmm_interpret"``
-    the kernel interpreted).
-    ``first_expert`` may be a traced scalar (a shard's index times
-    ``held``). ``return_choice`` also hands back the router's top-k
-    (for a comparison, not for training)."""
+    the kernel interpreted). ``first_expert`` may be a traced scalar (a
+    shard's index times ``held``)."""
     B, D = tokens.shape
-    E = router_w.shape[1]
+    E = int(num_experts)
     held = w_gate.shape[0]
-    k = int(top_k)
+    k = choice.shape[1]
     if not 1 <= k <= E:
         raise ValueError(f"top_k={k} must be in [1, {E}]")
     if impl is None:
@@ -434,15 +459,9 @@ def routed_experts(tokens: jax.Array,        # [B, D]
     if impl not in ("gmm", "gmm_interpret", "ragged_dot"):
         raise ValueError(f"unknown impl {impl!r}")
 
-    probs = jax.nn.softmax(tokens.astype(jnp.float32)
-                           @ router_w.astype(jnp.float32), axis=-1)
-    top_probs, top_idx = jax.lax.top_k(probs, k)               # [B, k]
-    gates = top_probs / jnp.sum(top_probs, axis=-1, keepdims=True)
-    aux_loss = load_balance_loss(probs, top_idx)
-
-    local = top_idx - first_expert
+    local = choice - first_expert
     here = (local >= 0) & (local < held)                        # [B, k]
-    weight = jnp.where(here, gates, 0.0).astype(jnp.float32)
+    weight = jnp.where(here, gate, 0.0).astype(jnp.float32)
     # absent experts' rows sort last, under the sentinel group `held`
     group = jnp.where(here, local, held).reshape(-1).astype(jnp.int32)
     order = jnp.argsort(group, stable=True).astype(jnp.int32)   # [B * k]
@@ -474,7 +493,5 @@ def routed_experts(tokens: jax.Array,        # [B, D]
 
     dropped = (rows_here - covered).astype(jnp.float32)
     mean_load = jnp.maximum(rows_here.astype(jnp.float32) / held, 1e-9)
-    return RoutedOut(out, aux_loss, dropped,
-                     rows_here.astype(jnp.float32),
-                     jnp.max(sizes).astype(jnp.float32) / mean_load,
-                     top_idx if return_choice else None)
+    return RoutedOut(out, dropped, rows_here.astype(jnp.float32),
+                     jnp.max(sizes).astype(jnp.float32) / mean_load)

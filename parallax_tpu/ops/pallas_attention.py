@@ -13,6 +13,15 @@ kernel, grid over k-tiles) flash-attention style, so the backward never
 materializes [Tq, Tk] either. Set ``xla_backward=True`` to fall back to
 the einsum-recompute backward.
 
+Grouped key/value heads: ``q`` may bring ``g`` times the heads of ``k``
+and ``v`` (query head ``h`` reads key/value head ``h // g``). No kernel
+repeats K or V in HBM: the forward and ``dq`` kernels map ``g``
+consecutive query heads onto one key/value block (fetched once for the
+group, the grid walking the heads in order), and the ``dk``/``dv``
+kernel walks a group's query heads in its last grid axis and sums their
+contributions in a float32 scratch. The three calls are named
+``flash_fwd``, ``flash_dq`` and ``flash_dkv``.
+
 On non-TPU backends the same kernels run in interpret mode (tests), so
 numerics are validated everywhere the framework runs.
 """
@@ -25,6 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 _NEG_INF = -1e30
@@ -114,12 +124,30 @@ def _snap(tile, total):
     return max(tile, 1)
 
 
+def _group(q, k) -> int:
+    """Query heads a key/value head (``[B, H, T, D]`` layouts)."""
+    Hq, Hkv = q.shape[1], k.shape[1]
+    if Hq % Hkv:
+        raise ValueError(f"{Hq} query heads do not group onto {Hkv} "
+                         f"key/value heads")
+    return Hq // Hkv
+
+
+def _params(*semantics):
+    from jax.experimental.pallas import tpu as pltpu
+    # a whole key/value head (forward, dq) or a whole query head (dkv)
+    # stays in VMEM beside its second buffer: 8 MB at 8,192 x 128 bf16
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=64 * 1024 * 1024)
+
+
 def _flash_forward(q, k, v, kv_mask, causal: bool, scale: float,
                    q_tile: int, block_k: int, interpret: bool):
-    """q, k, v: [B, H, T, D]; kv_mask: [B, Tk] int32 (1 = attendable).
-    Returns (out [B, H, T, D], lse [B, H, T])."""
+    """q: [B, Hq, T, D]; k, v: [B, Hkv, Tk, D]; kv_mask: [B, Tk] int32
+    (1 = attendable). Returns (out [B, Hq, T, D], lse [B, Hq, T])."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
+    g = _group(q, k)
     q_tile = _snap(q_tile, Tq)
     block_k = _snap(block_k, Tk)
     grid = (B, H, Tq // q_tile)
@@ -129,8 +157,8 @@ def _flash_forward(q, k, v, kv_mask, causal: bool, scale: float,
         scale=scale, q_tile=q_tile, has_mask=has_mask)
     in_specs = [
         pl.BlockSpec((1, 1, q_tile, D), lambda b, h, i: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h, 0, 0)),
+        pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h // g, 0, 0)),
+        pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h // g, 0, 0)),
     ]
     operands = [q, k, v]
     if has_mask:
@@ -151,7 +179,8 @@ def _flash_forward(q, k, v, kv_mask, causal: bool, scale: float,
             _sds((B, H, Tq, D), q.dtype, q),
             _sds((B, H, Tq, _LANES), jnp.float32, q),
         ],
-        interpret=interpret,
+        compiler_params=_params("parallel", "parallel", "parallel"),
+        name="flash_fwd", interpret=interpret,
     )(*operands)
     return out, lse[..., 0]
 
@@ -205,20 +234,31 @@ def _flash_dq_kernel(*refs, kv_len: int, block_k: int, causal: bool,
 
 
 def _flash_dkv_kernel(*refs, q_len: int, q_blk: int, causal: bool,
-                      scale: float, k_tile: int, has_mask: bool):
+                      scale: float, k_tile: int, has_mask: bool,
+                      group: int):
+    """``dk`` and ``dv`` of one key tile of one key/value head. The last
+    grid axis walks the ``group`` query heads that read this head; their
+    contributions add up in the float32 scratch and leave with the last.
+    The tile's scores are held transposed, ``[keys, queries]``, so that
+    the per-query ``lse`` and ``delta`` broadcast along sublanes from
+    lane-major rows and all four products are plain or NT."""
     if has_mask:
         (q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref) = refs
+         dk_ref, dv_ref, dk_acc, dv_acc) = refs
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-         dv_ref) = refs
+         dv_ref, dk_acc, dv_acc) = refs
         mask_ref = None
     kt = pl.program_id(2)
+    j = pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
     k = k_ref[0, 0]                                        # [kt_, D]
     v = v_ref[0, 0].astype(jnp.float32)
-    D = k.shape[-1]
-    dk = jnp.zeros((k_tile, D), jnp.float32)
-    dv = jnp.zeros((k_tile, D), jnp.float32)
     num_q = q_len // q_blk
     # Q blocks entirely before this k-tile's diagonal see none of it
     q_lo = (kt * k_tile) // q_blk if causal else 0
@@ -228,43 +268,49 @@ def _flash_dkv_kernel(*refs, q_len: int, q_blk: int, causal: bool,
         q = q_ref[0, 0, pl.dslice(qi * q_blk, q_blk), :] * scale
         do = do_ref[0, 0, pl.dslice(qi * q_blk, q_blk), :].astype(
             jnp.float32)
-        lse = lse_ref[0, 0, pl.dslice(qi * q_blk, q_blk), 0]
-        delta = delta_ref[0, 0, pl.dslice(qi * q_blk, q_blk), 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [qb, kt_]
+        lse = lse_ref[0, 0, qi]                            # [1, qb]
+        delta = delta_ref[0, 0, qi]
+        st = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [kt_, qb]
         if mask_ref is not None:
-            kv_ok = mask_ref[0, 0, :]
-            s = jnp.where(kv_ok[None, :] > 0, s, _NEG_INF)
+            st = jnp.where(mask_ref[0][:, 0:1] > 0, st, _NEG_INF)
         if causal:
-            q_pos = qi * q_blk + jax.lax.broadcasted_iota(
-                jnp.int32, (q_blk, k_tile), 0)
             k_pos = kt * k_tile + jax.lax.broadcasted_iota(
-                jnp.int32, (q_blk, k_tile), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        p = jnp.where(s > _NEG_INF / 2, p, 0.0)
+                jnp.int32, (k_tile, q_blk), 0)
+            q_pos = qi * q_blk + jax.lax.broadcasted_iota(
+                jnp.int32, (k_tile, q_blk), 1)
+            st = jnp.where(q_pos >= k_pos, st, _NEG_INF)
+        pt = jnp.exp(st - lse)
+        pt = jnp.where(st > _NEG_INF / 2, pt, 0.0)
         dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            pt, do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [kt_, D]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [qb, kt_]
-        ds = p * (dp - delta[:, None])
+        dpt = jax.lax.dot_general(
+            v, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [kt_, qb]
+        dst = pt * (dpt - delta)
         dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            dst, q.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return dk, dv
-    dk, dv = jax.lax.fori_loop(q_lo, num_q, body, (dk, dv))
-    # q was pre-scaled, so dk absorbed one factor of `scale` already
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    dk, dv = jax.lax.fori_loop(q_lo, num_q, body, (dk_acc[...], dv_acc[...]))
+    dk_acc[...] = dk
+    dv_acc[...] = dv
+
+    @pl.when(j == group - 1)
+    def _():
+        # q was pre-scaled, so dk absorbed one factor of `scale` already
+        dk_ref[0, 0] = dk.astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
 
 def _flash_backward(q, k, v, kv_mask, out, lse, g, causal, scale,
                     q_tile, block_k, interpret, dlse=None):
+    from jax.experimental.pallas import tpu as pltpu
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Hkv, Tk = k.shape[1], k.shape[2]
+    grp = _group(q, k)
     q_tile = _snap(q_tile, Tq)
     block_k = _snap(block_k, Tk)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
@@ -277,8 +323,8 @@ def _flash_backward(q, k, v, kv_mask, out, lse, g, causal, scale,
     has_mask = kv_mask is not None
     dq_specs = [
         pl.BlockSpec((1, 1, q_tile, D), lambda b, h, i: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h, 0, 0)),
+        pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h // grp, 0, 0)),
+        pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h // grp, 0, 0)),
     ]
     dq_operands = [q, k, v]
     if has_mask:
@@ -303,47 +349,69 @@ def _flash_backward(q, k, v, kv_mask, out, lse, g, causal, scale,
         out_specs=pl.BlockSpec((1, 1, q_tile, D),
                                lambda b, h, i: (b, h, i, 0)),
         out_shape=_sds((B, H, Tq, D), q.dtype, q),
-        interpret=interpret,
+        compiler_params=_params("parallel", "parallel", "parallel"),
+        name="flash_dq", interpret=interpret,
     )(*dq_operands)
 
+    # grid (batch, key/value head, key tile, query head of the group):
+    # a key tile's dk and dv leave once, after the group's last head
+    def of_q(b, hk, j, x):
+        return (b, hk * grp + x, 0, 0)
+
+    def of_row(b, hk, j, x):
+        return (b, hk * grp + x, 0, 0, 0)
+
+    def of_kv(b, hk, j, x):
+        return (b, hk, j, 0)
+
     dkv_specs = [
-        pl.BlockSpec((1, 1, Tq, D), lambda b, h, j: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h, j, 0)),
-        pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h, j, 0)),
+        pl.BlockSpec((1, 1, Tq, D), of_q),
+        pl.BlockSpec((1, 1, block_k, D), of_kv),
+        pl.BlockSpec((1, 1, block_k, D), of_kv),
     ]
     dkv_operands = [q, k, v]
     if has_mask:
-        dkv_specs.append(pl.BlockSpec((1, 1, block_k),
-                                      lambda b, h, j: (b, 0, j)))
-        dkv_operands.append(kv_mask[:, None, :])
-    dkv_specs += [
-        pl.BlockSpec((1, 1, Tq, D), lambda b, h, j: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, Tq, _LANES), lambda b, h, j: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, Tq, _LANES), lambda b, h, j: (b, h, 0, 0)),
-    ]
-    dkv_operands += [g, lse_b, delta_b]
+        # down the sublanes here: the scores are held [keys, queries]
+        dkv_specs.append(pl.BlockSpec((1, block_k, _LANES),
+                                      lambda b, hk, j, x: (b, j, 0)))
+        dkv_operands.append(jnp.broadcast_to(
+            kv_mask[:, :, None], (*kv_mask.shape, _LANES)))
+    # ... and lse/delta along the lanes, one row a query block
+    nq = Tq // q_tile
+    rows = pl.BlockSpec((1, 1, nq, 1, q_tile), of_row)
+    dkv_specs += [pl.BlockSpec((1, 1, Tq, D), of_q), rows, rows]
+    dkv_operands += [g, lse.reshape(B, H, nq, 1, q_tile),
+                     delta.reshape(B, H, nq, 1, q_tile)]
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, q_len=Tq, q_blk=q_tile,
                           causal=causal, scale=scale, k_tile=block_k,
-                          has_mask=has_mask),
-        grid=(B, H, Tk // block_k),
+                          has_mask=has_mask, group=grp),
+        grid=(B, Hkv, Tk // block_k, grp),
         in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, j: (b, h, j, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, 1, block_k, D), of_kv),
+                   pl.BlockSpec((1, 1, block_k, D), of_kv)],
         out_shape=[
-            _sds((B, H, Tk, D), k.dtype, k),
-            _sds((B, H, Tk, D), v.dtype, v),
+            _sds((B, Hkv, Tk, D), k.dtype, k),
+            _sds((B, Hkv, Tk, D), v.dtype, v),
         ],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                        pltpu.VMEM((block_k, D), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "parallel",
+                                "arbitrary"),
+        name="flash_dkv", interpret=interpret,
     )(*dkv_operands)
     return dq, dk, dv
 
 
+def _repeat_kv(q, k, v):
+    g = _group(q, k)
+    if g == 1:
+        return k, v
+    return jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
+
+
 def _xla_attention(q, k, v, kv_mask, causal, scale):
+    k, v = _repeat_kv(q, k, v)
     s = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k,
                    preferred_element_type=jnp.float32)
     if kv_mask is not None:
@@ -372,6 +440,10 @@ def _fwd_masked(q, k, v, kv_mask, causal, scale, q_tile, block_k,
                 interpret, xla_backward):
     out, lse = _flash_forward(q, k, v, kv_mask, causal, scale, q_tile,
                               block_k, interpret)
+    # what a caller's checkpoint policy may keep for the backward pass
+    # in place of running the forward kernel again
+    out = checkpoint_name(out, "flash_attn")
+    lse = checkpoint_name(lse, "flash_attn")
     return out, (q, k, v, kv_mask, out, lse)
 
 
@@ -413,6 +485,7 @@ def _fwd_lse(q, k, v, kv_mask, causal, scale, q_tile, block_k,
 
 
 def _xla_attention_lse(q, k, v, kv_mask, causal, scale):
+    k, v = _repeat_kv(q, k, v)
     s = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k,
                    preferred_element_type=jnp.float32)
     if kv_mask is not None:
@@ -486,7 +559,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     q_tile: int = 256, block_k: int = 256,
                     interpret: Optional[bool] = None,
                     xla_backward: bool = False) -> jax.Array:
-    """Fused attention: q, k, v [B, T, H, D] -> [B, T, H, D].
+    """Fused attention: q [B, T, Hq, D], k, v [B, Tk, Hkv, D] -> [B, T,
+    Hq, D]; ``Hq`` a multiple of ``Hkv`` (query head ``h`` reads
+    key/value head ``h // (Hq / Hkv)``).
 
     ``kv_mask`` [B, Tk] marks attendable key positions (padding mask for
     NMT/BERT-style models); None means all keys attend. ``interpret``

@@ -1009,6 +1009,22 @@ class ParallaxSession:
     def state(self):
         return self._state
 
+    def set_model_state(self, model_state) -> None:
+        """Replace the non-trainable state of a stateful ``Model`` (the
+        step's ``model_state``: statistics, a router's balancing biases)
+        with a tree of the same shapes, placed as the old one was. What
+        a step does not learn by gradient a caller may bring to rest
+        before training starts; parameters and optimizer state stay."""
+        old = self._state.model_state
+        if jax.tree.structure(old) != jax.tree.structure(model_state):
+            raise ValueError("model_state of another structure than the "
+                             "model's own")
+        new = jax.tree.map(
+            lambda o, n: jax.device_put(
+                np.asarray(n, o.dtype).reshape(o.shape), o.sharding),
+            old, model_state)
+        self._state = self._state.replace(model_state=new)
+
     @property
     def engine(self):
         return self._engine

@@ -45,6 +45,39 @@ def test_gathered_and_dense_use_is_dense():
     assert specs["emb"].reason == "gathered but also used densely"
 
 
+def test_a_tied_table_in_chunks_and_casts_is_still_dense():
+    """The mixed-use branch through what a real model puts around it: the
+    lookup behind the embedding op, the head behind a cast and inside a
+    rematerialised scan body. One dense use is enough; no name decides."""
+    from parallax_tpu.ops import embedding as emb_ops
+    params = {"emb": jnp.zeros((16, 8)), "w": jnp.zeros((3, 8, 8))}
+
+    def loss(params, batch):
+        h = emb_ops.embedding_lookup(params["emb"], batch["ids"])
+
+        @jax.checkpoint
+        def body(h, w):
+            return jnp.tanh(h @ w), None
+
+        h, _ = jax.lax.scan(body, h, params["w"])
+        head = params["emb"].astype(jnp.bfloat16).T
+        return jnp.sum(h.astype(jnp.bfloat16) @ head)
+
+    specs = classify_params(loss, params, _batch())
+    assert not specs["emb"].is_sparse
+    assert specs["emb"].reason == "gathered but also used densely"
+    assert specs["w"].reason == "no gather use"
+    # untied, the same table is sparse: the head's use was what decided
+    untied = {**params, "head": jnp.zeros((16, 8))}
+
+    def loss_untied(params, batch):
+        h = emb_ops.embedding_lookup(params["emb"], batch["ids"])
+        return jnp.sum(h @ params["head"].T)
+
+    specs = classify_params(loss_untied, untied, _batch())
+    assert specs["emb"].is_sparse and not specs["head"].is_sparse
+
+
 def test_gather_through_cast_is_sparse():
     params = {"emb": jnp.zeros((16, 8), jnp.bfloat16)}
 

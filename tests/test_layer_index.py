@@ -26,6 +26,9 @@ LM1B_SCOPES = ["embedding", "lstm", "sampled_softmax", "dense_update",
                "table_update"]
 KEYE_SCOPES = ["embedding", "layer_scan", "attention", "indexer", "moe",
                "lm_head", "dense_update", "table_update"]
+# no `table_update`: its tied table is the dense group's
+ZAYA_SCOPES = ["embedding", "layer_scan", "attention", "cca_mix", "moe",
+               "router", "lm_head", "dense_update"]
 
 
 def _session(**cfg_kw):
@@ -90,7 +93,8 @@ def test_every_declared_scope_is_found_in_the_keye_step():
     sess.close()
     assert index["scopes_found"] == KEYE_SCOPES
     assert [s for s in xprof.LAYER_SCOPES if s in KEYE_SCOPES] == KEYE_SCOPES
-    assert set(LM1B_SCOPES) | set(KEYE_SCOPES) == set(xprof.LAYER_SCOPES)
+    assert set(LM1B_SCOPES) | set(KEYE_SCOPES) | set(ZAYA_SCOPES) \
+        == set(xprof.LAYER_SCOPES)
     inner = {n: m for n, m in index["hlo_index"].items()
              if re.search(r"attention\)*/(.*/)?indexer", m.get("op_name", ""))}
     assert inner
@@ -101,6 +105,66 @@ def test_every_declared_scope_is_found_in_the_keye_step():
     assert scan
     assert not any(re.search(r"/(attention|indexer|moe)\)*(/|$)", o)
                    for o in scan)
+
+
+@pytest.fixture(scope="module")
+def zaya_index():
+    from parallax_tpu.models import zaya
+    cfg = zaya.tiny_config()
+    sess, *_ = parallax.parallel_run(
+        zaya.build_model(cfg),
+        parallax_config=parallax.Config(
+            run_option="HYBRID", search_partitions=False,
+            shape_buckets=[8]))
+    batch = zaya.make_batch(np.random.default_rng(0), 8, cfg.seq_len,
+                            cfg.vocab_size)
+    sess.warmup(feed_dict=batch)
+    index = sess.layer_index()
+    index["text"] = next(iter(sess.engine._executables.values())).as_text()
+    sess.close()
+    return index
+
+
+def test_every_declared_scope_is_found_in_the_zaya_step(zaya_index):
+    """ZAYA1-8B's step holds its eight scopes, in ``LAYER_SCOPES``'
+    order, and no ``table_update``: nothing of it rides the slices
+    path."""
+    assert zaya_index["scopes_found"] == ZAYA_SCOPES
+    assert [s for s in xprof.LAYER_SCOPES if s in ZAYA_SCOPES] == ZAYA_SCOPES
+
+
+@pytest.mark.parametrize("outer,inner", [("attention", "cca_mix"),
+                                         ("moe", "router")])
+def test_the_inner_scope_wins_in_the_zaya_step(zaya_index, outer, inner):
+    """``cca_mix`` is traced inside ``attention`` and ``router`` inside
+    ``moe``: their operations go by the inner name, forward and
+    backward, and the outer scope keeps operations of its own."""
+    nested = {n: m for n, m in zaya_index["hlo_index"].items()
+              if re.search(rf"{outer}\)*/(.*/)?{inner}",
+                           m.get("op_name", ""))}
+    assert nested
+    assert {zaya_index["layers"][n] for n in nested} == {inner}
+    assert any("transpose(" in m["op_name"] for m in nested.values())
+    own = [n for n, layer in zaya_index["layers"].items() if layer == outer]
+    assert own
+
+
+def test_the_scans_second_carry_lands_under_layer_scan(zaya_index):
+    """The router's state ``r`` rides the scan's carry beside the
+    stream: its zeros, and what the loop does with the carry, sit under
+    ``layer_scan``, not under no scope."""
+    scan = {n: m for n, m in zaya_index["hlo_index"].items()
+            if zaya_index["layers"][n] == "layer_scan"}
+    assert scan
+    assert not any(re.search(r"/(attention|cca_mix|moe|router)\)*(/|$)",
+                             m["op_name"]) for m in scan.values())
+    # the carry as each of the two layers received it, kept for the
+    # backward pass in a stack the loop writes: [layers, tokens a device
+    # (8 x 16 over 8), router_hidden_size]
+    kept = re.findall(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = f32\[2,16,8\]\S* "
+                      r"dynamic-update-slice\(", zaya_index["text"], re.M)
+    assert kept
+    assert {zaya_index["layers"][n] for n in kept} == {"layer_scan"}
 
 
 def test_table_scatter_maps_to_table_update(warmed):
@@ -230,6 +294,11 @@ def test_lax_scan_branch_carries_the_lstm_scope():
     ("jit(train_step)/table_update/jit(_unique_sorted_mask)/sort",
      "table_update", "sparse"),
     ("jit(train_step)/dense_update/mul", "dense_update", "dense"),
+    ("jit(train_step)/layer_scan/while/body/checkpoint/attention/cca_mix/"
+     "dot_general", "cca_mix", "dense"),
+    ("jit(train_step)/transpose(jvp(layer_scan))/while/body/checkpoint/moe/"
+     "router/erf", "router", "dense"),
+    ("jit(train_step)/jvp(moe)/jvp(router)/sub", "router", "dense"),
     # a primitive or a user's scope that merely contains a layer's name
     ("jit(train_step)/jvp(my_lstm_block)/dot_general", None, None),
     ("jit(train_step)/model/embedding_norm/mul", None, None),

@@ -159,6 +159,19 @@ def _routed_weights(rng, held=RE):
         n(held, RF, RD)
 
 
+def _routed(tokens, router, w_gate, w_up, w_down, top_k, first_expert=0,
+            impl=None):
+    """``linear_router`` and ``routed_experts`` under its choice: the
+    layer as ``models/keye_vl2`` calls it."""
+    import types
+    route = moe.linear_router(tokens, router, top_k)
+    got = moe.routed_experts(tokens, route.choice, route.gate, w_gate, w_up,
+                             w_down, num_experts=router.shape[1],
+                             first_expert=first_expert, impl=impl)
+    return types.SimpleNamespace(aux_loss=route.aux_loss, choice=route.choice,
+                                 gate=route.gate, **got._asdict())
+
+
 def _routed_plain(tokens, router, w_gate, w_up, w_down, k, first=0):
     """Every held expert for every token, then the token's own gates."""
     probs = jax.nn.softmax(tokens @ router, -1)
@@ -175,7 +188,7 @@ def _routed_plain(tokens, router, w_gate, w_up, w_down, k, first=0):
 @pytest.mark.parametrize("k", [1, 2, 4])
 def test_routed_experts_match_every_expert_for_every_token(tokens, rng, k):
     router, wg, wu, wd = _routed_weights(rng)
-    got = moe.routed_experts(tokens, router, wg, wu, wd, top_k=k)
+    got = _routed(tokens, router, wg, wu, wd, top_k=k)
     want = _routed_plain(tokens, router, wg, wu, wd, k)
     np.testing.assert_allclose(np.asarray(got.out), np.asarray(want),
                                rtol=2e-5, atol=2e-6)
@@ -187,8 +200,8 @@ def test_the_shares_of_a_layer_add_up_to_the_whole(tokens, rng):
     """Four chips holding two experts each: their parts sum to what one
     chip holding all eight computes; the router is counted once."""
     router, wg, wu, wd = _routed_weights(rng)
-    whole = moe.routed_experts(tokens, router, wg, wu, wd, top_k=3)
-    parts = [moe.routed_experts(tokens, router, wg[f:f + 2], wu[f:f + 2],
+    whole = _routed(tokens, router, wg, wu, wd, top_k=3)
+    parts = [_routed(tokens, router, wg[f:f + 2], wu[f:f + 2],
                                 wd[f:f + 2], top_k=3, first_expert=f)
              for f in range(0, RE, 2)]
     np.testing.assert_allclose(
@@ -197,7 +210,7 @@ def test_the_shares_of_a_layer_add_up_to_the_whole(tokens, rng):
     assert sum(float(p.rows_here) for p in parts) == B * 3
     assert all(float(p.aux_loss) == float(whole.aux_loss) for p in parts)
     # a traced first_expert (a shard's index) gives the same part
-    traced = jax.jit(lambda f: moe.routed_experts(
+    traced = jax.jit(lambda f: _routed(
         tokens, router, wg[2:4], wu[2:4], wd[2:4], top_k=3,
         first_expert=f).out)(jnp.int32(2))
     np.testing.assert_allclose(np.asarray(traced), np.asarray(parts[1].out),
@@ -213,7 +226,7 @@ def test_routed_experts_drop_nothing_under_a_skewed_router(tokens, rng):
     pad = lambda w: jnp.concatenate(
         [w, jnp.zeros((w.shape[0], 1, w.shape[2]))], axis=1)
     wd_p = jnp.concatenate([wd, jnp.zeros((RE, RF, 1))], axis=2)
-    got = moe.routed_experts(bias_tokens, skew, pad(wg), pad(wu), wd_p,
+    got = _routed(bias_tokens, skew, pad(wg), pad(wu), wd_p,
                              top_k=2)
     want = _routed_plain(bias_tokens, skew, pad(wg), pad(wu), wd_p, 2)
     np.testing.assert_allclose(np.asarray(got.out), np.asarray(want),
@@ -222,7 +235,7 @@ def test_routed_experts_drop_nothing_under_a_skewed_router(tokens, rng):
     assert float(got.rows_here) == 2 * B
     assert float(got.load_max_over_mean) == pytest.approx(RE / 2)
     # the chip that holds neither of the two computes nothing, drops nothing
-    idle = moe.routed_experts(bias_tokens, skew, pad(wg)[2:4], pad(wu)[2:4],
+    idle = _routed(bias_tokens, skew, pad(wg)[2:4], pad(wu)[2:4],
                               wd_p[2:4], top_k=2, first_expert=2)
     assert float(idle.rows_here) == 0.0 and float(idle.dropped) == 0.0
     assert float(jnp.abs(idle.out).max()) == 0.0
@@ -237,7 +250,7 @@ def test_routed_experts_gradients_match(tokens, rng):
         return jax.grad(f, argnums=(0, 1, 2, 3, 4))(tokens, router, wg, wu,
                                                     wd)
 
-    got = loss(lambda t, r, a, b, c: moe.routed_experts(
+    got = loss(lambda t, r, a, b, c: _routed(
         t, r, a, b, c, top_k=3, first_expert=2).out)
     want = loss(lambda t, r, a, b, c: _routed_plain(t, r, a, b, c, 3, 2))
     for g, w in zip(got, want):
@@ -251,9 +264,9 @@ def test_routed_experts_kernel_path_interpreted(rng):
     toks = jnp.asarray(rng.standard_normal((64, RD)).astype(np.float32))
     router, wg, wu, wd = _routed_weights(rng, held=4)
     args = (toks, router, wg, wu, wd)
-    ref = moe.routed_experts(*args, top_k=2, first_expert=4,
+    ref = _routed(*args, top_k=2, first_expert=4,
                              impl="ragged_dot")
-    got = moe.routed_experts(*args, top_k=2, first_expert=4,
+    got = _routed(*args, top_k=2, first_expert=4,
                              impl="gmm_interpret")
     np.testing.assert_allclose(np.asarray(got.out), np.asarray(ref.out),
                                rtol=2e-5, atol=2e-6)
@@ -263,9 +276,9 @@ def test_routed_experts_kernel_path_interpreted(rng):
 def test_routed_experts_reject_bad_arguments(tokens, rng):
     router, wg, wu, wd = _routed_weights(rng)
     with pytest.raises(ValueError, match="top_k"):
-        moe.routed_experts(tokens, router, wg, wu, wd, top_k=RE + 1)
+        _routed(tokens, router, wg, wu, wd, top_k=RE + 1)
     with pytest.raises(ValueError, match="impl"):
-        moe.routed_experts(tokens, router, wg, wu, wd, top_k=2, impl="x")
+        _routed(tokens, router, wg, wu, wd, top_k=2, impl="x")
 
 
 @pytest.mark.parametrize("skewed", [False, True])
@@ -282,13 +295,13 @@ def test_routed_experts_rows_past_the_fast_part(rng, skewed):
         toks = toks.at[:, 0].set(4.0)
         router = router.at[0, 3].set(9.0)
     args = (toks, router, wg, wu, wd)
-    got = moe.routed_experts(*args, top_k=2, first_expert=3)
+    got = _routed(*args, top_k=2, first_expert=3)
     want = _routed_plain(*args, 2, 3)
     np.testing.assert_allclose(np.asarray(got.out), np.asarray(want),
                                rtol=2e-5, atol=2e-6)
     assert float(got.dropped) == 0.0
     assert (float(got.rows_here) > 512) == skewed
-    grads = jax.grad(lambda *a: jnp.sum(moe.routed_experts(
+    grads = jax.grad(lambda *a: jnp.sum(_routed(
         *a, top_k=2, first_expert=3).out ** 2), argnums=(0, 1, 2, 4))(*args)
     plain = jax.grad(lambda *a: jnp.sum(_routed_plain(*a, 2, 3) ** 2),
                      argnums=(0, 1, 2, 4))(*args)
@@ -320,7 +333,7 @@ def test_dropped_counts_rows_that_no_part_covered(rng, monkeypatch):
     router, wg, wu, wd = _routed_weights(rng, held=1)
     router = router.at[0, 3].set(9.0)
     args = (toks, router, wg, wu, wd)
-    sound = moe.routed_experts(*args, top_k=2, first_expert=3)
+    sound = _routed(*args, top_k=2, first_expert=3)
     assert float(sound.rows_here) > 512 and float(sound.dropped) == 0.0
     # each part leaves the last 128 of its rows out: the first part's
     # [384, 512) are live and lost, the second's lie past the live rows
@@ -328,5 +341,82 @@ def test_dropped_counts_rows_that_no_part_covered(rng, monkeypatch):
     monkeypatch.setattr(
         moe, "_part_sizes",
         lambda sizes, ends, lo, n: whole(sizes, ends, lo, max(n - 128, 0)))
-    lossy = moe.routed_experts(*args, top_k=2, first_expert=3)
+    lossy = _routed(*args, top_k=2, first_expert=3)
     assert float(lossy.dropped) == 128.0
+
+
+def test_routed_experts_under_a_given_choice_are_the_old_entry(rng):
+    """``routed_experts`` used to route for itself (softmax of one
+    matrix, top-k, renormalised gates). Under that routing, handed in,
+    it gives what it gave: bit for bit the old prologue's numbers here,
+    and the parent commit's own outputs on these inputs as recorded."""
+    gen = np.random.default_rng(31)
+    n, first, k = 96, 2, 2
+
+    def draw(*shape, scale=0.2):
+        return jnp.asarray(gen.standard_normal(shape).astype(np.float32)
+                           ) * scale
+    toks, router = draw(n, RD, scale=1.0), draw(RD, RE, scale=0.7)
+    wg, wu, wd = draw(4, RD, RF), draw(4, RD, RF), draw(4, RF, RD)
+
+    def value(t, r, a, b, c, by_hand):
+        if by_hand:
+            # the four lines the old entry began with
+            probs = jax.nn.softmax(t.astype(jnp.float32)
+                                   @ r.astype(jnp.float32), axis=-1)
+            top_p, choice = jax.lax.top_k(probs, k)
+            gate = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            aux = moe.load_balance_loss(probs, choice)
+        else:
+            choice, gate, aux = moe.linear_router(t, r, k)
+        out = moe.routed_experts(t, choice, gate, a, b, c, num_experts=RE,
+                                 first_expert=first)
+        return jnp.sum(out.out * jnp.cos(jnp.arange(RD))) + 0.1 * aux, out
+
+    run = jax.jit(jax.value_and_grad(value, argnums=(0, 1, 2, 3, 4),
+                                     has_aux=True), static_argnums=5)
+    (v_new, out_new), g_new = run(toks, router, wg, wu, wd, False)
+    (v_old, out_old), g_old = run(toks, router, wg, wu, wd, True)
+    assert float(v_new) == float(v_old)
+    np.testing.assert_array_equal(np.asarray(out_new.out),
+                                  np.asarray(out_old.out))
+    for a, b in zip(g_new, g_old):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the parent commit's entry on the same inputs (bit-equal on the
+    # machine that recorded them; another CPU may add in another order)
+    assert float(v_new) == pytest.approx(7.438641548156738, rel=1e-6)
+    assert float(out_new.rows_here) == 105.0
+    assert float(out_new.load_max_over_mean) == pytest.approx(
+        1.409523844718933, rel=1e-6)
+    sums = [float(jnp.sum(g)) for g in g_new]
+    np.testing.assert_allclose(
+        sums, [-5.355449676513672, -2.652406692504883e-06,
+               1.0701560974121094, -46.37874984741211, -0.9740736484527588],
+        rtol=2e-5, atol=2e-6)
+
+
+def test_routed_experts_take_an_unrenormalised_top1_gate(tokens, rng):
+    """A caller's own routing (``models/zaya``: one expert a token, the
+    gate its probability as it is): the gate scales the expert's output
+    and takes its gradient; a choice carries none."""
+    router, wg, wu, wd = _routed_weights(rng)
+    probs = jax.nn.softmax(tokens @ router, -1)
+    choice = jnp.argmax(probs, -1, keepdims=True).astype(jnp.int32)
+
+    def f(scale):
+        gate = jnp.take_along_axis(probs, choice, -1) * scale
+        return moe.routed_experts(tokens, choice, gate, wg, wu, wd,
+                                  num_experts=RE)
+    one, half = f(1.0), f(0.5)
+    np.testing.assert_allclose(np.asarray(half.out), 0.5 * np.asarray(one.out),
+                               rtol=1e-6, atol=1e-7)
+    assert float(one.rows_here) == B and float(one.dropped) == 0.0
+    act = jax.nn.silu(jnp.einsum("nd,edf->nef", tokens, wg)) \
+        * jnp.einsum("nd,edf->nef", tokens, wu)
+    each = jnp.einsum("nef,efd->ned", act, wd)
+    want = jnp.take_along_axis(each, choice[..., None], 1)[:, 0] \
+        * jnp.take_along_axis(probs, choice, -1)
+    np.testing.assert_allclose(np.asarray(one.out), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+    d_scale = jax.grad(lambda s: jnp.sum(f(s).out))(1.0)
+    assert float(d_scale) == pytest.approx(float(jnp.sum(one.out)), rel=1e-4)

@@ -1,6 +1,7 @@
-"""The Mosaic kernels of the Keye-VL-2.0 step, compiled at the
-published widths by the TPU's own compiler against a described v5e (no
-chip is attached, nothing runs): what interpret mode cannot show: a
+"""The Mosaic kernels of the Keye-VL-2.0 and ZAYA1-8B steps (and the
+latter's whole step, for its peak memory), compiled at the published
+widths by the TPU's own compiler against a described v5e (no chip is
+attached, nothing runs): what interpret mode cannot show: a
 block that is not aligned to the tiling, more VMEM than a kernel may
 take, a transposed product Mosaic refuses.
 
@@ -128,9 +129,10 @@ def test_routed_experts_kernels_compile_at_published_widths(one_chip):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def loss(tokens, router, w_gate, w_up, w_down):
+        route = moe.linear_router(tokens, router, 8)
         return jnp.sum(moe.routed_experts(
-            tokens, router, w_gate, w_up, w_down, top_k=8,
-            impl="gmm").out.astype(jnp.float32))
+            tokens, route.choice, route.gate, w_gate, w_up, w_down,
+            num_experts=128, impl="gmm").out.astype(jnp.float32))
 
     compiled = _compile(
         jax.grad(loss, argnums=(0, 2, 3, 4)),
@@ -140,3 +142,88 @@ def test_routed_experts_kernels_compile_at_published_widths(one_chip):
     assert _kernels(compiled) == 18
     # no product over tokens x experts held
     assert "[2048,16,768]" not in compiled.as_text()
+
+
+def test_flash_kernels_compile_with_grouped_heads_at_8k(one_chip):
+    """8 query heads on 2 key/value heads of 128 against 8,192 causal
+    keys in tiles of 512: the dense flash kernels' forward, ``dq`` and
+    ``dk``/``dv`` pass Mosaic under their names, and no K or V of the
+    query heads' count is in memory."""
+    from parallax_tpu.ops.pallas_attention import flash_attention
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, q_tile=512, block_k=512,
+            interpret=False).astype(jnp.float32))
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                        sds(1, 8192, 8, 128), sds(1, 8192, 2, 128),
+                        sds(1, 8192, 2, 128))
+    text = compiled.as_text()
+    assert _kernels(compiled) == 3
+    calls = _outside_fusions(text, 'custom_call_target="tpu_custom_call"')
+    assert [next(n for n in ("flash_fwd", "flash_dq", "flash_dkv")
+                 if n in c.split(" = ")[0]) for c in calls] \
+        == ["flash_fwd", "flash_dq", "flash_dkv"]
+    # dk and dv leave at the key/value heads' count
+    assert "bf16[1,2,8192,128]" in calls[2]
+    assert "bf16[1,8,8192,128]" in calls[1]
+
+
+def test_zaya_step_compiles_at_published_widths_and_fits(topo):
+    """ZAYA1-8B's training step as the benchmark's cell runs it (6
+    layers, 8 of 16 experts, 32,784 rows, one sequence of 8,192; every
+    width as published) through ``Engine`` for the described v5e: the
+    flash and grouped-product kernels pass Mosaic inside the scan's
+    body, the tied table is the dense group's, and the executable's
+    peak (``peak_memory_in_bytes``: parameters, moments and the
+    compiler's temporaries) is under 15.5 GB of the chip's 16.9."""
+    import numpy as np
+    import parallax_tpu as parallax
+    from parallax_tpu.core import engine as engine_lib, mesh as mesh_lib
+    from parallax_tpu.models import zaya
+
+    dev = topo.devices[0]
+    one = SingleDeviceSharding(dev)
+    cfg = zaya.ZayaConfig(vocab_size=32784, num_layers=6, experts_held=8,
+                          warmup_steps=1000, num_partitions=1)
+    model = zaya.build_model(cfg, impls=("flash", "gmm"))
+    mesh = mesh_lib.build_mesh(devices=[dev], num_partitions=1)
+    batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+             for k, v in zaya.make_batch(np.random.default_rng(0), 1,
+                                         cfg.seq_len, cfg.vocab_size).items()}
+    engine = engine_lib.Engine(model, mesh,
+                               parallax.Config(run_option="HYBRID"), batch)
+    spec = engine.plan.var_specs["emb"]
+    assert not spec.is_sparse
+    assert spec.reason == "gathered but also used densely"
+    state = jax.eval_shape(engine._init_jit,
+                           jax.ShapeDtypeStruct((), jnp.int32))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    with mesh:
+        compiled = engine._step_jit.trace(on_chip(state), on_chip(batch)) \
+            .lower(lowering_platforms=("tpu",)).compile()
+    memory = compiled.memory_analysis()
+    peak = memory.peak_memory_in_bytes
+    print(f"zaya1-8b step: peak_memory_in_bytes {peak / 1e9:.2f} GB "
+          f"(arguments {memory.argument_size_in_bytes / 1e9:.2f}, "
+          f"temporaries {memory.temp_size_in_bytes / 1e9:.2f})")
+    params = sum(int(np.prod(s.shape))
+                 for s in jax.tree.leaves(state.params))
+    assert params == pytest.approx(708e6, rel=2e-3)
+    assert 4.23e9 < peak < 15.5e9
+    text = compiled.as_text()
+    names = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    kinds = sorted({n.rsplit(".", 1)[0] for n in names})
+    assert kinds == ["flash_dkv", "flash_dq", "flash_fwd", "gmm", "tgmm"]
+    # neither every expert for every token nor whole float32 scores
+    assert "[8192,8,2048]" not in text
+    assert not re.search(r"f32\[(1,)?8192,8192\]", text)
