@@ -28,7 +28,9 @@ Training: the embedding is a gather-only table on the engine's slices
 path (``SliceAdam``); everything else Adam behind a global-norm clip;
 bfloat16 compute on float32 weights, with the router, the indexer's
 scores, every softmax and every norm's statistics in float32; each
-layer rematerialised, the layers under one ``lax.scan``. The loss is
+layer rematerialised, the layers under one ``lax.scan`` over their
+stacked parameters, whose matrices are cast to bfloat16 before the loop
+(``in_compute_dtype``). The loss is
 the cross-entropy plus ``router_aux_loss_coef`` x the load-balance loss
 plus ``indexer_loss_weight`` x the indexer's KL loss, which alone
 reaches the indexer's weights (its input is cut off by
@@ -93,6 +95,11 @@ class KeyeVL2Config:
                                         self.num_partitions)
 
 
+# the layers' leaves that a block multiplies in the compute dtype
+MATRICES = ("wq", "wk", "wv", "wo", "idx_wq", "idx_wk", "idx_ww", "w_gate",
+            "w_up", "w_down")
+
+
 def tiny_config(**kw) -> KeyeVL2Config:
     defaults = dict(vocab_size=96, model_dim=32, num_layers=2, num_heads=4,
                     num_kv_heads=2, head_dim=16, mrope_section=(2, 2, 4),
@@ -135,6 +142,20 @@ def rope3(x, pos, theta: float, section):
     x2 = x[..., n:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
+
+
+def in_compute_dtype(layers, names, dtype):
+    """The stacked ``layers`` with the leaves ``names`` cast to ``dtype``
+    whole, before the loop over the blocks (a block's own ``.astype`` of
+    them is then a no-op). The cast's transposition stands outside the
+    loop with it: the backward loop writes those leaves' gradient stacks
+    in ``dtype``, as the products made them, and the optimizer's fusions
+    read them through the cast back to float32; with the cast inside
+    the block each layer's piece was widened first and its stack
+    zero-filled, written and read in float32. The same cast of the same
+    float32 weight, and the same values in the gradient."""
+    return {k: v.astype(dtype) if k in names else v
+            for k, v in layers.items()}
 
 
 def _layer(cfg: KeyeVL2Config, p, h, pos, impls=(None, None),
@@ -255,11 +276,13 @@ def build_model(cfg: KeyeVL2Config, impls=(None, None)) -> Model:
 
         body = jax.checkpoint(
             lambda h, p: _layer(cfg, p, h, pos, impls)[:2], policy=policy)
-        # the scan's own operations (a layer's weights cut out of the
-        # stack, its kept arrays and its gradients written into theirs,
-        # the loop) go by this name; inside a block its layers' names win
+        # the scan's own operations (the matrices' cast, a layer's
+        # weights cut out of the stack, its kept arrays and its gradients
+        # written into theirs, the loop) go by this name; inside a block
+        # its layers' names win
         with jax.named_scope("layer_scan"):
-            h, per_layer = jax.lax.scan(body, h, params["layers"])
+            h, per_layer = jax.lax.scan(
+                body, h, in_compute_dtype(params["layers"], MATRICES, dt))
         s = jax.tree.map(lambda a: jnp.mean(a.astype(jnp.float32)),
                          per_layer)
 

@@ -57,7 +57,9 @@ compute on float32 weights, with the router (projection, carry, MLP,
 softmax, gate), every norm's statistics, RoPE's angles and every softmax
 in float32; each layer rematerialised, keeping the attention's output
 and logsumexp and the experts' row buffers so that no kernel runs a
-second time.
+second time; the layers' matrices are cast to bfloat16 before the
+``lax.scan`` (``models/keye_vl2.in_compute_dtype``), so that their
+gradient stacks leave the backward loop in bfloat16.
 
 Not built (departures, ``benchmark/configs/zaya1-8b.json``): a router
 output that skips the layer, and learned scales on the residual stream.
@@ -79,7 +81,7 @@ import numpy as np
 import optax
 
 from parallax_tpu.core.engine import Model
-from parallax_tpu.models.keye_vl2 import rms_norm
+from parallax_tpu.models.keye_vl2 import in_compute_dtype, rms_norm
 from parallax_tpu.ops import embedding as emb_ops
 from parallax_tpu.ops import moe as moe_ops
 from parallax_tpu.ops import pallas_attention as pa
@@ -123,6 +125,11 @@ class ZayaConfig:
     def padded_vocab(self) -> int:
         return emb_ops.padded_vocab_for(self.vocab_size,
                                         self.num_partitions)
+
+
+# the layers' leaves that a block multiplies in the compute dtype
+MATRICES = ("wq", "wk", "wv1", "wv2", "wo", "conv1_w", "w_gate", "w_up",
+            "w_down")
 
 
 def tiny_config(**kw) -> ZayaConfig:
@@ -373,12 +380,14 @@ def forward(cfg: ZayaConfig, params, beta, batch, impls=(None, None)):
     scanned = jax.checkpoint(
         scanned, policy=jax.checkpoint_policies.save_only_these_names(
             "flash_attn", "moe_rows"))
-    # the scan's own operations (a layer's weights cut out of the stack,
-    # its kept arrays and gradients written into theirs, the carry `r`)
-    # go by this name; inside a block its layers' names win
+    # the scan's own operations (the matrices' cast, a layer's weights
+    # cut out of the stack, its kept arrays and gradients written into
+    # theirs, the carry `r`) go by this name; inside a block its layers'
+    # names win
     with jax.named_scope("layer_scan"):
+        layers = in_compute_dtype(params["layers"], MATRICES, dt)
         (h, _), (scalars, picked) = jax.lax.scan(
-            scanned, (h, r0), (params["layers"], beta, forced))
+            scanned, (h, r0), (layers, beta, forced))
 
     with jax.named_scope("lm_head"):
         hidden = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)

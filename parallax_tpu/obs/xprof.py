@@ -72,10 +72,10 @@ _COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
 # (``_layer``: norm, router, grouping, the experts' products, the
 # combine, the auxiliary loss) and ``lm_head`` (``loss_fn``: final
 # norm, head, cross-entropy), with ``layer_scan`` around ``loss_fn``'s
-# ``lax.scan`` over the blocks for what the scan itself costs (a
-# layer's weights cut out of the stack, its kept arrays and gradients
-# written into theirs, the loop; the outermost, so a block's own names
-# win). A step holds the scopes of its own model only. ``layer_of``
+# ``lax.scan`` over the blocks for what the scan itself costs (the
+# matrices' cast to the compute dtype before the loop, a layer's
+# weights cut out of the stack, its kept arrays and gradients written
+# into theirs, the loop; the outermost, so a block's own names win). A step holds the scopes of its own model only. ``layer_of``
 # reads them back off a compiled instruction's ``op_name``.
 LAYER_SCOPES = ("embedding", "lstm", "sampled_softmax", "layer_scan",
                 "attention", "indexer", "cca_mix", "moe", "router",

@@ -4,6 +4,7 @@ three parts, every position's NLL, every gradient, what layer 0
 selects; the three-stream RoPE; the chip's share of the experts; the
 model through ``parallel_run``."""
 
+import collections
 import dataclasses
 import importlib.util
 import os
@@ -93,6 +94,91 @@ def test_every_gradient_matches_the_reference(ref):
         np.testing.assert_allclose(np.asarray(cut[k]),
                                    np.asarray(want["layers"][k]),
                                    rtol=1e-5, atol=1e-8)
+
+
+def _scanned_loss(cfg, params, batch):
+    """The model's loss with the blocks under a ``lax.scan`` over the
+    float32 stacks as they are, each under the model's own
+    ``jax.checkpoint``: the reference of the one test below. ``(loss,
+    per-layer scalars)``."""
+    B, T = batch["x"].shape
+    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :, None],
+                           (B, T, 3))
+    body = jax.checkpoint(
+        lambda h, p: kv._layer(cfg, p, h, pos)[:2],
+        policy=jax.checkpoint_policies.save_only_these_names(
+            "sparse_attn_chunk", "moe_rows"))
+    h, per_layer = jax.lax.scan(
+        body, jnp.take(params["emb"], batch["x"], axis=0), params["layers"])
+    hidden = kv.rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+    logits = hidden.reshape(B * T, -1) @ params["head"]
+    logits = jnp.where(jnp.arange(logits.shape[-1]) < cfg.vocab_size,
+                       logits, -jnp.inf)
+    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits),
+                               batch["y"].reshape(B * T, 1), axis=-1)
+    w = batch["w"].reshape(B * T)
+    lm_loss = jnp.sum(nll[:, 0] * w) / jnp.sum(w)
+    loss = (lm_loss
+            + cfg.router_aux_loss_coef * jnp.mean(per_layer["aux_loss"])
+            + cfg.indexer_loss_weight
+            * jnp.mean(per_layer["indexer_loss"]) / (B * T))
+    return loss, {**per_layer, "lm_loss": lm_loss}
+
+
+def test_the_blocks_equal_a_plain_scan_and_the_stacks_keep_their_layout():
+    """Loss, its parts, what the gauges read and every leaf's gradient
+    of ``loss_fn`` against the same ``_layer`` under a ``lax.scan``, to
+    float32 round-off; ``params["layers"]`` and its gradient stay
+    stacked ``[L, ...]`` leaf by leaf."""
+    cfg, model, params, batch = _setup(seed=5, num_layers=3)
+    L, (B, T) = cfg.num_layers, batch["x"].shape
+    (loss, metrics), grads = jax.jit(jax.value_and_grad(
+        lambda p: model.loss_fn(p, batch, None), has_aux=True))(params)
+    (want, s), want_grads = jax.jit(jax.value_and_grad(
+        lambda p: _scanned_loss(cfg, p, batch), has_aux=True))(params)
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
+    for key, read in {
+            "lm_loss": s["lm_loss"], "aux_loss": jnp.mean(s["aux_loss"]),
+            "indexer_loss": jnp.mean(s["indexer_loss"]) / (B * T),
+            "moe_dropped": jnp.max(s["moe_dropped"]),
+            "moe_rows_here": jnp.mean(s["moe_rows_here"]),
+            "moe_load_max_over_mean": jnp.mean(s["moe_load_max_over_mean"]),
+            "attn_selected_share":
+                jnp.sum(s["selected"]) / jnp.sum(s["causal"])}.items():
+        np.testing.assert_allclose(float(metrics[key]), float(read),
+                                   rtol=1e-6, err_msg=key)
+    assert set(metrics) == {"lm_loss", "aux_loss"} | {
+        out if isinstance(out, str) else out[0]
+        for out in model.gauges.values()}
+    assert jax.tree.structure(grads) == jax.tree.structure(params)
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    for (path, got), ref_g in zip(flat, jax.tree.leaves(want_grads)):
+        scale = float(jnp.abs(ref_g).max())
+        assert scale > 0, path
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(ref_g), rtol=1e-4,
+            atol=1e-5 * scale, err_msg=jax.tree_util.keystr(path))
+    for name, leaf in params["layers"].items():
+        assert leaf.shape[0] == L, name
+        assert grads["layers"][name].shape == leaf.shape, name
+        assert grads["layers"][name].dtype == jnp.float32, name
+
+
+def test_the_matrices_gradient_stacks_leave_the_loop_in_bfloat16():
+    """The matrices' cast stands before the loop, so the backward scan
+    hands their gradient stacks out in the compute dtype; every other
+    leaf's in float32."""
+    cfg, model, params, batch = _setup(compute_dtype=jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: model.loss_fn(p, batch, None)[0]))(params)
+    backward, = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"
+                 and e.params["reverse"]]
+    stacks = backward.outvars[backward.params["num_carry"]:]
+    want = collections.Counter(
+        (leaf.shape, "bfloat16" if name in kv.MATRICES else "float32")
+        for name, leaf in params["layers"].items())
+    assert collections.Counter((v.aval.shape, str(v.aval.dtype))
+                               for v in stacks) == want
 
 
 def test_indexer_learns_from_its_loss_alone(ref):

@@ -5,6 +5,7 @@ routers; the causality of the value shift and of both convolutions; the
 chip's share of the experts; the tied table on the dense side; the
 balancing biases; the model through ``parallel_run``."""
 
+import collections
 import dataclasses
 import importlib.util
 import os
@@ -108,6 +109,96 @@ def test_a_fed_choice_takes_the_routers_place_on_both_sides(ref):
         np.asarray(scalars["load"]),
         np.stack([np.bincount(np.asarray(o).ravel(),
                               minlength=cfg.num_experts) for o in other]))
+
+
+def _scanned_loss(cfg, params, beta, batch):
+    """The model's loss with the blocks under a ``lax.scan`` over the
+    float32 stacks as they are, each under the model's own
+    ``jax.checkpoint``: the reference of the one test below. ``(loss,
+    per-layer scalars)``."""
+    B, T = batch["x"].shape
+    h = jnp.take(params["emb"], batch["x"], axis=0) * np.sqrt(cfg.model_dim)
+    r0 = jnp.zeros((B * T, cfg.router_hidden_size), jnp.float32)
+
+    def body(carry, xs):
+        carry, scalars, _ = zaya._layer(cfg, *xs, *carry)
+        return carry, scalars
+
+    body = jax.checkpoint(
+        body, policy=jax.checkpoint_policies.save_only_these_names(
+            "flash_attn", "moe_rows"))
+    (h, _), scalars = jax.lax.scan(body, (h.astype(cfg.compute_dtype), r0),
+                                   (params["layers"], beta))
+    hidden = zaya.rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+    logits = hidden.reshape(B * T, -1) @ params["emb"].T
+    logits = jnp.where(jnp.arange(logits.shape[-1]) < cfg.vocab_size,
+                       logits, -jnp.inf)
+    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits),
+                               batch["y"].reshape(B * T, 1), axis=-1)
+    w = batch["w"].reshape(B * T)
+    return jnp.sum(nll[:, 0] * w) / jnp.sum(w), scalars
+
+
+def test_the_blocks_equal_a_plain_scan_and_the_stacks_keep_their_layout():
+    """Loss, what the gauges read, the biases' step and every leaf's
+    gradient of ``loss_fn`` against the same ``_layer`` under a
+    ``lax.scan``, to float32 round-off; ``params["layers"]`` and its
+    gradient stay stacked ``[L, ...]`` leaf by leaf."""
+    cfg, model, params, beta, batch = _setup(seed=5, num_layers=3)
+    L = cfg.num_layers
+
+    def system(p):
+        loss, metrics, state = model.loss_fn(p, {"beta": beta}, batch, None)
+        return loss, (metrics, state)
+
+    (loss, (metrics, state)), grads = jax.jit(jax.value_and_grad(
+        system, has_aux=True))(params)
+    (want, s), want_grads = jax.jit(jax.value_and_grad(
+        lambda p: _scanned_loss(cfg, p, beta, batch), has_aux=True))(params)
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
+    new_beta = zaya.balance_step(cfg, beta, s["load"])
+    np.testing.assert_array_equal(np.asarray(state["beta"]),
+                                  np.asarray(new_beta))
+    for key, read in {
+            "lm_loss": want, "moe_dropped": jnp.max(s["moe_dropped"]),
+            "moe_rows_here": jnp.mean(s["moe_rows_here"]),
+            "moe_load_max_over_mean": jnp.mean(s["moe_load_max_over_mean"]),
+            "router_gate_mean": jnp.mean(s["gate_mean"]),
+            "router_bias_abs_max": jnp.max(jnp.abs(new_beta))}.items():
+        np.testing.assert_allclose(float(metrics[key]), float(read),
+                                   rtol=1e-6, err_msg=key)
+    assert set(metrics) == {"lm_loss"} | {
+        out if isinstance(out, str) else out[0]
+        for out in model.gauges.values()}
+    assert jax.tree.structure(grads) == jax.tree.structure(params)
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    for (path, got), ref_g in zip(flat, jax.tree.leaves(want_grads)):
+        scale = float(jnp.abs(ref_g).max())
+        assert scale > 0, path
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(ref_g), rtol=1e-4,
+            atol=1e-5 * scale, err_msg=jax.tree_util.keystr(path))
+    for name, leaf in params["layers"].items():
+        assert leaf.shape[0] == L, name
+        assert grads["layers"][name].shape == leaf.shape, name
+        assert grads["layers"][name].dtype == jnp.float32, name
+
+
+def test_the_matrices_gradient_stacks_leave_the_loop_in_bfloat16():
+    """The matrices' cast stands before the loop, so the backward scan
+    hands their gradient stacks out in the compute dtype; every other
+    leaf's in float32."""
+    cfg, model, params, beta, batch = _setup(compute_dtype=jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: model.loss_fn(p, {"beta": beta}, batch, None)[0]))(params)
+    backward, = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"
+                 and e.params["reverse"]]
+    stacks = backward.outvars[backward.params["num_carry"]:]
+    want = collections.Counter(
+        (leaf.shape, "bfloat16" if name in zaya.MATRICES else "float32")
+        for name, leaf in params["layers"].items())
+    assert collections.Counter((v.aval.shape, str(v.aval.dtype))
+                               for v in stacks) == want
 
 
 def test_the_routers_state_is_carried_from_layer_to_layer():
