@@ -75,11 +75,17 @@ _COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
 # ``lax.scan`` over the blocks for what the scan itself costs (the
 # matrices' cast to the compute dtype before the loop, a layer's
 # weights cut out of the stack, its kept arrays and gradients written
-# into theirs, the loop; the outermost, so a block's own names win). A step holds the scopes of its own model only. ``layer_of``
-# reads them back off a compiled instruction's ``op_name``.
+# into theirs, the loop; the outermost, so a block's own names win);
+# ``window_attention`` is ops/pallas_attention's, around the windowed
+# flash calls of a layer that attends under a window, forward and
+# backward (inside a block's ``attention``, where it wins: models/mellum2
+# has both kinds of layer under one loop body, and the account tells a
+# window layer's kernels from a full layer's by it). A step holds the
+# scopes of its own model only. ``layer_of`` reads them back off a
+# compiled instruction's ``op_name``.
 LAYER_SCOPES = ("embedding", "lstm", "sampled_softmax", "layer_scan",
-                "attention", "indexer", "cca_mix", "moe", "router",
-                "lm_head", "dense_update", "table_update")
+                "attention", "window_attention", "indexer", "cca_mix",
+                "moe", "router", "lm_head", "dense_update", "table_update")
 # the row-sharded table path — the paper's sparse side of the
 # dense-vs-sparse variable split
 SPARSE_LAYERS = ("embedding", "sampled_softmax", "table_update")

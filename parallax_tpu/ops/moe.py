@@ -223,8 +223,8 @@ class LinearRoute(NamedTuple):
 def _gmm_tiling(m: int, k: int, n: int):
     def fit(size, choices):
         return next((c for c in choices if size % c == 0), size)
-    return (fit(m, (512, 256, 128)), fit(k, (1024, 768, 512, 256, 128)),
-            fit(n, (1024, 768, 512, 256, 128)))
+    wide = (1024, 896, 768, 512, 256, 128)
+    return fit(m, (512, 256, 128)), fit(k, wide), fit(n, wide)
 
 
 # The sorted rows are taken in two parts: the first, this many times a
@@ -403,17 +403,25 @@ _rows_if.defvjp(_rows_if_fwd, _rows_if_bwd)
 
 def linear_router(tokens: jax.Array,         # [B, D]
                   router_w: jax.Array,       # [D, E]: all E experts
-                  top_k: int) -> LinearRoute:
+                  top_k: int,
+                  choice: Optional[jax.Array] = None) -> LinearRoute:
     """The one-matrix router: ``softmax(tokens @ router_w)`` over all
     ``E`` experts in float32, its top-k, their probabilities
-    renormalised to one, and the load-balance loss."""
+    renormalised to one, and the load-balance loss. A given ``choice``
+    (int ``[B, k]``) takes the top-k's place: its experts' probabilities
+    renormalised, its loads in the loss (a comparison under one
+    routing)."""
     E = router_w.shape[1]
     k = int(top_k)
     if not 1 <= k <= E:
         raise ValueError(f"top_k={k} must be in [1, {E}]")
     probs = jax.nn.softmax(tokens.astype(jnp.float32)
                            @ router_w.astype(jnp.float32), axis=-1)
-    top_probs, top_idx = jax.lax.top_k(probs, k)               # [B, k]
+    if choice is None:
+        top_probs, top_idx = jax.lax.top_k(probs, k)           # [B, k]
+    else:
+        top_idx = choice
+        top_probs = jnp.take_along_axis(probs, choice, axis=-1)
     gates = top_probs / jnp.sum(top_probs, axis=-1, keepdims=True)
     return LinearRoute(top_idx, gates, load_balance_loss(probs, top_idx))
 

@@ -22,6 +22,23 @@ kernel walks a group's query heads in its last grid axis and sums their
 contributions in a float32 scratch. The three calls are named
 ``flash_fwd``, ``flash_dq`` and ``flash_dkv``.
 
+A window (``window=W`` with ``causal=True``): query ``t`` reads key
+``s`` iff ``0 <= t - s < W``, the band behind the causal diagonal. The
+kernels walk the band's tiles only: the forward and ``dq`` loops start
+at the first key tile the query tile still sees, the ``dk``/``dv`` loop
+ends at the last query tile that still sees the key tile, and each loop
+runs in three consecutive ranges (``_key_ranges``, ``_query_ranges``):
+the tiles on the band's edge (compared against both bounds), the tiles
+wholly inside (no compare, no mask) and the diagonal's (the causal
+compare, as without a window). Any positive ``W`` is legal. The windowed
+calls are named ``flash_fwd_win``, ``flash_dq_win`` and
+``flash_dkv_win`` and traced under the scope ``window_attention``, so
+that a trace tells them from a full layer's; with a traced ``window_on``
+a ``cond`` inside the ``custom_vjp``'s forward and backward chooses
+between the two kinds' calls (six names in one program, each once), and
+one loop body serves layers of both kinds. Without a window nothing of
+this is traced.
+
 On non-TPU backends the same kernels run in interpret mode (tests), so
 numerics are validated everywhere the framework runs.
 """
@@ -51,9 +68,53 @@ _NEG_INF = -1e30
 # boundary).
 _LANES = 8
 
+# the scope a windowed call sits under, forward and backward (one of
+# ``obs/xprof.LAYER_SCOPES``: inside a model's ``attention`` it wins)
+WINDOW_SCOPE = "window_attention"
+
+
+def _tile_ok(q_pos, k_pos, diag: bool, band: bool, window):
+    """Which (query, key) places of a tile are attended: not past the
+    diagonal (``diag``) and less than ``window`` behind it (``band``)."""
+    ok = None
+    if diag:
+        ok = q_pos >= k_pos
+    if band:
+        near = q_pos - k_pos < window
+        ok = near if ok is None else ok & near
+    return ok
+
+
+def _key_ranges(q0, q_tile: int, block_k: int, window: int, num_k):
+    """The key tiles of the query tile that starts at ``q0``, under a
+    window, as three consecutive ranges ``[first, inside)``, ``[inside,
+    diag)``, ``[diag, num_k)``: the band's edge (tiles some query of the
+    tile sees in part only, compared against both bounds), the tiles
+    every query sees whole (no compare) and the diagonal's (the causal
+    compare). A window under a tile has no middle range: its tiles are
+    all edge or diagonal."""
+    first = jnp.maximum(q0 - window + 1, 0) // block_k
+    inside = jnp.maximum(q0 + q_tile - window + block_k - 1, 0) // block_k
+    inside = jnp.clip(inside, first, num_k)
+    diag = jnp.clip((q0 + 1) // block_k, inside, num_k)
+    return first, inside, diag
+
+
+def _query_ranges(k0, k_tile: int, q_blk: int, window: int, q_lo, num_q):
+    """The query blocks that see the key tile starting at ``k0``, under
+    a window: ``[q_lo, diag)`` on the diagonal (the causal compare),
+    ``[diag, band)`` seeing it whole, ``[band, last)`` on the band's
+    edge (both compares); ``last`` is the first block that sees none of
+    it any more."""
+    last = jnp.minimum(num_q, (k0 + k_tile + window - 2) // q_blk + 1)
+    band = jnp.clip((k0 + window + q_blk) // q_blk - 1, q_lo, last)
+    diag = jnp.clip((k0 + k_tile + q_blk - 2) // q_blk, q_lo, band)
+    return diag, band, last
+
 
 def _flash_fwd_kernel(*refs, kv_len: int, block_k: int, causal: bool,
-                      scale: float, q_tile: int, has_mask: bool):
+                      scale: float, q_tile: int, has_mask: bool,
+                      window: Optional[int] = None):
     if has_mask:
         q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref = refs
     else:
@@ -75,7 +136,7 @@ def _flash_fwd_kernel(*refs, kv_len: int, block_k: int, causal: bool,
         num_k = jnp.minimum(
             num_k, ((qt + 1) * q_tile + block_k - 1) // block_k)
 
-    def body(kt, carry):
+    def body(kt, carry, diag=causal, band=False, masked=True):
         m, l, acc = carry
         k_blk = k_ref[0, 0, pl.dslice(kt * block_k, block_k), :]
         v_blk = v_ref[0, 0, pl.dslice(kt * block_k, block_k), :]
@@ -85,23 +146,40 @@ def _flash_fwd_kernel(*refs, kv_len: int, block_k: int, causal: bool,
         if mask_ref is not None:
             kv_ok = mask_ref[0, 0, pl.dslice(kt * block_k, block_k)]
             s = jnp.where(kv_ok[None, :] > 0, s, _NEG_INF)
-        if causal:
+        if diag or band:
             q_pos = qt * q_tile + jax.lax.broadcasted_iota(
                 jnp.int32, (q_tile, block_k), 0)
             k_pos = kt * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (q_tile, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = jnp.where(_tile_ok(q_pos, k_pos, diag, band, window), s,
+                          _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         alpha = jnp.exp(jnp.minimum(m - m_new, 0.0))
         p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(s > _NEG_INF / 2, p, 0.0)
+        if masked:
+            p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         l = l * alpha + p.sum(axis=-1)
         acc = acc * alpha[:, None] + jax.lax.dot_general(
             p, v_blk.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return m_new, l, acc
 
-    m, l, acc = jax.lax.fori_loop(0, num_k, body, (m, l, acc))
+    carry = (m, l, acc)
+    if window is None:
+        m, l, acc = jax.lax.fori_loop(0, num_k, body, carry)
+    else:
+        # the loop starts at the first key tile the query tile still
+        # sees; only the tiles on the band's edge or on the diagonal pay
+        # for a compare
+        first, inside, diag = _key_ranges(qt * q_tile, q_tile, block_k,
+                                          window, num_k)
+        carry = jax.lax.fori_loop(
+            first, inside, functools.partial(body, diag=True, band=True),
+            carry)
+        carry = jax.lax.fori_loop(
+            inside, diag,
+            functools.partial(body, diag=False, masked=has_mask), carry)
+        m, l, acc = jax.lax.fori_loop(diag, num_k, body, carry)
     o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
     lse_ref[0, 0] = jax.lax.broadcast_in_dim(
         m + jnp.log(jnp.maximum(l, 1e-30)), (q_tile, _LANES), (0,))
@@ -133,6 +211,12 @@ def _group(q, k) -> int:
     return Hq // Hkv
 
 
+def _named(kernel: str, window) -> str:
+    """The call's name: a windowed call carries ``_win`` behind it, so
+    that a trace tells a window layer's kernels from a full layer's."""
+    return kernel if window is None else kernel + "_win"
+
+
 def _params(*semantics):
     from jax.experimental.pallas import tpu as pltpu
     # a whole key/value head (forward, dq) or a whole query head (dkv)
@@ -141,10 +225,37 @@ def _params(*semantics):
                                 vmem_limit_bytes=64 * 1024 * 1024)
 
 
+def _by_kind(window, window_on, call):
+    """``call(window)`` made for the kind of attention this call site
+    runs: without a ``window`` the plain call; with one the windowed
+    call under the scope ``window_attention``; with a traced
+    ``window_on`` beside it a ``cond`` between the two, each built
+    once."""
+    if window is None:
+        return call(None)
+
+    def banded():
+        with jax.named_scope(WINDOW_SCOPE):
+            return call(window)
+
+    if window_on is None:
+        return banded()
+    return jax.lax.cond(window_on, banded, lambda: call(None))
+
+
 def _flash_forward(q, k, v, kv_mask, causal: bool, scale: float,
-                   q_tile: int, block_k: int, interpret: bool):
+                   q_tile: int, block_k: int, interpret: bool,
+                   window=None, window_on=None):
     """q: [B, Hq, T, D]; k, v: [B, Hkv, Tk, D]; kv_mask: [B, Tk] int32
     (1 = attendable). Returns (out [B, Hq, T, D], lse [B, Hq, T])."""
+    def call(window):
+        return _forward_call(q, k, v, kv_mask, causal, scale, q_tile,
+                             block_k, interpret, window)
+    return _by_kind(window, window_on, call)
+
+
+def _forward_call(q, k, v, kv_mask, causal, scale, q_tile, block_k,
+                  interpret, window):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     g = _group(q, k)
@@ -154,7 +265,7 @@ def _flash_forward(q, k, v, kv_mask, causal: bool, scale: float,
     has_mask = kv_mask is not None
     kernel = functools.partial(
         _flash_fwd_kernel, kv_len=Tk, block_k=block_k, causal=causal,
-        scale=scale, q_tile=q_tile, has_mask=has_mask)
+        scale=scale, q_tile=q_tile, has_mask=has_mask, window=window)
     in_specs = [
         pl.BlockSpec((1, 1, q_tile, D), lambda b, h, i: (b, h, i, 0)),
         pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h // g, 0, 0)),
@@ -180,13 +291,14 @@ def _flash_forward(q, k, v, kv_mask, causal: bool, scale: float,
             _sds((B, H, Tq, _LANES), jnp.float32, q),
         ],
         compiler_params=_params("parallel", "parallel", "parallel"),
-        name="flash_fwd", interpret=interpret,
+        name=_named("flash_fwd", window), interpret=interpret,
     )(*operands)
     return out, lse[..., 0]
 
 
 def _flash_dq_kernel(*refs, kv_len: int, block_k: int, causal: bool,
-                     scale: float, q_tile: int, has_mask: bool):
+                     scale: float, q_tile: int, has_mask: bool,
+                     window: Optional[int] = None):
     if has_mask:
         (q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
          dq_ref) = refs
@@ -205,7 +317,7 @@ def _flash_dq_kernel(*refs, kv_len: int, block_k: int, causal: bool,
         num_k = jnp.minimum(
             num_k, ((qt + 1) * q_tile + block_k - 1) // block_k)
 
-    def body(kt, dq):
+    def body(kt, dq, diag=causal, band=False, masked=True):
         k_blk = k_ref[0, 0, pl.dslice(kt * block_k, block_k), :]
         v_blk = v_ref[0, 0, pl.dslice(kt * block_k, block_k), :]
         s = jax.lax.dot_general(
@@ -214,14 +326,16 @@ def _flash_dq_kernel(*refs, kv_len: int, block_k: int, causal: bool,
         if mask_ref is not None:
             kv_ok = mask_ref[0, 0, pl.dslice(kt * block_k, block_k)]
             s = jnp.where(kv_ok[None, :] > 0, s, _NEG_INF)
-        if causal:
+        if diag or band:
             q_pos = qt * q_tile + jax.lax.broadcasted_iota(
                 jnp.int32, (q_tile, block_k), 0)
             k_pos = kt * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (q_tile, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = jnp.where(_tile_ok(q_pos, k_pos, diag, band, window), s,
+                          _NEG_INF)
         p = jnp.exp(s - lse[:, None])
-        p = jnp.where(s > _NEG_INF / 2, p, 0.0)
+        if masked:
+            p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         dp = jax.lax.dot_general(
             do, v_blk.astype(jnp.float32), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [qt, bk]
@@ -229,13 +343,25 @@ def _flash_dq_kernel(*refs, kv_len: int, block_k: int, causal: bool,
         return dq + jax.lax.dot_general(
             ds, k_blk.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-    dq = jax.lax.fori_loop(0, num_k, body, dq)
+    if window is None:
+        dq = jax.lax.fori_loop(0, num_k, body, dq)
+    else:
+        # the forward kernel's three ranges
+        first, inside, diag = _key_ranges(qt * q_tile, q_tile, block_k,
+                                          window, num_k)
+        dq = jax.lax.fori_loop(
+            first, inside, functools.partial(body, diag=True, band=True),
+            dq)
+        dq = jax.lax.fori_loop(
+            inside, diag,
+            functools.partial(body, diag=False, masked=has_mask), dq)
+        dq = jax.lax.fori_loop(diag, num_k, body, dq)
     dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _flash_dkv_kernel(*refs, q_len: int, q_blk: int, causal: bool,
                       scale: float, k_tile: int, has_mask: bool,
-                      group: int):
+                      group: int, window: Optional[int] = None):
     """``dk`` and ``dv`` of one key tile of one key/value head. The last
     grid axis walks the ``group`` query heads that read this head; their
     contributions add up in the float32 scratch and leave with the last.
@@ -263,7 +389,7 @@ def _flash_dkv_kernel(*refs, q_len: int, q_blk: int, causal: bool,
     # Q blocks entirely before this k-tile's diagonal see none of it
     q_lo = (kt * k_tile) // q_blk if causal else 0
 
-    def body(qi, carry):
+    def body(qi, carry, diag=causal, band=False, masked=True):
         dk, dv = carry
         q = q_ref[0, 0, pl.dslice(qi * q_blk, q_blk), :] * scale
         do = do_ref[0, 0, pl.dslice(qi * q_blk, q_blk), :].astype(
@@ -275,14 +401,16 @@ def _flash_dkv_kernel(*refs, q_len: int, q_blk: int, causal: bool,
             preferred_element_type=jnp.float32)            # [kt_, qb]
         if mask_ref is not None:
             st = jnp.where(mask_ref[0][:, 0:1] > 0, st, _NEG_INF)
-        if causal:
+        if diag or band:
             k_pos = kt * k_tile + jax.lax.broadcasted_iota(
                 jnp.int32, (k_tile, q_blk), 0)
             q_pos = qi * q_blk + jax.lax.broadcasted_iota(
                 jnp.int32, (k_tile, q_blk), 1)
-            st = jnp.where(q_pos >= k_pos, st, _NEG_INF)
+            st = jnp.where(_tile_ok(q_pos, k_pos, diag, band, window), st,
+                           _NEG_INF)
         pt = jnp.exp(st - lse)
-        pt = jnp.where(st > _NEG_INF / 2, pt, 0.0)
+        if masked:
+            pt = jnp.where(st > _NEG_INF / 2, pt, 0.0)
         dv = dv + jax.lax.dot_general(
             pt, do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [kt_, D]
@@ -294,7 +422,22 @@ def _flash_dkv_kernel(*refs, q_len: int, q_blk: int, causal: bool,
             dst, q.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return dk, dv
-    dk, dv = jax.lax.fori_loop(q_lo, num_q, body, (dk_acc[...], dv_acc[...]))
+    carry = (dk_acc[...], dv_acc[...])
+    if window is None:
+        dk, dv = jax.lax.fori_loop(q_lo, num_q, body, carry)
+    else:
+        # the loop ends at the last query block that still sees the key
+        # tile; only the blocks on the diagonal or on the band's edge
+        # pay for a compare
+        diag, band, last = _query_ranges(kt * k_tile, k_tile, q_blk, window,
+                                         q_lo, num_q)
+        carry = jax.lax.fori_loop(q_lo, diag, body, carry)
+        carry = jax.lax.fori_loop(
+            diag, band,
+            functools.partial(body, diag=False, masked=has_mask), carry)
+        dk, dv = jax.lax.fori_loop(
+            band, last, functools.partial(body, diag=True, band=True),
+            carry)
     dk_acc[...] = dk
     dv_acc[...] = dv
 
@@ -306,19 +449,29 @@ def _flash_dkv_kernel(*refs, q_len: int, q_blk: int, causal: bool,
 
 
 def _flash_backward(q, k, v, kv_mask, out, lse, g, causal, scale,
-                    q_tile, block_k, interpret, dlse=None):
-    from jax.experimental.pallas import tpu as pltpu
-    B, H, Tq, D = q.shape
-    Hkv, Tk = k.shape[1], k.shape[2]
-    grp = _group(q, k)
-    q_tile = _snap(q_tile, Tq)
-    block_k = _snap(block_k, Tk)
+                    q_tile, block_k, interpret, dlse=None, window=None,
+                    window_on=None):
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                               # [B, H, Tq]
     if dlse is not None:
         # lse cotangent folds into the existing kernels exactly:
         # d s = p*(dp - delta) + dlse*p = p*(dp - (delta - dlse))
         delta = delta - dlse.astype(jnp.float32)
+
+    def call(window):
+        return _backward_calls(q, k, v, kv_mask, lse, delta, g, causal,
+                               scale, q_tile, block_k, interpret, window)
+    return _by_kind(window, window_on, call)
+
+
+def _backward_calls(q, k, v, kv_mask, lse, delta, g, causal, scale, q_tile,
+                    block_k, interpret, window):
+    from jax.experimental.pallas import tpu as pltpu
+    B, H, Tq, D = q.shape
+    Hkv, Tk = k.shape[1], k.shape[2]
+    grp = _group(q, k)
+    q_tile = _snap(q_tile, Tq)
+    block_k = _snap(block_k, Tk)
 
     has_mask = kv_mask is not None
     dq_specs = [
@@ -343,14 +496,14 @@ def _flash_backward(q, k, v, kv_mask, out, lse, g, causal, scale,
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, kv_len=Tk, block_k=block_k,
                           causal=causal, scale=scale, q_tile=q_tile,
-                          has_mask=has_mask),
+                          has_mask=has_mask, window=window),
         grid=(B, H, Tq // q_tile),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, 1, q_tile, D),
                                lambda b, h, i: (b, h, i, 0)),
         out_shape=_sds((B, H, Tq, D), q.dtype, q),
         compiler_params=_params("parallel", "parallel", "parallel"),
-        name="flash_dq", interpret=interpret,
+        name=_named("flash_dq", window), interpret=interpret,
     )(*dq_operands)
 
     # grid (batch, key/value head, key tile, query head of the group):
@@ -385,7 +538,7 @@ def _flash_backward(q, k, v, kv_mask, out, lse, g, causal, scale,
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, q_len=Tq, q_blk=q_tile,
                           causal=causal, scale=scale, k_tile=block_k,
-                          has_mask=has_mask, group=grp),
+                          has_mask=has_mask, group=grp, window=window),
         grid=(B, Hkv, Tk // block_k, grp),
         in_specs=dkv_specs,
         out_specs=[pl.BlockSpec((1, 1, block_k, D), of_kv),
@@ -398,7 +551,7 @@ def _flash_backward(q, k, v, kv_mask, out, lse, g, causal, scale,
                         pltpu.VMEM((block_k, D), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "parallel",
                                 "arbitrary"),
-        name="flash_dkv", interpret=interpret,
+        name=_named("flash_dkv", window), interpret=interpret,
     )(*dkv_operands)
     return dq, dk, dv
 
@@ -410,8 +563,9 @@ def _repeat_kv(q, k, v):
     return jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
 
 
-def _xla_attention(q, k, v, kv_mask, causal, scale):
-    k, v = _repeat_kv(q, k, v)
+def _xla_scores(q, k, kv_mask, causal, scale, window=None):
+    """Float32 scores ``[B, H, T, Tk]`` with everything unattended at
+    ``_NEG_INF``; ``window`` may be a traced scalar."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k,
                    preferred_element_type=jnp.float32)
     if kv_mask is not None:
@@ -419,7 +573,16 @@ def _xla_attention(q, k, v, kv_mask, causal, scale):
     if causal:
         T, Tk = q.shape[2], k.shape[2]
         mask = jnp.tril(jnp.ones((T, Tk), bool))
+        if window is not None:
+            behind = jnp.arange(T)[:, None] - jnp.arange(Tk)[None, :]
+            mask = mask & (behind < window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
+    return s
+
+
+def _xla_attention(q, k, v, kv_mask, causal, scale, window=None):
+    k, v = _repeat_kv(q, k, v)
+    s = _xla_scores(q, k, kv_mask, causal, scale, window)
     p = jax.nn.softmax(s, axis=-1)
     # fully-masked rows: zero the uniform softmax so outputs and grads
     # match the Pallas kernels (which emit exact zeros there)
@@ -428,72 +591,80 @@ def _xla_attention(q, k, v, kv_mask, causal, scale):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash_attention_masked(q, k, v, kv_mask, causal, scale, q_tile,
-                            block_k, interpret, xla_backward):
+def _xla_window(window, window_on, Tk: int):
+    """The window the einsum fallbacks mask by: none, the call's, or
+    under a traced ``window_on`` the call's or every key."""
+    if window is None or window_on is None:
+        return window
+    return jnp.where(window_on, window, Tk)
+
+
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+def _flash_attention_masked(q, k, v, kv_mask, window_on, causal, scale,
+                            q_tile, block_k, interpret, xla_backward, window):
     out, _ = _flash_forward(q, k, v, kv_mask, causal, scale, q_tile,
-                            block_k, interpret)
+                            block_k, interpret, window, window_on)
     return out
 
 
-def _fwd_masked(q, k, v, kv_mask, causal, scale, q_tile, block_k,
-                interpret, xla_backward):
+def _fwd_masked(q, k, v, kv_mask, window_on, causal, scale, q_tile,
+                block_k, interpret, xla_backward, window):
     out, lse = _flash_forward(q, k, v, kv_mask, causal, scale, q_tile,
-                              block_k, interpret)
+                              block_k, interpret, window, window_on)
     # what a caller's checkpoint policy may keep for the backward pass
     # in place of running the forward kernel again
     out = checkpoint_name(out, "flash_attn")
     lse = checkpoint_name(lse, "flash_attn")
-    return out, (q, k, v, kv_mask, out, lse)
+    return out, (q, k, v, kv_mask, window_on, out, lse)
+
+
+def _no_cotangent(x):
+    return None if x is None else np.zeros(x.shape, dtype=jax.dtypes.float0)
 
 
 def _bwd_masked(causal, scale, q_tile, block_k, interpret, xla_backward,
-                res, g):
-    q, k, v, kv_mask, out, lse = res
+                window, res, g):
+    q, k, v, kv_mask, window_on, out, lse = res
     if xla_backward:
+        masked_by = _xla_window(window, window_on, k.shape[2])
         _, vjp = jax.vjp(
             lambda q, k, v: _xla_attention(q, k, v, kv_mask, causal,
-                                           scale), q, k, v)
+                                           scale, masked_by), q, k, v)
         dq, dk, dv = vjp(g)
     else:
         dq, dk, dv = _flash_backward(q, k, v, kv_mask, out, lse, g,
                                      causal, scale, q_tile, block_k,
-                                     interpret)
-    mask_ct = (None if kv_mask is None else
-               np.zeros(kv_mask.shape, dtype=jax.dtypes.float0))
-    return dq, dk, dv, mask_ct
+                                     interpret, window=window,
+                                     window_on=window_on)
+    return dq, dk, dv, _no_cotangent(kv_mask), _no_cotangent(window_on)
 
 
 _flash_attention_masked.defvjp(_fwd_masked, _bwd_masked)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash_attention_with_lse(q, k, v, kv_mask, causal, scale, q_tile,
-                              block_k, interpret, xla_backward):
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+def _flash_attention_with_lse(q, k, v, kv_mask, window_on, causal, scale,
+                              q_tile, block_k, interpret, xla_backward,
+                              window):
     """(out, lse) variant — the composition surface for ring attention:
     per-block partial softmaxes merge exactly from (out, lse) pairs, and
     the lse cotangent is a delta-shift in the unchanged backward kernels."""
     return _flash_forward(q, k, v, kv_mask, causal, scale, q_tile,
-                          block_k, interpret)
+                          block_k, interpret, window, window_on)
 
 
-def _fwd_lse(q, k, v, kv_mask, causal, scale, q_tile, block_k,
-             interpret, xla_backward):
+def _fwd_lse(q, k, v, kv_mask, window_on, causal, scale, q_tile, block_k,
+             interpret, xla_backward, window):
     out, lse = _flash_forward(q, k, v, kv_mask, causal, scale, q_tile,
-                              block_k, interpret)
-    return (out, lse), (q, k, v, kv_mask, out, lse)
+                              block_k, interpret, window, window_on)
+    return (out, lse), (q, k, v, kv_mask, window_on, out, lse)
 
 
-def _xla_attention_lse(q, k, v, kv_mask, causal, scale):
+def _xla_attention_lse(q, k, v, kv_mask, causal, scale, window=None):
     k, v = _repeat_kv(q, k, v)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k,
-                   preferred_element_type=jnp.float32)
-    if kv_mask is not None:
-        s = jnp.where(kv_mask[:, None, None, :] > 0, s, _NEG_INF)
-    if causal:
-        T, Tk = q.shape[2], k.shape[2]
-        mask = jnp.tril(jnp.ones((T, Tk), bool))
-        s = jnp.where(mask[None, None], s, _NEG_INF)
+    s = _xla_scores(q, k, kv_mask, causal, scale, window)
     lse = jax.nn.logsumexp(s, axis=-1)
     # clamp so fully-masked rows (lse == -inf) yield 0, not exp(nan)
     p = jnp.exp(s - jnp.maximum(lse, _NEG_INF)[..., None])
@@ -503,25 +674,48 @@ def _xla_attention_lse(q, k, v, kv_mask, causal, scale):
     return out, lse
 
 
-def _bwd_lse(causal, scale, q_tile, block_k, interpret, xla_backward,
+def _bwd_lse(causal, scale, q_tile, block_k, interpret, xla_backward, window,
              res, g):
-    q, k, v, kv_mask, out, lse = res
+    q, k, v, kv_mask, window_on, out, lse = res
     dout, dlse = g
     if xla_backward:
+        masked_by = _xla_window(window, window_on, k.shape[2])
         _, vjp = jax.vjp(
             lambda q, k, v: _xla_attention_lse(q, k, v, kv_mask, causal,
-                                               scale), q, k, v)
+                                               scale, masked_by), q, k, v)
         dq, dk, dv = vjp((dout, dlse))
     else:
         dq, dk, dv = _flash_backward(q, k, v, kv_mask, out, lse, dout,
                                      causal, scale, q_tile, block_k,
-                                     interpret, dlse=dlse)
-    mask_ct = (None if kv_mask is None else
-               np.zeros(kv_mask.shape, dtype=jax.dtypes.float0))
-    return dq, dk, dv, mask_ct
+                                     interpret, dlse=dlse, window=window,
+                                     window_on=window_on)
+    return dq, dk, dv, _no_cotangent(kv_mask), _no_cotangent(window_on)
 
 
 _flash_attention_with_lse.defvjp(_fwd_lse, _bwd_lse)
+
+
+def _call_arguments(q, causal, scale, kv_mask, interpret, window,
+                    window_on):
+    """The defaults and checks the two public entries share: ``(scale,
+    interpret, kv_mask, window, window_on)``."""
+    if scale is None:
+        scale = 1.0 / np.sqrt(q.shape[-1])
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if kv_mask is not None:
+        kv_mask = kv_mask.astype(jnp.int32)
+    if window is None:
+        if window_on is not None:
+            raise ValueError("window_on goes with a window")
+    else:
+        if not causal or int(window) < 1:
+            raise ValueError("a window is a positive count of keys behind "
+                             "a causal diagonal")
+        window = int(window)
+        if window_on is not None:
+            window_on = jnp.asarray(window_on, bool)
+    return float(scale), interpret, kv_mask, window, window_on
 
 
 def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -530,25 +724,23 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
                         kv_mask: Optional[jax.Array] = None,
                         q_tile: int = 256, block_k: int = 256,
                         interpret: Optional[bool] = None,
-                        xla_backward: bool = False):
+                        xla_backward: bool = False,
+                        window: Optional[int] = None,
+                        window_on: Optional[jax.Array] = None):
     """Fused attention returning (out [B, T, H, D], lse [B, H, T]).
 
     Same kernels as `flash_attention` plus the log-sum-exp output, so a
     caller (ops/ring_attention.py block_impl='pallas') can merge partial
     attentions over key blocks exactly: out = Σ_b out_b·exp(lse_b-lse),
     lse = logaddexp_b(lse_b). Differentiable in all inputs including
-    through lse.
+    through lse. ``window`` and ``window_on`` as `flash_attention`'s.
     """
-    if scale is None:
-        scale = 1.0 / np.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if kv_mask is not None:
-        kv_mask = kv_mask.astype(jnp.int32)
+    scale, interpret, kv_mask, window, window_on = _call_arguments(
+        q, causal, scale, kv_mask, interpret, window, window_on)
     out, lse = _flash_attention_with_lse(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), kv_mask, causal, float(scale), q_tile,
-        block_k, interpret, xla_backward)
+        v.transpose(0, 2, 1, 3), kv_mask, window_on, causal, scale, q_tile,
+        block_k, interpret, xla_backward, window)
     return out.transpose(0, 2, 1, 3), lse
 
 
@@ -558,7 +750,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     kv_mask: Optional[jax.Array] = None,
                     q_tile: int = 256, block_k: int = 256,
                     interpret: Optional[bool] = None,
-                    xla_backward: bool = False) -> jax.Array:
+                    xla_backward: bool = False,
+                    window: Optional[int] = None,
+                    window_on: Optional[jax.Array] = None) -> jax.Array:
     """Fused attention: q [B, T, Hq, D], k, v [B, Tk, Hkv, D] -> [B, T,
     Hq, D]; ``Hq`` a multiple of ``Hkv`` (query head ``h`` reads
     key/value head ``h // (Hq / Hkv)``).
@@ -568,17 +762,26 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     defaults to True off-TPU (so CPU tests exercise the same kernels)
     and False on TPU. ``xla_backward=True`` swaps the Pallas backward
     kernels for the einsum-recompute fallback.
+
+    ``window`` (static, with ``causal=True``): query ``t`` reads key
+    ``s`` iff ``0 <= t - s < window``, the token itself and the ``window
+    - 1`` before it; any positive count is legal, ``>= Tk`` being plain
+    causal attention. The three kernels then walk the band's tiles only
+    and are named ``flash_fwd_win``, ``flash_dq_win``, ``flash_dkv_win``
+    under the scope ``window_attention``, on the call's own tiles (at
+    32 on 4 heads of 128, 8,192 keys and a window of 1,024 the v5e runs
+    512 x 512 fastest of nine pairs: ``PERF.md`` section 6, PR 33).
+    ``window_on``, a traced boolean scalar, says whether THIS call applies the window (absent:
+    always): one loop body then serves layers of both kinds, a ``cond``
+    choosing between the windowed calls and the plain ones. Without a
+    ``window`` nothing of this is traced.
     """
-    if scale is None:
-        scale = 1.0 / np.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if kv_mask is not None:
-        kv_mask = kv_mask.astype(jnp.int32)
+    scale, interpret, kv_mask, window, window_on = _call_arguments(
+        q, causal, scale, kv_mask, interpret, window, window_on)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    out = _flash_attention_masked(qt, kt, vt, kv_mask, causal,
-                                  float(scale), q_tile, block_k,
-                                  interpret, xla_backward)
+    out = _flash_attention_masked(qt, kt, vt, kv_mask, window_on, causal,
+                                  scale, q_tile, block_k, interpret,
+                                  xla_backward, window)
     return out.transpose(0, 2, 1, 3)

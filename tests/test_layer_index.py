@@ -29,6 +29,9 @@ KEYE_SCOPES = ["embedding", "layer_scan", "attention", "indexer", "moe",
 # no `table_update`: its tied table is the dense group's
 ZAYA_SCOPES = ["embedding", "layer_scan", "attention", "cca_mix", "moe",
                "router", "lm_head", "dense_update"]
+# the windowed flash calls' scope is ops/pallas_attention's own
+MELLUM2_SCOPES = ["embedding", "layer_scan", "attention", "window_attention",
+                  "moe", "lm_head", "dense_update", "table_update"]
 
 
 def _session(**cfg_kw):
@@ -94,7 +97,7 @@ def test_every_declared_scope_is_found_in_the_keye_step():
     assert index["scopes_found"] == KEYE_SCOPES
     assert [s for s in xprof.LAYER_SCOPES if s in KEYE_SCOPES] == KEYE_SCOPES
     assert set(LM1B_SCOPES) | set(KEYE_SCOPES) | set(ZAYA_SCOPES) \
-        == set(xprof.LAYER_SCOPES)
+        | set(MELLUM2_SCOPES) == set(xprof.LAYER_SCOPES)
     inner = {n: m for n, m in index["hlo_index"].items()
              if re.search(r"attention\)*/(.*/)?indexer", m.get("op_name", ""))}
     assert inner
@@ -165,6 +168,58 @@ def test_the_scans_second_carry_lands_under_layer_scan(zaya_index):
                       r"dynamic-update-slice\(", zaya_index["text"], re.M)
     assert kept
     assert {zaya_index["layers"][n] for n in kept} == {"layer_scan"}
+
+
+@pytest.fixture(scope="module")
+def mellum2_index():
+    from parallax_tpu.models import mellum2
+    cfg = mellum2.tiny_config(flash_tiles=(8, 8))
+    sess, *_ = parallax.parallel_run(
+        mellum2.build_model(cfg, impls=("flash_interpret", None)),
+        parallax_config=parallax.Config(
+            run_option="HYBRID", sparse_grad_mode="slices",
+            search_partitions=False, shape_buckets=[8]))
+    batch = mellum2.make_batch(np.random.default_rng(0), 8, cfg.seq_len,
+                               cfg.vocab_size)
+    sess.warmup(feed_dict=batch)
+    index = sess.layer_index()
+    sess.close()
+    return index
+
+
+def test_every_declared_scope_is_found_in_the_mellum2_step(mellum2_index):
+    """Mellum2's step holds its eight scopes, in ``LAYER_SCOPES``'
+    order: the windowed calls' among them, and no model's own inner
+    scope."""
+    assert mellum2_index["scopes_found"] == MELLUM2_SCOPES
+    assert [s for s in xprof.LAYER_SCOPES if s in MELLUM2_SCOPES] \
+        == MELLUM2_SCOPES
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_window_attention_resolves_inside_attention(mellum2_index,
+                                                    direction):
+    """The windowed branch of the ``cond`` between the two kinds'
+    kernels is traced inside ``attention``, in both passes: its
+    operations go by the inner name, the full branch's stay
+    ``attention``'s."""
+    def of(direction, meta):
+        return ("transpose(" in meta.get("op_name", "")) \
+            == (direction == "backward")
+
+    index = mellum2_index
+    nested = {n: m for n, m in index["hlo_index"].items()
+              if re.search(r"attention\)*/(.*/)?window_attention",
+                           m.get("op_name", "")) and of(direction, m)}
+    assert nested
+    assert {index["layers"][n] for n in nested} == {"window_attention"}
+    # the other branch of the same `cond`: under `attention`, not under
+    # the window's scope
+    full = [n for n, m in index["hlo_index"].items()
+            if re.search(r"attention\)*/cond/", m.get("op_name", ""))
+            and "window_attention" not in m["op_name"] and of(direction, m)]
+    assert full
+    assert {index["layers"][n] for n in full} == {"attention"}
 
 
 def test_table_scatter_maps_to_table_update(warmed):
@@ -299,6 +354,14 @@ def test_lax_scan_branch_carries_the_lstm_scope():
     ("jit(train_step)/transpose(jvp(layer_scan))/while/body/checkpoint/moe/"
      "router/erf", "router", "dense"),
     ("jit(train_step)/jvp(moe)/jvp(router)/sub", "router", "dense"),
+    # the compiled Mellum2 step's own: a window layer's kernels, and the
+    # full layer's in the other branch of the same `cond`
+    ("jit(train_step)/jvp(layer_scan)/while/body/closed_call/attention/"
+     "cond/branch_1_fun/window_attention/flash_fwd_win", "window_attention",
+     "dense"),
+    ("jit(train_step)/transpose(jvp(layer_scan))/while/body/closed_call/"
+     "checkpoint/attention/cond/branch_0_fun/flash_dq", "attention",
+     "dense"),
     # a primitive or a user's scope that merely contains a layer's name
     ("jit(train_step)/jvp(my_lstm_block)/dot_general", None, None),
     ("jit(train_step)/model/embedding_norm/mul", None, None),

@@ -420,3 +420,46 @@ def test_routed_experts_take_an_unrenormalised_top1_gate(tokens, rng):
                                rtol=2e-5, atol=2e-6)
     d_scale = jax.grad(lambda s: jnp.sum(f(s).out))(1.0)
     assert float(d_scale) == pytest.approx(float(jnp.sum(one.out)), rel=1e-4)
+
+
+@pytest.mark.parametrize("shape,want", [
+    # Mellum2's experts (width 896 = 7 x 128): the up and gate products'
+    # n and the down product's k take the width whole, not 128
+    ((16384, 2304, 896), (512, 768, 896)),
+    ((16384, 896, 2304), (512, 896, 768)),
+    # Keye's (2048 x 768) and ZAYA's (2048 x 2048) as before 896 was a
+    # choice
+    ((32768, 2048, 768), (512, 1024, 768)),
+    ((32768, 768, 2048), (512, 768, 1024)),
+    ((8192, 2048, 2048), (512, 1024, 1024)),
+    # no choice divides: the size itself
+    ((96, 200, 72), (96, 200, 72)),
+])
+def test_gmm_tiling_takes_the_widest_choice_that_divides(shape, want):
+    assert moe._gmm_tiling(*shape) == want
+
+
+def test_linear_router_under_a_given_choice(tokens, rng):
+    """A fed choice takes the top-k's place: its experts' probabilities
+    renormalised to one, its loads in the loss; the router's own top-k
+    fed back is the router's own route."""
+    router = jnp.asarray(rng.standard_normal((tokens.shape[1], RE)),
+                         jnp.float32)
+    own = moe.linear_router(tokens, router, 2)
+    same = moe.linear_router(tokens, router, 2, choice=own.choice)
+    np.testing.assert_array_equal(np.asarray(same.choice),
+                                  np.asarray(own.choice))
+    np.testing.assert_allclose(np.asarray(same.gate), np.asarray(own.gate),
+                               rtol=1e-6)
+    assert float(same.aux_loss) == pytest.approx(float(own.aux_loss),
+                                                 rel=1e-6)
+    other = (own.choice + 1) % RE
+    fed = moe.linear_router(tokens, router, 2, choice=other)
+    probs = jax.nn.softmax(tokens @ router, -1)
+    picked = jnp.take_along_axis(probs, other, -1)
+    np.testing.assert_allclose(
+        np.asarray(fed.gate),
+        np.asarray(picked / picked.sum(-1, keepdims=True)), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(fed.gate.sum(-1)), 1.0, rtol=1e-6)
+    assert float(fed.aux_loss) != pytest.approx(float(own.aux_loss),
+                                                rel=1e-6)

@@ -173,6 +173,36 @@ def test_flash_kernels_compile_with_grouped_heads_at_8k(one_chip):
     assert "bf16[1,8,8192,128]" in calls[1]
 
 
+@pytest.mark.parametrize("tiles", [(512, 512), (256, 256)])
+def test_windowed_flash_kernels_compile_at_mellum2s_heads(one_chip, tiles):
+    """32 query heads on 4 key/value heads of 128 against 8,192 keys
+    under a window of 1,024 and a traced flag: the three windowed
+    kernels pass Mosaic beside the three plain ones, each kind once a
+    pass under its ``conditional``."""
+    from parallax_tpu.ops.pallas_attention import flash_attention
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v, flag):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, q_tile=tiles[0], block_k=tiles[1],
+            window=1024, window_on=flag,
+            interpret=False).astype(jnp.float32))
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), sds(1, 8192, 32, 128),
+        sds(1, 8192, 4, 128), sds(1, 8192, 4, 128),
+        jax.ShapeDtypeStruct((), jnp.bool_, sharding=one_chip))
+    text = compiled.as_text()
+    names = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert sorted(n.rsplit(".", 1)[0] if "." in n else n for n in names) \
+        == ["flash_dkv", "flash_dkv_win", "flash_dq", "flash_dq_win",
+            "flash_fwd", "flash_fwd_win"]
+    assert len(re.findall(r" conditional\(", text)) == 2
+
+
 def test_zaya_step_compiles_at_published_widths_and_fits(topo):
     """ZAYA1-8B's training step as the benchmark's cell runs it (6
     layers, 8 of 16 experts, 32,784 rows, one sequence of 8,192; every
@@ -226,4 +256,98 @@ def test_zaya_step_compiles_at_published_widths_and_fits(topo):
     assert kinds == ["flash_dkv", "flash_dq", "flash_fwd", "gmm", "tgmm"]
     # neither every expert for every token nor whole float32 scores
     assert "[8192,8,2048]" not in text
+    assert not re.search(r"f32\[(1,)?8192,8192\]", text)
+
+
+def test_mellum2_step_compiles_at_published_widths_and_fits(topo):
+    """Mellum2-12B-A2.5B's training step as the benchmark's cell runs it
+    (one period of 4 layers, 16 of 64 experts, 12,288 rows, one sequence
+    of 8,192; every width as published) through ``Engine`` for the
+    described v5e: 538.5 M parameters, a peak
+    (``peak_memory_in_bytes``) between the driver's floor and 15.5 GB
+    of the chip's 16.9, ONE loop over the layers in each direction, and
+    in them both kinds' flash kernels once each: the plain and the
+    windowed forward call in the forward loop's body, the four backward
+    calls in the backward loop's, no forward kernel made again by the
+    rematerialisation."""
+    import numpy as np
+    import parallax_tpu as parallax
+    from parallax_tpu.core import engine as engine_lib, mesh as mesh_lib
+    from parallax_tpu.models import mellum2
+
+    dev = topo.devices[0]
+    one = SingleDeviceSharding(dev)
+    cfg = mellum2.Mellum2Config(vocab_size=12288, num_layers=4,
+                                experts_held=16, warmup_steps=20000,
+                                num_partitions=1)
+    model = mellum2.build_model(cfg, impls=("flash", "gmm"))
+    mesh = mesh_lib.build_mesh(devices=[dev], num_partitions=1)
+    batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+             for k, v in mellum2.make_batch(
+                 np.random.default_rng(0), 1, cfg.seq_len,
+                 cfg.vocab_size).items()}
+    engine = engine_lib.Engine(
+        model, mesh, parallax.Config(run_option="HYBRID",
+                                     sparse_grad_mode="slices"), batch)
+    assert engine.plan.var_specs["emb"].is_sparse
+    state = jax.eval_shape(engine._init_jit,
+                           jax.ShapeDtypeStruct((), jnp.int32))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    with mesh:
+        compiled = engine._step_jit.trace(on_chip(state), on_chip(batch)) \
+            .lower(lowering_platforms=("tpu",)).compile()
+    memory = compiled.memory_analysis()
+    peak = memory.peak_memory_in_bytes
+    print(f"mellum2-12b-a2.5b step: peak_memory_in_bytes {peak / 1e9:.2f} "
+          f"GB (arguments {memory.argument_size_in_bytes / 1e9:.2f}, "
+          f"temporaries {memory.temp_size_in_bytes / 1e9:.2f})")
+    params = sum(int(np.prod(s.shape))
+                 for s in jax.tree.leaves(state.params))
+    assert params == pytest.approx(538.5e6, rel=1e-3)
+    assert 4.23e9 < peak < 15.5e9
+    text = compiled.as_text()
+    names = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    flash = sorted(n for n in names if n.startswith("flash_"))
+    # each of the six once in the whole program: the loops' bodies are
+    # traced once, and the kept output and logsumexp spare the backward
+    # pass a second forward call
+    assert [n.rsplit(".", 1)[0] if "." in n else n for n in flash] == [
+        "flash_dkv", "flash_dkv_win", "flash_dq", "flash_dq_win",
+        "flash_fwd", "flash_fwd_win"]
+    # (the experts' second part, under its own `cond`, names its calls
+    # after the transformation that made them)
+    assert all("gmm" in n for n in names if not n.startswith("flash_"))
+    # ONE loop over the layers a direction: the entry computation holds
+    # two, the forward one's body the `conditional` with the two forward
+    # calls and the backward one's the four backward calls
+    entry = text[text.index("\nENTRY "):]
+    bodies = re.findall(r" while\([^\n]*body=%([\w.\-]+)", entry)
+    assert len(bodies) == 2
+
+    def computation(name):
+        start = text.index(f"\n%{name} (")
+        return text[start:text.index("\n}\n", start)]
+
+    def calls_under_conditionals(body):
+        found = []
+        for line in computation(body).splitlines():
+            if " conditional(" in line:
+                branches = re.search(r"branch_computations=\{([^}]*)\}",
+                                     line).group(1)
+                for branch in re.findall(r"%([\w.\-]+)", branches):
+                    found += re.findall(r"%(flash_\w+)\.\d+ = ",
+                                        computation(branch))
+        return sorted(found)
+
+    assert calls_under_conditionals(bodies[0]) == ["flash_fwd",
+                                                   "flash_fwd_win"]
+    assert calls_under_conditionals(bodies[1]) == [
+        "flash_dkv", "flash_dkv_win", "flash_dq", "flash_dq_win"]
+    # neither every expert for every token nor whole float32 scores
+    assert "[8192,16,896]" not in text
     assert not re.search(r"f32\[(1,)?8192,8192\]", text)
