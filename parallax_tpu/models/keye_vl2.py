@@ -204,6 +204,7 @@ def _layer(cfg: KeyeVL2Config, p, h, pos, impls=(None, None),
     scalars = {"indexer_loss": attn.indexer_loss, "aux_loss": route.aux_loss,
                "selected": attn.selected, "causal": attn.causal,
                "moe_dropped": moe.dropped, "moe_rows_here": moe.rows_here,
+               "moe_rows_walked": moe.rows_walked,
                "moe_load_max_over_mean": moe.load_max_over_mean}
     extra = ({"selection": attn.selection, "expert_choice": route.choice}
              if collect else None)
@@ -305,6 +306,7 @@ def build_model(cfg: KeyeVL2Config, impls=(None, None)) -> Model:
             "indexer_loss": indexer_loss,
             "moe_dropped": jnp.max(per_layer["moe_dropped"]),
             "moe_rows_here": s["moe_rows_here"],
+            "moe_rows_walked": s["moe_rows_walked"],
             "moe_load_max_over_mean": s["moe_load_max_over_mean"],
             "attn_selected_share":
                 jnp.sum(per_layer["selected"]) / causal_total}
@@ -318,6 +320,7 @@ def build_model(cfg: KeyeVL2Config, impls=(None, None)) -> Model:
                  slice_updaters={"emb": SliceAdam(cfg.learning_rate)},
                  gauges={"moe.dropped": ("moe_dropped", "max"),
                          "moe.rows_here": "moe_rows_here",
+                         "moe.rows_walked": "moe_rows_walked",
                          "moe.load_max_over_mean": "moe_load_max_over_mean",
                          "sparse_attn.indexer_loss": "indexer_loss",
                          "sparse_attn.selected_share":

@@ -273,6 +273,7 @@ def _layer(cfg: Mellum2Config, p, kind, h, impls=(None, None),
         h = h + moe.out.reshape(B, T, D)
     scalars = {"aux_loss": route.aux_loss, "moe_dropped": moe.dropped,
                "moe_rows_here": moe.rows_here,
+               "moe_rows_walked": moe.rows_walked,
                "moe_load_max_over_mean": moe.load_max_over_mean}
     return h, scalars, own.choice
 
@@ -376,6 +377,7 @@ def build_model(cfg: Mellum2Config, impls=(None, None)) -> Model:
             "lm_loss": lm_loss, "aux_loss": aux_loss,
             "moe_dropped": jnp.max(s["moe_dropped"]),
             "moe_rows_here": jnp.mean(s["moe_rows_here"]),
+            "moe_rows_walked": jnp.mean(s["moe_rows_walked"]),
             "moe_load_max_over_mean": jnp.mean(s["moe_load_max_over_mean"])}
 
     from parallax_tpu.ops.sparse_optim import SliceAdam
@@ -385,6 +387,7 @@ def build_model(cfg: Mellum2Config, impls=(None, None)) -> Model:
                  slice_updaters={"emb": SliceAdam(cfg.learning_rate)},
                  gauges={"moe.dropped": ("moe_dropped", "max"),
                          "moe.rows_here": "moe_rows_here",
+                         "moe.rows_walked": "moe_rows_walked",
                          "moe.load_max_over_mean": "moe_load_max_over_mean"})
 
 

@@ -287,6 +287,7 @@ def _layer(cfg: ZayaConfig, p, beta, h, r_prev, impls=(None, None),
         h = h + moe.out.reshape(B, T, D)
     scalars = {"load": load, "gate_mean": jnp.mean(gate),
                "moe_dropped": moe.dropped, "moe_rows_here": moe.rows_here,
+               "moe_rows_walked": moe.rows_walked,
                "moe_load_max_over_mean": moe.load_max_over_mean}
     picked = {"choice": own, "margin": top2[:, 0] - top2[:, 1]}
     return (h, r), scalars, picked
@@ -432,6 +433,7 @@ def build_model(cfg: ZayaConfig, impls=(None, None)) -> Model:
             "lm_loss": loss,
             "moe_dropped": jnp.max(s["moe_dropped"]),
             "moe_rows_here": jnp.mean(s["moe_rows_here"]),
+            "moe_rows_walked": jnp.mean(s["moe_rows_walked"]),
             "moe_load_max_over_mean": jnp.mean(s["moe_load_max_over_mean"]),
             "router_gate_mean": jnp.mean(s["gate_mean"]),
             "router_bias_abs_max": jnp.max(jnp.abs(new_beta))}
@@ -442,6 +444,7 @@ def build_model(cfg: ZayaConfig, impls=(None, None)) -> Model:
     return Model(init_fn, loss_fn, optimizer=tx, stateful=True,
                  gauges={"moe.dropped": ("moe_dropped", "max"),
                          "moe.rows_here": "moe_rows_here",
+                         "moe.rows_walked": "moe_rows_walked",
                          "moe.load_max_over_mean": "moe_load_max_over_mean",
                          "router.gate_mean": "router_gate_mean",
                          "router.bias_abs_max": "router_bias_abs_max"})

@@ -20,9 +20,11 @@ MLP's ``argmax(p + bias)`` with the unrenormalised ``p`` as its gate).
 The chip computes the part of the result its own ``[first_expert,
 first_expert + held)`` experts give, for exactly the rows routed to
 them, grouped by expert (a grouped matrix product: ``megablox.gmm`` on
-the TPU, ``lax.ragged_dot`` elsewhere). **It never drops**: its buffers
-hold the worst case (every choice of every token), the products visit
-only the rows routed here. Three-matrix gated (SwiGLU) experts. What
+the TPU, ``lax.ragged_dot`` elsewhere). **It never drops**: its row
+buffers hold the worst case (every choice of every token), the products
+and the per-token sums (``sum_rows_by_token``: the combine, and the
+dispatch's cotangent) visit only the rows routed here, and nothing is
+held by (token, choice) pair. Three-matrix gated (SwiGLU) experts. What
 the absent experts would add is left out; on one device ``first_expert``
 is an argument, under expert parallelism it follows the shard index
 (``jax.lax.axis_index``), and the exchange that would bring other chips'
@@ -207,6 +209,9 @@ class RoutedOut(NamedTuple):
     dropped: jax.Array      # (token, choice) rows routed here that no
                             # part's grouped products covered (must be 0)
     rows_here: jax.Array    # rows routed to the held experts
+    rows_walked: jax.Array  # sorted rows the per-token sums visited: the
+                            # live ones, rounded up to the kernel's row
+                            # block in each part where the kernel runs
     load_max_over_mean: jax.Array   # fullest held expert over their mean
 
 
@@ -258,68 +263,241 @@ def _grouped_dot(lhs, rhs, group_sizes, impl, transpose_rhs=False):
                         None, None, transpose_rhs, impl == "gmm_interpret")
 
 
-def _slots(inv, lo: int, rows: int):
-    """For every (token, choice) pair its place among the sorted rows
-    ``[lo, lo + rows)`` and whether it has one."""
-    pos = inv - lo
-    return jnp.clip(pos, 0, rows - 1), (pos >= 0) & (pos < rows)
+# What the per-token sums' kernel may take of a TPU's VMEM (a v5e has
+# 128 MiB), and of that its float32 accumulator, one buffer of it.
+# Alone on a v5e at Mellum2's shapes (16.4 k live rows of 2,304) the
+# whole width in one chunk took 0.38 ms a call, two chunks of 1,152
+# 0.52, three of 768 0.61 (PERF.md section 6, PR 34): a row's update
+# waits on the row before it whatever the width.
+_SUM_ROWS_VMEM_BYTES = 100 * 1024 * 1024
+_SUM_ROWS_ACC_BYTES = 80 * 1024 * 1024
 
 
-def _gather_pairs(y, inv, lo: int, k: int):
-    """``[B, k, D]``: for every (token, choice) pair its row of ``y``
-    (the sorted rows ``[lo, lo + M)``), zeros where it has none."""
-    pos, valid = _slots(inv, lo, y.shape[0])
-    pairs = jnp.where(valid[:, None], y[pos], jnp.zeros((), y.dtype))
-    return pairs.reshape(-1, k, y.shape[1])
+def _sum_rows_block(m: int) -> int:
+    """``sum_rows_by_token``'s row block: the rows as the grouped
+    products take them."""
+    return next((c for c in (512, 256, 128) if m % c == 0), m)
+
+
+def _sum_rows_chunk(num_tokens: int, dim: int) -> int:
+    """``sum_rows_by_token``'s column chunk: the widest multiple of 128
+    lanes that divides ``dim`` and keeps ``[num_tokens, chunk]`` float32
+    under ``_SUM_ROWS_ACC_BYTES``."""
+    chunks = [c for c in range(dim, 0, -128) if dim % c == 0] \
+        if dim % 128 == 0 else [dim]
+    return next((c for c in chunks
+                 if 4 * num_tokens * c <= _SUM_ROWS_ACC_BYTES), chunks[-1])
+
+
+def _sum_rows_kernel(nl_ref, tok_ref, *refs, tm: int, scaled: bool):
+    from jax.experimental import pallas as pl
+
+    if scaled:
+        scale_ref, rows_ref, out_ref, buf = refs
+    else:
+        rows_ref, out_ref, buf = refs
+    block = pl.program_id(1)
+    base = block * tm
+    n_live = nl_ref[0]
+
+    @pl.when(block == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(base < n_live)
+    def _():
+        # widened once a block; what lies past the live rows adds zeros
+        index = base + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        buf[...] = jnp.where(index < n_live,
+                             rows_ref[...].astype(jnp.float32), 0.0)
+
+        # eight rows a trip, in row order: two rows of one token (the
+        # last of an expert's group and the first of the next) are two
+        # updates of one accumulator row, the second on the first's
+        def eight(group, carry):
+            first = pl.multiple_of(group * 8, 8)
+            for u in range(8):
+                r = first + u
+                row = buf[pl.ds(r, 1), :]
+                if scaled:
+                    row = scale_ref[base + r] * row
+                token = pl.ds(tok_ref[base + r], 1)
+                out_ref[token, :] = out_ref[token, :] + row
+            return carry
+        jax.lax.fori_loop(
+            0, (jnp.minimum(tm, n_live - base) + 7) // 8, eight, 0)
+
+
+def sum_rows_by_token(rows, scale, token_of_row, n_live, num_tokens: int,
+                      impl: str):
+    """float32 ``[num_tokens, D]``: the sum over the rows ``r < n_live``
+    of ``scale[r] * rows[r]`` (of ``rows[r]`` where ``scale`` is None)
+    into row ``token_of_row[r]``, each row widened to float32 before it
+    is scaled and added. The work follows ``n_live``, not ``M``. Relies
+    on: ``rows [M, D]`` with the live ones a prefix (``0 <= n_live <=
+    M``; a row at or past ``n_live`` is never added, whatever it holds);
+    ``token_of_row [M]`` in ``[0, num_tokens)`` for EVERY row, live or
+    not; ``scale [M]`` float32 and finite; ``M`` a multiple of 8. A
+    token's rows are added in row order.
+
+    ``impl`` as ``routed_experts`` takes it: ``"ragged_dot"`` is
+    ``jax.ops.segment_sum`` over the masked rows; ``"gmm"`` the Mosaic
+    kernel ``sum_rows`` (``"gmm_interpret"``: interpreted), a grid of
+    (column chunks, row blocks): the chunk's float32 accumulator
+    ``[num_tokens, chunk]`` stays in VMEM across the row blocks and is
+    written once, a ``[tm, chunk]`` block of rows streams in for every
+    live block (dead blocks re-use the last live block: nothing is
+    fetched), and every live row is one read-modify-write of its
+    token's row of the accumulator, its token and scale read off SMEM.
+    It visits ``n_live`` rounded up to ``tm`` rows."""
+    M, D = rows.shape
+    n_live = jnp.reshape(n_live, (1,)).astype(jnp.int32)
+    if impl == "ragged_dot":
+        wide = rows.astype(jnp.float32)
+        if scale is not None:
+            wide = wide * scale[:, None]
+        wide = jnp.where((jnp.arange(M) < n_live)[:, None], wide, 0.0)
+        return jax.ops.segment_sum(wide, token_of_row,
+                                   num_segments=num_tokens)
+    # imported where a kernel is traced (``ops/sparse_optim``)
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if M % 8:
+        raise ValueError(f"sum_rows_by_token: {M} rows, not a multiple of 8")
+    tm, dc = _sum_rows_block(M), _sum_rows_chunk(num_tokens, D)
+
+    def rows_map(chunk, block, nl_ref, *_):
+        last = jnp.maximum((nl_ref[0] + tm - 1) // tm - 1, 0)
+        return jnp.minimum(block, last), chunk
+
+    prefetch = (n_live, token_of_row.astype(jnp.int32))
+    if scale is not None:
+        prefetch += (scale.astype(jnp.float32),)
+    return pl.pallas_call(
+        functools.partial(_sum_rows_kernel, tm=tm, scaled=scale is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(D // dc, M // tm),
+            in_specs=[pl.BlockSpec((tm, dc), rows_map)],
+            out_specs=pl.BlockSpec((num_tokens, dc),
+                                   lambda chunk, block, *_: (0, chunk),
+                                   pipeline_mode=pl.Buffered(1)),
+            scratch_shapes=[pltpu.VMEM((tm, dc), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((num_tokens, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_SUM_ROWS_VMEM_BYTES),
+        name="sum_rows",
+        interpret=impl == "gmm_interpret",
+    )(*prefetch, rows)
+
+
+def _one_row_a_token(m: int, num_tokens: int, k: int) -> bool:
+    """Whether a part of ``m`` sorted rows holds exactly one row of every
+    token: one choice a token and all of them in the part
+    (``models/zaya``: top-1, half of the experts held)."""
+    return k == 1 and m == num_tokens
+
+
+def _sum_by_token(rows, scale, mine, n_live, num_tokens: int, k: int,
+                  impl: str):
+    """``sum_rows_by_token`` for the sorted rows of a part (``mine
+    [M]``: the pair ``b * k + c`` of every row, so its token is ``mine
+    // k``). Where the part holds one row of every token the rows are a
+    permutation of the tokens and there are no pairs to outnumber them:
+    the sum is each token's own row, fetched by a gather that XLA fuses
+    into what reads it (zeros where the row is not live), where the
+    kernel would write float32 ``[B, D]`` for that to read back. Read
+    off the static shapes alone; on a v5e ZAYA's cell lost 1.4 % with
+    the kernel there, Mellum2's and Keye's (8 pairs a token for 2 and 1
+    live rows) gained 21 % and 3.9 % with it (PERF.md section 6, PR
+    34)."""
+    if not _one_row_a_token(rows.shape[0], num_tokens, k):
+        return sum_rows_by_token(rows, scale, mine // k, n_live, num_tokens,
+                                 impl)
+    row_of_token = jnp.argsort(mine)
+    wide = rows[row_of_token].astype(jnp.float32)
+    if scale is not None:
+        wide = wide * scale[row_of_token][:, None]
+    return jnp.where((row_of_token < n_live)[:, None], wide, 0.0)
+
+
+def _rows_walked(n_live, m: int, num_tokens: int, k: int, impl: str):
+    """The rows ``_sum_by_token`` visits for ``n_live`` live ones of
+    ``m``: every token's where it gathers, the live blocks' where the
+    kernel runs, the live rows elsewhere."""
+    if _one_row_a_token(m, num_tokens, k):
+        return jnp.int32(m)
+    if impl == "ragged_dot":
+        return n_live
+    tm = _sum_rows_block(m)
+    return -(-n_live // tm) * tm
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _dispatch(tokens, token_of_row, inv, lo: int, k: int):
-    """``tokens[token_of_row]``: the rows ``[lo, lo + M)`` of the sorted
-    (token, choice) pairs, each a copy of its token. The cotangent comes
-    back by a gather through ``inv`` (the pair's place among the sorted
-    rows) where plain AD would scatter-add ``M`` rows."""
-    return tokens[token_of_row]
+def _dispatch(tokens, mine, n_live, k: int, impl: str):
+    """``tokens[mine // k]``: the sorted (token, choice) rows of a part
+    (``mine [M]``: the pair ``b * k + c`` of every row), each a copy of
+    its token. The cotangent is the live rows' (``n_live`` of them, a
+    prefix) summed by token (``_sum_by_token``) where plain AD would
+    scatter-add all ``M`` rows; it is rounded to the rows' dtype once,
+    at the end."""
+    return tokens[mine // k]
 
 
-def _dispatch_fwd(tokens, token_of_row, inv, lo, k):
-    return tokens[token_of_row], inv
+def _dispatch_fwd(tokens, mine, n_live, k, impl):
+    # (an empty slice carries the tokens' count to the backward pass)
+    return tokens[mine // k], (mine, n_live, tokens[:, :0])
 
 
-def _dispatch_bwd(lo, k, res, g):
-    d_tokens = jnp.sum(_gather_pairs(g, res, lo, k).astype(jnp.float32),
-                       axis=1).astype(g.dtype)
+def _dispatch_bwd(k, impl, res, g):
+    mine, n_live, like_tokens = res
+    d_tokens = _sum_by_token(g, None, mine, n_live, like_tokens.shape[0], k,
+                             impl).astype(g.dtype)
     return d_tokens, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _combine(y, weight, inv, token_of_row, choice_of_row, lo: int, k: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _combine(y, weight, mine, n_live, k: int, impl: str):
     """``out[b] = sum_c weight[b, c] * y[row of pair (b, c)]`` over the
-    pairs that have a row in ``[lo, lo + M)``: float32 ``[B, D]``. Its
-    cotangents are gathers too."""
-    return jnp.einsum("bkd,bk->bd",
-                      _gather_pairs(y, inv, lo, k).astype(jnp.float32),
-                      weight)
+    pairs that have a live row in the part: float32 ``[B, D]``, by
+    ``_sum_by_token`` over the part's ``n_live`` live rows (``mine
+    [M]``: the pair ``b * k + c`` of every row), each scaled by its
+    pair's weight. Backward, ``d_y`` is the token's cotangent gathered
+    to the rows and scaled, and the gates' ``d_w[b, c] = <y[row of (b,
+    c)], g[b]>`` is the row-wise dot of ``y`` with those gathered rows
+    (float32, ``[M]`` numbers) put at the rows' pairs, zero at a pair
+    without a row here: it reads every row of ``y``, so the rows past
+    the live ones have to be zeros (``_rows``' selects)."""
+    return _sum_by_token(y, weight.reshape(-1)[mine], mine, n_live,
+                         weight.shape[0], k, impl)
 
 
-def _combine_fwd(y, weight, inv, token_of_row, choice_of_row, lo, k):
-    out = _combine(y, weight, inv, token_of_row, choice_of_row, lo, k)
-    return out, (y, weight, inv, token_of_row, choice_of_row)
+def _combine_fwd(y, weight, mine, n_live, k, impl):
+    return _combine(y, weight, mine, n_live, k, impl), (y, weight, mine)
 
 
-def _combine_bwd(lo, k, res, g):
-    y, weight, inv, token_of_row, choice_of_row = res
-    w_row = weight[token_of_row, choice_of_row]                  # [M]
-    d_y = (g[token_of_row] * w_row[:, None]).astype(y.dtype)
-    d_w = jnp.einsum("bkd,bd->bk",
-                     _gather_pairs(y, inv, lo, k).astype(jnp.float32), g)
-    return d_y, d_w, None, None, None
+def _combine_bwd(k, impl, res, g):
+    y, weight, mine = res
+    g_rows = g[mine // k]                                        # [M, D]
+    d_y = (g_rows * weight.reshape(-1)[mine][:, None]).astype(y.dtype)
+    dw_row = jnp.sum(y.astype(jnp.float32) * g_rows, axis=1)     # [M]
+    d_w = jnp.zeros(weight.size, jnp.float32).at[mine].set(
+        dw_row, unique_indices=True).reshape(weight.shape)
+    return d_y, d_w, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _live_rows(ends, lo: int, n: int):
+    """How many of the sorted rows ``[lo, lo + n)`` are routed to the
+    held experts: a prefix of them, the absent experts' rows sort last."""
+    return jnp.clip(ends[-1] - lo, 0, n)
 
 
 def _part_sizes(sizes, ends, lo: int, n: int):
@@ -331,22 +509,25 @@ def _part_sizes(sizes, ends, lo: int, n: int):
 def _rows(tokens, weight, w_gate, w_up, w_down, route, lo: int, n: int,
           k: int, impl: str):
     """What the held experts give for the sorted rows ``[lo, lo + n)``
-    of ``route`` (``order``, its inverse, the held experts' row counts
-    and their running sum), combined per token: float32 ``[B, D]``. The
-    rows past the last held expert's are the absent experts': the
-    products skip them and leave them unspecified, so they are zeroed on
-    the way in and on the way out, forward and (by the same selects)
-    backward."""
-    order, inv, sizes, ends = route
+    of ``route`` (``order``, the held experts' row counts and their
+    running sum), summed per token: float32 ``[B, D]``. The row buffers
+    hold all ``n`` rows; the first ``n_live`` of them are routed here,
+    and the per-token sums (``_combine`` forward, ``_dispatch``
+    backward) walk those alone. The rows past them are the absent
+    experts': the products skip them and leave them unspecified, so
+    they are zeroed on the way in and on the way out, forward and (by
+    the same selects) backward, for the products' own cotangents and
+    for the gates', which read every row."""
+    order, sizes, ends = route
     mine = order[lo:lo + n]
-    token_of_row, choice_of_row = mine // k, mine % k
     part = _part_sizes(sizes, ends, lo, n)
-    live = (lo + jnp.arange(n) < ends[-1])[:, None]
+    n_live = _live_rows(ends, lo, n)
+    live = (jnp.arange(n) < n_live)[:, None]
 
     def only_live(a):
         return jnp.where(live, a, jnp.zeros((), a.dtype))
 
-    x = only_live(_dispatch(tokens, token_of_row, inv, lo, k))
+    x = only_live(_dispatch(tokens, mine, n_live, k, impl))
     gate_act = only_live(_grouped_dot(x, w_gate, part, impl))
     up = only_live(_grouped_dot(x, w_up, part, impl))
     if lo == 0:
@@ -360,7 +541,7 @@ def _rows(tokens, weight, w_gate, w_up, w_down, route, lo: int, n: int,
     y = only_live(_grouped_dot(h, w_down, part, impl))
     if lo == 0:
         y = checkpoint_name(y, "moe_rows")
-    return _combine(y, weight, inv, token_of_row, choice_of_row, lo, k)
+    return _combine(y, weight, mine, n_live, k, impl)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
@@ -450,7 +631,9 @@ def routed_experts(tokens: jax.Array,        # [B, D]
     the remainder only in a step whose rows reach into it. ``dropped``
     counts the rows routed here that neither part covered, off the
     parts' own group sizes and the predicate the second part ran under:
-    0 unless the split loses rows. In each part the three products run
+    0 unless the split loses rows; ``rows_walked`` the sorted rows the
+    per-token sums visited, which follows ``rows_here`` and not ``B *
+    k``. In each part the three products run
     as grouped matrix products over the rows routed here (``impl``:
     ``"gmm"`` the megablox kernel, the default on a TPU;
     ``"ragged_dot"`` XLA's, the default elsewhere; ``"gmm_interpret"``
@@ -473,7 +656,6 @@ def routed_experts(tokens: jax.Array,        # [B, D]
     # absent experts' rows sort last, under the sentinel group `held`
     group = jnp.where(here, local, held).reshape(-1).astype(jnp.int32)
     order = jnp.argsort(group, stable=True).astype(jnp.int32)   # [B * k]
-    inv = jnp.argsort(order).astype(jnp.int32)
     sizes = jnp.sum(jax.nn.one_hot(group, held, dtype=jnp.int32), axis=0)
     ends = jnp.cumsum(sizes)
     rows_here = ends[-1]
@@ -485,21 +667,24 @@ def routed_experts(tokens: jax.Array,        # [B, D]
     # under a `cond` that costs nothing while they are empty.
     fast = fast_rows(B, k, held, E)
     dt = tokens.dtype
-    route = (order, inv, sizes, ends)
+    route = (order, sizes, ends)
     operands = (tokens, weight, w_gate.astype(dt), w_up.astype(dt),
                 w_down.astype(dt), route)
     out = _rows(*operands, 0, fast, k, impl)
     covered = jnp.sum(_part_sizes(sizes, ends, 0, fast))
+    walked = _rows_walked(_live_rows(ends, 0, fast), fast, B, k, impl)
     if fast < B * k:
         reached = rows_here > fast
-        out = out + _rows_if(reached, *operands, fast, B * k - fast, k,
-                             impl)
+        rest = B * k - fast
+        out = out + _rows_if(reached, *operands, fast, rest, k, impl)
         covered = covered + jnp.where(
-            reached, jnp.sum(_part_sizes(sizes, ends, fast, B * k - fast)),
-            0)
+            reached, jnp.sum(_part_sizes(sizes, ends, fast, rest)), 0)
+        walked = walked + _rows_walked(_live_rows(ends, fast, rest), rest,
+                                       B, k, impl)
     out = out.astype(dt)
 
     dropped = (rows_here - covered).astype(jnp.float32)
     mean_load = jnp.maximum(rows_here.astype(jnp.float32) / held, 1e-9)
     return RoutedOut(out, dropped, rows_here.astype(jnp.float32),
+                     walked.astype(jnp.float32),
                      jnp.max(sizes).astype(jnp.float32) / mean_load)

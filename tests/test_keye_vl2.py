@@ -142,6 +142,7 @@ def test_the_blocks_equal_a_plain_scan_and_the_stacks_keep_their_layout():
             "indexer_loss": jnp.mean(s["indexer_loss"]) / (B * T),
             "moe_dropped": jnp.max(s["moe_dropped"]),
             "moe_rows_here": jnp.mean(s["moe_rows_here"]),
+            "moe_rows_walked": jnp.mean(s["moe_rows_walked"]),
             "moe_load_max_over_mean": jnp.mean(s["moe_load_max_over_mean"]),
             "attn_selected_share":
                 jnp.sum(s["selected"]) / jnp.sum(s["causal"])}.items():
@@ -294,6 +295,8 @@ def test_trains_through_parallel_run_and_reports_its_outputs():
     snap = sess.metrics_snapshot()
     assert snap["moe.dropped"] == 0.0
     assert snap["moe.rows_here"] == float(out[3])
+    # off the TPU the per-token sums visit the live rows and no other
+    assert snap["moe.rows_walked"] == snap["moe.rows_here"]
     assert snap["moe.load_max_over_mean"] >= 1.0
     assert 0.0 < snap["sparse_attn.selected_share"] <= 1.0
     assert snap["sparse_attn.indexer_loss"] >= 0.0

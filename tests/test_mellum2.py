@@ -272,6 +272,7 @@ def test_trains_through_parallel_run_with_its_table_and_gauges():
     snap = sess.metrics_snapshot()
     assert snap["moe.dropped"] == 0.0
     assert snap["moe.rows_here"] == float(out[3])
+    assert snap["moe.rows_walked"] == snap["moe.rows_here"]
     assert snap["moe.load_max_over_mean"] >= 1.0
     sess.close()
 

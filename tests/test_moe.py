@@ -422,6 +422,150 @@ def test_routed_experts_take_an_unrenormalised_top1_gate(tokens, rng):
     assert float(d_scale) == pytest.approx(float(jnp.sum(one.out)), rel=1e-4)
 
 
+def _sorted_rows(choice, held, lo, m):
+    """The sorted rows ``[lo, lo + m)`` of ``choice`` as ``routed_experts``
+    takes them: (the pair of every row, the live rows among them)."""
+    group = jnp.where(choice < held, choice, held).reshape(-1)
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    rows_here = int(jnp.sum(choice < held))
+    return order[lo:lo + m], min(max(rows_here - lo, 0), m)
+
+
+def _draw_choice(rng, b, k, num_experts):
+    return jnp.asarray(np.stack([rng.permutation(num_experts)[:k]
+                                 for _ in range(b)]), jnp.int32)
+
+
+# token 5 is the last row of expert 0's group and the first of expert
+# 1's: two updates in a row of one accumulator row
+_NEIGHBOURS = np.asarray([[0, 2]] * 5 + [[0, 1]] + [[1, 2]] * 2, np.int32)
+
+# b tokens choosing k of e experts, `held` of them here; the sorted rows
+# [lo, lo + m) of width d
+_SUM_ROWS_CASE = dict(lo=0, scaled=True, dtype=jnp.float32, d=128,
+                      acc_bytes=None, choice=None)
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param(dict(b=256, k=1, e=16, held=8, m=256, dtype=jnp.bfloat16,
+                      d=256), id="k1-pairs-are-rows"),
+    pytest.param(dict(b=128, k=8, e=64, held=16, m=512, dtype=jnp.bfloat16,
+                      d=256), id="k8-a-quarter-live"),
+    pytest.param(dict(b=128, k=8, e=64, held=48, lo=512, m=512),
+                 id="second-part-lo-512"),
+    pytest.param(dict(b=64, k=2, e=8, held=0, m=128), id="no-live-row"),
+    pytest.param(dict(b=96, k=8, e=64, held=24, m=768, dtype=jnp.bfloat16),
+                 id="live-rows-end-inside-a-block"),
+    pytest.param(dict(b=8, k=2, e=4, held=4, m=16, choice=_NEIGHBOURS),
+                 id="one-token-ends-and-starts-groups"),
+    pytest.param(dict(b=128, k=8, e=64, held=16, m=512, scaled=False,
+                      dtype=jnp.bfloat16, d=256),
+                 id="no-scale-dispatch-backward"),
+    pytest.param(dict(b=96, k=8, e=64, held=24, m=768, scaled=False, d=384,
+                      acc_bytes=4 * 96 * 128), id="three-column-chunks"),
+])
+def test_sum_rows_by_token_kernel_against_segment_sum(rng, case,
+                                                      monkeypatch):
+    """The Mosaic kernel interpreted against ``jax.ops.segment_sum`` over
+    the live rows, and the path off the TPU against the same; what lies
+    past the live rows is NaN and must not be added."""
+    c = {**_SUM_ROWS_CASE, **case}
+    b, k, m, d = c["b"], c["k"], c["m"], c["d"]
+    choice = _draw_choice(rng, b, k, c["e"]) if c["choice"] is None \
+        else jnp.asarray(c["choice"])
+    mine, n_live = _sorted_rows(choice, c["held"], c["lo"], m)
+    token_of_row = mine // k
+    if c["acc_bytes"] is not None:
+        monkeypatch.setattr(moe, "_SUM_ROWS_ACC_BYTES", c["acc_bytes"])
+    assert d // moe._sum_rows_chunk(b, d) == (3 if c["acc_bytes"] else 1)
+    if c["lo"]:
+        assert 0 < n_live < m
+    rows = rng.standard_normal((m, d)).astype(np.float32)
+    rows[n_live:] = np.nan
+    rows = jnp.asarray(rows, c["dtype"])
+    scale = jnp.asarray(rng.standard_normal(m), jnp.float32) \
+        if c["scaled"] else None
+    wide = rows.astype(jnp.float32)[:n_live]
+    if scale is not None:
+        wide = wide * scale[:n_live, None]
+    want = jax.ops.segment_sum(wide, token_of_row[:n_live], num_segments=b)
+    for impl in ("gmm_interpret", "ragged_dot"):
+        got = jax.jit(lambda r, s, t, n: moe.sum_rows_by_token(
+            r, s, t, n, b, impl))(rows, scale, token_of_row,
+                                  jnp.int32(n_live))
+        assert got.dtype == jnp.float32 and got.shape == (b, d)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6, err_msg=impl)
+        # as `_combine` and `_dispatch` call it: a part with one row
+        # of every token (the first case) gathers, the rest is the same
+        got = jax.jit(lambda r, s, p, n: moe._sum_by_token(
+            r, s, p, n, b, k, impl))(rows, scale, mine, jnp.int32(n_live))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6, err_msg=impl)
+    one_row = moe._one_row_a_token(m, b, k)
+    assert one_row == (k == 1)
+    tm = moe._sum_rows_block(m)
+    walked = {impl: int(moe._rows_walked(jnp.int32(n_live), m, b, k, impl))
+              for impl in ("gmm_interpret", "ragged_dot")}
+    assert walked == ({"gmm_interpret": m, "ragged_dot": m} if one_row else
+                      {"gmm_interpret": -(-n_live // tm) * tm,
+                       "ragged_dot": n_live})
+
+
+def test_sum_rows_by_token_cases_are_what_they_say(rng):
+    """The shapes the cases above stand for: a token in the last row of
+    one group and the first of the next, a part whose live rows end
+    inside a row block, a part without a live row; and the rows the
+    kernel refuses."""
+    mine, n_live = _sorted_rows(jnp.asarray(_NEIGHBOURS), 4, 0, 16)
+    assert n_live == 16 and int(mine[5] // 2) == int(mine[6] // 2) == 5
+    _, n_live = _sorted_rows(_draw_choice(rng, 96, 8, 64), 24, 0, 768)
+    tm = moe._sum_rows_block(768)
+    assert tm == 256 and 0 < n_live % tm and n_live < 768 - tm
+    assert _sorted_rows(_draw_choice(rng, 64, 2, 8), 0, 0, 128)[1] == 0
+    with pytest.raises(ValueError, match="multiple of 8"):
+        moe.sum_rows_by_token(jnp.zeros((12, 128)), None,
+                              jnp.zeros(12, jnp.int32), 3, 4, "gmm_interpret")
+
+
+@pytest.mark.parametrize("part", ["first", "second"])
+def test_gate_cotangent_by_rows_is_plain_ad_of_the_pair_formula(rng, part):
+    """``_combine``'s cotangents against plain AD of what it used to be:
+    every (token, choice) pair's row gathered through the inverse of the
+    sort into ``[B, k, D]`` and summed over ``k`` under the gates."""
+    b, k, held, e, d = 128, 4, 6, 8, 16
+    choice = _draw_choice(rng, b, k, e)
+    fast = 256                                  # of 512 pairs, ~384 live
+    assert int(jnp.sum(choice < held)) > fast
+    lo, m = (0, fast) if part == "first" else (fast, b * k - fast)
+    mine, n_live = _sorted_rows(choice, held, lo, m)
+    assert 0 < n_live and (part == "first" or n_live < m)
+    group = jnp.where(choice < held, choice, held).reshape(-1)
+    inv = jnp.argsort(jnp.argsort(group, stable=True))
+    y = jnp.asarray(rng.standard_normal((m, d)), jnp.float32)
+    y = jnp.where((jnp.arange(m) < n_live)[:, None], y, 0.0)
+    weight = jnp.where(choice < held, jnp.asarray(
+        rng.uniform(0.1, 1.0, (b, k)), jnp.float32), 0.0)
+    g = jnp.asarray(rng.standard_normal((b, d)), jnp.float32)
+
+    def pairs(y, weight):
+        pos = inv - lo
+        valid = (pos >= 0) & (pos < m)
+        rows = jnp.where(valid[:, None], y[jnp.clip(pos, 0, m - 1)], 0.0)
+        return jnp.einsum("bkd,bk->bd", rows.reshape(b, k, d), weight)
+
+    want_out, want_vjp = jax.vjp(pairs, y, weight)
+    got_out, got_vjp = jax.vjp(
+        lambda y, w: moe._combine(y, w, mine, jnp.int32(n_live), k,
+                                  "ragged_dot"), y, weight)
+    np.testing.assert_allclose(np.asarray(got_out), np.asarray(want_out),
+                               rtol=2e-4, atol=2e-5)
+    for got, want in zip(got_vjp(g), want_vjp(g)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-5)
+    assert float(jnp.abs(want_vjp(g)[1]).max()) > 0.1
+
+
 @pytest.mark.parametrize("shape,want", [
     # Mellum2's experts (width 896 = 7 x 128): the up and gate products'
     # n and the down product's k take the width whole, not 128

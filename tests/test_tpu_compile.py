@@ -118,30 +118,48 @@ def test_sparse_attn_fwd_alone_against_the_longest_band(one_chip):
     assert "f32[1,4,8,512,8]" in calls[0]
 
 
-def test_routed_experts_kernels_compile_at_published_widths(one_chip):
-    """2,048 tokens of width 2048 through 16 held experts of width 768,
-    top-8 of 128: three grouped products forward and six backward, for
-    the fast part of the rows and again for the part behind the
-    ``cond``."""
+@pytest.mark.parametrize("tokens,dim,width,experts,held", [
+    pytest.param(2048, 2048, 768, 128, 16, id="keye"),
+    pytest.param(8192, 2304, 896, 64, 16, id="mellum2"),
+])
+def test_routed_experts_kernels_compile_at_published_widths(
+        one_chip, tokens, dim, width, experts, held):
+    """Keye's experts (2,048 tokens of width 2048 through 16 held
+    experts of width 768, top-8 of 128) and Mellum2's (8,192 tokens of
+    width 2304, a row that is no multiple of 1,024 lanes, through 16
+    held of width 896, top-8 of 64), value and gradient. The fast part
+    of the rows: three grouped products and the combine's
+    ``sum_rows`` forward, six grouped products and the dispatch's
+    ``sum_rows`` backward. The part behind the ``cond``: the same four
+    forward, and in the backward ``cond`` the three forward products
+    again (their ``sum_rows`` is nobody's there) with the same seven.
+    No buffer holds a row for every (token, choice) pair."""
     from parallax_tpu.ops import moe
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def loss(tokens, router, w_gate, w_up, w_down):
-        route = moe.linear_router(tokens, router, 8)
+    def loss(toks, router, w_gate, w_up, w_down):
+        route = moe.linear_router(toks, router, 8)
         return jnp.sum(moe.routed_experts(
-            tokens, route.choice, route.gate, w_gate, w_up, w_down,
-            num_experts=128, impl="gmm").out.astype(jnp.float32))
+            toks, route.choice, route.gate, w_gate, w_up, w_down,
+            num_experts=experts, impl="gmm").out.astype(jnp.float32) ** 2)
 
     compiled = _compile(
-        jax.grad(loss, argnums=(0, 2, 3, 4)),
-        sds((2048, 2048), jnp.bfloat16), sds((2048, 128), jnp.float32),
-        sds((16, 2048, 768), jnp.float32), sds((16, 2048, 768), jnp.float32),
-        sds((16, 768, 2048), jnp.float32))
-    assert _kernels(compiled) == 18
-    # no product over tokens x experts held
-    assert "[2048,16,768]" not in compiled.as_text()
+        jax.value_and_grad(loss, argnums=(0, 2, 3, 4)),
+        sds((tokens, dim), jnp.bfloat16), sds((dim, experts), jnp.float32),
+        sds((held, dim, width), jnp.float32),
+        sds((held, dim, width), jnp.float32),
+        sds((held, width, dim), jnp.float32))
+    text = compiled.as_text()
+    names = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert _kernels(compiled) == len(names) == 25
+    assert sum("sum_rows" in n for n in names) == 4
+    assert sum("gmm" in n for n in names) == 21
+    # no product over tokens x experts held, no row for every pair
+    assert f"[{tokens},{held},{width}]" not in text
+    assert f"[{8 * tokens},{dim}]" not in text
 
 
 def test_flash_kernels_compile_with_grouped_heads_at_8k(one_chip):
@@ -253,6 +271,8 @@ def test_zaya_step_compiles_at_published_widths_and_fits(topo):
     names = re.findall(
         r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
     kinds = sorted({n.rsplit(".", 1)[0] for n in names})
+    # (no `sum_rows`: one choice a token and every pair in one part, so
+    # the per-token sums are gathers of each token's own row)
     assert kinds == ["flash_dkv", "flash_dq", "flash_fwd", "gmm", "tgmm"]
     # neither every expert for every token nor whole float32 scores
     assert "[8192,8,2048]" not in text
@@ -321,7 +341,9 @@ def test_mellum2_step_compiles_at_published_widths_and_fits(topo):
         "flash_fwd", "flash_fwd_win"]
     # (the experts' second part, under its own `cond`, names its calls
     # after the transformation that made them)
-    assert all("gmm" in n for n in names if not n.startswith("flash_"))
+    assert all("gmm" in n or "sum_rows" in n for n in names
+               if not n.startswith("flash_"))
+    assert any("sum_rows" in n for n in names)
     # ONE loop over the layers a direction: the entry computation holds
     # two, the forward one's body the `conditional` with the two forward
     # calls and the backward one's the four backward calls
@@ -348,6 +370,8 @@ def test_mellum2_step_compiles_at_published_widths_and_fits(topo):
                                                    "flash_fwd_win"]
     assert calls_under_conditionals(bodies[1]) == [
         "flash_dkv", "flash_dkv_win", "flash_dq", "flash_dq_win"]
-    # neither every expert for every token nor whole float32 scores
+    # neither every expert for every token, nor a row for every (token,
+    # choice) pair, nor whole float32 scores
     assert "[8192,16,896]" not in text
+    assert "[65536,2304]" not in text
     assert not re.search(r"f32\[(1,)?8192,8192\]", text)

@@ -162,6 +162,7 @@ def test_the_blocks_equal_a_plain_scan_and_the_stacks_keep_their_layout():
     for key, read in {
             "lm_loss": want, "moe_dropped": jnp.max(s["moe_dropped"]),
             "moe_rows_here": jnp.mean(s["moe_rows_here"]),
+            "moe_rows_walked": jnp.mean(s["moe_rows_walked"]),
             "moe_load_max_over_mean": jnp.mean(s["moe_load_max_over_mean"]),
             "router_gate_mean": jnp.mean(s["gate_mean"]),
             "router_bias_abs_max": jnp.max(jnp.abs(new_beta))}.items():
@@ -380,6 +381,9 @@ def test_trains_through_parallel_run_with_its_state_and_gauges():
     snap = sess.metrics_snapshot()
     assert snap["moe.dropped"] == 0.0
     assert snap["moe.rows_here"] == float(out[2])
+    # one choice a token and every pair in one part: the per-token sums
+    # fetch each token's own row (`ops/moe._sum_by_token`)
+    assert snap["moe.rows_walked"] == batch["x"].size > snap["moe.rows_here"]
     assert snap["moe.load_max_over_mean"] >= 1.0
     assert 1.0 / cfg.num_experts < snap["router.gate_mean"] < 1.0
     assert snap["router.bias_abs_max"] == pytest.approx(float(out[4]))
