@@ -25,18 +25,29 @@ Two cache layers with different lifetimes:
   relative to the checkout) is part of the key: a stale cache can only
   miss, never corrupt — not the program and not the names that
   ``Engine.layer_index()`` reads off the executable.
+
+What jax itself knows about a compile — how long it traced, lowered
+and compiled, and whether the persistent cache served it — is heard by
+``compile_events`` (one ``CompileEvents`` a process, listening from the
+first ``ensure_persistent_cache`` on) and shown by every session's
+registry under ``compile.*``.
 """
 
 from __future__ import annotations
 
+import functools
 import os
 import re
+import threading
+import time
 from typing import Dict, Optional, Tuple
 
 import jax
 
 from parallax_tpu.common.lib import parallax_log
+from parallax_tpu.obs import _state as obs_state
 from parallax_tpu.obs import metrics as obs_metrics
+from parallax_tpu.obs import trace
 
 CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -44,6 +55,142 @@ CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 # directory is part of how a cache is found again, so one made from
 # tempfile, a pid or the time would never hit.
 CHECKOUT_CACHE_DIR = os.path.join(CHECKOUT_ROOT, ".jax_cache")
+
+
+# jax's three nested-able compile phases (``dispatch.log_elapsed_time``
+# announces each one's start as a scalar and its end as a duration):
+# event -> (registry name, span name)
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration":
+        ("compile.trace_s", "jax.trace"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("compile.lower_s", "jax.lower"),
+    "/jax/core/compile/backend_compile_duration":
+        ("compile.backend_s", "jax.backend_compile"),
+}
+# plain durations, parts of ``compile.backend_s``
+_DURATIONS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "compile.cache_retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec":
+        "compile.cache_saved_s",
+}
+_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+    "/jax/compilation_cache/compile_requests_use_cache":
+        "compile.cache_requests",
+}
+# a trace or a lowering shorter than this leaves no span: one step's
+# trace holds hundreds of inner jits of a millisecond each
+_SPAN_MIN_S = 0.010
+
+
+class CompileEvents:
+    """jax's own compile events, summed over the process.
+
+    ``jax.monitoring`` tells whoever listens how long every jitted
+    function took to trace (``compile.trace_s``), to lower to MLIR,
+    Pallas kernels to Mosaic included (``compile.lower_s``), and to
+    compile or to be read back from the persistent cache
+    (``compile.backend_s``: the event wraps ``compile_or_get_cached``;
+    ``compile.cache_retrieval_s`` is the part of it spent reading and
+    deserialising hits, ``compile.cache_saved_s`` what the hits say
+    they saved), and counts the persistent cache's requests, hits and
+    misses (a miss is counted where its entry is written).
+
+    The three phases nest — an inner ``jit`` is traced inside its
+    caller's trace, and an operation on concrete values inside a trace
+    is dispatched, so traced, lowered and compiled, inside it — and
+    each second is counted ONCE, under the innermost phase open on its
+    thread: the three sums never exceed the wall clock they cover.
+
+    A backend compile, and a trace or a lowering over 10 ms, also
+    leaves a span (``jax.backend_compile``, ``jax.trace``,
+    ``jax.lower``) carrying ``fun``, the function's name, so that the
+    exported chrome trace says which function compiled when.
+
+    The events are the process's, not a session's: ``expose`` hangs the
+    sums into a registry as gauges that read them at snapshot time.
+    Nothing is heard while ``obs`` is disabled.
+    """
+
+    NAMES = tuple(name for name, _ in _PHASES.values()) \
+        + tuple(_DURATIONS.values()) + tuple(_COUNTS.values())
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._sums: Dict[str, float] = {
+            name: 0 if name in _COUNTS.values() else 0.0
+            for name in self.NAMES}
+        # per thread: the seconds of finished phases inside each phase
+        # that is still open
+        self._open = threading.local()
+        self._listening = False
+
+    def listen(self) -> None:
+        """Register with ``jax.monitoring``, once however often called."""
+        with self._lock:
+            if self._listening:
+                return
+            self._listening = True
+        jax.monitoring.register_scalar_listener(self._on_start)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def value(self, name: str):
+        with self._lock:
+            return self._sums[name]
+
+    def snapshot(self) -> Dict[str, float]:
+        with self._lock:
+            return dict(self._sums)
+
+    def expose(self, registry: obs_metrics.MetricsRegistry) -> None:
+        for name in self.NAMES:
+            registry.gauge(name).set_fn(
+                functools.partial(self.value, name))
+
+    def _add(self, name: str, amount) -> None:
+        with self._lock:
+            self._sums[name] += amount
+
+    def _on_start(self, event: str, value, **kwargs) -> None:
+        if event in _PHASES:
+            stack = getattr(self._open, "stack", None)
+            if stack is None:
+                stack = self._open.stack = []
+            stack.append(0.0)
+
+    def _on_duration(self, event: str, duration: float, **kwargs) -> None:
+        phase = _PHASES.get(event)
+        if phase is None:
+            name = _DURATIONS.get(event)
+            if name is not None and obs_state.enabled:
+                self._add(name, duration)
+            return
+        # kept in step with _on_start whether or not obs is enabled
+        stack = getattr(self._open, "stack", None)
+        inside = stack.pop() if stack else 0.0
+        if stack:
+            stack[-1] += duration
+        if not obs_state.enabled:
+            return
+        name, span_name = phase
+        self._add(name, max(0.0, duration - inside))
+        if span_name == "jax.backend_compile" or duration >= _SPAN_MIN_S:
+            now = time.perf_counter()
+            trace.record_span(span_name, now - duration, now,
+                              fun=kwargs.get("fun_name"))
+
+    def _on_event(self, event: str, **kwargs) -> None:
+        name = _COUNTS.get(event)
+        if name is not None and obs_state.enabled:
+            self._add(name, 1)
+
+
+compile_events = CompileEvents()
 
 
 # the directory the first call settled on; decided once per process so
@@ -83,8 +230,12 @@ def ensure_persistent_cache(explicit_dir: Optional[str] = None) -> str:
     commit still share entries. The price: an edit that moves a source
     line under a jitted function compiles it once more at the next
     start; a relaunch of unchanged code still hits.
+
+    Every call also makes sure ``compile_events`` listens, so that the
+    process's compiles are heard from its first entry point on.
     """
     global _decided_dir
+    compile_events.listen()
     if _decided_dir is not None:
         if explicit_dir and explicit_dir != _decided_dir:
             parallax_log.warning(

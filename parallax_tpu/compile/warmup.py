@@ -9,7 +9,10 @@ XLA compile. The resulting executables are held by the engine and
 dispatched by shape signature (``Engine.step``); per-signature compile
 wall-time lands in the ``engine.compile_seconds`` histogram and in
 ``Engine.warmup_seconds`` (both reported by
-``ParallaxSession.compile_stats``).
+``ParallaxSession.compile_stats``). Inside the ``engine.warmup_compile``
+span the two halves have spans of their own: ``engine.lower`` (the
+step traced and lowered, in Python) and ``engine.compile`` (XLA's
+compile, or the persistent cache's entry read back: ``cache_hit``).
 
 Lowering needs concrete input layouts: the live ``TrainState`` carries
 its real shardings, and batch avals are ``ShapeDtypeStruct``s with the
@@ -24,6 +27,7 @@ from typing import Dict, Optional, Sequence
 
 from parallax_tpu.common.lib import parallax_log
 from parallax_tpu.compile import bucketing
+from parallax_tpu.compile.cache import compile_events
 from parallax_tpu.obs import trace
 
 
@@ -52,13 +56,25 @@ def aot_warmup(engine, state, batch_sizes: Optional[Sequence[int]] = None
             continue
         t0 = time.perf_counter()
         with trace.span("engine.warmup_compile", batch=b):
-            compiled = engine._step_jit.lower(state, avals).compile()
+            with trace.span("engine.lower", batch=b):
+                lowered = engine._step_jit.lower(state, avals)
+            t1 = time.perf_counter()
+            hits = compile_events.value("compile.cache_hits")
+            compiled = lowered.compile()
+            t2 = time.perf_counter()
+            # (False too while obs is disabled: nobody counted the hit)
+            cache_hit = compile_events.value("compile.cache_hits") > hits
+            trace.record_span("engine.compile", t1, t2, batch=b,
+                              cache_hit=cache_hit)
         dt = time.perf_counter() - t0
         engine._executables[sig] = compiled
         engine._traced_signatures.add(sig)
         engine.metrics.histogram("engine.compile_seconds").record(dt)
         stats[b] = dt
-        parallax_log.info("warmup: compiled step for batch bucket %d "
-                          "in %.2fs", b, dt)
+        parallax_log.info(
+            "warmup: step for batch bucket %d %s in %.2fs (traced and "
+            "lowered in %.2fs, %s in %.2fs)", b,
+            "loaded from the cache" if cache_hit else "compiled", dt,
+            t1 - t0, "loaded" if cache_hit else "compiled", t2 - t1)
     engine.warmup_seconds.update(stats)
     return stats

@@ -157,6 +157,9 @@ class Model:
         except (TypeError, ValueError):
             n_pos = 4 if stateful else 2
         self._loss_takes_rng = n_pos >= (4 if stateful else 3)
+        # the ``engine.model_traces`` counter of the registry whose
+        # engine holds this model (``Engine.__init__`` hangs it here)
+        self.trace_counter: Optional[obs_metrics.Counter] = None
 
     def call_init(self, rng):
         """Returns (params, model_state); model_state is None for
@@ -167,7 +170,12 @@ class Model:
         return out, None
 
     def call_loss(self, params, batch, rng, model_state=None):
-        """Returns (loss, metrics, new_model_state)."""
+        """Returns (loss, metrics, new_model_state). Runs only under a
+        trace (``make_jaxpr``, ``eval_shape``, ``jit``), and counts
+        each one: a whole trace of the user's forward pass is seconds
+        of start-up at a real model's size."""
+        if self.trace_counter is not None:
+            self.trace_counter.inc()
         if self.stateful:
             args = (params, model_state, batch)
         else:
@@ -225,11 +233,12 @@ def build_plan(model: Model, mesh: Mesh, config: ParallaxConfig,
         return model.call_loss(params, batch, rng, mstate)[0]
 
     rng_shape = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    var_specs = classify.classify_params(
-        abstract_loss, params_shapes, example_batch, rng_shape,
-        model_state_shapes,
-        sparse_override=model.sparse_params,
-        dense_override=model.dense_params)
+    with trace.span("engine.classify"):
+        var_specs = classify.classify_params(
+            abstract_loss, params_shapes, example_batch, rng_shape,
+            model_state_shapes,
+            sparse_override=model.sparse_params,
+            dense_override=model.dense_params)
 
     flat, treedef = jax.tree_util.tree_flatten_with_path(params_shapes)
     paths = [classify._pathname(kp) for kp, _ in flat]
@@ -357,6 +366,7 @@ class Engine:
         self.metrics = metrics if metrics is not None \
             else obs_metrics.MetricsRegistry()
         self._recompiles = self.metrics.counter("engine.recompiles")
+        model.trace_counter = self.metrics.counter("engine.model_traces")
         # batch-shape signatures already traced: a growing set means
         # shape-driven retraces (each one a full XLA compile)
         self._traced_signatures: set = set()
@@ -542,9 +552,11 @@ class Engine:
                     loss, _, _ = model.call_loss(params, batch, rng,
                                                  mstate)
                 return loss
-            jax.eval_shape(_discover, self._params_shapes, batch_shapes,
-                           jax.ShapeDtypeStruct((2,), jnp.uint32),
-                           mstate_shapes)
+            with trace.span("engine.discover_slices"):
+                jax.eval_shape(_discover, self._params_shapes,
+                               batch_shapes,
+                               jax.ShapeDtypeStruct((2,), jnp.uint32),
+                               mstate_shapes)
             events = holder[0].events
             missing = set(slice_resolved) - {p for p, _, _ in events}
             if missing:

@@ -35,7 +35,8 @@ from parallax_tpu.obs import _state
 
 
 class Counter:
-    """Monotonic named count."""
+    """Monotonic named count, or sum: ``inc`` takes any amount, seconds
+    too (``startup.api_s``)."""
 
     __slots__ = ("name", "_lock", "_value")
 
@@ -44,7 +45,7 @@ class Counter:
         self._lock = threading.Lock()
         self._value = 0
 
-    def inc(self, n: int = 1) -> None:
+    def inc(self, n: float = 1) -> None:
         if not _state.enabled:
             return
         with self._lock:

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import os
 import sys
+import time
 from typing import Optional, Tuple
 
 import jax
@@ -34,6 +35,7 @@ from parallax_tpu.common.lib import (HostInfo, deserialize_resource_info,
                                      parallax_log, parse_resource_info)
 from parallax_tpu import launcher, shard as shard_lib
 from parallax_tpu.core.engine import Model
+from parallax_tpu.obs import trace
 from parallax_tpu.parallel.partitions import PartitionSearch, get_partitioner
 from parallax_tpu.session import ParallaxSession
 
@@ -51,7 +53,20 @@ def parallel_run(model: Model,
     PARALLAX_MIN_PARTITIONS is set. A ``Config.tune_config`` supersedes
     the 1-D search entirely: the session plans through
     ``tune.MeshSearch`` over (dp x tp) mesh shapes and run options,
-    with ``num_partitions`` (when given) only seeding the base plan."""
+    with ``num_partitions`` (when given) only seeding the base plan.
+
+    Its wall seconds are the first part of the session's
+    ``startup.api_s`` (``session._startup_entry``)."""
+    t0 = time.perf_counter()
+    with trace.span("parallax.parallel_run"):
+        out = _parallel_run(model, resource_info, sync, parallax_config,
+                            seed, num_partitions)
+    out[0].metrics.counter("startup.api_s").inc(time.perf_counter() - t0)
+    return out
+
+
+def _parallel_run(model, resource_info, sync, parallax_config, seed,
+                  num_partitions):
     config = parallax_config or ParallaxConfig()
     config.set_sync(sync)
 

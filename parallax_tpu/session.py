@@ -38,6 +38,7 @@ we re-jit and reshard in place).
 
 from __future__ import annotations
 
+import functools
 import operator
 import threading
 import time
@@ -249,6 +250,27 @@ class StepHandle:
         return materialize(self._value)
 
 
+def _startup_entry(method):
+    """A public entry point that runs before a loop: the wall seconds
+    inside the OUTERMOST such call on a thread (``warmup`` calling
+    ``prepare`` counts once) are added to the float counter
+    ``startup.api_s``, the program's share of a caller's set-up.
+    ``runner.parallel_run`` adds its own seconds to the same counter."""
+    @functools.wraps(method)
+    def entry(self, *args, **kwargs):
+        phase = self._phase
+        if getattr(phase, "in_entry", False):
+            return method(self, *args, **kwargs)
+        phase.in_entry = True
+        t0 = time.perf_counter()
+        try:
+            return method(self, *args, **kwargs)
+        finally:
+            phase.in_entry = False
+            self._api_s.inc(time.perf_counter() - t0)
+    return entry
+
+
 class ParallaxSession:
     def __init__(self, model: engine_lib.Model, config: ParallaxConfig,
                  num_workers: int, worker_id: int,
@@ -314,6 +336,7 @@ class ParallaxSession:
         if config.trace_buffer_events > trace.get_collector().capacity:
             trace.get_collector().set_capacity(config.trace_buffer_events)
         self.metrics = MetricsRegistry()
+        self._api_s = self.metrics.counter("startup.api_s")
         # async pipeline stats flow through the registry (pipeline.*)
         self.pipeline_stats = PipelineStats(self.metrics)
         # -- training forensics (obs/timeline, anomaly, flightrec) -----
@@ -448,6 +471,9 @@ class ParallaxSession:
         self._warmup_threads: List[threading.Thread] = []
         compile_cache.ensure_persistent_cache(
             config.compilation_cache_dir)
+        # jax's own compile events (compile.*): the process's sums,
+        # read at snapshot time
+        compile_cache.compile_events.expose(self.metrics)
         self._install_preemption_handler()
 
     # -- lazy build (needs the first batch to know shapes) ----------------
@@ -688,15 +714,18 @@ class ParallaxSession:
 
     # -- the patched-run equivalent ---------------------------------------
 
+    @_startup_entry
     def prepare(self, feed_dict: Dict[str, Any]) -> int:
         """Build the engine (and restore any configured checkpoint)
         from an example batch WITHOUT running a step; returns the
         restored global step (0 on a fresh run). Lets callers read
         ``state``/``engine``/the mesh — or seed per-step data correctly
         on an elastic resume — before the first training step."""
-        self._ensure_engine(self._convert_feed(feed_dict))
+        with trace.span("session.prepare"):
+            self._ensure_engine(self._convert_feed(feed_dict))
         return int(self._state.step)
 
+    @_startup_entry
     def run(self, fetches: Union[None, str, Sequence[str]] = None,
             feed_dict: Optional[Dict[str, Any]] = None):
         if feed_dict is None:
@@ -1755,6 +1784,7 @@ class ParallaxSession:
 
     # -- compile-ahead engine (compile/) ----------------------------------
 
+    @_startup_entry
     def warmup(self, feed_dict: Optional[Dict[str, Any]] = None,
                batch_sizes: Optional[Sequence[int]] = None,
                background: bool = False):
@@ -1813,10 +1843,18 @@ class ParallaxSession:
     def compile_stats(self) -> Dict[str, Any]:
         """JSON-ready compile/caching report (the tuner's and the
         cache's tests read it): declared bucket sizes, per-bucket AOT compile
-        seconds, and the executable-/engine-cache hit and miss
-        counters."""
+        seconds, the executable-/engine-cache hit and miss
+        counters, how often the model's loss was traced
+        (``model_traces``) and, under ``jax``, what jax itself reported
+        of this PROCESS's compiles (``compile/cache.CompileEvents``:
+        seconds traced, lowered, and compiled or read back from the
+        persistent cache; its requests, hits and misses)."""
         eng = self._engine
         return {
+            "model_traces": self.metrics.counter(
+                "engine.model_traces").value,
+            "jax": {name[len("compile."):]: value for name, value in
+                    compile_cache.compile_events.snapshot().items()},
             "shape_buckets": (list(eng._buckets)
                               if eng is not None and eng._buckets
                               else None),

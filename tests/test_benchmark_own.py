@@ -28,8 +28,8 @@ for _p in (BENCH_DIR, ROOT):
         sys.path.insert(0, _p)
 
 FILES = ("test_contract", "test_generators", "test_indexer_kernel_reader",
-         "test_layer_account", "test_stats", "test_table_kernel_reader",
-         "test_xplane")
+         "test_layer_account", "test_setup_readers", "test_stats",
+         "test_table_kernel_reader", "test_xplane")
 
 
 def _is_fixture(obj) -> bool:
