@@ -2,6 +2,7 @@
 adagrad parity with the reference's SparseApplyAdagrad semantics
 (reference graph_transform_lib.py:71-77)."""
 
+import collections
 import functools
 
 import jax
@@ -260,6 +261,105 @@ def test_row_kernel_trajectory_matches_optax(rng, monkeypatch):
     rest = np.setdiff1d(np.arange(V), rows)
     np.testing.assert_array_equal(np.asarray(p_k)[rest],
                                   np.asarray(p_d)[rest])
+
+
+# ---------------------------------------------------------------------------
+# The combining (`_combine_slices`): the distinct ids, sorted, ahead of
+# the sentinel V, and every slot's row summed onto its id's place,
+# against NumPy's `unique` and `add.at`; and that it is made of sorts:
+# an id-sized gather or scatter walks its indices one at a time on the
+# chip (PERF.md section 6, PR 36).
+# ---------------------------------------------------------------------------
+
+def _zipf_ids(rng, V, cap):
+    return (rng.zipf(1.3, size=cap) - 1) % V
+
+
+def _ids_one_id(rng, V, cap):
+    return np.full(cap, V // 3)
+
+
+COMBINE_CASES = {
+    # name: (V, slots, D, ids, average, grad_scale)
+    "no_duplicates": (1000, 64, 8, _ids_all_live, False, 1.0),
+    "every_id_the_same": (1000, 48, 8, _ids_one_id, False, 1.0),
+    "negative_and_past_the_table": (1000, 96, 8, _ids_out_of_range,
+                                    False, 1.0),
+    "every_id_out_of_range": (1000, 32, 8, _ids_none_live, False, 1.0),
+    "slots_not_a_multiple_of_8": (1000, 13, 3, _ids_duplicates, False, 1.0),
+    "one_slot": (1000, 1, 4, _ids_duplicates, False, 1.0),
+    "average": (1000, 96, 8, _ids_out_of_range, True, 1.0),
+    "grad_scale": (1000, 96, 8, _ids_duplicates, False, 128.0),
+    "average_and_grad_scale": (1000, 41, 8, _ids_duplicates, True, 0.25),
+    "zipf_lm1b_emb_slots": (793470, 2560, 8, _zipf_ids, False, 1.0),
+    "zipf_lm1b_softmax_slots": (793470, 10752, 8, _zipf_ids, False, 1.0),
+}
+
+
+def _combine_reference(ids, drows, V, average, grad_scale):
+    key = np.where((ids >= 0) & (ids < V), ids, V)
+    distinct, inv = np.unique(key, return_inverse=True)
+    uids = np.full(ids.shape[0], V, np.int64)
+    uids[:distinct.size] = distinct
+    # float32 and in the slots' order, as the program sums
+    gsum = np.zeros(drows.shape, np.float32)
+    np.add.at(gsum, inv, drows * np.float32(grad_scale))
+    if average:
+        cnt = np.bincount(inv, minlength=ids.shape[0])
+        gsum = gsum * (np.float32(1.0) / np.maximum(cnt, 1).astype(
+            np.float32))[:, None]
+    return uids, gsum
+
+
+@pytest.mark.parametrize("case", sorted(COMBINE_CASES))
+def test_combine_slices_matches_numpy_unique_and_add_at(rng, case):
+    V, cap, D, make_ids, average, grad_scale = COMBINE_CASES[case]
+    ids = np.asarray(make_ids(rng, V, cap)).astype(np.int32)
+    drows = rng.standard_normal((cap, D)).astype(np.float32)
+    uids, gsum = jax.jit(functools.partial(
+        so._combine_slices, V=V, dtype=jnp.float32, average=average,
+        grad_scale=grad_scale))(jnp.asarray(ids), jnp.asarray(drows))
+    want_uids, want_gsum = _combine_reference(ids, drows, V, average,
+                                              grad_scale)
+    assert uids.dtype == jnp.int32 and gsum.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(uids), want_uids)
+    n = int(np.sum(want_uids < V))
+    assert np.all(np.diff(want_uids[:n]) > 0)      # sorted, distinct, then V
+    scale = max(1.0, float(np.max(np.abs(want_gsum))))
+    np.testing.assert_allclose(np.asarray(gsum), want_gsum, rtol=0,
+                               atol=1e-6 * scale)
+    if not average:
+        # and to the bit what jnp.unique's inverse would have summed
+        _, inv = jnp.unique(jnp.where((ids >= 0) & (ids < V), ids, V),
+                            size=cap, fill_value=V, return_inverse=True)
+        through_unique = jnp.zeros((cap, D), jnp.float32).at[
+            inv.reshape(-1)].add(jnp.asarray(drows) * jnp.float32(grad_scale))
+        np.testing.assert_array_equal(np.asarray(gsum),
+                                      np.asarray(through_unique))
+
+
+def _primitives(jaxpr, into):
+    for eqn in jaxpr.eqns:
+        into[eqn.primitive.name] += 1
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                _primitives(inner, into)
+    return into
+
+
+@pytest.mark.parametrize("average", [False, True])
+def test_combine_slices_is_sorts_and_one_scatter_add(average):
+    """No id-sized array is walked by index: no `gather`, no `scatter`,
+    one `scatter-add` (the rows' sum; the occurrence count is a second
+    under `average`), at most three sorts."""
+    jaxpr = jax.make_jaxpr(functools.partial(
+        so._combine_slices, V=793470, dtype=jnp.float32, average=average))(
+            jnp.zeros((10752,), jnp.int32), jnp.zeros((10752, 512)))
+    count = _primitives(jaxpr.jaxpr, collections.Counter())
+    assert count["gather"] == 0 and count["scatter"] == 0, count
+    assert count["scatter-add"] == (2 if average else 1), count
+    assert 1 <= count["sort"] <= 3, count
 
 
 def _mesh(n):
