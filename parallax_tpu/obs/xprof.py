@@ -80,11 +80,15 @@ _COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
 # flash calls of a layer that attends under a window, forward and
 # backward (inside a block's ``attention``, where it wins: models/mellum2
 # has both kinds of layer under one loop body, and the account tells a
-# window layer's kernels from a full layer's by it). A step holds the
+# window layer's kernels from a full layer's by it); models/olmo_hybrid's
+# ``linear_attention`` (a linear layer's mixer), ``delta_rule`` inside it
+# (ops/delta_rule's kernels and their call site; the inner scope wins)
+# and ``mlp`` (the dense SwiGLU MLP of both kinds). A step holds the
 # scopes of its own model only. ``layer_of`` reads them back off a
 # compiled instruction's ``op_name``.
 LAYER_SCOPES = ("embedding", "lstm", "sampled_softmax", "layer_scan",
                 "attention", "window_attention", "indexer", "cca_mix",
+                "linear_attention", "delta_rule", "mlp",
                 "moe", "router", "lm_head", "dense_update", "table_update")
 # the row-sharded table path — the paper's sparse side of the
 # dense-vs-sparse variable split

@@ -1,5 +1,5 @@
-"""The layers' names inside the compiled step: the five
-``jax.named_scope``s of ``obs/xprof.LAYER_SCOPES`` as
+"""The layers' names inside the compiled step: the
+``jax.named_scope``s of ``obs/xprof.LAYER_SCOPES`` (sixteen) as
 ``Engine.layer_index()`` reads them back off the executable, the one
 rule for reading a scope (``xprof.layer_of`` / ``sparse_split``), and
 the compile cache's key, which must hold the names
@@ -32,6 +32,10 @@ ZAYA_SCOPES = ["embedding", "layer_scan", "attention", "cca_mix", "moe",
 # the windowed flash calls' scope is ops/pallas_attention's own
 MELLUM2_SCOPES = ["embedding", "layer_scan", "attention", "window_attention",
                   "moe", "lm_head", "dense_update", "table_update"]
+# two kinds of layer with weights of their own under a scan of periods
+OLMO_SCOPES = ["embedding", "layer_scan", "attention", "linear_attention",
+               "delta_rule", "mlp", "lm_head", "dense_update",
+               "table_update"]
 
 
 def _session(**cfg_kw):
@@ -97,7 +101,7 @@ def test_every_declared_scope_is_found_in_the_keye_step():
     assert index["scopes_found"] == KEYE_SCOPES
     assert [s for s in xprof.LAYER_SCOPES if s in KEYE_SCOPES] == KEYE_SCOPES
     assert set(LM1B_SCOPES) | set(KEYE_SCOPES) | set(ZAYA_SCOPES) \
-        | set(MELLUM2_SCOPES) == set(xprof.LAYER_SCOPES)
+        | set(MELLUM2_SCOPES) | set(OLMO_SCOPES) == set(xprof.LAYER_SCOPES)
     inner = {n: m for n, m in index["hlo_index"].items()
              if re.search(r"attention\)*/(.*/)?indexer", m.get("op_name", ""))}
     assert inner
@@ -220,6 +224,59 @@ def test_window_attention_resolves_inside_attention(mellum2_index,
             and "window_attention" not in m["op_name"] and of(direction, m)]
     assert full
     assert {index["layers"][n] for n in full} == {"attention"}
+
+
+@pytest.fixture(scope="module")
+def olmo_index():
+    from parallax_tpu.models import olmo_hybrid
+    cfg = olmo_hybrid.tiny_config(flash_tiles=(8, 8), heads_held=2)
+    sess, *_ = parallax.parallel_run(
+        olmo_hybrid.build_model(cfg, impls=("flash_interpret", "interpret")),
+        parallax_config=parallax.Config(
+            run_option="HYBRID", sparse_grad_mode="slices",
+            search_partitions=False, shape_buckets=[8]))
+    batch = olmo_hybrid.make_batch(np.random.default_rng(0), 8, cfg.seq_len,
+                                   cfg.vocab_size)
+    sess.warmup(feed_dict=batch)
+    index = sess.layer_index()
+    sess.close()
+    return index
+
+
+def test_sixteen_scopes_and_the_olmo_step_declares_its_own(olmo_index):
+    """``LAYER_SCOPES`` holds sixteen names; Olmo-Hybrid's step holds
+    its nine in their order, no MoE model's among them."""
+    assert len(xprof.LAYER_SCOPES) == len(set(xprof.LAYER_SCOPES)) == 16
+    assert olmo_index["scopes_found"] == OLMO_SCOPES
+    assert [s for s in xprof.LAYER_SCOPES if s in OLMO_SCOPES] \
+        == OLMO_SCOPES
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_the_rule_resolves_inside_linear_attention(olmo_index, direction):
+    """``delta_rule`` is traced inside ``linear_attention`` in both
+    passes and wins there: the rule's kernel calls go by it, the
+    projections, convolutions and gates around it stay the outer
+    scope's; ``mlp`` stands beside them under ``layer_scan``."""
+    def of(meta):
+        return ("transpose(" in meta.get("op_name", "")) \
+            == (direction == "backward")
+
+    index = olmo_index
+    nested = {n: m for n, m in index["hlo_index"].items()
+              if re.search(r"linear_attention\)*/(.*/)?delta_rule",
+                           m.get("op_name", "")) and of(m)}
+    assert nested
+    assert {index["layers"][n] for n in nested} == {"delta_rule"}
+    kernel = "delta_bwd" if direction == "backward" else "delta_fwd"
+    assert any(kernel in m["op_name"] for m in nested.values())
+    outer = [n for n, m in index["hlo_index"].items()
+             if re.search(r"linear_attention\)*(/|$)", m.get("op_name", ""))
+             and "delta_rule" not in m["op_name"] and of(m)]
+    assert outer
+    assert {index["layers"][n] for n in outer} == {"linear_attention"}
+    assert "mlp" in {index["layers"][n] for n, m in
+                     index["hlo_index"].items() if of(m)}
 
 
 def test_table_scatter_maps_to_table_update(warmed):
@@ -362,6 +419,14 @@ def test_lax_scan_branch_carries_the_lstm_scope():
     ("jit(train_step)/transpose(jvp(layer_scan))/while/body/closed_call/"
      "checkpoint/attention/cond/branch_0_fun/flash_dq", "attention",
      "dense"),
+    # the Olmo-Hybrid step's own: the rule inside a linear layer's mixer,
+    # the MLP beside it
+    ("jit(train_step)/jvp(layer_scan)/while/body/while/body/checkpoint/"
+     "linear_attention/delta_rule/delta_fwd", "delta_rule", "dense"),
+    ("jit(train_step)/transpose(jvp(layer_scan))/while/body/while/body/"
+     "checkpoint/linear_attention/dot_general", "linear_attention", "dense"),
+    ("jit(train_step)/jvp(layer_scan)/while/body/checkpoint/mlp/"
+     "dot_general", "mlp", "dense"),
     # a primitive or a user's scope that merely contains a layer's name
     ("jit(train_step)/jvp(my_lstm_block)/dot_general", None, None),
     ("jit(train_step)/model/embedding_norm/mul", None, None),
