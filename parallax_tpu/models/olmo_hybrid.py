@@ -35,7 +35,17 @@ compiled body a kind of layer however deep the model (``PERF.md`` section
 ``models/mellum2``'s one body with a ``cond`` could not hold two kinds of
 weights. Each layer is rematerialised, keeping the flash kernels' output
 and logsumexp and the rule's outputs, states and systems
-(``ops/delta_rule.KEPT``) so that no kernel runs a second time.
+(``ops/delta_rule.KEPT``) so that no kernel runs a second time, and two
+of the MLP's three products (``MLP_KEPT``: the up projection's output
+``[B, T, F]`` and the down product's ``[B, T, D]``, 0.97 GB in bfloat16
+over the benchmark cell's four layers of 8,192 x 11,008) so that only
+the gate's is made again. The down product's output is among them
+because the norm stands on the sub-block's OUTPUT: its backward needs
+``mlp(h)`` itself. The gate's pre-activation is not: with all three kept
+(1.69 GB) the step fits the chip but the benchmark's comparison, which
+evaluates this ``forward`` beside the whole training state and a rounded
+copy of the weights, does not (``PERF.md`` section 6, PR 38). The
+mixers' projections are still made again.
 
 **The chip's share** (``PERF.md`` section 4): ``heads_held`` of the
 layer's ``num_heads``, of both mixers alike (a unit is the full layer's
@@ -66,6 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.ad_checkpoint import checkpoint_name
 
 from parallax_tpu.core.engine import Model
 from parallax_tpu.models.keye_vl2 import in_compute_dtype, rms_norm
@@ -76,6 +87,8 @@ from parallax_tpu.ops import embedding as emb_ops
 from parallax_tpu.ops import pallas_attention as pa
 
 LINEAR, FULL = "linear_attention", "full_attention"
+# the name by which a layer's remat keeps the MLP's up and down products
+MLP_KEPT = "mlp_rows"
 
 
 @dataclasses.dataclass
@@ -176,7 +189,9 @@ def mlp(p, x, dt):
     """The dense SwiGLU MLP on ``x [B, T, D]``."""
     with jax.named_scope("mlp"):
         gate = jax.nn.silu(x @ p["w_gate"].astype(dt))
-        return (gate * (x @ p["w_up"].astype(dt))) @ p["w_down"].astype(dt)
+        up = checkpoint_name(x @ p["w_up"].astype(dt), MLP_KEPT)
+        return checkpoint_name((gate * up) @ p["w_down"].astype(dt),
+                               MLP_KEPT)
 
 
 def linear_mixer(cfg: OlmoHybridConfig, p, x, impl=None):
@@ -355,10 +370,13 @@ def forward(cfg: OlmoHybridConfig, params, batch, impls=(None, None)):
     D = cfg.model_dim
     h = emb_ops.embedding_lookup(params["emb"], x).astype(dt)
 
-    # what a rematerialised layer keeps for its backward pass, so that
-    # no kernel runs a second time
+    # what a rematerialised layer keeps for its backward pass: the
+    # kernels' outputs, so that no kernel runs a second time, and the
+    # MLP's up product and its output (the norm behind it needs that
+    # one), so that of its three products the gate's alone does: 243 MB
+    # a layer at 8,192 x 11,008, 0.97 GB over the benchmark cell's four
     keep = jax.checkpoint_policies.save_only_these_names(
-        "flash_attn", delta_rule.KEPT)
+        "flash_attn", delta_rule.KEPT, MLP_KEPT)
     one_linear = jax.checkpoint(
         lambda h, p: linear_layer(cfg, p, h, impls[1]), policy=keep)
     one_full = jax.checkpoint(

@@ -2,8 +2,9 @@
 (benchmark/reference/olmo-hybrid-7b.py, the rule token by token) at a
 tiny size with TWO periods of (linear, linear, full): loss, every
 position's NLL, every gradient; the period's stacking against the layers
-written one after another; one compiled body a kind of layer; the chip's
-share of the heads; the model through ``parallel_run``."""
+written one after another; one compiled body a kind of layer; what a
+layer's remat keeps of the MLP; the chip's share of the heads; the model
+through ``parallel_run``."""
 
 import dataclasses
 import importlib.util
@@ -131,6 +132,87 @@ def test_one_compiled_body_a_kind_of_layer_however_deep():
     assert text.count("name=delta_fwd") == 1
     assert text.count("name=flash_fwd") == 1
     assert text.count("scan[") == 2
+
+
+@pytest.fixture
+def mlp_name_out_of_the_policy(monkeypatch):
+    """Calling it takes ``MLP_KEPT`` out of every policy built from then
+    on, to the test's end: the model as it was before the name."""
+    names_only = jax.checkpoint_policies.save_only_these_names
+
+    def take_out():
+        monkeypatch.setattr(
+            jax.checkpoint_policies, "save_only_these_names",
+            lambda *names: names_only(
+                *(n for n in names if n != oh.MLP_KEPT)))
+    return take_out
+
+
+def _mlp_products(jaxpr, D, F, under=()):
+    """``(primitives above, name stack)`` of every ``dot_general`` in
+    ``jaxpr`` with an operand or a result ``[.., D, F]`` or ``[.., F,
+    D]``: the MLP's forward products and their two cotangents each."""
+    found = []
+    for eqn in jaxpr.eqns:
+        shapes = [v.aval.shape[-2:] for v in (*eqn.invars, *eqn.outvars)]
+        if eqn.primitive.name == "dot_general" and (
+                (D, F) in shapes or (F, D) in shapes):
+            found.append((under, str(eqn.source_info.name_stack)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _mlp_products(sub, D, F, under + (eqn.primitive.name,))
+    return found
+
+
+def test_the_remat_makes_one_mlp_product_again(mlp_name_out_of_the_policy):
+    """The gradient's jaxpr holds, in each kind of layer's bodies, ten
+    products of the MLP's shapes (three forward, six backward and the
+    gate's made again under the rematerialised computation); with
+    ``MLP_KEPT`` out of the policy twelve, all three made again."""
+    cfg, model, params, batch = _setup()
+    D, F = cfg.model_dim, cfg.intermediate_size
+
+    def by_kind():
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p: model.loss_fn(p, batch, None)[0]))(params)
+        found = _mlp_products(jaxpr.jaxpr, D, F)
+        assert all("mlp" in stack for _, stack in found)
+        # a linear layer's body lies under the scan of a scan
+        kinds = {"linear": [s for under, s in found
+                            if under.count("scan") == 2],
+                 "full": [s for under, s in found
+                          if under.count("scan") == 1]}
+        assert sum(map(len, kinds.values())) == len(found)
+        return {kind: (len(stacks), sum("rematted_computation" in s
+                                        for s in stacks))
+                for kind, stacks in kinds.items()}
+
+    assert by_kind() == {"linear": (10, 1), "full": (10, 1)}
+    mlp_name_out_of_the_policy()
+    assert by_kind() == {"linear": (12, 3), "full": (12, 3)}
+
+
+@pytest.mark.parametrize("impls", [("xla", "xla"),
+                                   ("flash_interpret", "interpret")])
+def test_keeping_the_mlps_products_changes_no_number(
+        impls, mlp_name_out_of_the_policy):
+    """Loss and every gradient leaf with ``MLP_KEPT`` kept are bit for
+    bit those of the model that makes all three products again."""
+    cfg, model, params, batch = _setup(impls=impls, flash_tiles=(8, 8))
+
+    def loss_and_grads():
+        return jax.jit(jax.value_and_grad(
+            lambda p: model.loss_fn(p, batch, None)[0]))(params)
+
+    loss, grads = loss_and_grads()
+    mlp_name_out_of_the_policy()
+    want_loss, want_grads = loss_and_grads()
+    assert np.array_equal(np.asarray(loss), np.asarray(want_loss))
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    assert len(flat) == len(jax.tree.leaves(params))
+    for (path, got), want in zip(flat, jax.tree.leaves(want_grads)):
+        assert float(jnp.abs(want).max()) > 0, path
+        assert np.array_equal(np.asarray(got), np.asarray(want)), \
+            jax.tree_util.keystr(path)
 
 
 def test_the_two_head_shares_add_up_to_the_uncut_layer():
