@@ -26,7 +26,8 @@ float32, the 8 largest, their gates renormalised to one
 (``ops/moe.linear_router``); ``h += sum_e g_e (silu(y Wg_e) * (y Wu_e))
 Wd_e`` over the chosen experts held here (``ops/moe.routed_experts``:
 ``experts_held`` from ``first_expert`` on, dropless), nothing for the
-others. No shared expert.
+others. No shared expert (``models/trinity`` has one:
+``ops/moe.shared_expert``).
 
 **Two kinds under ONE loop body.** The layers run under one ``lax.scan``
 over their stacked parameters; beside the weights its ``xs`` carry
@@ -217,8 +218,16 @@ def _attend(cfg: Mellum2Config, q, k, v, is_window, impl):
     """Causal grouped-query attention, under the window where
     ``is_window`` (a traced scalar of the scan)."""
     kinds = set(cfg.kinds)
-    window = cfg.sliding_window if SLIDING in kinds else None
-    flag = is_window if len(kinds) == 2 else None
+    return attend(cfg, q, k, v,
+                  cfg.sliding_window if SLIDING in kinds else None,
+                  is_window if len(kinds) == 2 else None, impl)
+
+
+def attend(cfg, q, k, v, window, flag, impl):
+    """Causal grouped-query attention at ``cfg``'s ``head_dim`` and
+    ``flash_tiles``, each query under its last ``window`` keys (None:
+    every causal key) where the traced scalar ``flag`` (None: wherever
+    there is a window)."""
     if impl is None:
         impl = "flash" if jax.default_backend() == "tpu" else "xla"
     if impl == "xla":

@@ -295,11 +295,9 @@ def _layer(cfg: ZayaConfig, p, beta, h, r_prev, impls=(None, None),
 
 def balance_step(cfg: ZayaConfig, beta, load):
     """The balancing biases after a step that sent ``load [L, E]``
-    tokens to each expert: every bias moves by ``bias_update_rate``
-    against the sign of its expert's load over the layer's mean."""
-    with jax.named_scope("moe"), jax.named_scope("router"):
-        mean = jnp.mean(load, axis=-1, keepdims=True)
-        return beta - cfg.bias_update_rate * jnp.sign(load - mean)
+    tokens to each expert: ``ops/moe.balance_step`` at
+    ``bias_update_rate``."""
+    return moe_ops.balance_step(beta, load, cfg.bias_update_rate)
 
 
 def scheduled_rate(cfg: ZayaConfig):

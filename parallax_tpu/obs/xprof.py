@@ -83,13 +83,19 @@ _COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
 # window layer's kernels from a full layer's by it); models/olmo_hybrid's
 # ``linear_attention`` (a linear layer's mixer), ``delta_rule`` inside it
 # (ops/delta_rule's kernels and their call site; the inner scope wins)
-# and ``mlp`` (the dense SwiGLU MLP of both kinds). A step holds the
+# and ``mlp`` (the dense SwiGLU MLP of both kinds); models/trinity's
+# ``attn_gate`` (the gate's product and ``o * sigmoid(g)`` between the
+# flash kernels' output and ``Wo``, inside ``attention``) and
+# ops/moe.shared_expert's ``shared_expert`` (the SwiGLU every token takes,
+# inside ``moe``; the inner scopes win, so ``attention`` and ``moe`` keep
+# the rest of their blocks). A step holds the
 # scopes of its own model only. ``layer_of`` reads them back off a
 # compiled instruction's ``op_name``.
 LAYER_SCOPES = ("embedding", "lstm", "sampled_softmax", "layer_scan",
                 "attention", "window_attention", "indexer", "cca_mix",
-                "linear_attention", "delta_rule", "mlp",
-                "moe", "router", "lm_head", "dense_update", "table_update")
+                "attn_gate", "linear_attention", "delta_rule", "mlp",
+                "moe", "router", "shared_expert", "lm_head", "dense_update",
+                "table_update")
 # the row-sharded table path — the paper's sparse side of the
 # dense-vs-sparse variable split
 SPARSE_LAYERS = ("embedding", "sampled_softmax", "table_update")

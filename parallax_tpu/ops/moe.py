@@ -15,8 +15,12 @@ sizes only. Two-matrix ReLU experts, top-1 / top-2.
 the experts. **Routing is the caller's**: it hands over, for every
 token, the ``k`` experts it chose among all ``E`` and the gate of each
 (``linear_router`` is the usual one: one matrix, softmax, top-k,
-renormalised gates, the load-balance loss; ``models/zaya`` brings an
-MLP's ``argmax(p + bias)`` with the unrenormalised ``p`` as its gate).
+renormalised gates, the load-balance loss; ``sigmoid_router`` scores
+each expert by itself, chooses under balancing biases that select and do
+not weigh, and returns every expert's load for ``balance_step``, the
+auxiliary-loss-free rule that moves those biases; ``models/zaya`` brings
+an MLP's ``argmax(p + bias)`` with the unrenormalised ``p`` as its gate
+and the same rule).
 The chip computes the part of the result its own ``[first_expert,
 first_expert + held)`` experts give, for exactly the rows routed to
 them, grouped by expert (a grouped matrix product: ``megablox.gmm`` on
@@ -28,7 +32,10 @@ held by (token, choice) pair. Three-matrix gated (SwiGLU) experts. What
 the absent experts would add is left out; on one device ``first_expert``
 is an argument, under expert parallelism it follows the shard index
 (``jax.lax.axis_index``), and the exchange that would bring other chips'
-tokens here is not part of it.
+tokens here is not part of it. ``shared_expert`` is the SwiGLU every
+token takes beside its routed ones: every chip of an expert-parallel
+group computes it alike, so where the chips' shares are added it counts
+ONCE.
 
 ``switch_moe`` layout:
   * expert weights: [E, D, F] sharded P('shard', None, None) — each
@@ -220,6 +227,19 @@ class LinearRoute(NamedTuple):
     gate: jax.Array         # float32 [B, k]: their probabilities,
                             # renormalised to one
     aux_loss: jax.Array     # scalar load-balance loss over all E experts
+
+
+class SigmoidRoute(NamedTuple):
+    choice: jax.Array       # int [B, k]: the top-k of ``score + bias``,
+                            # or the choice that was given
+    gate: jax.Array         # float32 [B, k]: the chosen scores WITHOUT
+                            # the bias, normalised and scaled
+    load: jax.Array         # float32 [E]: the (token, choice) pairs sent
+                            # to each of ALL the experts
+    gate_sum_mean: jax.Array    # the mean over tokens of the chosen
+                                # scores' sum, before any normalisation
+    own_choice: jax.Array   # int [B, k]: the top-k of ``score + bias``
+                            # whatever choice was given
 
 
 # megablox tiles (rows, contraction, columns): the largest of these that
@@ -605,6 +625,70 @@ def linear_router(tokens: jax.Array,         # [B, D]
         top_probs = jnp.take_along_axis(probs, choice, axis=-1)
     gates = top_probs / jnp.sum(top_probs, axis=-1, keepdims=True)
     return LinearRoute(top_idx, gates, load_balance_loss(probs, top_idx))
+
+
+def sigmoid_router(tokens: jax.Array,        # [B, D]
+                   router_w: jax.Array,      # [D, E]: all E experts
+                   bias: jax.Array,          # [E]: the balancing biases
+                   top_k: int,
+                   route_norm: bool = True,
+                   route_scale: float = 1.0,
+                   choice: Optional[jax.Array] = None) -> SigmoidRoute:
+    """The router that scores each expert by itself:
+    ``s = sigmoid(tokens @ router_w)`` over all ``E`` experts in
+    float32; the choice is the top-k of ``s + bias`` (``bias`` carries
+    no gradient: it selects and does not weigh); the gates are ``s`` of
+    the chosen, divided by their sum (``route_norm``; + 1e-20) and
+    multiplied by ``route_scale``. A given ``choice`` (int ``[B, k]``)
+    takes the top-k's place, gates and loads with it (a comparison
+    under one routing; ``own_choice`` is still the router's).
+    ``load`` is what ``balance_step`` reads."""
+    E = router_w.shape[1]
+    k = int(top_k)
+    if not 1 <= k <= E:
+        raise ValueError(f"top_k={k} must be in [1, {E}]")
+    scores = jax.nn.sigmoid(tokens.astype(jnp.float32)
+                            @ router_w.astype(jnp.float32))
+    biased = scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
+    own = jax.lax.top_k(biased, k)[1]                          # [B, k]
+    if choice is None:
+        choice = own
+    top = jnp.take_along_axis(scores, choice, axis=-1)
+    total = jnp.sum(top, axis=-1, keepdims=True)
+    gates = top / (total + 1e-20) if route_norm else top
+    load = jnp.sum(jax.nn.one_hot(choice, E, dtype=jnp.float32),
+                   axis=(0, 1))
+    return SigmoidRoute(choice, gates * route_scale, load, jnp.mean(total),
+                        own)
+
+
+def balance_step(beta: jax.Array, load: jax.Array, rate: float):
+    """The balancing biases after a step that sent ``load [..., E]``
+    (token, choice) pairs to each expert: every bias moves by ``rate``
+    against the sign of its expert's load over its layer's mean (the
+    auxiliary-loss-free rule). No gradient passes."""
+    with jax.named_scope("moe"), jax.named_scope("router"):
+        load = jax.lax.stop_gradient(load)
+        mean = jnp.mean(load, axis=-1, keepdims=True)
+        return beta - rate * jnp.sign(load - mean)
+
+
+def shared_expert(tokens: jax.Array,         # [B, D]
+                  w_gate: jax.Array,         # [D, F]
+                  w_up: jax.Array,           # [D, F]
+                  w_down: jax.Array):        # [F, D]
+    """The expert every token takes: ``w_down (silu(w_gate y) * w_up
+    y)`` on ALL rows, a plain product and no group of the grouped ones
+    (``routed_experts``' rows, loads and drops stay the routed experts'
+    alone). Computes in ``tokens.dtype``, the activation in float32 as
+    a routed expert's."""
+    dt = tokens.dtype
+    with jax.named_scope("shared_expert"):
+        gate_act = tokens @ w_gate.astype(dt)
+        up = tokens @ w_up.astype(dt)
+        h = (jax.nn.silu(gate_act.astype(jnp.float32))
+             * up.astype(jnp.float32)).astype(dt)
+        return h @ w_down.astype(dt)
 
 
 def routed_experts(tokens: jax.Array,        # [B, D]

@@ -1,5 +1,5 @@
 """The layers' names inside the compiled step: the
-``jax.named_scope``s of ``obs/xprof.LAYER_SCOPES`` (sixteen) as
+``jax.named_scope``s of ``obs/xprof.LAYER_SCOPES`` (eighteen) as
 ``Engine.layer_index()`` reads them back off the executable, the one
 rule for reading a scope (``xprof.layer_of`` / ``sparse_split``), and
 the compile cache's key, which must hold the names
@@ -36,6 +36,11 @@ MELLUM2_SCOPES = ["embedding", "layer_scan", "attention", "window_attention",
 OLMO_SCOPES = ["embedding", "layer_scan", "attention", "linear_attention",
                "delta_rule", "mlp", "lm_head", "dense_update",
                "table_update"]
+# a dense layer before the loop, the gate inside `attention`, the router
+# and the shared expert inside `moe`
+TRINITY_SCOPES = ["embedding", "layer_scan", "attention", "window_attention",
+                  "attn_gate", "mlp", "moe", "router", "shared_expert",
+                  "lm_head", "dense_update", "table_update"]
 
 
 def _session(**cfg_kw):
@@ -101,7 +106,8 @@ def test_every_declared_scope_is_found_in_the_keye_step():
     assert index["scopes_found"] == KEYE_SCOPES
     assert [s for s in xprof.LAYER_SCOPES if s in KEYE_SCOPES] == KEYE_SCOPES
     assert set(LM1B_SCOPES) | set(KEYE_SCOPES) | set(ZAYA_SCOPES) \
-        | set(MELLUM2_SCOPES) | set(OLMO_SCOPES) == set(xprof.LAYER_SCOPES)
+        | set(MELLUM2_SCOPES) | set(OLMO_SCOPES) | set(TRINITY_SCOPES) \
+        == set(xprof.LAYER_SCOPES)
     inner = {n: m for n, m in index["hlo_index"].items()
              if re.search(r"attention\)*/(.*/)?indexer", m.get("op_name", ""))}
     assert inner
@@ -244,9 +250,9 @@ def olmo_index():
 
 
 def test_sixteen_scopes_and_the_olmo_step_declares_its_own(olmo_index):
-    """``LAYER_SCOPES`` holds sixteen names; Olmo-Hybrid's step holds
+    """``LAYER_SCOPES`` holds eighteen names; Olmo-Hybrid's step holds
     its nine in their order, no MoE model's among them."""
-    assert len(xprof.LAYER_SCOPES) == len(set(xprof.LAYER_SCOPES)) == 16
+    assert len(xprof.LAYER_SCOPES) == len(set(xprof.LAYER_SCOPES)) == 18
     assert olmo_index["scopes_found"] == OLMO_SCOPES
     assert [s for s in xprof.LAYER_SCOPES if s in OLMO_SCOPES] \
         == OLMO_SCOPES
@@ -277,6 +283,73 @@ def test_the_rule_resolves_inside_linear_attention(olmo_index, direction):
     assert {index["layers"][n] for n in outer} == {"linear_attention"}
     assert "mlp" in {index["layers"][n] for n, m in
                      index["hlo_index"].items() if of(m)}
+
+
+@pytest.fixture(scope="module")
+def trinity_index():
+    from parallax_tpu.models import trinity
+    cfg = trinity.tiny_config(flash_tiles=(8, 8))
+    sess, *_ = parallax.parallel_run(
+        trinity.build_model(cfg, impls=("flash_interpret", None)),
+        parallax_config=parallax.Config(
+            run_option="HYBRID", sparse_grad_mode="slices",
+            search_partitions=False, shape_buckets=[8]))
+    batch = trinity.make_batch(np.random.default_rng(0), 8, cfg.seq_len,
+                               cfg.vocab_size)
+    sess.warmup(feed_dict=batch)
+    index = sess.layer_index()
+    sess.close()
+    return index
+
+
+def test_every_declared_scope_is_found_in_the_trinity_step(trinity_index):
+    """Trinity-Mini's step holds its twelve scopes in ``LAYER_SCOPES``'
+    order: a dense MLP and the experts in ONE step, the gate and the
+    shared expert by names of their own."""
+    assert trinity_index["scopes_found"] == TRINITY_SCOPES
+    assert [s for s in xprof.LAYER_SCOPES if s in TRINITY_SCOPES] \
+        == TRINITY_SCOPES
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("outer,inner", [("attention", "attn_gate"),
+                                         ("moe", "shared_expert"),
+                                         ("moe", "router")])
+def test_the_inner_scope_wins_in_the_trinity_step(trinity_index, outer,
+                                                  inner, direction):
+    """``attn_gate`` is traced inside ``attention``, ``shared_expert``
+    and ``router`` inside ``moe``, in both passes: their operations go
+    by the inner name, and the outer scope keeps operations of its own
+    (so ``attention_ms_per_step`` and ``moe_ms_per_step`` read the rest
+    of their blocks)."""
+    def of(meta):
+        return ("transpose(" in meta.get("op_name", "")) \
+            == (direction == "backward")
+
+    index = trinity_index
+    nested = {n: m for n, m in index["hlo_index"].items()
+              if re.search(rf"{outer}\)*/(.*/)?{inner}",
+                           m.get("op_name", "")) and of(m)}
+    assert nested
+    assert {index["layers"][n] for n in nested} == {inner}
+    own = [n for n, m in index["hlo_index"].items()
+           if index["layers"][n] == outer and of(m)]
+    assert own
+    # the gate's and the shared expert's products are among them
+    if inner != "router":
+        assert any(m["opcode"] in ("dot", "fusion", "convolution")
+                   for m in nested.values())
+
+
+def test_the_biases_update_goes_by_the_router(trinity_index):
+    """``ops/moe.balance_step`` runs outside the layers' loop, under its
+    own ``moe`` / ``router`` scopes: ``router_ms_per_step`` holds the
+    rule's update as ZAYA's does."""
+    index = trinity_index
+    rule = [n for n, m in index["hlo_index"].items()
+            if re.search(r"train_step\)/moe/router/", m.get("op_name", ""))]
+    assert rule
+    assert {index["layers"][n] for n in rule} == {"router"}
 
 
 def test_table_scatter_maps_to_table_update(warmed):

@@ -607,3 +607,168 @@ def test_linear_router_under_a_given_choice(tokens, rng):
     np.testing.assert_allclose(np.asarray(fed.gate.sum(-1)), 1.0, rtol=1e-6)
     assert float(fed.aux_loss) != pytest.approx(float(own.aux_loss),
                                                 rel=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# The sigmoid router under balancing biases, its rule, the shared expert.
+# ---------------------------------------------------------------------------
+
+def _sigmoid_case(tokens, rng, k=3):
+    router = jnp.asarray(rng.standard_normal((tokens.shape[1], RE)),
+                         jnp.float32)
+    bias = jnp.asarray(0.3 * rng.standard_normal(RE), jnp.float32)
+    scores = np.asarray(jax.nn.sigmoid(tokens @ router))
+    return router, bias, scores, k
+
+
+def test_sigmoid_router_chooses_by_the_bias_and_weighs_without_it(tokens,
+                                                                  rng):
+    """The choice is the top-k of ``s + b``; the gates are ``s`` of the
+    chosen WITHOUT ``b``, over their sum, times ``route_scale``: they
+    sum to ``route_scale``. A bias large enough decides the choice and
+    leaves the weights the scores'."""
+    router, bias, scores, k = _sigmoid_case(tokens, rng)
+    route = moe.sigmoid_router(tokens, router, bias, k, True, 2.5)
+    want = np.argsort(-(scores + np.asarray(bias)), axis=-1)[:, :k]
+    np.testing.assert_array_equal(np.sort(np.asarray(route.choice), -1),
+                                  np.sort(want, -1))
+    picked = np.take_along_axis(scores, np.asarray(route.choice), -1)
+    np.testing.assert_allclose(
+        np.asarray(route.gate),
+        2.5 * picked / picked.sum(-1, keepdims=True), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(route.gate.sum(-1)), 2.5,
+                               rtol=1e-5)
+    assert float(route.gate_sum_mean) == pytest.approx(
+        picked.sum(-1).mean(), rel=1e-5)
+    # the biases alone move the choice, not a weight
+    unbiased = moe.sigmoid_router(tokens, router, jnp.zeros(RE), k, True,
+                                  2.5)
+    assert (np.sort(np.asarray(unbiased.choice), -1)
+            != np.sort(np.asarray(route.choice), -1)).any()
+    pushed = jnp.zeros(RE).at[5].set(10.0)
+    forced = moe.sigmoid_router(tokens, router, pushed, k, True, 2.5)
+    assert (np.asarray(forced.choice) == 5).any(axis=-1).all()
+    at5 = np.take_along_axis(scores, np.asarray(forced.choice), -1)
+    np.testing.assert_allclose(
+        np.asarray(forced.gate), 2.5 * at5 / at5.sum(-1, keepdims=True),
+        rtol=1e-5)
+
+
+def test_sigmoid_router_without_the_norm_and_under_a_given_choice(tokens,
+                                                                  rng):
+    """``route_norm`` off: the raw scores times the scale. A fed choice
+    takes the top-k's place, gates and loads with it, and
+    ``own_choice`` stays the router's; the router's own top-k fed back
+    is the router's own route."""
+    router, bias, scores, k = _sigmoid_case(tokens, rng)
+    raw = moe.sigmoid_router(tokens, router, bias, k, False, 2.0)
+    np.testing.assert_allclose(
+        np.asarray(raw.gate),
+        2.0 * np.take_along_axis(scores, np.asarray(raw.choice), -1),
+        rtol=1e-5)
+    own = moe.sigmoid_router(tokens, router, bias, k, True, 1.0)
+    same = moe.sigmoid_router(tokens, router, bias, k, True, 1.0,
+                              choice=own.choice)
+    np.testing.assert_array_equal(np.asarray(same.choice),
+                                  np.asarray(own.choice))
+    np.testing.assert_allclose(np.asarray(same.gate), np.asarray(own.gate),
+                               rtol=1e-6)
+    other = (own.choice + 1) % RE
+    fed = moe.sigmoid_router(tokens, router, bias, k, True, 1.0,
+                             choice=other)
+    np.testing.assert_array_equal(np.asarray(fed.choice), np.asarray(other))
+    # the router's own top-k is told whatever was fed
+    for route in (own, same, fed):
+        np.testing.assert_array_equal(np.asarray(route.own_choice),
+                                      np.asarray(own.choice))
+    picked = np.take_along_axis(scores, np.asarray(other), -1)
+    np.testing.assert_allclose(np.asarray(fed.gate),
+                               picked / picked.sum(-1, keepdims=True),
+                               rtol=1e-5)
+    np.testing.assert_array_equal(
+        np.asarray(fed.load),
+        np.bincount(np.asarray(other).ravel(), minlength=RE))
+    with pytest.raises(ValueError):
+        moe.sigmoid_router(tokens, router, bias, RE + 1)
+
+
+def test_sigmoid_router_loads_count_every_expert_and_pass_no_gradient(
+        tokens, rng):
+    """``load [E]`` counts the (token, choice) pairs of ALL the experts;
+    the gates carry the router's gradient, the bias none."""
+    router, bias, _, k = _sigmoid_case(tokens, rng)
+    route = moe.sigmoid_router(tokens, router, bias, k)
+    assert float(route.load.sum()) == tokens.shape[0] * k
+    np.testing.assert_array_equal(
+        np.asarray(route.load),
+        np.bincount(np.asarray(route.choice).ravel(), minlength=RE))
+
+    def weight(router, bias):
+        gate = moe.sigmoid_router(tokens, router, bias, k, True, 1.0).gate
+        return jnp.sum(gate[:, 0])
+
+    d_router, d_bias = jax.grad(weight, argnums=(0, 1))(router, bias)
+    assert float(jnp.abs(d_router).max()) > 0
+    assert not np.asarray(d_bias).any()
+
+
+def test_balance_step_moves_each_bias_against_its_load():
+    """Every bias moves by ``rate`` against the sign of its expert's
+    load over ITS layer's mean; an expert at the mean stays; ZAYA's rule
+    is this one at its own rate."""
+    from parallax_tpu.models import zaya
+    load = jnp.asarray([[4.0, 0.0, 2.0, 2.0], [1.0, 1.0, 1.0, 5.0]])
+    beta = jnp.asarray([[0.1, 0.1, 0.1, 0.1], [0.0, 0.2, -0.2, 0.0]])
+    new = moe.balance_step(beta, load, 0.01)
+    np.testing.assert_allclose(
+        np.asarray(new), [[0.09, 0.11, 0.1, 0.1], [0.01, 0.21, -0.19, -0.01]],
+        atol=1e-7)
+    cfg = zaya.tiny_config(bias_update_rate=0.01)
+    np.testing.assert_array_equal(
+        np.asarray(zaya.balance_step(cfg, beta, load)), np.asarray(new))
+    # no gradient reaches the loads
+    assert not np.asarray(jax.grad(
+        lambda x: jnp.sum(moe.balance_step(beta, x, 0.01)))(load)).any()
+
+
+def test_balance_step_brings_a_sigmoid_routers_loads_together(tokens, rng):
+    router, _, _, k = _sigmoid_case(tokens, rng)
+    bias = jnp.zeros(RE)
+
+    def spread(bias):
+        load = moe.sigmoid_router(tokens, router, bias, k).load
+        return float(load.max() / load.mean())
+
+    before = spread(bias)
+    for _ in range(60):
+        bias = moe.balance_step(
+            bias, moe.sigmoid_router(tokens, router, bias, k).load, 0.01)
+    assert before > 1.25 and spread(bias) < min(before, 1.2)
+
+
+def test_shared_expert_is_a_plain_swiglu_on_every_row(tokens, rng):
+    """Every row, whatever the routing; value and gradients against the
+    formula."""
+    D_, F_ = tokens.shape[1], 24
+    w_gate, w_up = (jnp.asarray(rng.standard_normal((D_, F_)), jnp.float32)
+                    for _ in range(2))
+    w_down = jnp.asarray(rng.standard_normal((F_, D_)), jnp.float32)
+
+    def plain(t, g, u, d):
+        return (jax.nn.silu(t @ g) * (t @ u)) @ d
+
+    got = moe.shared_expert(tokens, w_gate, w_up, w_down)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(plain(tokens, w_gate, w_up, w_down)),
+                               rtol=1e-5, atol=1e-5)
+    grads = jax.grad(lambda *a: jnp.sum(moe.shared_expert(*a) ** 2),
+                     argnums=(0, 1, 2, 3))(tokens, w_gate, w_up, w_down)
+    wants = jax.grad(lambda *a: jnp.sum(plain(*a) ** 2),
+                     argnums=(0, 1, 2, 3))(tokens, w_gate, w_up, w_down)
+    for g, w in zip(grads, wants):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-4)
+    # bfloat16 rows compute in bfloat16
+    low = moe.shared_expert(tokens.astype(jnp.bfloat16), w_gate, w_up,
+                            w_down)
+    assert low.dtype == jnp.bfloat16

@@ -1,6 +1,6 @@
-"""The Mosaic kernels of the Keye-VL-2.0, ZAYA1-8B, Mellum2 and
-Olmo-Hybrid steps (and the last three's whole steps, for their peak
-memory), compiled at the published
+"""The Mosaic kernels of the Keye-VL-2.0, ZAYA1-8B, Mellum2,
+Olmo-Hybrid and Trinity-Mini steps (and the last four's whole steps, for
+their peak memory), compiled at the published
 widths by the TPU's own compiler against a described v5e (no chip is
 attached, nothing runs): what interpret mode cannot show: a
 block that is not aligned to the tiling, more VMEM than a kernel may
@@ -208,6 +208,33 @@ def test_windowed_flash_kernels_compile_at_mellum2s_heads(one_chip, tiles):
             q, k, v, causal=True, q_tile=tiles[0], block_k=tiles[1],
             window=1024, window_on=flag,
             interpret=False).astype(jnp.float32))
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), sds(1, 8192, 32, 128),
+        sds(1, 8192, 4, 128), sds(1, 8192, 4, 128),
+        jax.ShapeDtypeStruct((), jnp.bool_, sharding=one_chip))
+    text = compiled.as_text()
+    names = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert sorted(n.rsplit(".", 1)[0] if "." in n else n for n in names) \
+        == ["flash_dkv", "flash_dkv_win", "flash_dq", "flash_dq_win",
+            "flash_fwd", "flash_fwd_win"]
+    assert len(re.findall(r" conditional\(", text)) == 2
+
+
+def test_windowed_flash_kernels_compile_at_trinitys_window(one_chip):
+    """The same heads under Trinity-Mini's window of 2,048, four key
+    tiles of 512 and not two: the three windowed kernels pass Mosaic
+    beside the three plain ones under the traced flag."""
+    from parallax_tpu.ops.pallas_attention import flash_attention
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v, flag):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, q_tile=512, block_k=512, window=2048,
+            window_on=flag, interpret=False).astype(jnp.float32))
 
     compiled = _compile(
         jax.grad(loss, argnums=(0, 1, 2)), sds(1, 8192, 32, 128),
@@ -474,4 +501,79 @@ def test_olmo_hybrid_step_compiles_at_published_widths_and_fits(topo):
         r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
     assert sorted(n.split(".")[0] for n in names) == [
         "delta_bwd", "delta_fwd", "flash_dkv", "flash_dq", "flash_fwd"]
+    assert not re.search(r"f32\[(1,)?8192,8192\]", text)
+
+
+def test_trinity_step_compiles_at_published_widths_and_fits(topo):
+    """Trinity-Mini's training step as the benchmark's cell runs it (one
+    dense layer and one period of 4 expert layers, 16 of 128 experts,
+    25,024 rows, one sequence of 8,192; every width as published)
+    through ``Engine`` for the described v5e: 705.47 M parameters, a
+    peak (``peak_memory_in_bytes``) between the driver's floor and 15.7
+    GB of the chip's 16.9; the dense layer's windowed kernels called
+    straight, ONE loop over the expert layers in each direction with
+    both kinds' kernels under its ``conditional``s, no forward kernel
+    made again by the rematerialisation."""
+    import numpy as np
+    import parallax_tpu as parallax
+    from parallax_tpu.core import engine as engine_lib, mesh as mesh_lib
+    from parallax_tpu.models import trinity
+
+    dev = topo.devices[0]
+    one = SingleDeviceSharding(dev)
+    cfg = trinity.TrinityConfig(
+        vocab_size=25024, num_layers=5, num_dense_layers=1,
+        layer_types=(trinity.SLIDING,) * 4 + (trinity.FULL,),
+        experts_held=16, warmup_steps=20000, num_partitions=1)
+    model = trinity.build_model(cfg, impls=("flash", "gmm"))
+    mesh = mesh_lib.build_mesh(devices=[dev], num_partitions=1)
+    batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+             for k, v in trinity.make_batch(
+                 np.random.default_rng(0), 1, cfg.seq_len,
+                 cfg.vocab_size).items()}
+    engine = engine_lib.Engine(
+        model, mesh, parallax.Config(run_option="HYBRID",
+                                     sparse_grad_mode="slices"), batch)
+    assert engine.plan.var_specs["emb"].is_sparse
+    state = jax.eval_shape(engine._init_jit,
+                           jax.ShapeDtypeStruct((), jnp.int32))
+    assert state.model_state["router_bias"].shape == (4, 128)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    with mesh:
+        compiled = engine._step_jit.trace(on_chip(state), on_chip(batch)) \
+            .lower(lowering_platforms=("tpu",)).compile()
+    memory = compiled.memory_analysis()
+    peak = memory.peak_memory_in_bytes
+    print(f"trinity-mini step: peak_memory_in_bytes {peak / 1e9:.2f} GB "
+          f"(arguments {memory.argument_size_in_bytes / 1e9:.2f}, "
+          f"temporaries {memory.temp_size_in_bytes / 1e9:.2f})")
+    params = sum(int(np.prod(s.shape))
+                 for s in jax.tree.leaves(state.params))
+    assert params == pytest.approx(705.47e6, rel=1e-4)
+    assert 4.23e9 < peak < 15.7e9
+    text = compiled.as_text()
+    names = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    flash = sorted(n.rsplit(".", 1)[0] if "." in n else n
+                   for n in names if n.startswith("flash_"))
+    # the dense layer's three windowed calls, straight, and each of the
+    # six once in the loops' bodies: the kept output and logsumexp spare
+    # the backward pass a second forward call
+    assert flash == ["flash_dkv", "flash_dkv_win", "flash_dkv_win",
+                     "flash_dq", "flash_dq_win", "flash_dq_win",
+                     "flash_fwd", "flash_fwd_win", "flash_fwd_win"]
+    assert all("gmm" in n or "sum_rows" in n for n in names
+               if not n.startswith("flash_"))
+    assert any("sum_rows" in n for n in names)
+    entry = text[text.index("\nENTRY "):]
+    bodies = re.findall(r" while\([^\n]*body=%([\w.\-]+)", entry)
+    assert len(bodies) == 2
+    # neither every expert for every token, nor a row for every (token,
+    # choice) pair, nor whole float32 scores
+    assert "[8192,16,1024]" not in text
+    assert "[65536,2048]" not in text
     assert not re.search(r"f32\[(1,)?8192,8192\]", text)
