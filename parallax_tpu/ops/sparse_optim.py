@@ -38,24 +38,39 @@ sorted id changes, a sort on the slots to carry each place back to its
 slot, a sort that moves the distinct ids ahead of the sentinels) and
 sums the rows through those places with one scatter-add.
 
-**Which executor updates a SliceAdagrad table's rows** is read off the
-table, with no option: the in-place kernel ``adagrad_rows`` where the
-backend is a TPU, the engine's mesh (``table_update_scope``) holds one
-device so the table is whole on it, parameter and accumulator are
-float32 and the row width is a multiple of 128 lanes; the gather and
-two scatters over every slot (``_scatter_rows``) everywhere else: a
-[V, 1] bias table, a bf16 table, the CPU, a table sharded over a mesh,
-a call outside the engine's scope. Both do the same arithmetic in the
-same order. ``trace_records()`` says which one each table got.
+**Which executor updates a slice table's rows** is read off the table,
+with no option, by one rule for both updaters: the in-place walk where
+the backend is a TPU, the engine's mesh (``table_update_scope``) holds
+one device so the table is whole on it, parameter and state are float32,
+the row width is a multiple of 128 lanes and the table is larger than
+the core's VMEM; the gathers and scatters over every slot
+(``_scatter_rows``) everywhere else: a [V, 1] bias table, a bf16 table,
+the CPU, a table sharded over a mesh, a call outside the engine's
+scope, a table the compiler can hold in VMEM. That last: XLA stages
+such a table in VMEM for the embedding's gather and for its own
+scatters, and the step's other arrays are placed around it; with the
+kernel reading the table from HBM the compiler placed them anew, and on
+Mellum2's 113 MB table (128 MiB of VMEM on a v5e) a gather of the
+experts' backward lost its VMEM: 2.6 % of the step, more than the
+kernel saves (PERF.md, PR 40). The walk has two update rules, picked by
+the updater that calls it: ``adagrad_rows`` on (param, acc) for
+``SliceAdagrad``, ``adam_rows`` on (param, m, v) for ``SliceAdam``'s
+lazy Adam. Either executor does the same arithmetic in the same order.
+``trace_records()`` says which rule and executor each table got.
 
-**What the kernel relies on:** ``uids[:n_valid]`` is sorted, free of
+**What the walk relies on:** ``uids[:n_valid]`` is sorted, free of
 duplicates and below ``V - V % 8``, and ``gsum[i]`` belongs to
 ``uids[i]``. It walks those ``n_valid`` slots only — the price of a step
 follows its distinct rows, not its slot count — and moves aligned
-groups of 8 rows (the (8, 128) HBM tile; Mosaic refuses less), so the
-up to 7 untouched neighbours of a touched row are rewritten with the
-bits they had, and the at most ``V % 8`` rows of a partial last group
-are left to ``_scatter_rows`` on 8 slots.
+groups of 8 rows (the (8, 128) HBM tile; Mosaic refuses less), so up to
+7 untouched neighbours of a touched row are read and written back. Under
+Adagrad they see ``g = 0`` and keep their bits. Under lazy Adam they
+would not (``m`` would decay to ``b1 * m`` and ``m_hat`` move the row),
+so ``adam_rows`` marks which sublanes of a group hold a live id and
+writes the others back as they were read: an untouched row keeps
+param, m and v bit for bit, as the scatters leave it. The at most
+``V % 8`` rows of a partial last group are left to ``_scatter_rows`` on
+8 slots.
 """
 
 from __future__ import annotations
@@ -190,36 +205,18 @@ class SliceAdagrad:
         outside [0, V) are dropped (zero-row parity with the sharded
         lookup's sentinel handling).
         """
-        V, D = param.shape
+        V = param.shape[0]
         uids, gsum = _combine_slices(ids, drows, V, jnp.float32, average,
                                      self.grad_scale)
-        scope = _TABLE_SCOPE.get()
-        executor = _row_executor(param, acc, scope.mesh,
-                                 jax.default_backend())
-        _TRACE_RECORDS[(scope.path, uids.shape[0], D)] = executor
+        executor = _executor("adagrad", param, acc, uids)
         rows = self._kernel_rows if executor == "kernel" else \
             self._scatter_rows
         return rows(param, acc, uids, gsum)
 
     def _kernel_rows(self, param, acc, uids, gsum):
-        """The live slots' rows read and written once, in place, by
-        ``adagrad_rows``. It moves whole groups of 8 rows, so it takes
-        the ids below the last multiple of 8 (a prefix: ``uids`` is
-        sorted); the at most V % 8 rows past it go through the scatter
-        on 8 slots cut from the lists where the kernel stopped."""
-        V, D = param.shape
-        v_groups = V - V % _GROUP
-        n_valid = jnp.sum(uids < v_groups, dtype=jnp.int32)
-        param, acc = adagrad_rows(param, acc, uids, n_valid, gsum,
-                                  self.learning_rate, self.eps)
-        if v_groups == V:
-            return param, acc
-        n_tail = min(_GROUP, uids.shape[0])
-        start = jnp.minimum(n_valid, uids.shape[0] - n_tail)
-        uids = jax.lax.dynamic_slice(uids, (start,), (n_tail,))
-        uids = jnp.where(uids >= v_groups, uids, V)
-        gsum = jax.lax.dynamic_slice(gsum, (start, 0), (n_tail, D))
-        return self._scatter_rows(param, acc, uids, gsum)
+        return _in_place(
+            lambda *a: adagrad_rows(*a, self.learning_rate, self.eps),
+            self._scatter_rows, (param, acc), uids, gsum)
 
     def _scatter_rows(self, param, acc, uids, gsum):
         """Gather, update and scatter every slot of (uids, gsum); the
@@ -310,8 +307,8 @@ def _combine_slices(ids, drows, V, dtype, average, grad_scale=1.0):
 
 
 # ---------------------------------------------------------------------------
-# The in-place row update: SliceAdagrad's executor for a table that is
-# f32, lane-aligned and whole on one TPU (see the module docstring).
+# The in-place row update: both slice updaters' executor for a table that
+# is f32, lane-aligned and whole on one TPU (see the module docstring).
 # ---------------------------------------------------------------------------
 
 class _TableScope(NamedTuple):
@@ -335,6 +332,12 @@ def table_update_scope(path: str, mesh):
         _TABLE_SCOPE.reset(token)
 
 
+def _vmem_bytes() -> int:
+    """The VMEM of the TPU core that runs the step."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.get_tpu_info().vmem_capacity_bytes
+
+
 def _row_executor(param, acc, mesh, backend: str) -> str:
     """'kernel' for a table the in-place kernel can serve, else 'xla'
     (the module docstring's rule)."""
@@ -342,28 +345,62 @@ def _row_executor(param, acc, mesh, backend: str) -> str:
                         and mesh.size == 1)
     if (whole_on_one_tpu and param.dtype == jnp.float32
             and acc.dtype == jnp.float32 and param.shape[0] >= _GROUP
-            and param.shape[1] % 128 == 0):
+            and param.shape[1] % 128 == 0
+            and param.shape[0] * param.shape[1] * 4 > _vmem_bytes()):
         return "kernel"
     return "xla"
 
 
-# Which executor each table's row update got, noted at trace time like
-# ops/pallas_lstm's records: chip_smoke.py and the tests read it. One
-# entry a (table, slots, width), so a process's tables bound it.
+# Which rule and executor each table's row update got, noted at trace
+# time like ops/pallas_lstm's records: chip_smoke.py and the tests read
+# it. One entry a (table, slots, width, rule), so a process's tables
+# bound it.
 _TRACE_RECORDS: dict = {}
 
 
+def _executor(rule, param, acc, uids) -> str:
+    """The executor ``_row_executor`` picks for this table in the
+    engine's scope, noted under the updater's ``rule``."""
+    scope = _TABLE_SCOPE.get()
+    executor = _row_executor(param, acc, scope.mesh, jax.default_backend())
+    _TRACE_RECORDS[(scope.path, uids.shape[0], param.shape[1], rule)] = \
+        executor
+    return executor
+
+
 def trace_records():
-    """One ``{"table": path, "rows": slots, "dim": D, "executor":
-    "kernel" | "xla"}`` per distinct SliceAdagrad update traced since
-    the last reset, in trace order (``table`` is None outside the
-    engine's scope)."""
-    return [{"table": path, "rows": rows, "dim": dim, "executor": ex}
-            for (path, rows, dim), ex in _TRACE_RECORDS.items()]
+    """One ``{"table": path, "rows": slots, "dim": D, "rule": "adagrad" |
+    "adam", "executor": "kernel" | "xla"}`` per distinct slice update
+    traced since the last reset, in trace order (``table`` is None
+    outside the engine's scope)."""
+    return [{"table": path, "rows": rows, "dim": dim, "rule": rule,
+             "executor": ex}
+            for (path, rows, dim, rule), ex in _TRACE_RECORDS.items()]
 
 
 def reset_trace_records():
     _TRACE_RECORDS.clear()
+
+
+def _in_place(kernel, scatter, tables, uids, gsum):
+    """The live slots' rows of ``tables`` read and written once, in
+    place, by ``kernel(*tables, uids, n_valid, gsum)``. It moves whole
+    groups of 8 rows, so it takes the ids below the last multiple of 8
+    (a prefix: ``uids`` is sorted); the at most V % 8 rows past it go
+    through ``scatter(*tables, uids, gsum)`` on 8 slots cut from the
+    lists where the kernel stopped."""
+    V, D = tables[0].shape
+    v_groups = V - V % _GROUP
+    n_valid = jnp.sum(uids < v_groups, dtype=jnp.int32)
+    tables = tuple(kernel(*tables, uids, n_valid, gsum))
+    if v_groups == V:
+        return tables
+    n_tail = min(_GROUP, uids.shape[0])
+    start = jnp.minimum(n_valid, uids.shape[0] - n_tail)
+    uids = jax.lax.dynamic_slice(uids, (start,), (n_tail,))
+    uids = jnp.where(uids >= v_groups, uids, V)
+    gsum = jax.lax.dynamic_slice(gsum, (start, 0), (n_tail, D))
+    return scatter(*tables, uids, gsum)
 
 
 # HBM tiles f32 as (8, 128): Mosaic refuses a DMA of fewer than 8 rows,
@@ -374,17 +411,88 @@ _GROUP = 8
 # on a v5e; PERF.md, PR 26); wider rows take fewer, so that the three
 # buffers stay at 6 MiB
 _BLOCK_ROWS = 128
+# lazy Adam's four buffers (param, m, v, g) at most this many bytes: 32
+# ids a grid step at 3,840 lanes, 64 at 2,048 (blocks of 16 to 64 ids
+# within 20 % of each other on a v5e; PERF.md, PR 40)
+_ADAM_BLOCK_BYTES = 16 * 1024 * 1024
 
 
 def _block_rows(dim: int) -> int:
     return max(_GROUP, _BLOCK_ROWS * 512 // max(dim, 512) // _GROUP * _GROUP)
 
 
+def _adam_block_rows(dim: int) -> int:
+    per_row = 4 * _GROUP * dim * 4
+    return max(_GROUP, min(_BLOCK_ROWS, _ADAM_BLOCK_BYTES // per_row)
+               // _GROUP * _GROUP)
+
+
+def _group_copies(tables, sem):
+    """``copies(slot, group, out)``: the DMAs of one slot's aligned group
+    of 8 rows between each (HBM table, VMEM buffer) pair of ``tables``,
+    into the buffers or (``out``) back; ``sem(k, slot, out)`` is the
+    semaphore of table k's copy."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def rows8(group):
+        return pl.ds(pl.multiple_of(group * _GROUP, _GROUP), _GROUP)
+
+    def copies(slot, group, out):
+        return [pltpu.make_async_copy(buf.at[slot], hbm.at[rows8(group)],
+                                      sem(k, slot, out)) if out else
+                pltpu.make_async_copy(hbm.at[rows8(group)], buf.at[slot],
+                                      sem(k, slot, out))
+                for k, (hbm, buf) in enumerate(tables)]
+    return copies
+
+
+def _walk(uids_ref, g_ref, gbuf, group_of_slot, base, count, copies,
+          live_of_slot=None):
+    """The walk both rules share, over a block's ``count`` sorted ids on
+    the scalar core: a new group of 8 rows takes the next slot and
+    starts its reads; the id's gradient row goes to its sublane of that
+    slot and, where ``live_of_slot`` is given, the sublane's bit into
+    the slot's mask. Returns the number of slots taken."""
+    from jax.experimental import pallas as pl
+
+    def start_in(r, carry):
+        slot, prev = carry
+        i = uids_ref[base + r]
+        group = i // _GROUP
+        new = group != prev
+        slot = slot + new.astype(jnp.int32)
+
+        @pl.when(new)
+        def _():
+            group_of_slot[slot] = group
+            for dma in copies(slot, group, False):
+                dma.start()
+        gbuf[slot, pl.ds(i % _GROUP, 1), :] = g_ref[pl.ds(r, 1), :]
+        if live_of_slot is not None:
+            was = jnp.where(new, 0, live_of_slot[slot])
+            live_of_slot[slot] = was | (1 << (i % _GROUP))
+        return slot, group
+    last_slot, _ = jax.lax.fori_loop(
+        0, count, start_in, (jnp.int32(-1), jnp.int32(-1)))
+    return last_slot + 1
+
+
+def _drain(n_slots, copies):
+    """Every write-back waited for before the next block reads: a group
+    whose ids straddle two blocks is read back with this block's update
+    in it."""
+    def wait_out(s, c):
+        for dma in copies(s, jnp.int32(0), True):
+            dma.wait()
+        return c
+    jax.lax.fori_loop(0, n_slots, wait_out, 0)
+
+
 def _adagrad_rows_kernel(uids_ref, nv_ref, g_ref, p_in, a_in, p_out, a_out,
                          pbuf, abuf, gbuf, group_of_slot, sem, *, lr, eps,
                          rows):
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     del p_in, a_in                      # aliased to p_out / a_out
     base = pl.program_id(0) * rows
@@ -393,46 +501,13 @@ def _adagrad_rows_kernel(uids_ref, nv_ref, g_ref, p_in, a_in, p_out, a_out,
     @pl.when(base < nv)
     def _():
         gbuf[...] = jnp.zeros_like(gbuf)
-
-        tables = ((p_out, pbuf), (a_out, abuf))
-
-        def rows8(group):
-            return pl.ds(pl.multiple_of(group * _GROUP, _GROUP), _GROUP)
-
-        def reads(slot, group):
-            return [pltpu.make_async_copy(hbm.at[rows8(group)], buf.at[slot],
-                                          sem.at[k])
-                    for k, (hbm, buf) in enumerate(tables)]
-
-        def writes(slot, group):
-            return [pltpu.make_async_copy(buf.at[slot], hbm.at[rows8(group)],
-                                          sem.at[2 + k])
-                    for k, (hbm, buf) in enumerate(tables)]
-
-        # one walk over the block's sorted ids on the scalar core: a new
-        # group of 8 rows takes the next slot and starts its two reads;
-        # the id's gradient row goes to its sublane of that slot
-        def start_in(r, carry):
-            slot, prev = carry
-            i = uids_ref[base + r]
-            group = i // _GROUP
-            new = group != prev
-            slot = slot + new.astype(jnp.int32)
-
-            @pl.when(new)
-            def _():
-                group_of_slot[slot] = group
-                for dma in reads(slot, group):
-                    dma.start()
-            gbuf[slot, pl.ds(i % _GROUP, 1), :] = g_ref[pl.ds(r, 1), :]
-            return slot, group
-        last_slot, _ = jax.lax.fori_loop(
-            0, jnp.minimum(rows, nv - base), start_in,
-            (jnp.int32(-1), jnp.int32(-1)))
-        n_slots = last_slot + 1
+        copies = _group_copies(((p_out, pbuf), (a_out, abuf)),
+                               lambda k, slot, out: sem.at[2 * out + k])
+        n_slots = _walk(uids_ref, g_ref, gbuf, group_of_slot, base,
+                        jnp.minimum(rows, nv - base), copies)
 
         def wait_in(s, c):
-            for dma in reads(s, jnp.int32(0)):
+            for dma in copies(s, jnp.int32(0), False):
                 dma.wait()
             return c
         jax.lax.fori_loop(0, n_slots, wait_in, 0)
@@ -446,18 +521,102 @@ def _adagrad_rows_kernel(uids_ref, nv_ref, g_ref, p_in, a_in, p_out, a_out,
         pbuf[...] = pbuf[...] + (inv_rt * g) * jnp.float32(-lr)
 
         def start_out(s, c):
-            for dma in writes(s, group_of_slot[s]):
+            for dma in copies(s, group_of_slot[s], True):
                 dma.start()
             return c
         jax.lax.fori_loop(0, n_slots, start_out, 0)
+        _drain(n_slots, copies)
 
-        # drained before the next block reads: a group whose ids straddle
-        # two blocks is read back with this block's update in it
-        def wait_out(s, c):
-            for dma in writes(s, jnp.int32(0)):
+
+def _adam_rows_kernel(uids_ref, nv_ref, corr_ref, g_ref, p_in, m_in, v_in,
+                      p_out, m_out, v_out, pbuf, mbuf, vbuf, gbuf,
+                      group_of_slot, live_of_slot, sem_in, sem_out, *, lr,
+                      b1, b2, eps, rows):
+    from jax.experimental import pallas as pl
+
+    del p_in, m_in, v_in                # aliased to the outputs
+    base = pl.program_id(0) * rows
+    nv = nv_ref[0]
+
+    @pl.when(base < nv)
+    def _():
+        # a slot's reads signal semaphores of its own, so that it is
+        # updated and written back as soon as its rows are in, while the
+        # later slots' reads are still on their way
+        copies = _group_copies(
+            ((p_out, pbuf), (m_out, mbuf), (v_out, vbuf)),
+            lambda k, slot, out: sem_out.at[k] if out else sem_in.at[k, slot])
+        n_slots = _walk(uids_ref, g_ref, gbuf, group_of_slot, base,
+                        jnp.minimum(rows, nv - base), copies, live_of_slot)
+        sublane = jax.lax.broadcasted_iota(jnp.int32, gbuf.shape[1:], 0)
+        c1, c2 = corr_ref[0], corr_ref[1]
+
+        def update(s, c):
+            for dma in copies(s, jnp.int32(0), False):
                 dma.wait()
+            # SliceAdam's arithmetic in its order, kept on the sublanes
+            # that hold a live id: an untouched neighbour keeps param, m
+            # and v bit for bit (lazy Adam: its moments do not decay)
+            live = ((live_of_slot[s] >> sublane) & 1) == 1
+            g, p, m, v = gbuf[s], pbuf[s], mbuf[s], vbuf[s]
+            m_r = b1 * m + (1.0 - b1) * g
+            v_r = b2 * v + (1.0 - b2) * g * g
+            m_hat = m_r / c1
+            v_hat = v_r / c2
+            u = -lr * m_hat / (jnp.sqrt(v_hat) + eps)
+            pbuf[s] = jnp.where(live, p + u, p)
+            mbuf[s] = jnp.where(live, m_r, m)
+            vbuf[s] = jnp.where(live, v_r, v)
+            for dma in copies(s, group_of_slot[s], True):
+                dma.start()
             return c
-        jax.lax.fori_loop(0, n_slots, wait_out, 0)
+        jax.lax.fori_loop(0, n_slots, update, 0)
+        _drain(n_slots, copies)
+
+
+def _rows_call(kernel, name, tables, uids, n_valid, gsum, scalars, rows,
+               scratch, interpret):
+    """The pallas_call both rules share: the ids and ``n_valid`` (and the
+    rule's ``scalars``) scalar-prefetched, ``gsum`` in blocks of ``rows``,
+    the f32[V, D] ``tables`` left in HBM and aliased to the outputs, a
+    VMEM buffer of ``rows`` groups of 8 rows for each table and for the
+    gradients, the slots' groups in SMEM, then the rule's ``scratch``."""
+    # imported where a kernel is traced: `import parallax_tpu` stays
+    # free of pallas (0.9 s) for programs that run no kernel
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    D = tables[0].shape[1]
+    n_prefetch = 2 + len(scalars)
+
+    def g_map(b, uids_ref, nv_ref, *_):
+        # dead blocks re-use the last live block: nothing is fetched
+        del uids_ref
+        last = jnp.maximum((nv_ref[0] + rows - 1) // rows - 1, 0)
+        return jnp.minimum(b, last), 0
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((rows, _GROUP, D), jnp.float32)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_prefetch,
+            grid=(pl.cdiv(uids.shape[0], rows),),
+            in_specs=[pl.BlockSpec((rows, D), g_map)] + [hbm] * len(tables),
+            out_specs=[hbm] * len(tables),
+            scratch_shapes=[buf] * (len(tables) + 1)
+            + [pltpu.SMEM((rows,), jnp.int32)] + scratch),
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tables],
+        input_output_aliases={n_prefetch + 1 + k: k
+                              for k in range(len(tables))},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=48 * 1024 * 1024),
+        name=name,
+        interpret=interpret,
+    )(uids, jnp.reshape(n_valid, (1,)).astype(jnp.int32), *scalars, gsum,
+      *tables)
 
 
 def adagrad_rows(param, acc, uids, n_valid, gsum, lr, eps, *,
@@ -469,42 +628,32 @@ def adagrad_rows(param, acc, uids, n_valid, gsum, lr, eps, *,
     combined gradient. Slots at or past ``n_valid`` cost one empty grid
     step each and no transfer. The rows that share an aligned group of
     8 with a live row are rewritten with the bits they had."""
-    # imported where a kernel is traced: `import parallax_tpu` stays
-    # free of pallas (0.9 s) for programs that run no kernel
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    D = param.shape[1]
-    rows = block_rows or _block_rows(D)
-
-    def g_map(b, uids_ref, nv_ref):
-        # dead blocks re-use the last live block: nothing is fetched
-        del uids_ref
-        last = jnp.maximum((nv_ref[0] + rows - 1) // rows - 1, 0)
-        return jnp.minimum(b, last), 0
-
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
-    buf = pltpu.VMEM((rows, _GROUP, D), jnp.float32)
-    return pl.pallas_call(
+    rows = block_rows or _block_rows(param.shape[1])
+    return _rows_call(
         functools.partial(_adagrad_rows_kernel, lr=lr, eps=eps, rows=rows),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(pl.cdiv(uids.shape[0], rows),),
-            in_specs=[pl.BlockSpec((rows, D), g_map), hbm, hbm],
-            out_specs=[hbm, hbm],
-            scratch_shapes=[buf, buf, buf,
-                            pltpu.SMEM((rows,), jnp.int32),
-                            pltpu.SemaphoreType.DMA((4,))]),
-        out_shape=[jax.ShapeDtypeStruct(param.shape, param.dtype),
-                   jax.ShapeDtypeStruct(acc.shape, acc.dtype)],
-        input_output_aliases={3: 0, 4: 1},
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=48 * 1024 * 1024),
-        name="adagrad_rows",
-        interpret=interpret,
-    )(uids, jnp.reshape(n_valid, (1,)).astype(jnp.int32), gsum, param, acc)
+        "adagrad_rows", (param, acc), uids, n_valid, gsum, (), rows,
+        [pltpu.SemaphoreType.DMA((4,))], interpret)
+
+
+def adam_rows(param, m, v, uids, n_valid, gsum, corr, lr, b1, b2, eps, *,
+              block_rows=None, interpret=None):
+    """Lazy Adam on the rows ``uids[:n_valid]`` of (param, m, v)
+    f32[V, D] by the walk of ``adagrad_rows``, under the same contract;
+    ``corr`` f32[2] holds the bias corrections ``1 - b1**t``,
+    ``1 - b2**t``. A slot updates only the sublanes its ids named and
+    writes the rest of its group of 8 back as it was read."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows = block_rows or _adam_block_rows(param.shape[1])
+    return _rows_call(
+        functools.partial(_adam_rows_kernel, lr=lr, b1=b1, b2=b2, eps=eps,
+                          rows=rows),
+        "adam_rows", (param, m, v), uids, n_valid, gsum,
+        (corr.astype(jnp.float32),), rows,
+        [pltpu.SMEM((rows,), jnp.int32), pltpu.SemaphoreType.DMA((3, rows)),
+         pltpu.SemaphoreType.DMA((3,))], interpret)
 
 
 class SliceAdamState(NamedTuple):
@@ -523,6 +672,15 @@ class SliceAdam:
     adam decays every row's moments every step, costing a full [V, D]
     pass); it is the standard large-vocab tradeoff. Use via
     `Model.slice_updaters` with `Config(sparse_grad_mode="slices")`.
+
+    The rows are updated by the executor rule of ``SliceAdagrad``
+    (``_row_executor``): ``adam_rows``, the in-place walk of aligned
+    groups of 8 rows, on a float32 lane-aligned table whole on one TPU
+    and larger than its VMEM; the gathers and scatters over every slot
+    elsewhere. The walk marks
+    which sublanes of a group hold a live id and writes the others back
+    as they were read: where Adagrad's ``g = 0`` leaves a row's bits
+    alone, Adam's would decay its ``m`` and move it by ``m_hat``.
     """
 
     learning_rate: float
@@ -543,21 +701,40 @@ class SliceAdam:
         uids, gsum = _combine_slices(ids, drows, V, jnp.float32, average,
                                      self.grad_scale)
         t = state.count + 1
-        m_r = (self.b1 * state.m.at[uids, :].get(mode="fill",
-                                                 fill_value=0.0)
-               + (1.0 - self.b1) * gsum)
-        v_r = (self.b2 * state.v.at[uids, :].get(mode="fill",
-                                                 fill_value=0.0)
-               + (1.0 - self.b2) * gsum * gsum)
         tf_ = t.astype(jnp.float32)
-        m_hat = m_r / (1.0 - jnp.asarray(self.b1, jnp.float32) ** tf_)
-        v_hat = v_r / (1.0 - jnp.asarray(self.b2, jnp.float32) ** tf_)
+        # the bias corrections of the global count, handed to either
+        # executor as values: no compiled program depends on the step
+        corr = jnp.stack([1.0 - jnp.asarray(self.b1, jnp.float32) ** tf_,
+                          1.0 - jnp.asarray(self.b2, jnp.float32) ** tf_])
+        # init makes m and v alike: m stands for both
+        executor = _executor("adam", param, state.m, uids)
+        rows = self._kernel_rows if executor == "kernel" else \
+            self._scatter_rows
+        param, m, v = rows(param, state.m, state.v, uids, gsum, corr)
+        return param, SliceAdamState(m, v, t)
+
+    def _kernel_rows(self, param, m, v, uids, gsum, corr):
+        return _in_place(
+            lambda *a: adam_rows(*a, corr, self.learning_rate, self.b1,
+                                 self.b2, self.eps),
+            functools.partial(self._scatter_rows, corr=corr),
+            (param, m, v), uids, gsum)
+
+    def _scatter_rows(self, param, m, v, uids, gsum, corr):
+        """Gather, update and scatter every slot of (uids, gsum); the
+        sentinel slots (id V) are read as 0 and dropped."""
+        m_r = (self.b1 * m.at[uids, :].get(mode="fill", fill_value=0.0)
+               + (1.0 - self.b1) * gsum)
+        v_r = (self.b2 * v.at[uids, :].get(mode="fill", fill_value=0.0)
+               + (1.0 - self.b2) * gsum * gsum)
+        m_hat = m_r / corr[0]
+        v_hat = v_r / corr[1]
         u_rows = (-self.learning_rate * m_hat
                   / (jnp.sqrt(v_hat) + self.eps))
         # sentinel rows (id == V) have zero gsum; with zero moments their
         # update is exactly 0, and mode="drop" discards them anyway
-        new_m = state.m.at[uids, :].set(m_r, mode="drop")
-        new_v = state.v.at[uids, :].set(v_r, mode="drop")
+        new_m = m.at[uids, :].set(m_r, mode="drop")
+        new_v = v.at[uids, :].set(v_r, mode="drop")
         new_param = param.at[uids, :].add(u_rows.astype(param.dtype),
                                           mode="drop")
-        return new_param, SliceAdamState(new_m, new_v, t)
+        return new_param, new_m, new_v

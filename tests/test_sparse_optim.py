@@ -122,20 +122,23 @@ def test_lm1b_wiring_trajectory_unchanged(rng):
 
 
 # ---------------------------------------------------------------------------
-# SliceAdagrad's in-place row kernel (interpret mode here; compiled by
-# Mosaic and compared at the cells' shapes in chip_smoke.py's kernels
-# phase). The test steers the choice, the program has no option for it.
+# The in-place row walk under both rules: SliceAdagrad's `adagrad_rows`
+# and SliceAdam's `adam_rows` (interpret mode here; Adagrad's compiled
+# by Mosaic and compared at the cells' shapes in chip_smoke.py's kernels
+# phase, Adam's in every Adam cell's run). The test steers the choice,
+# the program has no option for it.
 # ---------------------------------------------------------------------------
 
 KD = 128          # one lane tile: the narrowest table the kernel takes
 
 
 def _through_kernel(monkeypatch):
-    """Route SliceAdagrad.update through the kernel (interpreted off the
+    """Route the updaters through the kernels (interpreted off the
     chip), in blocks of 16 ids so that a few dozen ids span blocks."""
     monkeypatch.setattr(so, "_row_executor", lambda *a: "kernel")
-    monkeypatch.setattr(so, "adagrad_rows", functools.partial(
-        so.adagrad_rows, block_rows=16))
+    for kernel in ("adagrad_rows", "adam_rows"):
+        monkeypatch.setattr(so, kernel, functools.partial(
+            getattr(so, kernel), block_rows=16))
 
 
 def _ids_duplicates(rng, V, cap):
@@ -190,33 +193,60 @@ def _table(rng, V):
     return p, a
 
 
+def _adam_state(rng, V):
+    """Moments an untouched row would visibly decay, at a count past 1."""
+    return so.SliceAdamState(
+        jnp.asarray(0.1 * rng.standard_normal((V, KD)).astype(np.float32)),
+        jnp.asarray(rng.uniform(0.01, 1.0, (V, KD)).astype(np.float32)),
+        jnp.int32(2))
+
+
+# rule: (updater, its state on a table of V rows, steps: Adam's several,
+# so that the bias corrections run at t > 1)
+RULES = {
+    "adagrad": (lambda: so.SliceAdagrad(0.2), lambda rng, V: _table(rng, V)[1],
+                1),
+    "adam": (lambda: so.SliceAdam(0.05), _adam_state, 3),
+}
+
+
 @pytest.mark.parametrize("case", sorted(ROW_KERNEL_CASES))
-def test_row_kernel_matches_scatter_path(rng, monkeypatch, case):
+@pytest.mark.parametrize("rule", sorted(RULES))
+def test_row_kernel_matches_scatter_path(rng, monkeypatch, rule, case):
     V, cap, make_ids, average = ROW_KERNEL_CASES[case]
-    ids = np.asarray(make_ids(rng, V, cap), np.int32)
-    drows = jnp.asarray(rng.standard_normal((cap, KD)).astype(np.float32))
-    p, a = _table(rng, V)
-    sl = so.SliceAdagrad(0.2)
-    want_p, want_a = sl.update(p, a, jnp.asarray(ids), drows,
-                               average=average)
+    make_updater, make_state, steps = RULES[rule]
+    sl = make_updater()
+    feeds = [(jnp.asarray(np.asarray(make_ids(rng, V, cap), np.int32)),
+              jnp.asarray(rng.standard_normal((cap, KD)).astype(np.float32)))
+             for _ in range(steps)]
+    p0, s0 = _table(rng, V)[0], make_state(rng, V)
+
+    def run():
+        p, s = p0, s0
+        for ids, drows in feeds:
+            p, s = sl.update(p, s, ids, drows, average=average)
+        return p, s
+    want = run()
     _through_kernel(monkeypatch)
-    got_p, got_a = sl.update(p, a, jnp.asarray(ids), drows,
-                             average=average)
-    np.testing.assert_allclose(got_p, want_p, rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(got_a, want_a, rtol=1e-6, atol=1e-6)
+    got = run()
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
+    ids = np.concatenate([np.asarray(i) for i, _ in feeds])
     live = np.unique(ids[(ids >= 0) & (ids < V)])
     untouched = np.setdiff1d(np.arange(V), live)
-    # the neighbours in a touched group of 8 are rewritten: same bits
-    np.testing.assert_array_equal(np.asarray(got_p)[untouched],
-                                  np.asarray(p)[untouched])
-    np.testing.assert_array_equal(np.asarray(got_a)[untouched],
-                                  np.asarray(a)[untouched])
+    # the neighbours in a touched group of 8 are written back as they
+    # were read: param, accumulator or both moments, bit for bit
+    for g, x in zip(jax.tree.leaves(got), jax.tree.leaves((p0, s0))):
+        if np.ndim(x) == 2:
+            np.testing.assert_array_equal(np.asarray(g)[untouched],
+                                          np.asarray(x)[untouched])
     if live.size:
-        assert not np.array_equal(np.asarray(got_p)[live],
-                                  np.asarray(p)[live])
+        assert not np.array_equal(np.asarray(got[0])[live],
+                                  np.asarray(p0)[live])
 
 
-def test_row_kernel_default_block_and_direct_call(rng):
+@pytest.mark.parametrize("rule", sorted(RULES))
+def test_row_kernel_default_block_and_direct_call(rng, rule):
     """The kernel's own contract at its shipped block size: sorted,
     duplicate-free ids, ``n_valid`` of them live, the rest ignored
     whatever they hold."""
@@ -225,12 +255,19 @@ def test_row_kernel_default_block_and_direct_call(rng):
     uids[:n_valid] = np.sort(rng.choice(V, size=n_valid, replace=False))
     gsum = jnp.asarray(rng.standard_normal((cap, KD)).astype(np.float32))
     p, a = _table(rng, V)
-    got_p, got_a = so.adagrad_rows(p, a, jnp.asarray(uids),
-                                   jnp.int32(n_valid), gsum, 0.2, 1e-7)
     live = jnp.asarray(np.where(np.arange(cap) < n_valid, uids, V))
-    want_p, want_a = so.SliceAdagrad(0.2)._scatter_rows(p, a, live, gsum)
-    np.testing.assert_allclose(got_p, want_p, rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(got_a, want_a, rtol=1e-6, atol=1e-6)
+    if rule == "adagrad":
+        got = so.adagrad_rows(p, a, jnp.asarray(uids), jnp.int32(n_valid),
+                              gsum, 0.2, 1e-7)
+        want = so.SliceAdagrad(0.2)._scatter_rows(p, a, live, gsum)
+    else:
+        m, v, _ = _adam_state(rng, V)
+        corr = jnp.asarray([1 - 0.9 ** 4, 1 - 0.999 ** 4], jnp.float32)
+        got = so.adam_rows(p, m, v, jnp.asarray(uids), jnp.int32(n_valid),
+                           gsum, corr, 0.05, 0.9, 0.999, 1e-8)
+        want = so.SliceAdam(0.05)._scatter_rows(p, m, v, live, gsum, corr)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
 
 
 def test_row_kernel_trajectory_matches_optax(rng, monkeypatch):
@@ -261,6 +298,35 @@ def test_row_kernel_trajectory_matches_optax(rng, monkeypatch):
     rest = np.setdiff1d(np.arange(V), rows)
     np.testing.assert_array_equal(np.asarray(p_k)[rest],
                                   np.asarray(p_d)[rest])
+
+
+def test_adam_row_kernel_trajectory_is_lazy_adam(rng, monkeypatch):
+    """Five steps through the kernel against lazy Adam written out in
+    float64: a row's moments move only in the steps that touch it, the
+    bias corrections follow the global count."""
+    _through_kernel(monkeypatch)
+    V, cap, lr, b1, b2, eps = 1006, 48, 0.05, 0.9, 0.999, 1e-8
+    sl = so.SliceAdam(lr, b1=b1, b2=b2, eps=eps)
+    p = jnp.asarray(rng.standard_normal((V, KD)).astype(np.float32))
+    st = sl.init(p)
+    ref_p, ref_m, ref_v = (np.asarray(p, np.float64), np.zeros((V, KD)),
+                           np.zeros((V, KD)))
+    for t in range(1, 6):
+        ids = rng.choice(V // 2, size=cap).astype(np.int32)
+        ids[0] = V - 1
+        drows = rng.standard_normal((cap, KD)).astype(np.float32)
+        g = np.zeros((V, KD))
+        np.add.at(g, ids, drows.astype(np.float64))
+        rows = np.unique(ids)
+        ref_m[rows] = b1 * ref_m[rows] + (1 - b1) * g[rows]
+        ref_v[rows] = b2 * ref_v[rows] + (1 - b2) * g[rows] ** 2
+        ref_p[rows] -= lr * (ref_m[rows] / (1 - b1 ** t)) / (
+            np.sqrt(ref_v[rows] / (1 - b2 ** t)) + eps)
+        p, st = sl.update(p, st, jnp.asarray(ids), jnp.asarray(drows))
+    assert int(st.count) == 5
+    for got, ref in ((p, ref_p), (st.m, ref_m), (st.v, ref_v)):
+        np.testing.assert_allclose(np.asarray(got), ref, rtol=1e-4,
+                                   atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -372,8 +438,13 @@ def _sds(shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+# the VMEM the executor rule is told the core has: a (64, 128) float32
+# table (32 KiB) is larger, an (8, 128) one is not
+VMEM = 16 * 1024
+
 EXECUTOR_CASES = {
     # name: (param, acc, devices in the mesh (None: no scope), backend)
+    "held_in_vmem": (_sds((8, 128)), _sds((8, 128)), 1, "tpu"),
     "one_lane": (_sds((64, 1)), _sds((64, 1)), 1, "tpu"),
     "eight_lanes": (_sds((64, 8)), _sds((64, 8)), 1, "tpu"),
     "bf16_table": (_sds((64, 128), jnp.bfloat16), _sds((64, 128)), 1,
@@ -385,9 +456,13 @@ EXECUTOR_CASES = {
 
 
 @pytest.mark.parametrize("case", sorted(EXECUTOR_CASES))
-def test_row_executor_choice_keeps_the_scatter_path(case):
-    """Every table the kernel is not for records "xla": by the rule,
-    and by what a traced update on this backend notes."""
+@pytest.mark.parametrize("rule", sorted(RULES))
+def test_row_executor_choice_keeps_the_scatter_path(monkeypatch, rule,
+                                                   case):
+    """Every table the kernel is not for records "xla" under either
+    rule: by the rule, and by what a traced update on this backend
+    notes."""
+    monkeypatch.setattr(so, "_vmem_bytes", lambda: VMEM)
     param, acc, n_dev, backend = EXECUTOR_CASES[case]
     mesh = _mesh(n_dev) if n_dev else None
     assert so._row_executor(param, acc, mesh, backend) == "xla"
@@ -396,21 +471,49 @@ def test_row_executor_choice_keeps_the_scatter_path(case):
                             "tpu") == "kernel"
     so.reset_trace_records()
     V, D = param.shape
+    sl = RULES[rule][0]()
     p = jnp.zeros((V, D), param.dtype)
-    a = jnp.full((V, D), 0.1, acc.dtype)
+    a = (jnp.full((V, D), 0.1, acc.dtype) if rule == "adagrad"
+         else sl.init(p))
     if n_dev and n_dev > 1:
         from jax.sharding import NamedSharding, PartitionSpec as P
         p = jax.device_put(p, NamedSharding(mesh, P("shard", None)))
-        a = jax.device_put(a, p.sharding)
+        a = jax.tree.map(lambda x: jax.device_put(x, p.sharding)
+                         if x.ndim == 2 else x, a)
     ids, drows = jnp.arange(8, dtype=jnp.int32), jnp.ones((8, D))
 
     def step(p, a):
         if mesh is None:
-            return so.SliceAdagrad(0.1).update(p, a, ids, drows)
+            return sl.update(p, a, ids, drows)
         with so.table_update_scope("t", mesh):
-            return so.SliceAdagrad(0.1).update(p, a, ids, drows)
+            return sl.update(p, a, ids, drows)
     jax.jit(step)(p, a)
     assert so.trace_records() == [
         {"table": "t" if mesh is not None else None, "rows": 8, "dim": D,
-         "executor": "xla"}]
+         "rule": rule, "executor": "xla"}]
+    so.reset_trace_records()
+
+
+@pytest.mark.parametrize("rule", sorted(RULES))
+def test_trace_records_note_the_kernel_on_one_tpu(monkeypatch, rule):
+    """A float32 lane-aligned table whole on a one-device mesh of a TPU,
+    and larger than its VMEM, is noted as the kernel's, under the
+    updater's rule: traced here for
+    a TPU backend the test claims (nothing compiles or runs)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(so, "_vmem_bytes", lambda: VMEM)
+    so.reset_trace_records()
+    sl = RULES[rule][0]()
+    p = jax.ShapeDtypeStruct((64, 256), jnp.float32)
+    state = jax.eval_shape(sl.init, p)
+
+    def step(p, state):
+        with so.table_update_scope("emb", _mesh(1)):
+            return sl.update(p, state, jnp.arange(24, dtype=jnp.int32),
+                             jnp.ones((24, 256)))
+    jaxpr = jax.make_jaxpr(step)(p, state)
+    assert so.trace_records() == [
+        {"table": "emb", "rows": 24, "dim": 256, "rule": rule,
+         "executor": "kernel"}]
+    assert f"name={rule}_rows" in str(jaxpr)
     so.reset_trace_records()

@@ -12,6 +12,7 @@ collects the same tests, and only the worker that is handed this file
 loads the TPU's library.
 """
 
+import functools
 import re
 
 import jax
@@ -439,7 +440,8 @@ def test_delta_rule_kernels_compile_at_olmo_hybrids_heads(one_chip, chunk):
     assert sum("delta_bwd" in n for n in names) == 1
 
 
-def test_olmo_hybrid_step_compiles_at_published_widths_and_fits(topo):
+def test_olmo_hybrid_step_compiles_at_published_widths_and_fits(
+        topo, monkeypatch):
     """Olmo-Hybrid-7B's training step as the benchmark's cell runs it
     (one period of 4 layers, 15 of 30 heads, 12,544 rows, one sequence
     of 8,192; every width as published) through ``Engine`` for the
@@ -453,11 +455,21 @@ def test_olmo_hybrid_step_compiles_at_published_widths_and_fits(topo):
     layer, no forward kernel made again by the rematerialisation) and of
     the MLP's products ONE a kind of layer in the rematerialised forward
     (the gate's; ``models/olmo_hybrid.MLP_KEPT``), where there were
-    three."""
+    three; the table's lazy Adam in place by ``adam_rows``, as on the
+    chip (the executor's rule reads the backend and its VMEM, the CPU's
+    here: the test steers them to a v5e's; ISSUE 40)."""
     import numpy as np
     import parallax_tpu as parallax
     from parallax_tpu.core import engine as engine_lib, mesh as mesh_lib
     from parallax_tpu.models import olmo_hybrid
+    from parallax_tpu.ops import sparse_optim as so
+
+    rule = so._row_executor
+    monkeypatch.setattr(so, "_row_executor",
+                        lambda p, a, mesh, backend: rule(p, a, mesh, "tpu"))
+    monkeypatch.setattr(so, "_vmem_bytes", lambda: 128 * 1024 * 1024)
+    monkeypatch.setattr(so, "adam_rows", functools.partial(
+        so.adam_rows, interpret=False))
 
     dev = topo.devices[0]
     one = SingleDeviceSharding(dev)
@@ -500,7 +512,8 @@ def test_olmo_hybrid_step_compiles_at_published_widths_and_fits(topo):
     names = re.findall(
         r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
     assert sorted(n.split(".")[0] for n in names) == [
-        "delta_bwd", "delta_fwd", "flash_dkv", "flash_dq", "flash_fwd"]
+        "adam_rows", "delta_bwd", "delta_fwd", "flash_dkv", "flash_dq",
+        "flash_fwd"]
     assert not re.search(r"f32\[(1,)?8192,8192\]", text)
 
 

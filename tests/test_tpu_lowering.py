@@ -195,6 +195,35 @@ def test_adagrad_rows_lowers_for_tpu(slots):
     assert "output_operand_aliases" in text      # in place: no [V, D] copy
 
 
+@pytest.mark.parametrize("V, D", [(25024, 2048), (12288, 2304),
+                                  (12544, 3840), (12547, 3840)])
+def test_adam_rows_lowers_for_tpu(V, D):
+    """ISSUE 40: SliceAdam's in-place row update at the Adam cells'
+    tables (Trinity-Mini's and Keye's 2,048 wide, Mellum2's 2,304,
+    Olmo-Hybrid's 3,840) and 8,192 slots: param, m and v left in HBM
+    and aliased to the outputs, the bias corrections scalar-prefetched
+    beside the ids, ONE custom call; a table whose rows are not a
+    multiple of 8 takes the partial last group through the scatter."""
+    from parallax_tpu.ops import sparse_optim as so
+
+    table = jax.ShapeDtypeStruct((V, D), jnp.float32)
+    sl = so.SliceAdam(3e-4)
+
+    def rows(param, m, v, uids, gsum, corr):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(so, "adam_rows", functools.partial(
+                so.adam_rows, interpret=False))
+            return sl._kernel_rows(param, m, v, uids, gsum, corr)
+    text = _export_tpu(rows, table, table, table,
+                       jax.ShapeDtypeStruct((8192,), jnp.int32),
+                       jax.ShapeDtypeStruct((8192, D), jnp.float32),
+                       jax.ShapeDtypeStruct((2,), jnp.float32))
+    assert text.count("tpu_custom_call") == 1, text.count(
+        "tpu_custom_call")
+    assert "output_operand_aliases" in text
+    assert "adam_rows" in text
+
+
 def test_paged_attention_kernel_lowers_for_tpu():
     """ISSUE 16: the fused paged-attention decode kernel at the
     flagship decode shape (bf16, 2048-cap 128-token pages, spec-verify
