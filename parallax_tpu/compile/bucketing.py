@@ -46,6 +46,18 @@ BucketsArg = Union[None, str, Sequence[int]]
 
 _warned_oversize: set = set()
 
+# str(dtype) costs microseconds (numpy builds the name anew on every
+# call) and batch_signature runs on every step's dispatch: each
+# distinct dtype's name is built once
+_dtype_names: Dict[np.dtype, str] = {}
+
+
+def _dtype_name(dtype) -> str:
+    name = _dtype_names.get(dtype)
+    if name is None:
+        name = _dtype_names[dtype] = str(dtype)
+    return name
+
 
 def resolve_buckets(shape_buckets: BucketsArg, example_batch_dim: int,
                     local_divisor: int = 1) -> Optional[Tuple[int, ...]]:
@@ -190,7 +202,8 @@ def batch_signature(batch) -> Tuple:
     """
     try:
         return tuple(sorted(
-            (k, tuple(v.shape), str(v.dtype)) for k, v in batch.items()))
+            [(k, tuple(v.shape), _dtype_name(v.dtype))
+             for k, v in batch.items()]))
     except AttributeError:
         import jax
 
@@ -205,7 +218,7 @@ def batch_signature(batch) -> Tuple:
 
         return tuple(
             (classify._pathname(kp), tuple(np.shape(leaf)),
-             str(leaf_dtype(leaf)))
+             _dtype_name(leaf_dtype(leaf)))
             for kp, leaf in
             jax.tree_util.tree_flatten_with_path(batch)[0])
 

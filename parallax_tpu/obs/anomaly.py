@@ -45,6 +45,30 @@ from parallax_tpu.obs.metrics import MetricsRegistry
 _MAD_SIGMA = 1.4826
 
 
+def _sorted_mad(vals: List[float]) -> float:
+    """``sorted(abs(v - med) for v in vals)[n // 2]`` for SORTED
+    ``vals`` with ``med = vals[n // 2]``, without building and sorting
+    the deviations. Below the median they are ``med - vals[m-1-i]``,
+    from it up ``vals[m+j] - med``: two ascending runs, so the wanted
+    order statistic is the ``t``-th smallest of their union, found by a
+    binary search on how many of it come from the lower run (O(log n);
+    the refresh runs every few steps)."""
+    n = len(vals)
+    m = n // 2
+    med = vals[m]
+    t = m + 1                      # the (n//2)-th deviation, 1-based
+    lo, hi = max(0, t - (n - m)), min(t, m)
+    while lo < hi:
+        i = (lo + hi) // 2         # take i from below, t - i from above
+        if med - vals[m - 1 - i] < vals[m + t - i - 1] - med:
+            lo = i + 1
+        else:
+            hi = i
+    below = med - vals[m - lo] if lo > 0 else 0.0
+    above = vals[m + t - lo - 1] - med if t - lo > 0 else 0.0
+    return max(below, above)
+
+
 class AnomalyEvent(NamedTuple):
     signal: str          # e.g. "step_time_ms", "grad_norm", "loss"
     kind: str            # "spike" | "shift"
@@ -60,7 +84,13 @@ class _SignalDetector:
     def __init__(self, cfg):
         self.window: collections.deque = collections.deque(
             maxlen=int(cfg.window))
-        self.cfg = cfg
+        # the thresholds as plain numbers, converted once: observe()
+        # runs several times a step
+        self._min_samples = int(cfg.min_samples)
+        self._cooldown = int(cfg.cooldown)
+        self._spike_min_ratio = float(cfg.spike_min_ratio)
+        self._spike_mad_scale = float(cfg.spike_mads) * _MAD_SIGMA
+        self._shift_ratio = float(cfg.shift_ratio)
         self._n = 0
         self._cooldown_until = 0
         # cached baseline, refreshed every REFRESH observations
@@ -76,9 +106,8 @@ class _SignalDetector:
 
     def _refresh(self) -> None:
         vals = sorted(self.window)
-        n = len(vals)
-        self._median = vals[n // 2]
-        self._mad = sorted(abs(v - self._median) for v in vals)[n // 2]
+        self._median = vals[len(vals) // 2]
+        self._mad = _sorted_mad(vals)
         self._stale = 0
 
     # baseline refresh cadence: the cached median/MAD may be up to this
@@ -121,14 +150,15 @@ class _SignalDetector:
         self._recent.clear()
         self._recent_sum = 0.0
         self._stale = 0
-        self._cooldown_until = self._n + int(self.cfg.cooldown)
+        self._cooldown_until = self._n + self._cooldown
 
     def observe(self, step: int, value: float) -> Optional[AnomalyEvent]:
-        cfg = self.cfg
+        value = float(value)
         self._n += 1
-        armed = (self._n > int(cfg.min_samples)
+        min_samples = self._min_samples
+        armed = (self._n > min_samples
                  and self._n >= self._cooldown_until
-                 and len(self.window) >= int(cfg.min_samples))
+                 and len(self.window) >= min_samples)
         event = None
         if armed:
             if self._stale <= 0:
@@ -136,11 +166,11 @@ class _SignalDetector:
                 self._stale = self.REFRESH
             med, mad = self._median, self._mad
             # spike: this one observation is an outlier above baseline
-            if (med > 0 and value > med * float(cfg.spike_min_ratio)
-                    and value - med > float(cfg.spike_mads)
-                    * _MAD_SIGMA * max(mad, 1e-12)):
-                event = AnomalyEvent("", "spike", step, float(value),
-                                     med, float(value) / med)
+            if (med > 0 and value > med * self._spike_min_ratio
+                    and value - med
+                    > self._spike_mad_scale * max(mad, 1e-12)):
+                event = AnomalyEvent("", "spike", step, value,
+                                     med, value / med)
             else:
                 # shift: the recent level moved, not just one sample —
                 # running recent mean vs the cached window median (the
@@ -148,26 +178,25 @@ class _SignalDetector:
                 # it before absorbing it)
                 sw = self._recent.maxlen
                 if (len(self._recent) == sw
-                        and len(self.window)
-                        >= int(cfg.min_samples) + sw):
+                        and len(self.window) >= min_samples + sw):
                     mean = (self._recent_sum - self._recent[0]
                             + value) / sw
-                    if med > 0 and mean > med * float(cfg.shift_ratio):
+                    if med > 0 and mean > med * self._shift_ratio:
                         event = AnomalyEvent("", "shift", step, mean,
                                              med, mean / med)
         if event is not None:
-            self._cooldown_until = self._n + int(cfg.cooldown)
+            self._cooldown_until = self._n + self._cooldown
             if event.kind == "shift":
                 # rebaseline: the new level is the new normal
                 self.window.clear()
                 self._recent.clear()
                 self._recent_sum = 0.0
                 self._stale = 0
-        self.window.append(float(value))
+        self.window.append(value)
         if len(self._recent) == self._recent.maxlen:
             self._recent_sum -= self._recent[0]
-        self._recent.append(float(value))
-        self._recent_sum += float(value)
+        self._recent.append(value)
+        self._recent_sum += value
         self._stale -= 1
         return event
 

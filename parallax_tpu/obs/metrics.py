@@ -74,9 +74,9 @@ class Gauge:
         self._fn = None
 
     def set(self, value) -> None:
-        if not _state.enabled:
-            return
-        with self._lock:
+        # no lock: one attribute store is atomic under the GIL, and the
+        # numerics consume sets a dozen gauges on every sampled step
+        if _state.enabled:
             self._value = value
 
     def set_fn(self, fn) -> None:

@@ -246,6 +246,18 @@ def _tree_ready(tree) -> bool:
     return True
 
 
+def _sample_ready(stats) -> bool:
+    """Whether ``_consume`` can read ``stats`` without blocking. Every
+    leaf is an output of the one step that made the sample, and a
+    step's outputs become ready together, so the flag answers for the
+    whole tree without a walk over it (this runs on every step)."""
+    flag = stats.get(SAMPLED_KEY)
+    if flag is None:
+        return _tree_ready(stats)
+    is_ready = getattr(flag, "is_ready", None)
+    return is_ready is None or is_ready()
+
+
 class NumericsMonitor:
     """Lazy consumer of the in-graph samples (obs/health.py pattern).
 
@@ -304,7 +316,7 @@ class NumericsMonitor:
     def _drain(self, block: bool) -> None:
         while self._pending:
             step, stats = self._pending[0]
-            if not block and not _tree_ready(stats):
+            if not block and not _sample_ready(stats):
                 return
             self._pending.popleft()
             try:

@@ -29,6 +29,7 @@ microseconds as the chrome format requires.
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import os
 import threading
@@ -50,6 +51,11 @@ class TraceEvent(NamedTuple):
     tid: int            # thread ident
     thread_name: str
     args: Optional[dict]
+
+
+# TraceEvent from one field tuple without the generated keyword-taking
+# __new__: half the cost of TraceEvent(...) on the per-span hot path
+_new_event = functools.partial(tuple.__new__, TraceEvent)
 
 
 class TraceCollector:
@@ -193,8 +199,8 @@ class _Span:
         if name is None:
             name = threading.current_thread().name
             _thread_name_cache.name = name
-        _collector.record(TraceEvent(self._name, self._t0 - _EPOCH,
-                                     end - self._t0, tid, name, args))
+        _collector.record(_new_event((self._name, self._t0 - _EPOCH,
+                                      end - self._t0, tid, name, args)))
         # returning None: never swallow the exception
 
 
@@ -242,9 +248,9 @@ def record_span(name: str, start: float, end: float, **attrs) -> None:
     if tname is None:
         tname = threading.current_thread().name
         _thread_name_cache.name = tname
-    _collector.record(TraceEvent(name, start - _EPOCH,
-                                 max(0.0, end - start), tid, tname,
-                                 attrs or None))
+    _collector.record(_new_event((name, start - _EPOCH,
+                                  max(0.0, end - start), tid, tname,
+                                  attrs or None)))
 
 
 def export_chrome_trace(path: str) -> str:

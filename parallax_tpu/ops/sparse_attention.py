@@ -54,7 +54,14 @@ softmax; output and per-row logsumexp), ``sparse_attn_bwd`` (one pass
 over the key tiles gives dk and dv of the tile and accumulates dq, the
 eight query heads of a group looping inside the kernel over one fetch
 of their key/value head and of the mask) and ``sparse_attn_probs`` (the
-heads' probabilities summed, the indexer's target). The fourth,
+heads' probabilities summed, the indexer's target). The target is made
+once a pass: in the forward by ``sparse_attn_probs``, for the loss's
+value (it needs each row's final logsumexp, which ``sparse_attn_fwd``
+has only at its end); in the backward by ``sparse_attn_bwd`` itself,
+which computes every head's probabilities on every key tile for the
+gradient anyway and sums them, in ``sparse_attn_probs``' order, into a
+second output: the backward runs no probabilities pass of its own, and
+the forward keeps nothing more for it. The fourth,
 ``indexer_bwd``, is the gradient of ``indexer_scores``: for a key tile
 and each indexer head in turn the products ``z [q_chunk, tile]`` again,
 what ``relu`` and the head's weight let through of the scores'
@@ -260,14 +267,24 @@ def _fwd_kernel(start_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
 
 
 def _bwd_kernel(start_ref, q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                delta_ref, dq_ref, dk_ref, dv_ref, dq_sc, *, tk: int):
+                delta_ref, dq_ref, dk_ref, dv_ref, t_ref, dq_sc, *, tk: int):
+    """One key tile of one group, the grid in ``sparse_attn_probs``'
+    order (batch, key tile, group): the heads' probabilities ``p``,
+    which the gradient computes anyway, are summed into the tile's block
+    of the indexer's target as that kernel sums them, group after group
+    and head after head. dq of every group accumulates in ``dq_sc``
+    across the tiles and leaves at the last one."""
     R, C = q_ref.shape[2], q_ref.shape[3]
-    kt = pl.program_id(2)
+    kt, g = pl.program_id(1), pl.program_id(2)
     needed = kt <= _last_tile(start_ref, C, tk)
 
     @pl.when(kt == 0)
     def _():
-        dq_sc[...] = jnp.zeros(dq_sc.shape, jnp.float32)
+        dq_sc[g] = jnp.zeros(dq_sc.shape[1:], jnp.float32)
+
+    @pl.when(g == 0)
+    def _():
+        t_ref[0] = jnp.zeros(t_ref.shape[1:], jnp.float32)
 
     @pl.when(needed)
     def _():
@@ -275,12 +292,14 @@ def _bwd_kernel(start_ref, q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         keep = mask_ref[0] != 0
         dk = jnp.zeros(dk_ref.shape[2:], jnp.float32)
         dv = jnp.zeros(dv_ref.shape[2:], jnp.float32)
+        total = jnp.zeros(t_ref.shape[1:], jnp.float32)
         for r in range(R):
             q, do = q_ref[0, 0, r], do_ref[0, 0, r]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             p = jnp.where(keep, jnp.exp(s - lse_ref[0, 0, r][:, :1]), 0.0)
+            total = total + p
             dp = jax.lax.dot_general(
                 do, v, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -291,20 +310,21 @@ def _bwd_kernel(start_ref, q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
             dk = dk + jax.lax.dot_general(
                 ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            dq_sc[r] = dq_sc[r] + jax.lax.dot_general(
+            dq_sc[g, r] = dq_sc[g, r] + jax.lax.dot_general(
                 ds, k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         dk_ref[0, 0] = dk.astype(dk_ref.dtype)
         dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+        t_ref[0] = t_ref[0] + total
 
     @pl.when(jnp.logical_not(needed))
     def _():
         dk_ref[0, 0] = jnp.zeros(dk_ref.shape[2:], dk_ref.dtype)
         dv_ref[0, 0] = jnp.zeros(dv_ref.shape[2:], dv_ref.dtype)
 
-    @pl.when(kt == pl.num_programs(2) - 1)
+    @pl.when(kt == pl.num_programs(1) - 1)
     def _():
-        dq_ref[0, 0] = dq_sc[...].astype(dq_ref.dtype)
+        dq_ref[0, g] = dq_sc[g].astype(dq_ref.dtype)
 
 
 def _probs_kernel(start_ref, q_ref, k_ref, mask_ref, lse_ref, out_ref, *,
@@ -330,16 +350,23 @@ def _probs_kernel(start_ref, q_ref, k_ref, mask_ref, lse_ref, out_ref, *,
         out_ref[0] = out_ref[0] + total
 
 
-def _specs(q, k, tk: int, grid_order: str):
+def _specs(q, k, tk: int, grid_order: str, fold_groups: bool):
     """Block specs of the chunk's operands for a grid over (batch,
     key/value head, key tile) (``"bgk"``) or (batch, key tile, key/value
     head) (``"bkg"``). A key tile past the chunk's last is not fetched:
-    its index folds onto the last needed one."""
+    its index folds onto the last needed one. ``fold_groups`` (``"bkg"``
+    only): the steps past the chunk's last tile read the last group's
+    inputs, which the step before them read, so that they fetch nothing
+    at all; the outputs stay each group's own."""
     B, Hkv, R, C, D = q.shape
 
-    def ix(fn):
+    def ix(fn, fold=fold_groups):
         if grid_order == "bgk":
             return lambda b, g, kt, start: fn(b, g, kt, start)
+        if fold:
+            return lambda b, kt, g, start: fn(
+                b, jnp.where(kt <= _last_tile(start, C, tk), g, Hkv - 1),
+                kt, start)
         return lambda b, kt, g, start: fn(b, g, kt, start)
 
     def seen(kt, start):
@@ -348,10 +375,12 @@ def _specs(q, k, tk: int, grid_order: str):
     return {
         "q": pl.BlockSpec((1, 1, R, C, D), ix(lambda b, g, kt, s:
                                               (b, g, 0, 0, 0))),
+        "q_all": pl.BlockSpec((1, Hkv, R, C, D), ix(lambda b, g, kt, s:
+                                                    (b, 0, 0, 0, 0))),
         "kv": pl.BlockSpec((1, 1, tk, D), ix(lambda b, g, kt, s:
                                              (b, g, seen(kt, s), 0))),
         "kv_out": pl.BlockSpec((1, 1, tk, D), ix(lambda b, g, kt, s:
-                                                 (b, g, kt, 0))),
+                                                 (b, g, kt, 0), fold=False)),
         "mask": pl.BlockSpec((1, C, tk), ix(lambda b, g, kt, s:
                                             (b, 0, seen(kt, s)))),
         "row": pl.BlockSpec((1, 1, R, C, _LANES), ix(
@@ -362,12 +391,13 @@ def _specs(q, k, tk: int, grid_order: str):
 
 
 def _call(kernel, name, q, k, grid_order, in_keys, out_keys, out_shapes,
-          scratch, operands, interpret):
+          scratch, operands, interpret, *, fold_groups=False,
+          semantics=("parallel", "parallel", "arbitrary")):
     from jax.experimental.pallas import tpu as pltpu
     B, Hkv, R, C, D = q.shape
     Tk = k.shape[2]
     tk = _tile(Tk)
-    specs = _specs(q, k, tk, grid_order)
+    specs = _specs(q, k, tk, grid_order, fold_groups)
     grid = (B, Hkv, Tk // tk) if grid_order == "bgk" \
         else (B, Tk // tk, Hkv)
     return pl.pallas_call(
@@ -379,7 +409,7 @@ def _call(kernel, name, q, k, grid_order, in_keys, out_keys, out_shapes,
             scratch_shapes=scratch),
         out_shape=out_shapes,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=semantics,
             vmem_limit_bytes=64 * 1024 * 1024),
         name=name, interpret=interpret)(*operands)
 
@@ -400,17 +430,25 @@ def _fwd_call(q, k, v, mask, start, interpret):
 
 
 def _bwd_call(q, k, v, mask, start, do, lse, delta, interpret):
+    """``(dq, dk, dv, target)``: the gradient of the attention under
+    the output's cotangent ``do`` (``delta``: each row's ``do . out``),
+    and the indexer's target, the heads' probabilities summed and
+    normalised to one, equal to what ``_probs_call`` gives on the same
+    operands: the kernel sums the ``p`` its gradient is made of."""
     from jax.experimental.pallas import tpu as pltpu
     B, Hkv, R, C, D = q.shape
-    return _call(
-        _bwd_kernel, "sparse_attn_bwd", q, k, "bgk",
+    dq, dk, dv, target = _call(
+        _bwd_kernel, "sparse_attn_bwd", q, k, "bkg",
         ("q", "kv", "kv", "mask", "q", "row", "row"),
-        ("q", "kv_out", "kv_out"),
+        ("q_all", "kv_out", "kv_out", "probs"),
         [jax.ShapeDtypeStruct(q.shape, q.dtype),
          jax.ShapeDtypeStruct(k.shape, k.dtype),
-         jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        [pltpu.VMEM((R, C, D), jnp.float32)],
-        (start, q, k, v, mask, do, lse, delta), interpret)
+         jax.ShapeDtypeStruct(v.shape, v.dtype),
+         jax.ShapeDtypeStruct((B, C, k.shape[2]), jnp.float32)],
+        [pltpu.VMEM((Hkv, R, C, D), jnp.float32)],
+        (start, q, k, v, mask, do, lse, delta), interpret,
+        fold_groups=True, semantics=("parallel", "arbitrary", "arbitrary"))
+    return dq, dk, dv, target / (Hkv * R)
 
 
 def _probs_call(q, k, mask, start, lse, interpret):
@@ -553,10 +591,14 @@ def _chunk(q, k, v, qi, ki, wi, q_start, topk, impl: str):
 
     Its backward pass is written out (``_chunk_bwd``): it keeps the
     selection, the output and the rows' logsumexp, and computes the
-    scores and the heads' summed probabilities again, so that neither
-    the per-head logits nor the indexer's per-head products of a chunk
-    outlive the pass that made them (under ``"kernel"`` they are never
-    in memory at all)."""
+    scores and the heads' summed probabilities (the indexer's target)
+    again, so that neither the per-head logits nor the indexer's
+    per-head products of a chunk outlive the pass that made them (under
+    ``"kernel"`` they are never in memory at all). Under ``"kernel"``
+    the forward's target comes from ``sparse_attn_probs`` and the
+    backward's from ``sparse_attn_bwd``, which sums the probabilities
+    its gradient is made of; under ``"xla"`` both from the einsum
+    executor's softmax."""
     return _chunk_fwd(q, k, v, qi, ki, wi, q_start, topk, impl)[0]
 
 
@@ -605,10 +647,10 @@ def _chunk_bwd(impl, res, cotangents):
         delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1, keepdims=True)
         delta = jnp.broadcast_to(delta, delta.shape[:-1] + (_LANES,))
-        dqs, dk, dv = _bwd_call(qs, k, v, mask, start,
-                                d_out.astype(q.dtype), lse, delta, interpret)
+        dqs, dk, dv, target = _bwd_call(
+            qs, k, v, mask, start, d_out.astype(q.dtype), lse, delta,
+            interpret)
         dq = _scaled(dqs)
-        target = _probs_call(qs, k, mask, start, lse, interpret)
     with jax.named_scope("indexer"):
         # the scores again, and the way back through the chunk's
         # per-head products

@@ -193,6 +193,23 @@ class TestAnomaly:
         am.observe("s", 20, 50.0)
         assert len(got) == 1 and got[0].kind == "spike"
 
+    @pytest.mark.parametrize("n", [1, 2, 3, 8, 31, 64])
+    def test_sorted_mad_matches_the_sort_definition(self, n):
+        """The baseline's MAD is selected from two ascending runs of
+        deviations; it must equal sorting every deviation, ties and
+        repeated values included."""
+        from parallax_tpu.obs.anomaly import _sorted_mad
+        rng = np.random.default_rng(n)
+        for trial in range(200):
+            if trial % 2:
+                vals = rng.integers(0, 4, n).astype(float).tolist()
+            else:
+                vals = rng.lognormal(0.0, 1.0, n).tolist()
+            vals.sort()
+            med = vals[n // 2]
+            want = sorted(abs(v - med) for v in vals)[n // 2]
+            assert _sorted_mad(vals) == want, vals
+
 
 # -- flight recorder (obs/flightrec.py) ------------------------------------
 

@@ -152,12 +152,12 @@ def test_shapes_that_do_not_divide_are_refused():
                             q_chunk=8)
 
 
-@pytest.mark.parametrize("topk,band", [(5, 2), (24, 3)])
+@pytest.mark.parametrize("topk,band", [(5, 2), (24, 3), (3, 1), (12, 2)])
 def test_kernels_interpreted_match_the_xla_executor(topk, band, monkeypatch):
-    """The three Mosaic kernels the TPU runs, interpreted here, against
-    the einsum executor: output, loss and every gradient; key tiles of
-    8, so that a chunk walks several and skips those above its
-    diagonal."""
+    """The four Mosaic kernels the TPU runs, interpreted here, against
+    the einsum executor: output, loss and every gradient (the indexer's
+    through the target ``sparse_attn_bwd`` hands back); key tiles of 8,
+    so that a chunk walks several and skips those above its diagonal."""
     monkeypatch.setattr(sa, "_KEY_TILE", 8)
     args = _inputs(seed=3)
 
@@ -215,6 +215,56 @@ def test_indexer_bwd_kernel_matches_the_vjp_of_the_scores(
     above = q_start + C
     assert float(jnp.abs(got[1][:, :above].astype(jnp.float32)).max()) > 0
     assert float(jnp.abs(got[1][:, above:].astype(jnp.float32)).max()) == 0.0
+
+
+@pytest.mark.parametrize("keys,q_start,dtype,rtol,atol", [
+    (16, 0, jnp.float32, 1e-5, 1e-5), (16, 8, jnp.float32, 1e-5, 1e-5),
+    (32, 8, jnp.float32, 1e-5, 1e-5), (32, 24, jnp.float32, 1e-5, 1e-5),
+    (32, 0, jnp.bfloat16, 2e-2, 2e-2), (32, 16, jnp.bfloat16, 2e-2, 2e-2)])
+def test_bwd_kernel_hands_back_the_target_of_the_probs_kernel(
+        keys, q_start, dtype, rtol, atol, monkeypatch):
+    """The kernel ``sparse_attn_bwd``, interpreted, for one chunk of 8
+    queries over 2 or 4 key tiles of 8 and 2 key/value heads of 3 query
+    heads: the indexer's target it sums from its own probabilities is
+    ``sparse_attn_probs``' on the same operands, bit for bit (the same
+    sums in the same order), zero on the tiles past the chunk's last
+    and one a row; its dq, dk and dv are the einsum executor's
+    gradient."""
+    monkeypatch.setattr(sa, "_KEY_TILE", 8)
+    C, R = 8, 3
+    rng = np.random.default_rng(17 + keys + q_start)
+
+    def n(*shape):
+        return jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+
+    q, k, v, do = (n(*shape).astype(dtype) for shape in (
+        (B, HKV, R, C, D), (B, HKV, keys, D), (B, HKV, keys, D),
+        (B, HKV, R, C, D)))
+    t = q_start + np.arange(C)[:, None]
+    s = np.arange(keys)[None]
+    sel = jnp.asarray(((s <= t) & (rng.random((B, C, keys)) < 0.5))
+                      | (s == t))
+    qs, mask, start = sa._kernel_operands(q, sel, jnp.int32(q_start))
+    out, lse = sa._fwd_call(qs, k, v, mask, start, True)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    delta = jnp.broadcast_to(delta, delta.shape[:-1] + (sa._LANES,))
+    dqs, dk, dv, target = sa._bwd_call(qs, k, v, mask, start, do, lse,
+                                       delta, True)
+    want = sa._probs_call(qs, k, mask, start, lse, True)
+    assert target.dtype == jnp.float32 and target.shape == (B, C, keys)
+    np.testing.assert_array_equal(np.asarray(target), np.asarray(want))
+    past = (q_start // 8 + 1) * 8
+    assert past == keys or float(jnp.abs(target[..., past:]).max()) == 0.0
+    np.testing.assert_allclose(np.asarray(target.sum(-1)), 1.0, rtol=1e-5)
+
+    _, pull = jax.vjp(lambda q, k, v: sa._attend_xla(q, k, v, sel)[0],
+                      q, k, v)
+    for g, w in zip((sa._scaled(dqs), dk, dv), pull(do)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32),
+                                   rtol=rtol, atol=atol)
 
 
 def _check_fwd_kernel(q, k, v, sel, q_start, rtol, atol):

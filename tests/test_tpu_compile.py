@@ -60,11 +60,16 @@ def _outside_fusions(text, shape):
     return hits
 
 
-def test_sparse_attention_kernels_compile_at_published_widths(one_chip):
+@pytest.mark.parametrize("keys", [2048, 8192])
+def test_sparse_attention_kernels_compile_at_published_widths(one_chip, keys):
     """One 512-query chunk of 32 query heads on 4 key/value heads of 128
-    against 2,048 keys: forward, the heads' summed probabilities, the
-    backward kernel, and the indexer's own backward kernel, which keeps
-    the chunk's per-head products ``[512, 16, keys]`` out of memory."""
+    against 2,048 keys, and against 8,192 (the longest band): forward,
+    the heads' summed probabilities, the backward kernel, which hands
+    back the indexer's target too, and the indexer's own backward
+    kernel, which keeps the chunk's per-head products ``[512, 16,
+    keys]`` out of memory. The summed probabilities are the forward's
+    alone: the chunk's backward, compiled by itself, holds no
+    ``sparse_attn_probs``; its target leaves ``sparse_attn_bwd``."""
     from parallax_tpu.ops import sparse_attention as sa
 
     def sds(shape, dtype=jnp.bfloat16):
@@ -75,21 +80,40 @@ def test_sparse_attention_kernels_compile_at_published_widths(one_chip):
                                   jnp.int32(2048), "kernel")
         return jnp.sum(out.astype(jnp.float32)) + kl
 
-    compiled = _compile(
-        jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4, 5)),
-        sds((1, 4, 8, 512, 128)), sds((1, 4, 2048, 128)),
-        sds((1, 4, 2048, 128)), sds((1, 512, 16, 64)), sds((1, 2048, 64)),
-        sds((1, 512, 16)), sds((), jnp.int32))
-    # forward, the summed probabilities (for the loss and again for its
-    # gradient), backward, the indexer's backward
-    assert _kernels(compiled) == 5
+    def bwd(q, k, v, qi, ki, wi, start, sel, out, lse, d_out):
+        return sa._chunk_bwd("kernel", (q, k, v, qi, ki, wi, start,
+                                        (sel, out, lse)),
+                             (d_out, jnp.float32(1)))
+
+    inputs = (sds((1, 4, 8, 512, 128)), sds((1, 4, keys, 128)),
+              sds((1, 4, keys, 128)), sds((1, 512, 16, 64)),
+              sds((1, keys, 64)), sds((1, 512, 16)), sds((), jnp.int32))
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4, 5)),
+                    *inputs).as_text()
+    # forward, the summed probabilities (for the loss), backward (and
+    # the target again, for the loss's gradient), the indexer's backward
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
     for name in ("sparse_attn_fwd", "sparse_attn_bwd", "sparse_attn_probs",
                  "indexer_bwd"):
-        assert name in compiled.as_text()
+        assert name in text
     # the forward's scores are one fusion down to [512, keys]; nothing
     # else may hold a head's products of the chunk
-    assert "[512,16,2048]" in compiled.as_text()
-    assert _outside_fusions(compiled.as_text(), "[512,16,2048]") == []
+    assert f"[512,16,{keys}]" in text
+    assert _outside_fusions(text, f"[512,16,{keys}]") == []
+
+    forward = _compile(lambda *a: sa._chunk(*a, jnp.int32(2048), "kernel"),
+                       *inputs)
+    assert _kernels(forward) == 2
+    assert "sparse_attn_probs" in forward.as_text()
+    backward = _compile(bwd, *inputs, sds((1, 512, keys), jnp.bool_),
+                        sds((1, 4, 8, 512, 128)),
+                        sds((1, 4, 8, 512, 8), jnp.float32),
+                        sds((1, 4, 8, 512, 128)))
+    text = backward.as_text()
+    assert _kernels(backward) == 2 and "sparse_attn_probs" not in text
+    calls = _outside_fusions(text, 'custom_call_target="tpu_custom_call"')
+    (call,) = [c for c in calls if "sparse_attn_bwd" in c]
+    assert f"f32[1,512,{keys}]" in call
 
 
 def test_sparse_attn_fwd_alone_against_the_longest_band(one_chip):
