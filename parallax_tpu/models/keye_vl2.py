@@ -30,7 +30,7 @@ bfloat16 compute on float32 weights, with the router, the indexer's
 scores, every softmax and every norm's statistics in float32; each
 layer rematerialised, the layers under one ``lax.scan`` over their
 stacked parameters, whose matrices are cast to bfloat16 before the loop
-(``in_compute_dtype``). The loss is
+(``models/decoder.in_compute_dtype``). The loss is
 the cross-entropy plus ``router_aux_loss_coef`` x the load-balance loss
 plus ``indexer_loss_weight`` x the indexer's KL loss, which alone
 reaches the indexer's weights (its input is cut off by
@@ -43,14 +43,18 @@ float weights.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 
 from parallax_tpu.core.engine import Model
+from parallax_tpu.models import decoder
+from parallax_tpu.models.decoder import (
+    clipped_adam, in_compute_dtype, lm_head_nll, normal_init, rms_norm,
+    weighted_mean)
 from parallax_tpu.ops import embedding as emb_ops
 from parallax_tpu.ops import moe as moe_ops
 from parallax_tpu.ops import sparse_attention as sa_ops
@@ -112,14 +116,6 @@ def tiny_config(**kw) -> KeyeVL2Config:
     return KeyeVL2Config(**defaults)
 
 
-def rms_norm(x, scale, eps):
-    """RMSNorm with float32 statistics, in ``x``'s dtype."""
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(var + eps)
-            * scale.astype(jnp.float32)).astype(x.dtype)
-
-
 def rope3(x, pos, theta: float, section):
     """Rotary embedding in three position streams: ``x [B, T, ..., 2n]``
     in half-split layout, pair ``i`` turning by ``pos[..., stream(i)] *
@@ -142,20 +138,6 @@ def rope3(x, pos, theta: float, section):
     x2 = x[..., n:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
-
-
-def in_compute_dtype(layers, names, dtype):
-    """The stacked ``layers`` with the leaves ``names`` cast to ``dtype``
-    whole, before the loop over the blocks (a block's own ``.astype`` of
-    them is then a no-op). The cast's transposition stands outside the
-    loop with it: the backward loop writes those leaves' gradient stacks
-    in ``dtype``, as the products made them, and the optimizer's fusions
-    read them through the cast back to float32; with the cast inside
-    the block each layer's piece was widened first and its stack
-    zero-filled, written and read in float32. The same cast of the same
-    float32 weight, and the same values in the gradient."""
-    return {k: v.astype(dtype) if k in names else v
-            for k, v in layers.items()}
 
 
 def _layer(cfg: KeyeVL2Config, p, h, pos, impls=(None, None),
@@ -203,9 +185,7 @@ def _layer(cfg: KeyeVL2Config, p, h, pos, impls=(None, None),
         h = h + moe.out.reshape(B, T, D)
     scalars = {"indexer_loss": attn.indexer_loss, "aux_loss": route.aux_loss,
                "selected": attn.selected, "causal": attn.causal,
-               "moe_dropped": moe.dropped, "moe_rows_here": moe.rows_here,
-               "moe_rows_walked": moe.rows_walked,
-               "moe_load_max_over_mean": moe.load_max_over_mean}
+               **moe_ops.moe_scalars(moe)}
     extra = ({"selection": attn.selection, "expert_choice": route.choice}
              if collect else None)
     return h, scalars, extra
@@ -224,32 +204,25 @@ def build_model(cfg: KeyeVL2Config, impls=(None, None)) -> Model:
     Hq, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     Hi, Di = cfg.indexer_heads, cfg.indexer_head_dim
     E, Eh, F = cfg.num_experts, cfg.experts_held, cfg.expert_dim
-    if not 0 <= cfg.first_expert <= E - Eh:
-        raise ValueError(
-            f"experts [{cfg.first_expert}, {cfg.first_expert + Eh}) are "
-            f"not among the router's {E}")
+    moe_ops.check_held(E, Eh, cfg.first_expert)
     dt = cfg.compute_dtype
 
     def init_fn(rng):
-        def dense(key, shape, fan_in):
-            return jax.random.normal(key, shape, jnp.float32) \
-                * (1.0 / np.sqrt(fan_in))
-
         ks = jax.random.split(rng, 12)
         layers = {
             "ln1": jnp.ones((L, D)), "ln2": jnp.ones((L, D)),
             "q_norm": jnp.ones((L, Dh)), "k_norm": jnp.ones((L, Dh)),
-            "wq": dense(ks[0], (L, D, Hq * Dh), D),
-            "wk": dense(ks[1], (L, D, Hkv * Dh), D),
-            "wv": dense(ks[2], (L, D, Hkv * Dh), D),
-            "wo": dense(ks[3], (L, Hq * Dh, D), Hq * Dh),
-            "idx_wq": dense(ks[4], (L, D, Hi * Di), D),
-            "idx_wk": dense(ks[5], (L, D, Di), D),
-            "idx_ww": dense(ks[6], (L, D, Hi), D),
-            "router": dense(ks[7], (L, D, E), D),
-            "w_gate": dense(ks[8], (L, Eh, D, F), D),
-            "w_up": dense(ks[9], (L, Eh, D, F), D),
-            "w_down": dense(ks[10], (L, Eh, F, D), F),
+            "wq": normal_init(ks[0], (L, D, Hq * Dh), D),
+            "wk": normal_init(ks[1], (L, D, Hkv * Dh), D),
+            "wv": normal_init(ks[2], (L, D, Hkv * Dh), D),
+            "wo": normal_init(ks[3], (L, Hq * Dh, D), Hq * Dh),
+            "idx_wq": normal_init(ks[4], (L, D, Hi * Di), D),
+            "idx_wk": normal_init(ks[5], (L, D, Di), D),
+            "idx_ww": normal_init(ks[6], (L, D, Hi), D),
+            "router": normal_init(ks[7], (L, D, E), D),
+            "w_gate": normal_init(ks[8], (L, Eh, D, F), D),
+            "w_up": normal_init(ks[9], (L, Eh, D, F), D),
+            "w_down": normal_init(ks[10], (L, Eh, F, D), F),
         }
         k_emb, k_head = jax.random.split(ks[11])
         # the embedding at unit scale, so that a token's own row and not
@@ -257,20 +230,17 @@ def build_model(cfg: KeyeVL2Config, impls=(None, None)) -> Model:
         return {"emb": jax.random.normal(k_emb, (V, D)),
                 "layers": layers,
                 "final_norm": jnp.ones((D,)),
-                "head": dense(k_head, (D, V), D)}
+                "head": normal_init(k_head, (D, V), D)}
 
     # what a rematerialised layer keeps for its backward pass: each
     # chunk's selection, attention output and logsumexp, and the
     # experts' row buffers (the ops name them), so that no kernel runs a
     # second time
     policy = jax.checkpoint_policies.save_only_these_names(
-        "sparse_attn_chunk", "moe_rows")
+        sa_ops.KEPT, moe_ops.KEPT)
 
     def loss_fn(params, batch, rng):
-        x, y = batch["x"], batch["y"]
-        w = batch.get("w")
-        if w is None:
-            w = jnp.ones(x.shape, jnp.float32)
+        x = batch["x"]
         B, T = x.shape
         pos = _positions(batch, B, T)
         h = emb_ops.embedding_lookup(params["emb"], x).astype(dt)
@@ -287,41 +257,22 @@ def build_model(cfg: KeyeVL2Config, impls=(None, None)) -> Model:
         s = jax.tree.map(lambda a: jnp.mean(a.astype(jnp.float32)),
                          per_layer)
 
-        with jax.named_scope("lm_head"):
-            hidden = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-            logits = jnp.dot(hidden.reshape(B * T, D),
-                             params["head"].astype(dt),
-                             preferred_element_type=jnp.float32)
-            logits = emb_ops.mask_padded_logits(logits, cfg.vocab_size)
-            nll = optax.softmax_cross_entropy_with_integer_labels(
-                logits, y.reshape(B * T))
-            wf = w.reshape(B * T)
-            lm_loss = jnp.sum(nll * wf) / jnp.maximum(jnp.sum(wf), 1e-8)
+        lm_loss = weighted_mean(lm_head_nll(
+            cfg, h, params["final_norm"], params["head"], batch["y"]), batch)
         indexer_loss = s["indexer_loss"] / (B * T)
         loss = (lm_loss + cfg.router_aux_loss_coef * s["aux_loss"]
                 + cfg.indexer_loss_weight * indexer_loss)
         causal_total = jnp.sum(per_layer["causal"])
         return loss, {
             "lm_loss": lm_loss, "aux_loss": s["aux_loss"],
-            "indexer_loss": indexer_loss,
-            "moe_dropped": jnp.max(per_layer["moe_dropped"]),
-            "moe_rows_here": s["moe_rows_here"],
-            "moe_rows_walked": s["moe_rows_walked"],
-            "moe_load_max_over_mean": s["moe_load_max_over_mean"],
+            "indexer_loss": indexer_loss, **moe_ops.moe_metrics(per_layer),
             "attn_selected_share":
                 jnp.sum(per_layer["selected"]) / causal_total}
 
     from parallax_tpu.ops.sparse_optim import SliceAdam
-    rate = cfg.learning_rate if not cfg.warmup_steps else \
-        optax.linear_schedule(0.0, cfg.learning_rate, cfg.warmup_steps)
-    tx = optax.chain(optax.clip_by_global_norm(cfg.max_grad_norm),
-                     optax.adam(rate))
-    return Model(init_fn, loss_fn, optimizer=tx,
+    return Model(init_fn, loss_fn, optimizer=clipped_adam(cfg),
                  slice_updaters={"emb": SliceAdam(cfg.learning_rate)},
-                 gauges={"moe.dropped": ("moe_dropped", "max"),
-                         "moe.rows_here": "moe_rows_here",
-                         "moe.rows_walked": "moe_rows_walked",
-                         "moe.load_max_over_mean": "moe_load_max_over_mean",
+                 gauges={**moe_ops.GAUGES,
                          "sparse_attn.indexer_loss": "indexer_loss",
                          "sparse_attn.selected_share":
                              "attn_selected_share"})
@@ -343,10 +294,5 @@ def layer_selection(cfg: KeyeVL2Config, params, batch, layer: int = 0,
     return extra
 
 
-def make_batch(rng: np.random.Generator, batch_size: int, seq_len: int,
-               vocab_size: int):
-    """Synthetic Zipf-ish batch with ``models/lm1b``'s feed keys."""
-    x = (rng.zipf(1.3, size=(batch_size, seq_len)) - 1) % vocab_size
-    return {"x": x.astype(np.int32),
-            "y": np.roll(x, -1, axis=1).astype(np.int32),
-            "w": np.ones((batch_size, seq_len), np.float32)}
+# the synthetic batch, Zipf(1.3)
+make_batch = functools.partial(decoder.make_batch, zipf=1.3)

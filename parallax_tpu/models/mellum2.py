@@ -46,7 +46,7 @@ norm's statistics and RoPE's angles in float32; each layer
 rematerialised, keeping the attention's output and logsumexp and the
 experts' row buffers so that no kernel runs a second time; the layers'
 matrices cast to bfloat16 before the loop
-(``models/keye_vl2.in_compute_dtype``). The loss is the cross-entropy
+(``models/decoder.in_compute_dtype``). The loss is the cross-entropy
 plus ``router_aux_loss_coef`` x the load-balance loss.
 
 The chip's share (``PERF.md`` section 4): each layer's 64 experts are
@@ -70,17 +70,15 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 
 from parallax_tpu.core.engine import Model
-from parallax_tpu.models.keye_vl2 import in_compute_dtype, rms_norm
-# Adam's rate on the dense group: `learning_rate` behind `warmup_steps`
-from parallax_tpu.models.zaya import scheduled_rate
+from parallax_tpu.models.decoder import (  # noqa: F401
+    FULL, SLIDING, attend, clipped_adam, in_compute_dtype, layer_kinds,
+    lm_head_nll, make_batch, normal_init, rms_norm, rope, scheduled_rate,
+    weighted_mean)
 from parallax_tpu.ops import embedding as emb_ops
 from parallax_tpu.ops import moe as moe_ops
 from parallax_tpu.ops import pallas_attention as pa
-
-SLIDING, FULL = "sliding_attention", "full_attention"
 
 
 @dataclasses.dataclass
@@ -131,13 +129,7 @@ class Mellum2Config:
     @property
     def kinds(self) -> Tuple[str, ...]:
         """Each layer's kind, ``layer_types`` repeated over the depth."""
-        period = tuple(self.layer_types)
-        if (not period or self.num_layers % len(period)
-                or set(period) - {SLIDING, FULL}):
-            raise ValueError(
-                f"layer_types {period} is no period of {self.num_layers} "
-                f"layers of {SLIDING} and {FULL}")
-        return period * (self.num_layers // len(period))
+        return layer_kinds(self.layer_types, self.num_layers)
 
 
 # the layers' leaves that a block multiplies in the compute dtype
@@ -200,20 +192,6 @@ def rope_tables(cfg: Mellum2Config):
             "is_window": jnp.asarray(window)}
 
 
-def rope(x, w, a):
-    """``x [B, T, H, 2n]`` in half-split layout, positions ``0 .. T -
-    1``: pair ``i`` turned by ``t * w[i]``, ``cos`` and ``sin`` times
-    ``a``. Angles in float32."""
-    n = x.shape[-1] // 2
-    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * w  # [T, n]
-    cos = (jnp.cos(angle) * a)[None, :, None, :]
-    sin = (jnp.sin(angle) * a)[None, :, None, :]
-    x1 = x[..., :n].astype(jnp.float32)
-    x2 = x[..., n:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
 def _attend(cfg: Mellum2Config, q, k, v, is_window, impl):
     """Causal grouped-query attention, under the window where
     ``is_window`` (a traced scalar of the scan)."""
@@ -221,28 +199,6 @@ def _attend(cfg: Mellum2Config, q, k, v, is_window, impl):
     return attend(cfg, q, k, v,
                   cfg.sliding_window if SLIDING in kinds else None,
                   is_window if len(kinds) == 2 else None, impl)
-
-
-def attend(cfg, q, k, v, window, flag, impl):
-    """Causal grouped-query attention at ``cfg``'s ``head_dim`` and
-    ``flash_tiles``, each query under its last ``window`` keys (None:
-    every causal key) where the traced scalar ``flag`` (None: wherever
-    there is a window)."""
-    if impl is None:
-        impl = "flash" if jax.default_backend() == "tpu" else "xla"
-    if impl == "xla":
-        if flag is not None:
-            window = jnp.where(flag, window, q.shape[1])
-        swap = lambda a: jnp.swapaxes(a, 1, 2)      # noqa: E731
-        return swap(pa._xla_attention(swap(q), swap(k), swap(v), None, True,
-                                      cfg.head_dim ** -0.5, window))
-    if impl not in ("flash", "flash_interpret"):
-        raise ValueError(f"unknown attention impl {impl!r}")
-    q_tile, block_k = cfg.flash_tiles
-    return pa.flash_attention(
-        q, k, v, causal=True, q_tile=int(q_tile), block_k=int(block_k),
-        window=window, window_on=flag,
-        interpret=impl == "flash_interpret")
 
 
 def _layer(cfg: Mellum2Config, p, kind, h, impls=(None, None),
@@ -280,10 +236,7 @@ def _layer(cfg: Mellum2Config, p, kind, h, impls=(None, None),
             p["w_down"], num_experts=cfg.num_experts,
             first_expert=cfg.first_expert, impl=impls[1])
         h = h + moe.out.reshape(B, T, D)
-    scalars = {"aux_loss": route.aux_loss, "moe_dropped": moe.dropped,
-               "moe_rows_here": moe.rows_here,
-               "moe_rows_walked": moe.rows_walked,
-               "moe_load_max_over_mean": moe.load_max_over_mean}
+    scalars = {"aux_loss": route.aux_loss, **moe_ops.moe_scalars(moe)}
     return h, scalars, own.choice
 
 
@@ -291,29 +244,24 @@ def init_params(cfg: Mellum2Config, rng):
     V, D, L = cfg.padded_vocab, cfg.model_dim, cfg.num_layers
     Hq, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     E, Eh, F = cfg.num_experts, cfg.experts_held, cfg.expert_dim
-
-    def dense(key, shape, fan_in):
-        return jax.random.normal(key, shape, jnp.float32) \
-            * (1.0 / np.sqrt(fan_in))
-
     ks = jax.random.split(rng, 10)
     layers = {
         "ln1": jnp.ones((L, D)), "ln2": jnp.ones((L, D)),
         "q_norm": jnp.ones((L, Dh)), "k_norm": jnp.ones((L, Dh)),
-        "wq": dense(ks[0], (L, D, Hq * Dh), D),
-        "wk": dense(ks[1], (L, D, Hkv * Dh), D),
-        "wv": dense(ks[2], (L, D, Hkv * Dh), D),
-        "wo": dense(ks[3], (L, Hq * Dh, D), Hq * Dh),
-        "router": dense(ks[4], (L, D, E), D),
-        "w_gate": dense(ks[5], (L, Eh, D, F), D),
-        "w_up": dense(ks[6], (L, Eh, D, F), D),
-        "w_down": dense(ks[7], (L, Eh, F, D), F),
+        "wq": normal_init(ks[0], (L, D, Hq * Dh), D),
+        "wk": normal_init(ks[1], (L, D, Hkv * Dh), D),
+        "wv": normal_init(ks[2], (L, D, Hkv * Dh), D),
+        "wo": normal_init(ks[3], (L, Hq * Dh, D), Hq * Dh),
+        "router": normal_init(ks[4], (L, D, E), D),
+        "w_gate": normal_init(ks[5], (L, Eh, D, F), D),
+        "w_up": normal_init(ks[6], (L, Eh, D, F), D),
+        "w_down": normal_init(ks[7], (L, Eh, F, D), F),
     }
     # the embedding at unit scale, as Keye's: a token's own row and not
     # the attention's near-uniform mean decides where it is routed
     return {"emb": jax.random.normal(ks[8], (V, D)), "layers": layers,
             "final_norm": jnp.ones((D,)),
-            "head": dense(ks[9], (D, V), D)}
+            "head": normal_init(ks[9], (D, V), D)}
 
 
 def forward(cfg: Mellum2Config, params, batch, impls=(None, None)):
@@ -322,7 +270,7 @@ def forward(cfg: Mellum2Config, params, batch, impls=(None, None)):
     dt = cfg.compute_dtype
     x = batch["x"]
     B, T = x.shape
-    D, L = cfg.model_dim, cfg.num_layers
+    L = cfg.num_layers
     h = emb_ops.embedding_lookup(params["emb"], x).astype(dt)
     forced = batch.get("expert_choice")
     if forced is not None:
@@ -339,7 +287,7 @@ def forward(cfg: Mellum2Config, params, batch, impls=(None, None)):
     # kernel runs a second time
     scanned = jax.checkpoint(
         scanned, policy=jax.checkpoint_policies.save_only_these_names(
-            "flash_attn", "moe_rows"))
+            pa.KEPT, moe_ops.KEPT))
     # the scan's own operations (the matrices' cast, a layer's weights
     # and constants cut out of their stacks, its kept arrays and
     # gradients written into theirs, the loop) go by this name; inside a
@@ -349,22 +297,13 @@ def forward(cfg: Mellum2Config, params, batch, impls=(None, None)):
         h, (scalars, choice) = jax.lax.scan(
             scanned, h, (layers, rope_tables(cfg), forced))
 
-    with jax.named_scope("lm_head"):
-        hidden = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-        logits = jnp.dot(hidden.reshape(B * T, D), params["head"].astype(dt),
-                         preferred_element_type=jnp.float32)
-        logits = emb_ops.mask_padded_logits(logits, cfg.vocab_size)
-        nll = optax.softmax_cross_entropy_with_integer_labels(
-            logits, batch["y"].reshape(B * T))
+    nll = lm_head_nll(cfg, h, params["final_norm"], params["head"],
+                      batch["y"])
     return nll.reshape(B, T), scalars, choice
 
 
 def build_model(cfg: Mellum2Config, impls=(None, None)) -> Model:
-    E, Eh = cfg.num_experts, cfg.experts_held
-    if not 0 <= cfg.first_expert <= E - Eh:
-        raise ValueError(
-            f"experts [{cfg.first_expert}, {cfg.first_expert + Eh}) are "
-            f"not among the router's {E}")
+    moe_ops.check_held(cfg.num_experts, cfg.experts_held, cfg.first_expert)
     if cfg.num_heads % cfg.num_kv_heads or cfg.head_dim % 2:
         raise ValueError("the query heads group onto the key/value heads, "
                          "and RoPE pairs a head's entries")
@@ -374,36 +313,14 @@ def build_model(cfg: Mellum2Config, impls=(None, None)) -> Model:
         return init_params(cfg, rng)
 
     def loss_fn(params, batch, rng):
-        w = batch.get("w")
-        if w is None:
-            w = jnp.ones(batch["x"].shape, jnp.float32)
         nll, s, _ = forward(cfg, params, batch, impls)
-        with jax.named_scope("lm_head"):
-            lm_loss = jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1e-8)
+        lm_loss = weighted_mean(nll, batch)
         aux_loss = jnp.mean(s["aux_loss"])
         loss = lm_loss + cfg.router_aux_loss_coef * aux_loss
-        return loss, {
-            "lm_loss": lm_loss, "aux_loss": aux_loss,
-            "moe_dropped": jnp.max(s["moe_dropped"]),
-            "moe_rows_here": jnp.mean(s["moe_rows_here"]),
-            "moe_rows_walked": jnp.mean(s["moe_rows_walked"]),
-            "moe_load_max_over_mean": jnp.mean(s["moe_load_max_over_mean"])}
+        return loss, {"lm_loss": lm_loss, "aux_loss": aux_loss,
+                      **moe_ops.moe_metrics(s)}
 
     from parallax_tpu.ops.sparse_optim import SliceAdam
-    tx = optax.chain(optax.clip_by_global_norm(cfg.max_grad_norm),
-                     optax.adam(scheduled_rate(cfg)))
-    return Model(init_fn, loss_fn, optimizer=tx,
+    return Model(init_fn, loss_fn, optimizer=clipped_adam(cfg),
                  slice_updaters={"emb": SliceAdam(cfg.learning_rate)},
-                 gauges={"moe.dropped": ("moe_dropped", "max"),
-                         "moe.rows_here": "moe_rows_here",
-                         "moe.rows_walked": "moe_rows_walked",
-                         "moe.load_max_over_mean": "moe_load_max_over_mean"})
-
-
-def make_batch(rng: np.random.Generator, batch_size: int, seq_len: int,
-               vocab_size: int):
-    """Synthetic Zipf(1.05) batch with ``models/lm1b``'s feed keys."""
-    x = (rng.zipf(1.05, size=(batch_size, seq_len)) - 1) % vocab_size
-    return {"x": x.astype(np.int32),
-            "y": np.roll(x, -1, axis=1).astype(np.int32),
-            "w": np.ones((batch_size, seq_len), np.float32)}
+                 gauges=moe_ops.GAUGES)

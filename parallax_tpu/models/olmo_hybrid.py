@@ -75,20 +75,16 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
-from jax.ad_checkpoint import checkpoint_name
 
 from parallax_tpu.core.engine import Model
-from parallax_tpu.models.keye_vl2 import in_compute_dtype, rms_norm
-# Adam's rate on the dense group: `learning_rate` behind `warmup_steps`
-from parallax_tpu.models.zaya import scheduled_rate
+from parallax_tpu.models.decoder import (  # noqa: F401
+    FULL, MLP_KEPT, attend, clipped_adam, in_compute_dtype, lm_head_nll,
+    make_batch, mlp, normal_init, rms_norm, weighted_mean)
 from parallax_tpu.ops import delta_rule
 from parallax_tpu.ops import embedding as emb_ops
 from parallax_tpu.ops import pallas_attention as pa
 
-LINEAR, FULL = "linear_attention", "full_attention"
-# the name by which a layer's remat keeps the MLP's up and down products
-MLP_KEPT = "mlp_rows"
+LINEAR = "linear_attention"
 
 
 @dataclasses.dataclass
@@ -185,15 +181,6 @@ def _unit(x, eps=1e-6):
     return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)
 
 
-def mlp(p, x, dt):
-    """The dense SwiGLU MLP on ``x [B, T, D]``."""
-    with jax.named_scope("mlp"):
-        gate = jax.nn.silu(x @ p["w_gate"].astype(dt))
-        up = checkpoint_name(x @ p["w_up"].astype(dt), MLP_KEPT)
-        return checkpoint_name((gate * up) @ p["w_down"].astype(dt),
-                               MLP_KEPT)
-
-
 def linear_mixer(cfg: OlmoHybridConfig, p, x, impl=None):
     """A linear layer's mixer on ``x [B, T, D]``: ``(y [B, T, D], the
     mean of alpha, the mean of beta)``."""
@@ -238,19 +225,7 @@ def full_qkv(cfg: OlmoHybridConfig, p, x):
 def full_attend(cfg: OlmoHybridConfig, p, q, k, v, impl=None):
     """Causal softmax attention without rotary embedding, and ``Wo``."""
     B, T, H, d = q.shape
-    if impl is None:
-        impl = "flash" if jax.default_backend() == "tpu" else "xla"
-    if impl == "xla":
-        swap = lambda a: jnp.swapaxes(a, 1, 2)      # noqa: E731
-        o = swap(pa._xla_attention(swap(q), swap(k), swap(v), None, True,
-                                   d ** -0.5))
-    elif impl in ("flash", "flash_interpret"):
-        q_tile, block_k = cfg.flash_tiles
-        o = pa.flash_attention(q, k, v, causal=True, q_tile=int(q_tile),
-                               block_k=int(block_k),
-                               interpret=impl == "flash_interpret")
-    else:
-        raise ValueError(f"unknown attention impl {impl!r}")
+    o = attend(cfg, q, k, v, None, None, impl)
     return o.reshape(B, T, H * d) @ p["wo"].astype(cfg.compute_dtype)
 
 
@@ -291,8 +266,7 @@ def init_params(cfg: OlmoHybridConfig, rng):
     keys = iter(jax.random.split(rng, 32))
 
     def dense(lead, shape, fan_in):
-        return jax.random.normal(next(keys), lead + shape, jnp.float32) \
-            * (1.0 / np.sqrt(fan_in))
+        return normal_init(next(keys), lead + shape, fan_in)
 
     def uniform(lead, shape, low, high):
         return jax.random.uniform(next(keys), lead + shape, jnp.float32,
@@ -365,10 +339,7 @@ def forward(cfg: OlmoHybridConfig, params, batch, impls=(None, None)):
     stacked [periods, 3])``. ``impls``: the executors of the full
     layer's attention and of the rule (None: by the backend)."""
     dt = cfg.compute_dtype
-    x = batch["x"]
-    B, T = x.shape
-    D = cfg.model_dim
-    h = emb_ops.embedding_lookup(params["emb"], x).astype(dt)
+    h = emb_ops.embedding_lookup(params["emb"], batch["x"]).astype(dt)
 
     # what a rematerialised layer keeps for its backward pass: the
     # kernels' outputs, so that no kernel runs a second time, and the
@@ -376,7 +347,7 @@ def forward(cfg: OlmoHybridConfig, params, batch, impls=(None, None)):
     # one), so that of its three products the gate's alone does: 243 MB
     # a layer at 8,192 x 11,008, 0.97 GB over the benchmark cell's four
     keep = jax.checkpoint_policies.save_only_these_names(
-        "flash_attn", delta_rule.KEPT, MLP_KEPT)
+        pa.KEPT, delta_rule.KEPT, MLP_KEPT)
     one_linear = jax.checkpoint(
         lambda h, p: linear_layer(cfg, p, h, impls[1]), policy=keep)
     one_full = jax.checkpoint(
@@ -396,14 +367,9 @@ def forward(cfg: OlmoHybridConfig, params, batch, impls=(None, None)):
                   in_compute_dtype(params["full"], MATRICES[FULL], dt))
         h, scalars = jax.lax.scan(period, h, stacks)
 
-    with jax.named_scope("lm_head"):
-        hidden = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-        logits = jnp.dot(hidden.reshape(B * T, D), params["head"].astype(dt),
-                         preferred_element_type=jnp.float32)
-        logits = emb_ops.mask_padded_logits(logits, cfg.vocab_size)
-        nll = optax.softmax_cross_entropy_with_integer_labels(
-            logits, batch["y"].reshape(B * T))
-    return nll.reshape(B, T), scalars
+    nll = lm_head_nll(cfg, h, params["final_norm"], params["head"],
+                      batch["y"])
+    return nll.reshape(batch["x"].shape), scalars
 
 
 def build_model(cfg: OlmoHybridConfig, impls=(None, None)) -> Model:
@@ -416,29 +382,14 @@ def build_model(cfg: OlmoHybridConfig, impls=(None, None)) -> Model:
         return init_params(cfg, rng)
 
     def loss_fn(params, batch, rng):
-        w = batch.get("w")
-        if w is None:
-            w = jnp.ones(batch["x"].shape, jnp.float32)
         nll, s = forward(cfg, params, batch, impls)
-        with jax.named_scope("lm_head"):
-            loss = jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1e-8)
+        loss = weighted_mean(nll, batch)
         return loss, {"lm_loss": loss,
                       "linear_decay_mean": jnp.mean(s["decay_mean"]),
                       "linear_beta_mean": jnp.mean(s["beta_mean"])}
 
     from parallax_tpu.ops.sparse_optim import SliceAdam
-    tx = optax.chain(optax.clip_by_global_norm(cfg.max_grad_norm),
-                     optax.adam(scheduled_rate(cfg)))
-    return Model(init_fn, loss_fn, optimizer=tx,
+    return Model(init_fn, loss_fn, optimizer=clipped_adam(cfg),
                  slice_updaters={"emb": SliceAdam(cfg.learning_rate)},
                  gauges={"linear_attn.decay_mean": "linear_decay_mean",
                          "linear_attn.beta_mean": "linear_beta_mean"})
-
-
-def make_batch(rng: np.random.Generator, batch_size: int, seq_len: int,
-               vocab_size: int):
-    """Synthetic Zipf(1.05) batch with ``models/lm1b``'s feed keys."""
-    x = (rng.zipf(1.05, size=(batch_size, seq_len)) - 1) % vocab_size
-    return {"x": x.astype(np.int32),
-            "y": np.roll(x, -1, axis=1).astype(np.int32),
-            "w": np.ones((batch_size, seq_len), np.float32)}

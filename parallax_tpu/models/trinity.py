@@ -13,7 +13,7 @@ input AND on its output (four RMSNorms a layer):
 of 128, ``k = a Wk``, ``v = a Wv`` as 4 heads, ``g = a Wg`` (the gate,
 as wide as ``q``, no bias); RMSNorm over each head on ``q`` and ``k``.
 **On a sliding layer** RoPE over all 64 pairs of a head in half-split
-layout (``models/mellum2.rope``) and the keys ``0 <= t - s <
+layout (``models/decoder.rope``) and the keys ``0 <= t - s <
 sliding_window``; **on a full layer no RoPE at all** and every causal
 key. ``o = softmax(q k^T / sqrt(128)) v``
 (``ops/pallas_attention.flash_attention`` with its ``window``); ``o <- o
@@ -21,7 +21,7 @@ key. ``o = softmax(q k^T / sqrt(128)) v``
 
 *Dense layers* (the first ``num_dense_layers``): ``m = RMSNorm(h)``; ``h
 += RMSNorm(Wd (silu(Wg m) * Wu m))`` at ``dense_mlp_dim``
-(``models/olmo_hybrid.mlp``, the scope ``mlp``).
+(``models/decoder.mlp``, the scope ``mlp``).
 
 *Expert layers.* ``m = RMSNorm(h)``; ``s = sigmoid(m Wr)`` over all 128
 in float32, the choice ``top8(s + b)``, the gates ``s`` of the chosen
@@ -56,7 +56,7 @@ norm's statistics, RoPE's angles and the gate's sigmoid in float32; each
 layer rematerialised, keeping the attention's output and logsumexp and
 the experts' row buffers so that no kernel runs a second time
 (``KEPT``); the layers' matrices cast to bfloat16 before the loop
-(``models/keye_vl2.in_compute_dtype``).
+(``models/decoder.in_compute_dtype``).
 
 The chip's share (``PERF.md`` section 4): each layer's 128 experts are
 shared by eight chips, the vocabulary's rows by eight; what the absent
@@ -78,19 +78,15 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 
 from parallax_tpu.core.engine import Model
-from parallax_tpu.models.keye_vl2 import in_compute_dtype, rms_norm
-# the layers' two kinds, their RoPE, the attention between the
-# projections and the synthetic batch are Mellum2's
-from parallax_tpu.models.mellum2 import (  # noqa: F401
-    FULL, SLIDING, attend, make_batch, rope)
-from parallax_tpu.models.olmo_hybrid import mlp
-# Adam's rate on the dense group: `learning_rate` behind `warmup_steps`
-from parallax_tpu.models.zaya import scheduled_rate
+from parallax_tpu.models.decoder import (  # noqa: F401
+    FULL, SLIDING, attend, clipped_adam, in_compute_dtype, layer_kinds,
+    lm_head_nll, make_batch, mlp, normal_init, rms_norm, rope,
+    weighted_mean)
 from parallax_tpu.ops import embedding as emb_ops
 from parallax_tpu.ops import moe as moe_ops
+from parallax_tpu.ops import pallas_attention as pa
 
 # what a rematerialised layer keeps for its backward pass: the
 # attention's output and logsumexp and the experts' row buffers (the ops
@@ -99,7 +95,7 @@ from parallax_tpu.ops import moe as moe_ops
 # expert's products kept too the step read 29,558 tokens/s/chip against
 # 29,464 (+0.3 %) for 1.1 GB more by the compiler's count, 15.57 GB
 # against 14.47 (PERF.md section 6, PR 39)
-KEPT = ("flash_attn", "moe_rows")
+KEPT = (pa.KEPT, moe_ops.KEPT)
 
 
 @dataclasses.dataclass
@@ -160,13 +156,7 @@ class TrinityConfig:
     def kinds(self) -> Tuple[str, ...]:
         """Each held layer's kind, ``layer_types`` repeated over the
         depth: the dense layers' first, then the expert layers'."""
-        period = tuple(self.layer_types)
-        if (not period or self.num_layers % len(period)
-                or set(period) - {SLIDING, FULL}):
-            raise ValueError(
-                f"layer_types {period} is no period of {self.num_layers} "
-                f"layers of {SLIDING} and {FULL}")
-        return period * (self.num_layers // len(period))
+        return layer_kinds(self.layer_types, self.num_layers)
 
     @property
     def num_moe_layers(self) -> int:
@@ -208,9 +198,9 @@ def rope_tables(cfg: TrinityConfig):
 
 
 def _attend(cfg: TrinityConfig, q, k, v, is_window, impl):
-    """Causal grouped-query attention (Mellum2's), under the window
-    where ``is_window``: a bool (a layer whose kind the trace knows) or
-    a traced scalar of the scan."""
+    """Causal grouped-query attention (``models/decoder.attend``), under
+    the window where ``is_window``: a bool (a layer whose kind the trace
+    knows) or a traced scalar of the scan."""
     traced = not isinstance(is_window, (bool, np.bool_))
     return attend(cfg, q, k, v,
                   cfg.sliding_window if traced or is_window else None,
@@ -271,9 +261,7 @@ def expert_mix(cfg: TrinityConfig, p, bias, m, impl=None,
         num_experts=cfg.num_experts, first_expert=cfg.first_expert,
         impl=impl)
     scalars = {"load": route.load, "gate_sum_mean": route.gate_sum_mean,
-               "moe_dropped": moe.dropped, "moe_rows_here": moe.rows_here,
-               "moe_rows_walked": moe.rows_walked,
-               "moe_load_max_over_mean": moe.load_max_over_mean}
+               **moe_ops.moe_scalars(moe)}
     return shared.astype(jnp.float32) + moe.out.astype(jnp.float32), \
         scalars, route.own_choice
 
@@ -302,45 +290,41 @@ def init_params(cfg: TrinityConfig, rng):
     E, Eh, F = cfg.num_experts, cfg.experts_held, cfg.expert_dim
     Fd, Fs = cfg.dense_mlp_dim, cfg.num_shared_experts * cfg.expert_dim
 
-    def dense(key, shape, fan_in):
-        return jax.random.normal(key, shape, jnp.float32) \
-            * (1.0 / np.sqrt(fan_in))
-
     def attention_leaves(key, n):
         ks = jax.random.split(key, 5)
         return {
             "ln1": jnp.ones((n, D)), "ln1_post": jnp.ones((n, D)),
             "ln2": jnp.ones((n, D)), "ln2_post": jnp.ones((n, D)),
             "q_norm": jnp.ones((n, Dh)), "k_norm": jnp.ones((n, Dh)),
-            "wq": dense(ks[0], (n, D, Hq * Dh), D),
-            "wk": dense(ks[1], (n, D, Hkv * Dh), D),
-            "wv": dense(ks[2], (n, D, Hkv * Dh), D),
-            "w_attn_gate": dense(ks[3], (n, D, Hq * Dh), D),
-            "wo": dense(ks[4], (n, Hq * Dh, D), Hq * Dh)}
+            "wq": normal_init(ks[0], (n, D, Hq * Dh), D),
+            "wk": normal_init(ks[1], (n, D, Hkv * Dh), D),
+            "wv": normal_init(ks[2], (n, D, Hkv * Dh), D),
+            "w_attn_gate": normal_init(ks[3], (n, D, Hq * Dh), D),
+            "wo": normal_init(ks[4], (n, Hq * Dh, D), Hq * Dh)}
 
     ks = jax.random.split(rng, 13)
     params = {
         # rows at 1 / sqrt(D): the stream starts at unit scale behind
         # the sqrt(D) multiplier, so that a token's own row and not the
         # attention's near-uniform mean decides where it is routed
-        "emb": dense(ks[0], (V, D), D),
+        "emb": normal_init(ks[0], (V, D), D),
         "layers": {
             **attention_leaves(ks[1], L),
-            "router": dense(ks[2], (L, D, E), D),
-            "w_gate": dense(ks[3], (L, Eh, D, F), D),
-            "w_up": dense(ks[4], (L, Eh, D, F), D),
-            "w_down": dense(ks[5], (L, Eh, F, D), F),
-            "shared_w_gate": dense(ks[6], (L, D, Fs), D),
-            "shared_w_up": dense(ks[7], (L, D, Fs), D),
-            "shared_w_down": dense(ks[8], (L, Fs, D), Fs)},
+            "router": normal_init(ks[2], (L, D, E), D),
+            "w_gate": normal_init(ks[3], (L, Eh, D, F), D),
+            "w_up": normal_init(ks[4], (L, Eh, D, F), D),
+            "w_down": normal_init(ks[5], (L, Eh, F, D), F),
+            "shared_w_gate": normal_init(ks[6], (L, D, Fs), D),
+            "shared_w_up": normal_init(ks[7], (L, D, Fs), D),
+            "shared_w_down": normal_init(ks[8], (L, Fs, D), Fs)},
         "final_norm": jnp.ones((D,)),
-        "head": dense(ks[9], (D, V), D)}
+        "head": normal_init(ks[9], (D, V), D)}
     if Ld:
         params["dense"] = {
             **attention_leaves(ks[10], Ld),
-            "w_gate": dense(ks[11], (Ld, D, Fd), D),
-            "w_up": dense(jax.random.fold_in(ks[11], 1), (Ld, D, Fd), D),
-            "w_down": dense(ks[12], (Ld, Fd, D), Fd)}
+            "w_gate": normal_init(ks[11], (Ld, D, Fd), D),
+            "w_up": normal_init(jax.random.fold_in(ks[11], 1), (Ld, D, Fd), D),
+            "w_down": normal_init(ks[12], (Ld, Fd, D), Fd)}
     return params
 
 
@@ -390,22 +374,14 @@ def forward(cfg: TrinityConfig, params, bias, batch, impls=(None, None)):
             scanned, h,
             (layers, jax.tree.map(lambda a: a[Ld:], tables), bias, forced))
 
-    with jax.named_scope("lm_head"):
-        hidden = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-        logits = jnp.dot(hidden.reshape(B * T, D), params["head"].astype(dt),
-                         preferred_element_type=jnp.float32)
-        logits = emb_ops.mask_padded_logits(logits, cfg.vocab_size)
-        nll = optax.softmax_cross_entropy_with_integer_labels(
-            logits, batch["y"].reshape(B * T))
+    nll = lm_head_nll(cfg, h, params["final_norm"], params["head"],
+                      batch["y"])
     return nll.reshape(B, T), scalars, choice
 
 
 def build_model(cfg: TrinityConfig, impls=(None, None)) -> Model:
-    E, Eh = cfg.num_experts, cfg.experts_held
-    if not 0 <= cfg.first_expert <= E - Eh:
-        raise ValueError(
-            f"experts [{cfg.first_expert}, {cfg.first_expert + Eh}) are "
-            f"not among the router's {E}")
+    E = cfg.num_experts
+    moe_ops.check_held(E, cfg.experts_held, cfg.first_expert)
     if cfg.num_heads % cfg.num_kv_heads or cfg.head_dim % 2:
         raise ValueError("the query heads group onto the key/value heads, "
                          "and RoPE pairs a head's entries")
@@ -422,21 +398,13 @@ def build_model(cfg: TrinityConfig, impls=(None, None)) -> Model:
             {"router_bias": jnp.zeros((cfg.num_moe_layers, E), jnp.float32)}
 
     def loss_fn(params, model_state, batch, rng):
-        w = batch.get("w")
-        if w is None:
-            w = jnp.ones(batch["x"].shape, jnp.float32)
         bias = model_state["router_bias"]
         nll, s, _ = forward(cfg, params, bias, batch, impls)
-        with jax.named_scope("lm_head"):
-            loss = jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1e-8)
+        loss = weighted_mean(nll, batch)
         new_bias = moe_ops.balance_step(bias, s["load"],
                                         cfg.load_balance_coeff)
         metrics = {
-            "lm_loss": loss,
-            "moe_dropped": jnp.max(s["moe_dropped"]),
-            "moe_rows_here": jnp.mean(s["moe_rows_here"]),
-            "moe_rows_walked": jnp.mean(s["moe_rows_walked"]),
-            "moe_load_max_over_mean": jnp.mean(s["moe_load_max_over_mean"]),
+            "lm_loss": loss, **moe_ops.moe_metrics(s),
             "router_gate_sum_mean": jnp.mean(s["gate_sum_mean"]),
             "router_bias_spread": jnp.mean(
                 jnp.max(new_bias, axis=-1) - jnp.min(new_bias, axis=-1))}
@@ -445,13 +413,8 @@ def build_model(cfg: TrinityConfig, impls=(None, None)) -> Model:
     from parallax_tpu.ops.sparse_optim import SliceAdam
     table_rate = cfg.learning_rate if cfg.table_learning_rate is None \
         else cfg.table_learning_rate
-    tx = optax.chain(optax.clip_by_global_norm(cfg.max_grad_norm),
-                     optax.adam(scheduled_rate(cfg)))
-    return Model(init_fn, loss_fn, optimizer=tx, stateful=True,
+    return Model(init_fn, loss_fn, optimizer=clipped_adam(cfg), stateful=True,
                  slice_updaters={"emb": SliceAdam(table_rate)},
-                 gauges={"moe.dropped": ("moe_dropped", "max"),
-                         "moe.rows_here": "moe_rows_here",
-                         "moe.rows_walked": "moe_rows_walked",
-                         "moe.load_max_over_mean": "moe_load_max_over_mean",
+                 gauges={**moe_ops.GAUGES,
                          "router.gate_sum_mean": "router_gate_sum_mean",
                          "router.bias_spread": "router_bias_spread"})

@@ -58,7 +58,7 @@ softmax, gate), every norm's statistics, RoPE's angles and every softmax
 in float32; each layer rematerialised, keeping the attention's output
 and logsumexp and the experts' row buffers so that no kernel runs a
 second time; the layers' matrices are cast to bfloat16 before the
-``lax.scan`` (``models/keye_vl2.in_compute_dtype``), so that their
+``lax.scan`` (``models/decoder.in_compute_dtype``), so that their
 gradient stacks leave the backward loop in bfloat16.
 
 Not built (departures, ``benchmark/configs/zaya1-8b.json``): a router
@@ -78,10 +78,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 
 from parallax_tpu.core.engine import Model
-from parallax_tpu.models.keye_vl2 import in_compute_dtype, rms_norm
+from parallax_tpu.models.decoder import (  # noqa: F401
+    attend, clipped_adam, in_compute_dtype, lm_head_nll, make_batch,
+    normal_init, rms_norm, scheduled_rate, weighted_mean)
 from parallax_tpu.ops import embedding as emb_ops
 from parallax_tpu.ops import moe as moe_ops
 from parallax_tpu.ops import pallas_attention as pa
@@ -219,21 +220,6 @@ def cca_mix(cfg: ZayaConfig, p, u):
     return q.astype(dt), k.astype(dt), v
 
 
-def _attend(cfg: ZayaConfig, q, k, v, impl):
-    if impl is None:
-        impl = "flash" if jax.default_backend() == "tpu" else "xla"
-    if impl == "xla":
-        swap = lambda a: jnp.swapaxes(a, 1, 2)      # noqa: E731
-        return swap(pa._xla_attention(swap(q), swap(k), swap(v), None, True,
-                                      cfg.head_dim ** -0.5))
-    if impl not in ("flash", "flash_interpret"):
-        raise ValueError(f"unknown attention impl {impl!r}")
-    q_tile, block_k = cfg.flash_tiles
-    return pa.flash_attention(q, k, v, causal=True, q_tile=int(q_tile),
-                              block_k=int(block_k),
-                              interpret=impl == "flash_interpret")
-
-
 def router(cfg: ZayaConfig, p, u, r_prev, beta):
     """The router on the normalised stream ``u [N, D]``: its new state
     ``r [N, R]`` (what the next layer receives), the probabilities ``[N,
@@ -265,7 +251,7 @@ def _layer(cfg: ZayaConfig, p, beta, h, r_prev, impls=(None, None),
         u = rms_norm(h, p["ln1"], eps)
         with jax.named_scope("cca_mix"):
             q, k, v = cca_mix(cfg, p, u)
-        o = _attend(cfg, q, k, v, impls[0])
+        o = attend(cfg, q, k, v, None, None, impls[0])
         h = h + o.reshape(B, T, -1) @ p["wo"].astype(dt)
 
     with jax.named_scope("moe"):
@@ -286,9 +272,7 @@ def _layer(cfg: ZayaConfig, p, beta, h, r_prev, impls=(None, None),
             impl=impls[1])
         h = h + moe.out.reshape(B, T, D)
     scalars = {"load": load, "gate_mean": jnp.mean(gate),
-               "moe_dropped": moe.dropped, "moe_rows_here": moe.rows_here,
-               "moe_rows_walked": moe.rows_walked,
-               "moe_load_max_over_mean": moe.load_max_over_mean}
+               **moe_ops.moe_scalars(moe)}
     picked = {"choice": own, "margin": top2[:, 0] - top2[:, 1]}
     return (h, r), scalars, picked
 
@@ -300,26 +284,12 @@ def balance_step(cfg: ZayaConfig, beta, load):
     return moe_ops.balance_step(beta, load, cfg.bias_update_rate)
 
 
-def scheduled_rate(cfg: ZayaConfig):
-    """Adam's rate: ``learning_rate``, or where ``warmup_steps`` is set
-    a function of the updates made so far that rises to it linearly
-    from 0."""
-    if not cfg.warmup_steps:
-        return cfg.learning_rate
-    return optax.linear_schedule(0.0, cfg.learning_rate, cfg.warmup_steps)
-
-
 def init_params(cfg: ZayaConfig, rng):
     V, D, L = cfg.padded_vocab, cfg.model_dim, cfg.num_layers
     Hq, Hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     H, R = Hq + Hkv, cfg.router_hidden_size
     E, Eh, F = cfg.num_experts, cfg.experts_held, cfg.expert_dim
     t0, t1 = cfg.cca_time0, cfg.cca_time1
-
-    def dense(key, shape, fan_in):
-        return jax.random.normal(key, shape, jnp.float32) \
-            * (1.0 / np.sqrt(fan_in))
-
     ks = jax.random.split(rng, 16)
     # the convolutions start as the identity on tap 0 plus noise, so
     # that the latent passes and the previous token is felt
@@ -327,27 +297,27 @@ def init_params(cfg: ZayaConfig, rng):
         + 0.1 * jax.random.normal(ks[5], (L, t0, H * d))
     layers = {
         "ln1": jnp.ones((L, D)), "ln2": jnp.ones((L, D)),
-        "wq": dense(ks[0], (L, D, Hq * d), D),
-        "wk": dense(ks[1], (L, D, Hkv * d), D),
-        "wv1": dense(ks[2], (L, D, (Hkv // 2) * d), D),
-        "wv2": dense(ks[3], (L, D, (Hkv - Hkv // 2) * d), D),
-        "wo": dense(ks[4], (L, Hq * d, D), Hq * d),
+        "wq": normal_init(ks[0], (L, D, Hq * d), D),
+        "wk": normal_init(ks[1], (L, D, Hkv * d), D),
+        "wv1": normal_init(ks[2], (L, D, (Hkv // 2) * d), D),
+        "wv2": normal_init(ks[3], (L, D, (Hkv - Hkv // 2) * d), D),
+        "wo": normal_init(ks[4], (L, Hq * d, D), Hq * d),
         "conv0_w": conv0, "conv0_b": jnp.zeros((L, H * d)),
-        "conv1_w": dense(ks[6], (L, H, t1, d, d), t1 * d),
+        "conv1_w": normal_init(ks[6], (L, H, t1, d, d), t1 * d),
         "conv1_b": jnp.zeros((L, H * d)),
         "tau": jnp.ones((L, Hkv)),
-        "r_wd": dense(ks[7], (L, D, R), D), "r_bd": jnp.zeros((L, R)),
+        "r_wd": normal_init(ks[7], (L, D, R), D), "r_bd": jnp.zeros((L, R)),
         "r_gamma": jnp.ones((L, R)), "r_norm": jnp.ones((L, R)),
-        "r_w1": dense(ks[8], (L, R, R), R), "r_b1": jnp.zeros((L, R)),
-        "r_w2": dense(ks[9], (L, R, R), R), "r_b2": jnp.zeros((L, R)),
-        "r_w3": dense(ks[10], (L, R, E), R),
-        "w_gate": dense(ks[11], (L, Eh, D, F), D),
-        "w_up": dense(ks[12], (L, Eh, D, F), D),
-        "w_down": dense(ks[13], (L, Eh, F, D), F),
+        "r_w1": normal_init(ks[8], (L, R, R), R), "r_b1": jnp.zeros((L, R)),
+        "r_w2": normal_init(ks[9], (L, R, R), R), "r_b2": jnp.zeros((L, R)),
+        "r_w3": normal_init(ks[10], (L, R, E), R),
+        "w_gate": normal_init(ks[11], (L, Eh, D, F), D),
+        "w_up": normal_init(ks[12], (L, Eh, D, F), D),
+        "w_down": normal_init(ks[13], (L, Eh, F, D), F),
     }
     # rows at 1 / sqrt(D): the stream starts at unit scale behind the
     # sqrt(D) multiplier, and the tied head's logits at unit variance
-    return {"emb": dense(ks[14], (V, D), D), "layers": layers,
+    return {"emb": normal_init(ks[14], (V, D), D), "layers": layers,
             "final_norm": jnp.ones((D,))}
 
 
@@ -378,7 +348,7 @@ def forward(cfg: ZayaConfig, params, beta, batch, impls=(None, None)):
     # ops name them), so that no kernel runs a second time
     scanned = jax.checkpoint(
         scanned, policy=jax.checkpoint_policies.save_only_these_names(
-            "flash_attn", "moe_rows"))
+            pa.KEPT, moe_ops.KEPT))
     # the scan's own operations (the matrices' cast, a layer's weights
     # cut out of the stack, its kept arrays and gradients written into
     # theirs, the carry `r`) go by this name; inside a block its layers'
@@ -388,25 +358,16 @@ def forward(cfg: ZayaConfig, params, beta, batch, impls=(None, None)):
         (h, _), (scalars, picked) = jax.lax.scan(
             scanned, (h, r0), (layers, beta, forced))
 
-    with jax.named_scope("lm_head"):
-        hidden = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-        # the float32 logits of 8,192 tokens over 32,784 rows are held
-        # whole: 1.07 GB, beside their cotangent
-        logits = jnp.dot(hidden.reshape(B * T, D),
-                         params["emb"].astype(dt).T,
-                         preferred_element_type=jnp.float32)
-        logits = emb_ops.mask_padded_logits(logits, cfg.vocab_size)
-        nll = optax.softmax_cross_entropy_with_integer_labels(
-            logits, batch["y"].reshape(B * T))
+    # the tied head; the float32 logits of 8,192 tokens over 32,784 rows
+    # are held whole: 1.07 GB, beside their cotangent
+    nll = lm_head_nll(cfg, h, params["final_norm"], params["emb"].T,
+                      batch["y"])
     return nll.reshape(B, T), scalars, picked
 
 
 def build_model(cfg: ZayaConfig, impls=(None, None)) -> Model:
-    E, Eh = cfg.num_experts, cfg.experts_held
-    if not 0 <= cfg.first_expert <= E - Eh:
-        raise ValueError(
-            f"experts [{cfg.first_expert}, {cfg.first_expert + Eh}) are "
-            f"not among the router's {E}")
+    E = cfg.num_experts
+    moe_ops.check_held(E, cfg.experts_held, cfg.first_expert)
     if cfg.experts_per_token != 1:
         raise ValueError("the router chooses one expert a token")
     if cfg.num_heads % cfg.num_kv_heads or cfg.num_kv_heads % 2:
@@ -418,40 +379,18 @@ def build_model(cfg: ZayaConfig, impls=(None, None)) -> Model:
             {"beta": jnp.zeros((cfg.num_layers, E), jnp.float32)}
 
     def loss_fn(params, model_state, batch, rng):
-        w = batch.get("w")
-        if w is None:
-            w = jnp.ones(batch["x"].shape, jnp.float32)
         beta = model_state["beta"]
         nll, s, _ = forward(cfg, params, beta, batch, impls)
-        with jax.named_scope("lm_head"):
-            loss = jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1e-8)
+        loss = weighted_mean(nll, batch)
         new_beta = balance_step(cfg, beta,
                                 jax.lax.stop_gradient(s["load"]))
         metrics = {
-            "lm_loss": loss,
-            "moe_dropped": jnp.max(s["moe_dropped"]),
-            "moe_rows_here": jnp.mean(s["moe_rows_here"]),
-            "moe_rows_walked": jnp.mean(s["moe_rows_walked"]),
-            "moe_load_max_over_mean": jnp.mean(s["moe_load_max_over_mean"]),
+            "lm_loss": loss, **moe_ops.moe_metrics(s),
             "router_gate_mean": jnp.mean(s["gate_mean"]),
             "router_bias_abs_max": jnp.max(jnp.abs(new_beta))}
         return loss, metrics, {"beta": new_beta}
 
-    tx = optax.chain(optax.clip_by_global_norm(cfg.max_grad_norm),
-                     optax.adam(scheduled_rate(cfg)))
-    return Model(init_fn, loss_fn, optimizer=tx, stateful=True,
-                 gauges={"moe.dropped": ("moe_dropped", "max"),
-                         "moe.rows_here": "moe_rows_here",
-                         "moe.rows_walked": "moe_rows_walked",
-                         "moe.load_max_over_mean": "moe_load_max_over_mean",
+    return Model(init_fn, loss_fn, optimizer=clipped_adam(cfg), stateful=True,
+                 gauges={**moe_ops.GAUGES,
                          "router.gate_mean": "router_gate_mean",
                          "router.bias_abs_max": "router_bias_abs_max"})
-
-
-def make_batch(rng: np.random.Generator, batch_size: int, seq_len: int,
-               vocab_size: int):
-    """Synthetic Zipf(1.05) batch with ``models/lm1b``'s feed keys."""
-    x = (rng.zipf(1.05, size=(batch_size, seq_len)) - 1) % vocab_size
-    return {"x": x.astype(np.int32),
-            "y": np.roll(x, -1, axis=1).astype(np.int32),
-            "w": np.ones((batch_size, seq_len), np.float32)}

@@ -64,6 +64,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from parallax_tpu.core.mesh import AXIS_REPL, AXIS_SHARD
 
+# what a caller's checkpoint policy keeps so that no grouped product runs
+# twice: ``routed_experts``' row buffers
+KEPT = "moe_rows"
+
 
 class MoEOut(NamedTuple):
     out: jax.Array        # [B, D]
@@ -242,13 +246,14 @@ class SigmoidRoute(NamedTuple):
                             # whatever choice was given
 
 
-# megablox tiles (rows, contraction, columns): the largest of these that
-# divides the size, so that an expert's 2048 x 768 matrix is two tiles
-# and not ninety-six
+# megablox tiles (rows, contraction, columns): the largest that divides
+# the size, of 512, 256 and 128 rows and of the multiples of 128 up to
+# 1,024 across, so that an expert's 2048 x 768 matrix is two tiles and
+# not ninety-six
 def _gmm_tiling(m: int, k: int, n: int):
     def fit(size, choices):
         return next((c for c in choices if size % c == 0), size)
-    wide = (1024, 896, 768, 512, 256, 128)
+    wide = range(1024, 0, -128)
     return fit(m, (512, 256, 128)), fit(k, wide), fit(n, wide)
 
 
@@ -553,14 +558,14 @@ def _rows(tokens, weight, w_gate, w_up, w_down, route, lo: int, n: int,
     if lo == 0:
         # kept for the backward pass where a caller's checkpoint
         # policy says so, in place of the products that made them
-        x = checkpoint_name(x, "moe_rows")
-        gate_act = checkpoint_name(gate_act, "moe_rows")
-        up = checkpoint_name(up, "moe_rows")
+        x = checkpoint_name(x, KEPT)
+        gate_act = checkpoint_name(gate_act, KEPT)
+        up = checkpoint_name(up, KEPT)
     h = (jax.nn.silu(gate_act.astype(jnp.float32))
          * up.astype(jnp.float32)).astype(x.dtype)
     y = only_live(_grouped_dot(h, w_down, part, impl))
     if lo == 0:
-        y = checkpoint_name(y, "moe_rows")
+        y = checkpoint_name(y, KEPT)
     return _combine(y, weight, mine, n_live, k, impl)
 
 
@@ -772,3 +777,38 @@ def routed_experts(tokens: jax.Array,        # [B, D]
     return RoutedOut(out, dropped, rows_here.astype(jnp.float32),
                      walked.astype(jnp.float32),
                      jnp.max(sizes).astype(jnp.float32) / mean_load)
+
+
+def check_held(num_experts: int, experts_held: int, first_expert: int):
+    """Refuses held experts ``[first_expert, first_expert +
+    experts_held)`` that are not among the router's ``num_experts``."""
+    if not 0 <= first_expert <= num_experts - experts_held:
+        raise ValueError(
+            f"experts [{first_expert}, {first_expert + experts_held}) are "
+            f"not among the router's {num_experts}")
+
+
+def moe_scalars(moe: RoutedOut):
+    """A layer's numbers of ``routed_experts`` under the names that
+    ``moe_metrics`` reads once they are stacked over the layers."""
+    return {"moe_dropped": moe.dropped, "moe_rows_here": moe.rows_here,
+            "moe_rows_walked": moe.rows_walked,
+            "moe_load_max_over_mean": moe.load_max_over_mean}
+
+
+def moe_metrics(per_layer):
+    """A step's metrics of its ``routed_experts`` layers from their
+    ``moe_scalars`` stacked over them: the most rows any layer dropped,
+    the layers' mean of the others."""
+    return {"moe_dropped": jnp.max(per_layer["moe_dropped"]),
+            "moe_rows_here": jnp.mean(per_layer["moe_rows_here"]),
+            "moe_rows_walked": jnp.mean(per_layer["moe_rows_walked"]),
+            "moe_load_max_over_mean":
+                jnp.mean(per_layer["moe_load_max_over_mean"])}
+
+
+# the gauges (``core/engine.Model(gauges=...)``) of ``moe_metrics``
+GAUGES = {"moe.dropped": ("moe_dropped", "max"),
+          "moe.rows_here": "moe_rows_here",
+          "moe.rows_walked": "moe_rows_walked",
+          "moe.load_max_over_mean": "moe_load_max_over_mean"}

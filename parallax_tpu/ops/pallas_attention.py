@@ -68,6 +68,10 @@ _NEG_INF = -1e30
 # boundary).
 _LANES = 8
 
+# what a caller's checkpoint policy keeps so that the forward kernel does
+# not run twice: its output and logsumexp
+KEPT = "flash_attn"
+
 # the scope a windowed call sits under, forward and backward (one of
 # ``obs/xprof.LAYER_SCOPES``: inside a model's ``attention`` it wins)
 WINDOW_SCOPE = "window_attention"
@@ -614,8 +618,8 @@ def _fwd_masked(q, k, v, kv_mask, window_on, causal, scale, q_tile,
                               block_k, interpret, window, window_on)
     # what a caller's checkpoint policy may keep for the backward pass
     # in place of running the forward kernel again
-    out = checkpoint_name(out, "flash_attn")
-    lse = checkpoint_name(lse, "flash_attn")
+    out = checkpoint_name(out, KEPT)
+    lse = checkpoint_name(lse, KEPT)
     return out, (q, k, v, kv_mask, window_on, out, lse)
 
 

@@ -84,6 +84,9 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 _NEG = -1e30
+# what a caller's checkpoint policy keeps so that no chunk runs twice: its
+# selection, output and logsumexp
+KEPT = "sparse_attn_chunk"
 
 
 class SparseAttnOut(NamedTuple):
@@ -610,7 +613,7 @@ def _chunk_fwd(q, k, v, qi, ki, wi, q_start, topk, impl):
     # what a caller's checkpoint policy may keep for the backward pass
     # in place of running the chunk again
     def keep(a):
-        return checkpoint_name(a, "sparse_attn_chunk")
+        return checkpoint_name(a, KEPT)
 
     sel = keep(sel)
     if impl == "xla":

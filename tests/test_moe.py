@@ -576,6 +576,10 @@ def test_gate_cotangent_by_rows_is_plain_ad_of_the_pair_formula(rng, part):
     ((32768, 2048, 768), (512, 1024, 768)),
     ((32768, 768, 2048), (512, 768, 1024)),
     ((8192, 2048, 2048), (512, 1024, 1024)),
+    # Trinity-Mini's (2048 x 1024)
+    ((8192, 2048, 1024), (512, 1024, 1024)),
+    # a width only the rule covers: 640 = 5 x 128, and 1920 = 3 x 640
+    ((4096, 1920, 640), (512, 640, 640)),
     # no choice divides: the size itself
     ((96, 200, 72), (96, 200, 72)),
 ])

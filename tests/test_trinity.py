@@ -17,7 +17,7 @@ import pytest
 
 import parallax_tpu as parallax
 from parallax_tpu.models import trinity
-from parallax_tpu.models.keye_vl2 import rms_norm
+from parallax_tpu.models.decoder import rms_norm
 from parallax_tpu.ops import moe as moe_ops
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
