@@ -1,8 +1,10 @@
 """What the decoder language models share (``models/keye_vl2``,
-``zaya``, ``mellum2``, ``olmo_hybrid``, ``trinity``): the norm, the cast
-of a layer stack, RoPE in half-split layout, the causal attention and
-the choice of its executor, the dense SwiGLU MLP, the initialiser, the
-head and its loss, the optimiser and the synthetic batch. Each of those
+``zaya``, ``mellum2``, ``olmo_hybrid``, ``trinity``, ``glm4_moe_lite``):
+the norm, the cast of a layer stack, RoPE in half-split layout, the
+causal attention and the choice of its executor, the dense SwiGLU MLP,
+a sigmoid-routed expert layer's mix beside its shared expert, the
+initialiser, the head and its loss, the optimiser and the synthetic
+batch. Each of those
 models imports these from here and nothing from another model, so a
 change here is a change to every model that calls it.
 """
@@ -16,6 +18,7 @@ import optax
 from jax.ad_checkpoint import checkpoint_name
 
 from parallax_tpu.ops import embedding as emb_ops
+from parallax_tpu.ops import moe as moe_ops
 from parallax_tpu.ops import pallas_attention as pa
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -107,6 +110,30 @@ def mlp(p, x, dt):
         up = checkpoint_name(x @ p["w_up"].astype(dt), MLP_KEPT)
         return checkpoint_name((gate * up) @ p["w_down"].astype(dt),
                                MLP_KEPT)
+
+
+def expert_mix(cfg, p, bias, m, impl=None, forced_choice=None):
+    """``f [N, D]`` (float32) of the normalised rows ``m [N, D]`` under
+    the layer's balancing biases ``bias [E]``: the shared expert plus
+    the chosen experts held here (``ops/moe.sigmoid_router`` at
+    ``cfg``'s ``experts_per_token``, ``route_norm`` and ``route_scale``;
+    ``routed_experts`` from ``first_expert`` on), before anything the
+    layer does to it; the layer's scalars; the router's own top-k.
+    ``forced_choice [N, k]`` takes the place of that top-k."""
+    with jax.named_scope("router"):
+        route = moe_ops.sigmoid_router(
+            m, p["router"], bias, cfg.experts_per_token, cfg.route_norm,
+            cfg.route_scale, choice=forced_choice)
+    shared = moe_ops.shared_expert(m, p["shared_w_gate"], p["shared_w_up"],
+                                   p["shared_w_down"])
+    moe = moe_ops.routed_experts(
+        m, route.choice, route.gate, p["w_gate"], p["w_up"], p["w_down"],
+        num_experts=cfg.num_experts, first_expert=cfg.first_expert,
+        impl=impl)
+    scalars = {"load": route.load, "gate_sum_mean": route.gate_sum_mean,
+               **moe_ops.moe_scalars(moe)}
+    return shared.astype(jnp.float32) + moe.out.astype(jnp.float32), \
+        scalars, route.own_choice
 
 
 def lm_head_nll(cfg, h, final_norm, head, y):

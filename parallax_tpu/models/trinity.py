@@ -81,8 +81,8 @@ import numpy as np
 
 from parallax_tpu.core.engine import Model
 from parallax_tpu.models.decoder import (  # noqa: F401
-    FULL, SLIDING, attend, clipped_adam, in_compute_dtype, layer_kinds,
-    lm_head_nll, make_batch, mlp, normal_init, rms_norm, rope,
+    FULL, SLIDING, attend, clipped_adam, expert_mix, in_compute_dtype,
+    layer_kinds, lm_head_nll, make_batch, mlp, normal_init, rms_norm, rope,
     weighted_mean)
 from parallax_tpu.ops import embedding as emb_ops
 from parallax_tpu.ops import moe as moe_ops
@@ -241,29 +241,6 @@ def dense_layer(cfg: TrinityConfig, p, kind, h, impl=None):
     m = rms_norm(h, p["ln2"], cfg.rms_norm_eps)
     return h + rms_norm(mlp(p, m, cfg.compute_dtype), p["ln2_post"],
                         cfg.rms_norm_eps)
-
-
-def expert_mix(cfg: TrinityConfig, p, bias, m, impl=None,
-               forced_choice=None):
-    """``f [N, D]`` (float32) of the normalised rows ``m [N, D]`` under
-    the layer's biases ``bias [E]``: the shared expert plus the chosen
-    experts held here, before the output's norm; the layer's scalars;
-    the router's own top-k. ``forced_choice [N, k]`` takes the place of
-    that top-k."""
-    with jax.named_scope("router"):
-        route = moe_ops.sigmoid_router(
-            m, p["router"], bias, cfg.experts_per_token, cfg.route_norm,
-            cfg.route_scale, choice=forced_choice)
-    shared = moe_ops.shared_expert(m, p["shared_w_gate"], p["shared_w_up"],
-                                   p["shared_w_down"])
-    moe = moe_ops.routed_experts(
-        m, route.choice, route.gate, p["w_gate"], p["w_up"], p["w_down"],
-        num_experts=cfg.num_experts, first_expert=cfg.first_expert,
-        impl=impl)
-    scalars = {"load": route.load, "gate_sum_mean": route.gate_sum_mean,
-               **moe_ops.moe_scalars(moe)}
-    return shared.astype(jnp.float32) + moe.out.astype(jnp.float32), \
-        scalars, route.own_choice
 
 
 def expert_layer(cfg: TrinityConfig, p, kind, bias, h, impls=(None, None),

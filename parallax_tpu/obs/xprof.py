@@ -88,14 +88,18 @@ _COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
 # flash kernels' output and ``Wo``, inside ``attention``) and
 # ops/moe.shared_expert's ``shared_expert`` (the SwiGLU every token takes,
 # inside ``moe``; the inner scopes win, so ``attention`` and ``moe`` keep
-# the rest of their blocks). A step holds the
-# scopes of its own model only. ``layer_of`` reads them back off a
-# compiled instruction's ``op_name``.
+# the rest of their blocks); models/glm4_moe_lite's ``mla_latent`` (both
+# low-rank paths of the latent attention, their norms, the rotary key's
+# broadcast and RoPE, inside ``attention``) and ``mtp`` (a
+# multi-token-prediction block's two norms and its input product; the
+# block's own ``attention``, ``moe`` and ``lm_head`` keep their names).
+# A step holds the scopes of its own model only. ``layer_of`` reads them
+# back off a compiled instruction's ``op_name``.
 LAYER_SCOPES = ("embedding", "lstm", "sampled_softmax", "layer_scan",
                 "attention", "window_attention", "indexer", "cca_mix",
-                "attn_gate", "linear_attention", "delta_rule", "mlp",
-                "moe", "router", "shared_expert", "lm_head", "dense_update",
-                "table_update")
+                "attn_gate", "mla_latent", "linear_attention", "delta_rule",
+                "mlp", "moe", "router", "shared_expert", "mtp", "lm_head",
+                "dense_update", "table_update")
 # the row-sharded table path — the paper's sparse side of the
 # dense-vs-sparse variable split
 SPARSE_LAYERS = ("embedding", "sampled_softmax", "table_update")

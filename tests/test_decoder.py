@@ -1,4 +1,4 @@
-"""models/decoder is the seam between the five decoder language models:
+"""models/decoder is the seam between the six decoder language models:
 what they share comes from it, and none of them imports another."""
 
 import ast
@@ -7,7 +7,8 @@ import os
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-MODELS = ("keye_vl2", "zaya", "mellum2", "olmo_hybrid", "trinity")
+MODELS = ("keye_vl2", "zaya", "mellum2", "olmo_hybrid", "trinity",
+          "glm4_moe_lite")
 
 
 def _imported_modules(tree):

@@ -1,5 +1,5 @@
 """The layers' names inside the compiled step: the
-``jax.named_scope``s of ``obs/xprof.LAYER_SCOPES`` (eighteen) as
+``jax.named_scope``s of ``obs/xprof.LAYER_SCOPES`` (twenty) as
 ``Engine.layer_index()`` reads them back off the executable, the one
 rule for reading a scope (``xprof.layer_of`` / ``sparse_split``), and
 the compile cache's key, which must hold the names
@@ -41,6 +41,10 @@ OLMO_SCOPES = ["embedding", "layer_scan", "attention", "linear_attention",
 TRINITY_SCOPES = ["embedding", "layer_scan", "attention", "window_attention",
                   "attn_gate", "mlp", "moe", "router", "shared_expert",
                   "lm_head", "dense_update", "table_update"]
+# the latent paths inside `attention`, the MTP block's input by `mtp`
+GLM_SCOPES = ["embedding", "layer_scan", "attention", "mla_latent", "mlp",
+              "moe", "router", "shared_expert", "mtp", "lm_head",
+              "dense_update", "table_update"]
 
 
 def _session(**cfg_kw):
@@ -107,7 +111,7 @@ def test_every_declared_scope_is_found_in_the_keye_step():
     assert [s for s in xprof.LAYER_SCOPES if s in KEYE_SCOPES] == KEYE_SCOPES
     assert set(LM1B_SCOPES) | set(KEYE_SCOPES) | set(ZAYA_SCOPES) \
         | set(MELLUM2_SCOPES) | set(OLMO_SCOPES) | set(TRINITY_SCOPES) \
-        == set(xprof.LAYER_SCOPES)
+        | set(GLM_SCOPES) == set(xprof.LAYER_SCOPES)
     inner = {n: m for n, m in index["hlo_index"].items()
              if re.search(r"attention\)*/(.*/)?indexer", m.get("op_name", ""))}
     assert inner
@@ -250,9 +254,9 @@ def olmo_index():
 
 
 def test_sixteen_scopes_and_the_olmo_step_declares_its_own(olmo_index):
-    """``LAYER_SCOPES`` holds eighteen names; Olmo-Hybrid's step holds
+    """``LAYER_SCOPES`` holds twenty names; Olmo-Hybrid's step holds
     its nine in their order, no MoE model's among them."""
-    assert len(xprof.LAYER_SCOPES) == len(set(xprof.LAYER_SCOPES)) == 18
+    assert len(xprof.LAYER_SCOPES) == len(set(xprof.LAYER_SCOPES)) == 20
     assert olmo_index["scopes_found"] == OLMO_SCOPES
     assert [s for s in xprof.LAYER_SCOPES if s in OLMO_SCOPES] \
         == OLMO_SCOPES
@@ -350,6 +354,57 @@ def test_the_biases_update_goes_by_the_router(trinity_index):
             if re.search(r"train_step\)/moe/router/", m.get("op_name", ""))]
     assert rule
     assert {index["layers"][n] for n in rule} == {"router"}
+
+
+@pytest.fixture(scope="module")
+def glm_index():
+    from parallax_tpu.models import glm4_moe_lite as glm
+    cfg = glm.tiny_config(flash_tiles=(8, 8))
+    sess, *_ = parallax.parallel_run(
+        glm.build_model(cfg, impls=("flash_interpret", None)),
+        parallax_config=parallax.Config(
+            run_option="HYBRID", sparse_grad_mode="slices",
+            search_partitions=False, shape_buckets=[8]))
+    batch = glm.make_batch(np.random.default_rng(0), 8, cfg.seq_len,
+                           cfg.vocab_size)
+    sess.warmup(feed_dict=batch)
+    index = sess.layer_index()
+    sess.close()
+    return index
+
+
+def test_every_declared_scope_is_found_in_the_glm_step(glm_index):
+    """GLM-4.7-Flash's step holds its twelve scopes in ``LAYER_SCOPES``'
+    order: the latent paths and the MTP block's input by names of their
+    own."""
+    assert glm_index["scopes_found"] == GLM_SCOPES
+    assert [s for s in xprof.LAYER_SCOPES if s in GLM_SCOPES] == GLM_SCOPES
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("outer,inner", [("attention", "mla_latent"),
+                                         ("layer_scan", "mtp")])
+def test_the_inner_scope_wins_in_the_glm_step(glm_index, outer, inner,
+                                              direction):
+    """``mla_latent`` is traced inside ``attention`` and ``mtp`` inside
+    ``layer_scan``, in both passes: their products go by the inner name,
+    and the outer scope keeps operations of its own (the flash kernels
+    and ``Wo`` stay ``attention``'s)."""
+    def of(meta):
+        return ("transpose(" in meta.get("op_name", "")) \
+            == (direction == "backward")
+
+    index = glm_index
+    nested = {n: m for n, m in index["hlo_index"].items()
+              if re.search(rf"{outer}\)*/(.*/)?{inner}",
+                           m.get("op_name", "")) and of(m)}
+    assert nested
+    assert {index["layers"][n] for n in nested} == {inner}
+    assert any(m["opcode"] in ("dot", "fusion", "convolution")
+               for m in nested.values())
+    own = [n for n, m in index["hlo_index"].items()
+           if index["layers"][n] == outer and of(m)]
+    assert own
 
 
 def test_table_scatter_maps_to_table_update(warmed):

@@ -12,17 +12,28 @@ from parallax_tpu.ops.ring_attention import full_attention_reference
 B, T, H, D = 2, 64, 2, 16
 
 
-@pytest.fixture
-def qkv(rng):
+def _qkv(rng, dim=D):
     def t():
         return jnp.asarray(
-            rng.standard_normal((B, T, H, D)).astype(np.float32))
+            rng.standard_normal((B, T, H, dim)).astype(np.float32))
     return t(), t(), t()
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_matches_reference(qkv, causal):
-    q, k, v = qkv
+@pytest.fixture
+def qkv(rng):
+    return _qkv(rng)
+
+
+# every head size the plain cases take, and latent attention's heads of
+# 256 for q . k and v alike, as many key/value heads as query heads
+BY_HEAD_SIZE = [pytest.param(False, D, id="False"),
+                pytest.param(True, D, id="True"),
+                pytest.param(True, 256, id="D256-group1")]
+
+
+@pytest.mark.parametrize("causal,dim", BY_HEAD_SIZE)
+def test_matches_reference(rng, causal, dim):
+    q, k, v = _qkv(rng, dim)
     expected = full_attention_reference(q, k, v, causal=causal)
     got = pa.flash_attention(q, k, v, causal=causal, q_tile=16,
                              block_k=16)
@@ -92,13 +103,13 @@ def test_flash_attention_through_engine(rng):
     np.testing.assert_allclose(run(True), run(False), rtol=2e-3)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_pallas_backward_matches_xla_backward(qkv, causal):
+@pytest.mark.parametrize("causal,dim", BY_HEAD_SIZE)
+def test_pallas_backward_matches_xla_backward(rng, causal, dim):
     """The fully-Pallas dq/dk/dv kernels agree with the einsum-recompute
     backward."""
-    q, k, v = qkv
+    q, k, v = _qkv(rng, dim)
     g = jnp.asarray(np.random.default_rng(9).standard_normal(
-        (B, T, H, D)).astype(np.float32))
+        (B, T, H, dim)).astype(np.float32))
 
     def loss(xla_backward):
         def f(q, k, v):

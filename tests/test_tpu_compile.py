@@ -614,3 +614,102 @@ def test_trinity_step_compiles_at_published_widths_and_fits(topo):
     assert "[8192,16,1024]" not in text
     assert "[65536,2048]" not in text
     assert not re.search(r"f32\[(1,)?8192,8192\]", text)
+
+
+@pytest.mark.parametrize("tiles", [(512, 512), (256, 512)])
+def test_flash_kernels_compile_at_latent_attentions_heads(one_chip, tiles):
+    """GLM-4.7-Flash's latent attention: 20 query heads on 20 key/value
+    heads (group 1) of 256 against 8,192 causal keys: the three dense
+    flash kernels pass Mosaic at twice the head size of every other
+    cell, under the same VMEM limit."""
+    from parallax_tpu.ops.pallas_attention import flash_attention
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, q_tile=tiles[0], block_k=tiles[1],
+            interpret=False).astype(jnp.float32))
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                        sds(1, 8192, 20, 256), sds(1, 8192, 20, 256),
+                        sds(1, 8192, 20, 256))
+    calls = _outside_fusions(compiled.as_text(),
+                             'custom_call_target="tpu_custom_call"')
+    assert [next(n for n in ("flash_fwd", "flash_dq", "flash_dkv")
+                 if n in c.split(" = ")[0]) for c in calls] \
+        == ["flash_fwd", "flash_dq", "flash_dkv"]
+    assert "bf16[1,20,8192,256]" in calls[2]
+
+
+def test_glm_step_compiles_at_published_widths_and_fits(topo):
+    """GLM-4.7-Flash's training step as the benchmark's cell runs it (the
+    dense layer, 4 expert layers and the MTP block, 8 of 64 experts,
+    19,360 rows, one sequence of 8,192; every width as published)
+    through ``Engine`` for the described v5e: 706.5 M parameters, a
+    peak (``peak_memory_in_bytes``) between a quarter of the chip (4.23
+    GB) and 15.6 GB of its 16.9; the flash kernels at heads of 256 ONCE a
+    layer body in each direction (the dense layer's and the MTP block's
+    straight, the loop's in its bodies: no forward kernel made again by
+    the rematerialisation); ONE loop over the expert layers in each
+    direction; the table looked up twice and updated once."""
+    import numpy as np
+    import parallax_tpu as parallax
+    from parallax_tpu.core import engine as engine_lib, mesh as mesh_lib
+    from parallax_tpu.models import glm4_moe_lite as glm
+
+    dev = topo.devices[0]
+    one = SingleDeviceSharding(dev)
+    cfg = glm.GlmConfig(vocab_size=19360, num_layers=5, experts_held=8,
+                        warmup_steps=20000, num_partitions=1)
+    model = glm.build_model(cfg, impls=("flash", "gmm"))
+    mesh = mesh_lib.build_mesh(devices=[dev], num_partitions=1)
+    batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+             for k, v in glm.make_batch(
+                 np.random.default_rng(0), 1, cfg.seq_len,
+                 cfg.vocab_size).items()}
+    engine = engine_lib.Engine(
+        model, mesh, parallax.Config(run_option="HYBRID",
+                                     sparse_grad_mode="slices"), batch)
+    assert engine.plan.var_specs["emb"].is_sparse
+    state = jax.eval_shape(engine._init_jit,
+                           jax.ShapeDtypeStruct((), jnp.int32))
+    assert state.model_state["router_bias"].shape == (5, 64)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    with mesh:
+        compiled = engine._step_jit.trace(on_chip(state), on_chip(batch)) \
+            .lower(lowering_platforms=("tpu",)).compile()
+    memory = compiled.memory_analysis()
+    peak = memory.peak_memory_in_bytes
+    print(f"glm-4.7-flash step: peak_memory_in_bytes {peak / 1e9:.2f} GB "
+          f"(arguments {memory.argument_size_in_bytes / 1e9:.2f}, "
+          f"temporaries {memory.temp_size_in_bytes / 1e9:.2f})")
+    params = sum(int(np.prod(s.shape))
+                 for s in jax.tree.leaves(state.params))
+    assert params == pytest.approx(706.5e6, rel=1e-3)
+    assert 4.23e9 < peak < 15.6e9
+    text = compiled.as_text()
+    names = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    flash = sorted(n.rsplit(".", 1)[0] if "." in n else n
+                   for n in names if n.startswith("flash_"))
+    assert flash == ["flash_dkv"] * 3 + ["flash_dq"] * 3 + ["flash_fwd"] * 3
+    assert all("gmm" in n or "sum_rows" in n or "adam_rows" in n
+               for n in names if not n.startswith("flash_"))
+    # the loops that carry the stream: the expert layers' scan, forward
+    # and backward (the MTP block's experts bring small loops of their
+    # own over the 8 held, straight in the step)
+    entry = text[text.index("\nENTRY "):]
+    loops = re.findall(r"[^\n]* while\([^\n]*", entry)
+    assert sum("bf16[1,8192,2048]" in w.split(" while(")[0]
+               for w in loops) == 2
+    # neither every expert for every token, nor a row for every (token,
+    # choice) pair, nor whole float32 scores
+    assert "[8192,8,1536]" not in text
+    assert "[32768,2048]" not in text
+    assert not re.search(r"f32\[(1,)?8192,8192\]", text)
