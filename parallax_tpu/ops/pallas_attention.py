@@ -7,37 +7,39 @@ with the online-softmax recurrence — the [Tq, Tk] score matrix never
 exists in HBM.
 
 Gradients: fully-Pallas backward — the forward kernel additionally emits
-the per-row logsumexp; the backward recomputes P tiles from (q, k, lse)
-and accumulates dq (one kernel, grid over q-tiles) and dk/dv (one
-kernel, grid over k-tiles) flash-attention style, so the backward never
-materializes [Tq, Tk] either. Set ``xla_backward=True`` to fall back to
-the einsum-recompute backward.
+the per-row logsumexp; ONE backward kernel walks a query head's key
+tiles, recomputes each tile's probabilities from (q, k, lse) once and
+makes dq, dk and dv from them (the five products, each once): dk and dv
+of the tile, dq of the whole head summed in a float32 scratch over the
+key tiles, so the backward never materializes [Tq, Tk] either. Set
+``xla_backward=True`` to fall back to the einsum-recompute backward.
 
 Grouped key/value heads: ``q`` may bring ``g`` times the heads of ``k``
 and ``v`` (query head ``h`` reads key/value head ``h // g``). No kernel
-repeats K or V in HBM: the forward and ``dq`` kernels map ``g``
-consecutive query heads onto one key/value block (fetched once for the
-group, the grid walking the heads in order), and the ``dk``/``dv``
-kernel walks a group's query heads in its last grid axis and sums their
-contributions in a float32 scratch. The three calls are named
-``flash_fwd``, ``flash_dq`` and ``flash_dkv``.
+repeats K or V in HBM: both kernels walk the query heads in order and
+map ``g`` consecutive ones onto one key/value head (the forward fetches
+its block once for the group); the backward sums a group's ``dk`` and
+``dv`` in float32 scratches of the whole key/value head, which leave
+with the group's last query head. The two calls are named
+``flash_fwd`` and ``flash_bwd``.
 
 A window (``window=W`` with ``causal=True``): query ``t`` reads key
 ``s`` iff ``0 <= t - s < W``, the band behind the causal diagonal. The
-kernels walk the band's tiles only: the forward and ``dq`` loops start
-at the first key tile the query tile still sees, the ``dk``/``dv`` loop
-ends at the last query tile that still sees the key tile, and each loop
-runs in three consecutive ranges (``_key_ranges``, ``_query_ranges``):
-the tiles on the band's edge (compared against both bounds), the tiles
+kernels walk the band's tiles only: the forward loop starts at the
+first key tile the query tile still sees, the backward loop ends at the
+last query block that still sees the key tile, and each loop runs in
+three consecutive ranges (``_key_ranges``, ``_query_ranges``): the
+tiles on the band's edge (compared against both bounds), the tiles
 wholly inside (no compare, no mask) and the diagonal's (the causal
-compare, as without a window). Any positive ``W`` is legal. The windowed
-calls are named ``flash_fwd_win``, ``flash_dq_win`` and
-``flash_dkv_win`` and traced under the scope ``window_attention``, so
-that a trace tells them from a full layer's; with a traced ``window_on``
-a ``cond`` inside the ``custom_vjp``'s forward and backward chooses
-between the two kinds' calls (six names in one program, each once), and
-one loop body serves layers of both kinds. Without a window nothing of
-this is traced.
+compare, as without a window; without a window the backward's loop is
+the last two ranges, so that only the diagonal's tiles pay for a
+compare). Any positive ``W`` is legal. The windowed calls are named
+``flash_fwd_win`` and ``flash_bwd_win`` and traced under the scope
+``window_attention``, so that a trace tells them from a full layer's;
+with a traced ``window_on`` a ``cond`` inside the ``custom_vjp``'s
+forward and backward chooses between the two kinds' calls (four names
+in one program, each once), and one loop body serves layers of both
+kinds. Without a window nothing of this is traced.
 
 On non-TPU backends the same kernels run in interpret mode (tests), so
 numerics are validated everywhere the framework runs.
@@ -104,14 +106,18 @@ def _key_ranges(q0, q_tile: int, block_k: int, window: int, num_k):
     return first, inside, diag
 
 
-def _query_ranges(k0, k_tile: int, q_blk: int, window: int, q_lo, num_q):
-    """The query blocks that see the key tile starting at ``k0``, under
-    a window: ``[q_lo, diag)`` on the diagonal (the causal compare),
-    ``[diag, band)`` seeing it whole, ``[band, last)`` on the band's
-    edge (both compares); ``last`` is the first block that sees none of
-    it any more."""
-    last = jnp.minimum(num_q, (k0 + k_tile + window - 2) // q_blk + 1)
-    band = jnp.clip((k0 + window + q_blk) // q_blk - 1, q_lo, last)
+def _query_ranges(k0, k_tile: int, q_blk: int, window, q_lo, num_q):
+    """The query blocks that see the key tile starting at ``k0`` under
+    a causal diagonal: ``[q_lo, diag)`` on the diagonal (the causal
+    compare), ``[diag, band)`` seeing it whole, ``[band, last)`` on a
+    window's edge (both compares); ``last`` is the first block that sees
+    none of it any more. Without a ``window`` every block past the
+    diagonal sees the tile whole: ``band == last == num_q``."""
+    if window is None:
+        last = band = num_q
+    else:
+        last = jnp.minimum(num_q, (k0 + k_tile + window - 2) // q_blk + 1)
+        band = jnp.clip((k0 + window + q_blk) // q_blk - 1, q_lo, last)
     diag = jnp.clip((k0 + k_tile + q_blk - 2) // q_blk, q_lo, band)
     return diag, band, last
 
@@ -223,8 +229,10 @@ def _named(kernel: str, window) -> str:
 
 def _params(*semantics):
     from jax.experimental.pallas import tpu as pltpu
-    # a whole key/value head (forward, dq) or a whole query head (dkv)
-    # stays in VMEM beside its second buffer: 8 MB at 8,192 x 128 bf16
+    # a whole key/value head (forward) or a query head's q, do and dq
+    # (backward, with dq's float32 sum) stays in VMEM beside its second
+    # buffer: the backward uses 41 MiB at 8,192 x 256, 33 MiB at 8,192 x
+    # 128 in a group of 8 (the compiler's count, described v5e)
     return pltpu.CompilerParams(dimension_semantics=semantics,
                                 vmem_limit_bytes=64 * 1024 * 1024)
 
@@ -300,94 +308,34 @@ def _forward_call(q, k, v, kv_mask, causal, scale, q_tile, block_k,
     return out, lse[..., 0]
 
 
-def _flash_dq_kernel(*refs, kv_len: int, block_k: int, causal: bool,
-                     scale: float, q_tile: int, has_mask: bool,
-                     window: Optional[int] = None):
-    if has_mask:
-        (q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-         dq_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs
-        mask_ref = None
-    qt = pl.program_id(2)
-    q = q_ref[0, 0] * scale                                # [qt, D]
-    do = do_ref[0, 0].astype(jnp.float32)                  # [qt, D]
-    lse = lse_ref[0, 0][:, 0]                              # [qt] (lane 0)
-    delta = delta_ref[0, 0][:, 0]                          # [qt]
-    D = q.shape[-1]
-    dq = jnp.zeros((q_tile, D), jnp.float32)
-    num_k = kv_len // block_k
-    if causal:
-        num_k = jnp.minimum(
-            num_k, ((qt + 1) * q_tile + block_k - 1) // block_k)
-
-    def body(kt, dq, diag=causal, band=False, masked=True):
-        k_blk = k_ref[0, 0, pl.dslice(kt * block_k, block_k), :]
-        v_blk = v_ref[0, 0, pl.dslice(kt * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [qt, bk]
-        if mask_ref is not None:
-            kv_ok = mask_ref[0, 0, pl.dslice(kt * block_k, block_k)]
-            s = jnp.where(kv_ok[None, :] > 0, s, _NEG_INF)
-        if diag or band:
-            q_pos = qt * q_tile + jax.lax.broadcasted_iota(
-                jnp.int32, (q_tile, block_k), 0)
-            k_pos = kt * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (q_tile, block_k), 1)
-            s = jnp.where(_tile_ok(q_pos, k_pos, diag, band, window), s,
-                          _NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        if masked:
-            p = jnp.where(s > _NEG_INF / 2, p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v_blk.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [qt, bk]
-        ds = p * (dp - delta[:, None])
-        return dq + jax.lax.dot_general(
-            ds, k_blk.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    if window is None:
-        dq = jax.lax.fori_loop(0, num_k, body, dq)
-    else:
-        # the forward kernel's three ranges
-        first, inside, diag = _key_ranges(qt * q_tile, q_tile, block_k,
-                                          window, num_k)
-        dq = jax.lax.fori_loop(
-            first, inside, functools.partial(body, diag=True, band=True),
-            dq)
-        dq = jax.lax.fori_loop(
-            inside, diag,
-            functools.partial(body, diag=False, masked=has_mask), dq)
-        dq = jax.lax.fori_loop(diag, num_k, body, dq)
-    dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _flash_dkv_kernel(*refs, q_len: int, q_blk: int, causal: bool,
+def _flash_bwd_kernel(*refs, q_len: int, q_blk: int, causal: bool,
                       scale: float, k_tile: int, has_mask: bool,
                       group: int, window: Optional[int] = None):
-    """``dk`` and ``dv`` of one key tile of one key/value head. The last
-    grid axis walks the ``group`` query heads that read this head; their
-    contributions add up in the float32 scratch and leave with the last.
+    """``dq``, ``dk`` and ``dv`` of one key tile of one query head, from
+    one pass over the tile's scores: the five products, each made once.
     The tile's scores are held transposed, ``[keys, queries]``, so that
     the per-query ``lse`` and ``delta`` broadcast along sublanes from
-    lane-major rows and all four products are plain or NT."""
+    lane-major rows. ``dq`` of the whole head adds up in a float32
+    scratch over the key tiles, the innermost grid axis, and leaves
+    after the last. Under a ``group`` of 1 a tile's ``dk`` and ``dv``
+    leave at once; over it they add up in float32 scratches of the
+    whole key/value head over its query heads, which the grid walks
+    one after another, and leave with the group's last."""
     if has_mask:
         (q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
+         dq_ref, dk_ref, dv_ref, dq_acc, *dkv_acc) = refs
     else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-         dv_ref, dk_acc, dv_acc) = refs
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
+         dv_ref, dq_acc, *dkv_acc) = refs
         mask_ref = None
     kt = pl.program_id(2)
-    j = pl.program_id(3)
 
-    @pl.when(j == 0)
+    @pl.when(kt == 0)
     def _():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     k = k_ref[0, 0]                                        # [kt_, D]
+    k32 = k.astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     num_q = q_len // q_blk
     # Q blocks entirely before this k-tile's diagonal see none of it
@@ -395,9 +343,9 @@ def _flash_dkv_kernel(*refs, q_len: int, q_blk: int, causal: bool,
 
     def body(qi, carry, diag=causal, band=False, masked=True):
         dk, dv = carry
-        q = q_ref[0, 0, pl.dslice(qi * q_blk, q_blk), :] * scale
-        do = do_ref[0, 0, pl.dslice(qi * q_blk, q_blk), :].astype(
-            jnp.float32)
+        rows = pl.dslice(pl.multiple_of(qi * q_blk, q_blk), q_blk)
+        q = q_ref[0, 0, rows, :] * scale
+        do = do_ref[0, 0, rows, :].astype(jnp.float32)
         lse = lse_ref[0, 0, qi]                            # [1, qb]
         delta = delta_ref[0, 0, qi]
         st = jax.lax.dot_general(
@@ -425,31 +373,58 @@ def _flash_dkv_kernel(*refs, q_len: int, q_blk: int, causal: bool,
         dk = dk + jax.lax.dot_general(
             dst, q.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(
+            dst, k32, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [qb, D]
         return dk, dv
-    carry = (dk_acc[...], dv_acc[...])
-    if window is None:
-        dk, dv = jax.lax.fori_loop(q_lo, num_q, body, carry)
+
+    tile = pl.dslice(pl.multiple_of(kt * k_tile, k_tile), k_tile)
+    if group == 1:
+        carry = (jnp.zeros(k.shape, jnp.float32),) * 2
     else:
-        # the loop ends at the last query block that still sees the key
-        # tile; only the blocks on the diagonal or on the band's edge
-        # pay for a compare
+        dk_acc, dv_acc = dkv_acc
+        x = pl.program_id(1) % group
+
+        @pl.when(x == 0)
+        def _():
+            dk_acc[tile, :] = jnp.zeros(k.shape, jnp.float32)
+            dv_acc[tile, :] = jnp.zeros(k.shape, jnp.float32)
+        carry = (dk_acc[tile, :], dv_acc[tile, :])
+    if causal:
+        # the blocks on the tile's diagonal pay for the causal compare,
+        # those wholly past it for none; under a window the loop ends at
+        # the last block that still sees the tile, its band's edge
+        # compared against both bounds
         diag, band, last = _query_ranges(kt * k_tile, k_tile, q_blk, window,
                                          q_lo, num_q)
         carry = jax.lax.fori_loop(q_lo, diag, body, carry)
         carry = jax.lax.fori_loop(
             diag, band,
             functools.partial(body, diag=False, masked=has_mask), carry)
-        dk, dv = jax.lax.fori_loop(
-            band, last, functools.partial(body, diag=True, band=True),
-            carry)
-    dk_acc[...] = dk
-    dv_acc[...] = dv
-
-    @pl.when(j == group - 1)
-    def _():
-        # q was pre-scaled, so dk absorbed one factor of `scale` already
+        if window is not None:
+            carry = jax.lax.fori_loop(
+                band, last, functools.partial(body, diag=True, band=True),
+                carry)
+    else:
+        carry = jax.lax.fori_loop(
+            0, num_q, functools.partial(body, masked=has_mask), carry)
+    dk, dv = carry
+    # q was pre-scaled, so dk absorbed one factor of `scale` already
+    if group == 1:
         dk_ref[0, 0] = dk.astype(dk_ref.dtype)
         dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    else:
+        dk_acc[tile, :] = dk
+        dv_acc[tile, :] = dv
+
+        @pl.when(x == group - 1)
+        def _():
+            dk_ref[0, 0, tile, :] = dk.astype(dk_ref.dtype)
+            dv_ref[0, 0, tile, :] = dv.astype(dv_ref.dtype)
+
+    @pl.when(kt == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0, 0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_backward(q, k, v, kv_mask, out, lse, g, causal, scale,
@@ -458,7 +433,7 @@ def _flash_backward(q, k, v, kv_mask, out, lse, g, causal, scale,
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                               # [B, H, Tq]
     if dlse is not None:
-        # lse cotangent folds into the existing kernels exactly:
+        # lse cotangent folds into the existing kernel exactly:
         # d s = p*(dp - delta) + dlse*p = p*(dp - (delta - dlse))
         delta = delta - dlse.astype(jnp.float32)
 
@@ -477,87 +452,59 @@ def _backward_calls(q, k, v, kv_mask, lse, delta, g, causal, scale, q_tile,
     q_tile = _snap(q_tile, Tq)
     block_k = _snap(block_k, Tk)
 
+    # grid (batch, query head, key tile): a query head's q, do and rows
+    # are fetched once, the key tiles stream under them
+    def of_q(b, h, j):
+        return (b, h, 0, 0)
+
+    def of_kv(b, h, j):
+        return (b, h // grp, j, 0)
+
     has_mask = kv_mask is not None
-    dq_specs = [
-        pl.BlockSpec((1, 1, q_tile, D), lambda b, h, i: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h // grp, 0, 0)),
-        pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h // grp, 0, 0)),
-    ]
-    dq_operands = [q, k, v]
-    if has_mask:
-        dq_specs.append(pl.BlockSpec((1, 1, Tk),
-                                     lambda b, h, i: (b, 0, 0)))
-        dq_operands.append(kv_mask[:, None, :])
-    # lse/delta travel lane-broadcast (see _LANES comment)
-    lse_b = jnp.broadcast_to(lse[..., None], (*lse.shape, _LANES))
-    delta_b = jnp.broadcast_to(delta[..., None], (*delta.shape, _LANES))
-    dq_specs += [
-        pl.BlockSpec((1, 1, q_tile, D), lambda b, h, i: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, q_tile, _LANES), lambda b, h, i: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, q_tile, _LANES), lambda b, h, i: (b, h, i, 0)),
-    ]
-    dq_operands += [g, lse_b, delta_b]
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, kv_len=Tk, block_k=block_k,
-                          causal=causal, scale=scale, q_tile=q_tile,
-                          has_mask=has_mask, window=window),
-        grid=(B, H, Tq // q_tile),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, 1, q_tile, D),
-                               lambda b, h, i: (b, h, i, 0)),
-        out_shape=_sds((B, H, Tq, D), q.dtype, q),
-        compiler_params=_params("parallel", "parallel", "parallel"),
-        name=_named("flash_dq", window), interpret=interpret,
-    )(*dq_operands)
-
-    # grid (batch, key/value head, key tile, query head of the group):
-    # a key tile's dk and dv leave once, after the group's last head
-    def of_q(b, hk, j, x):
-        return (b, hk * grp + x, 0, 0)
-
-    def of_row(b, hk, j, x):
-        return (b, hk * grp + x, 0, 0, 0)
-
-    def of_kv(b, hk, j, x):
-        return (b, hk, j, 0)
-
-    dkv_specs = [
+    specs = [
         pl.BlockSpec((1, 1, Tq, D), of_q),
         pl.BlockSpec((1, 1, block_k, D), of_kv),
         pl.BlockSpec((1, 1, block_k, D), of_kv),
     ]
-    dkv_operands = [q, k, v]
+    operands = [q, k, v]
     if has_mask:
-        # down the sublanes here: the scores are held [keys, queries]
-        dkv_specs.append(pl.BlockSpec((1, block_k, _LANES),
-                                      lambda b, hk, j, x: (b, j, 0)))
-        dkv_operands.append(jnp.broadcast_to(
+        # down the sublanes: the scores are held [keys, queries]
+        specs.append(pl.BlockSpec((1, block_k, _LANES),
+                                  lambda b, h, j: (b, j, 0)))
+        operands.append(jnp.broadcast_to(
             kv_mask[:, :, None], (*kv_mask.shape, _LANES)))
     # ... and lse/delta along the lanes, one row a query block
     nq = Tq // q_tile
-    rows = pl.BlockSpec((1, 1, nq, 1, q_tile), of_row)
-    dkv_specs += [pl.BlockSpec((1, 1, Tq, D), of_q), rows, rows]
-    dkv_operands += [g, lse.reshape(B, H, nq, 1, q_tile),
-                     delta.reshape(B, H, nq, 1, q_tile)]
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, q_len=Tq, q_blk=q_tile,
+    rows = pl.BlockSpec((1, 1, nq, 1, q_tile), lambda b, h, j: (b, h, 0, 0, 0))
+    specs += [pl.BlockSpec((1, 1, Tq, D), of_q), rows, rows]
+    operands += [g, lse.reshape(B, H, nq, 1, q_tile),
+                 delta.reshape(B, H, nq, 1, q_tile)]
+    scratch = [pltpu.VMEM((Tq, D), jnp.float32)]
+    if grp == 1:
+        dkv_spec = pl.BlockSpec((1, 1, block_k, D), of_kv)
+    else:
+        # a key/value head's blocks stay while the grid walks its group
+        dkv_spec = pl.BlockSpec((1, 1, Tk, D),
+                                lambda b, h, j: (b, h // grp, 0, 0))
+        scratch += [pltpu.VMEM((Tk, D), jnp.float32)] * 2
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, q_len=Tq, q_blk=q_tile,
                           causal=causal, scale=scale, k_tile=block_k,
                           has_mask=has_mask, group=grp, window=window),
-        grid=(B, Hkv, Tk // block_k, grp),
-        in_specs=dkv_specs,
-        out_specs=[pl.BlockSpec((1, 1, block_k, D), of_kv),
-                   pl.BlockSpec((1, 1, block_k, D), of_kv)],
+        grid=(B, H, Tk // block_k),
+        in_specs=specs,
+        out_specs=[pl.BlockSpec((1, 1, Tq, D), of_q), dkv_spec, dkv_spec],
         out_shape=[
+            _sds((B, H, Tq, D), q.dtype, q),
             _sds((B, Hkv, Tk, D), k.dtype, k),
             _sds((B, Hkv, Tk, D), v.dtype, v),
         ],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "parallel",
-                                "arbitrary"),
-        name=_named("flash_dkv", window), interpret=interpret,
-    )(*dkv_operands)
-    return dq, dk, dv
+        scratch_shapes=scratch,
+        compiler_params=_params(
+            "parallel", "parallel" if grp == 1 else "arbitrary",
+            "arbitrary"),
+        name=_named("flash_bwd", window), interpret=interpret,
+    )(*operands)
 
 
 def _repeat_kv(q, k, v):
@@ -654,7 +601,7 @@ def _flash_attention_with_lse(q, k, v, kv_mask, window_on, causal, scale,
                               window):
     """(out, lse) variant — the composition surface for ring attention:
     per-block partial softmaxes merge exactly from (out, lse) pairs, and
-    the lse cotangent is a delta-shift in the unchanged backward kernels."""
+    the lse cotangent is a delta-shift in the unchanged backward kernel."""
     return _flash_forward(q, k, v, kv_mask, causal, scale, q_tile,
                           block_k, interpret, window, window_on)
 
@@ -765,14 +712,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     NMT/BERT-style models); None means all keys attend. ``interpret``
     defaults to True off-TPU (so CPU tests exercise the same kernels)
     and False on TPU. ``xla_backward=True`` swaps the Pallas backward
-    kernels for the einsum-recompute fallback.
+    kernel for the einsum-recompute fallback.
 
     ``window`` (static, with ``causal=True``): query ``t`` reads key
     ``s`` iff ``0 <= t - s < window``, the token itself and the ``window
     - 1`` before it; any positive count is legal, ``>= Tk`` being plain
-    causal attention. The three kernels then walk the band's tiles only
-    and are named ``flash_fwd_win``, ``flash_dq_win``, ``flash_dkv_win``
-    under the scope ``window_attention``, on the call's own tiles (at
+    causal attention. The two kernels then walk the band's tiles only
+    and are named ``flash_fwd_win`` and ``flash_bwd_win`` under the
+    scope ``window_attention``, on the call's own tiles (at
     32 on 4 heads of 128, 8,192 keys and a window of 1,024 the v5e runs
     512 x 512 fastest of nine pairs: ``PERF.md`` section 6, PR 33).
     At 20 on 20 heads of 256 over 8,192 causal keys (latent

@@ -3,7 +3,9 @@
 no K or V repeated in memory. The kernels, interpreted here, against the
 einsum reference ``_xla_attention`` for ``g`` in {1, 4}: forward, all
 three gradients, the padding mask, the ``(out, lse)`` surface, and the
-names a trace reader finds the three calls by."""
+names a trace reader finds the two calls by; the one backward kernel
+against the einsum backward (``xla_backward=True``) at the heads' real
+sizes."""
 
 import jax
 import jax.numpy as jnp
@@ -163,9 +165,59 @@ def test_the_three_calls_carry_their_names_and_grids():
                     calls(sub, out)
         return out
 
-    # forward and dq a query head; dkv a key/value head, the group's
-    # query heads in its last axis
+    # forward a query tile of a query head; the backward a key tile of
+    # a query head, the group's query heads one after another
     assert calls(jaxpr.jaxpr, {}) == {
         "flash_fwd": (B, HKV * 4, T // 16),
-        "flash_dq": (B, HKV * 4, T // 16),
-        "flash_dkv": (B, HKV, T // 16, 4)}
+        "flash_bwd": (B, HKV * 4, T // 16)}
+
+
+# (causal, group, head size, queries, keys, padding mask, lse cotangent)
+FUSED_CASES = [
+    pytest.param(True, 1, 256, 1024, 1024, False, False, id="causal-g1-d256"),
+    pytest.param(True, 4, 128, 1024, 1024, False, False, id="causal-g4-d128"),
+    pytest.param(True, 8, 128, 1024, 1024, False, False, id="causal-g8-d128"),
+    pytest.param(False, 1, 128, 1024, 1024, True, False,
+                 id="noncausal-g1-mask"),
+    pytest.param(False, 4, 128, 512, 1024, True, False,
+                 id="noncausal-g4-mask-tq<tk"),
+    pytest.param(True, 4, 128, 512, 1024, False, False, id="causal-g4-tq<tk"),
+    pytest.param(True, 4, 128, 1024, 1024, False, True, id="causal-g4-dlse"),
+    pytest.param(True, 1, 256, 1024, 1024, True, True,
+                 id="causal-g1-d256-mask-dlse"),
+]
+
+
+@pytest.mark.parametrize(
+    "causal,g,d,tq,tk,masked,with_lse", FUSED_CASES)
+def test_the_fused_backward_matches_the_einsum_backward(
+        causal, g, d, tq, tk, masked, with_lse):
+    """``flash_bwd`` (dq, dk and dv from one pass over the scores) against
+    the same call's einsum backward, ``xla_backward=True``: the forward
+    is the same kernel on both sides, so only the backward is compared.
+    Tiles of 256 queries and 512 keys: a key tile spans two query
+    blocks, the diagonal's range and the range past it both run."""
+    rng = np.random.default_rng(d + g + tq)
+
+    def n(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    q, k, v = n(1, tq, 2 * g, d), n(1, tk, 2, d), n(1, tk, 2, d)
+    do = n(1, tq, 2 * g, d)
+    kv_mask = None
+    if masked:
+        kv_mask = jnp.asarray(np.arange(tk)[None, :] < tk - 200, jnp.int32)
+
+    def pulled(xla_backward):
+        def f(q, k, v):
+            kw = dict(causal=causal, kv_mask=kv_mask, q_tile=256,
+                      block_k=512, interpret=True, xla_backward=xla_backward)
+            if with_lse:
+                out, lse = pa.flash_attention_lse(q, k, v, **kw)
+                return jnp.sum(out * do) + jnp.sum(jnp.sin(lse))
+            return jnp.sum(pa.flash_attention(q, k, v, **kw) * do)
+        return jax.grad(f, (0, 1, 2))(q, k, v)
+
+    for name, a, b in zip("qkv", pulled(False), pulled(True)):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-5, atol=5e-6, err_msg=name)

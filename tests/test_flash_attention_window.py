@@ -1,11 +1,12 @@
 """A window in the dense flash kernels: query ``t`` reads key ``s`` iff
-``0 <= t - s < window``. The three kernels, interpreted, against an
+``0 <= t - s < window``. The kernels, interpreted, against an
 einsum under the band's mask (outputs, ``dq``, ``dk``, ``dv``), over
 windows smaller than a tile, a tile, between tiles, no multiple of
 either tile and past the sequence, over group sizes and tiles; the
 traced ``window_on`` that lets one loop body serve both kinds of layer;
-and that a call without a window is traced as before this argument
-existed."""
+the one backward kernel against the einsum backward at the cells'
+windows and tiles; and that a call without a window is traced as before
+this argument existed."""
 
 import re
 
@@ -107,6 +108,33 @@ def test_a_traced_flag_says_whether_this_call_applies_the_window(
         np.testing.assert_allclose(a, b, atol=5e-5)
 
 
+@pytest.mark.parametrize("window,on,g", [
+    (1024, True, 4), (1024, False, 4), (2048, True, 4), (2048, False, 4),
+    (1024, None, 8), (2048, None, 1),
+])
+def test_the_fused_backward_matches_the_einsum_backward_at_the_cells_windows(
+        window, on, g):
+    """``flash_bwd_win`` (and ``flash_bwd`` where a traced ``window_on``
+    is off) against the same call's einsum backward, ``xla_backward=True``,
+    at Mellum2's and Trinity-Mini's windows over tiles of 512 and heads of
+    128: a key tile's query blocks in all three ranges, the diagonal's,
+    the whole ones and the band's edge. ``on`` None: always windowed."""
+    t = 2560
+    ks = jax.random.split(jax.random.PRNGKey(window + g), 4)
+    q, do = (jax.random.normal(key, (1, t, g, 128)) for key in ks[:2])
+    k, v = (jax.random.normal(key, (1, t, 1, 128)) for key in ks[2:])
+    flag = None if on is None else jnp.asarray(on)
+
+    def pulled(xla_backward):
+        return jax.vjp(lambda *a: pa.flash_attention(
+            *a, causal=True, window=window, window_on=flag, q_tile=512,
+            block_k=512, interpret=True, xla_backward=xla_backward),
+            q, k, v)[1](do)
+
+    for name, a, b in zip(("dq", "dk", "dv"), pulled(False), pulled(True)):
+        np.testing.assert_allclose(a, b, atol=5e-5, err_msg=name)
+
+
 def test_a_padding_mask_composes_with_the_window():
     q, k, v, _ = _qkv(2, seed=11)
     mask = jnp.ones((2, T), jnp.int32).at[1, 40:].set(0)
@@ -163,25 +191,24 @@ def _traced(flag, q, k, v, do, **kw):
 
 
 def test_no_window_lowers_as_before():
-    """The windowless call's jaxpr holds exactly ``flash_fwd``,
-    ``flash_dq`` and ``flash_dkv``, no ``cond`` and no band scope."""
+    """The windowless call's jaxpr holds exactly ``flash_fwd`` and
+    ``flash_bwd``, no ``cond`` and no band scope."""
     traced = _traced(None, *_qkv(2))
-    assert _outline(traced.jaxpr) == ["flash_fwd", "flash_dq", "flash_dkv"]
+    assert _outline(traced.jaxpr) == ["flash_fwd", "flash_bwd"]
     assert pa.WINDOW_SCOPE not in str(traced)
 
 
 def test_the_windowed_calls_carry_their_names():
-    """Always windowed: the three ``_win`` calls alone, no ``cond``."""
+    """Always windowed: the two ``_win`` calls alone, no ``cond``."""
     traced = _traced(None, *_qkv(2), window=24)
-    assert _outline(traced.jaxpr) == ["flash_fwd_win", "flash_dq_win",
-                                      "flash_dkv_win"]
+    assert _outline(traced.jaxpr) == ["flash_fwd_win", "flash_bwd_win"]
 
 
 def test_a_traced_flag_is_one_cond_a_pass_holding_both_kinds_once():
     traced = _traced(True, *_qkv(2), window=24)
     assert _outline(traced.jaxpr) == [
         "cond", "flash_fwd", "flash_fwd_win",
-        "cond", "flash_dq", "flash_dkv", "flash_dq_win", "flash_dkv_win"]
+        "cond", "flash_bwd", "flash_bwd_win"]
 
 
 @pytest.mark.parametrize("kw", [

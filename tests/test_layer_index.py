@@ -545,7 +545,7 @@ def test_lax_scan_branch_carries_the_lstm_scope():
      "cond/branch_1_fun/window_attention/flash_fwd_win", "window_attention",
      "dense"),
     ("jit(train_step)/transpose(jvp(layer_scan))/while/body/closed_call/"
-     "checkpoint/attention/cond/branch_0_fun/flash_dq", "attention",
+     "checkpoint/attention/cond/branch_0_fun/flash_bwd", "attention",
      "dense"),
     # the Olmo-Hybrid step's own: the rule inside a linear layer's mixer,
     # the MLP beside it

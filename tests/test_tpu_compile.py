@@ -190,9 +190,9 @@ def test_routed_experts_kernels_compile_at_published_widths(
 
 def test_flash_kernels_compile_with_grouped_heads_at_8k(one_chip):
     """8 query heads on 2 key/value heads of 128 against 8,192 causal
-    keys in tiles of 512: the dense flash kernels' forward, ``dq`` and
-    ``dk``/``dv`` pass Mosaic under their names, and no K or V of the
-    query heads' count is in memory."""
+    keys in tiles of 512: the dense flash kernels' forward and their one
+    backward pass Mosaic under their names, and no K or V of the query
+    heads' count is in memory."""
     from parallax_tpu.ops.pallas_attention import flash_attention
 
     def sds(*shape):
@@ -207,21 +207,22 @@ def test_flash_kernels_compile_with_grouped_heads_at_8k(one_chip):
                         sds(1, 8192, 8, 128), sds(1, 8192, 2, 128),
                         sds(1, 8192, 2, 128))
     text = compiled.as_text()
-    assert _kernels(compiled) == 3
+    assert _kernels(compiled) == 2
     calls = _outside_fusions(text, 'custom_call_target="tpu_custom_call"')
-    assert [next(n for n in ("flash_fwd", "flash_dq", "flash_dkv")
+    assert [next(n for n in ("flash_fwd", "flash_bwd")
                  if n in c.split(" = ")[0]) for c in calls] \
-        == ["flash_fwd", "flash_dq", "flash_dkv"]
-    # dk and dv leave at the key/value heads' count
-    assert "bf16[1,2,8192,128]" in calls[2]
+        == ["flash_fwd", "flash_bwd"]
+    # dq leaves at the query heads' count, dk and dv at the key/value
+    # heads'
     assert "bf16[1,8,8192,128]" in calls[1]
+    assert "bf16[1,2,8192,128]" in calls[1]
 
 
 @pytest.mark.parametrize("tiles", [(512, 512), (256, 256)])
 def test_windowed_flash_kernels_compile_at_mellum2s_heads(one_chip, tiles):
     """32 query heads on 4 key/value heads of 128 against 8,192 keys
-    under a window of 1,024 and a traced flag: the three windowed
-    kernels pass Mosaic beside the three plain ones, each kind once a
+    under a window of 1,024 and a traced flag: the two windowed
+    kernels pass Mosaic beside the two plain ones, each kind once a
     pass under its ``conditional``."""
     from parallax_tpu.ops.pallas_attention import flash_attention
 
@@ -242,15 +243,14 @@ def test_windowed_flash_kernels_compile_at_mellum2s_heads(one_chip, tiles):
     names = re.findall(
         r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
     assert sorted(n.rsplit(".", 1)[0] if "." in n else n for n in names) \
-        == ["flash_dkv", "flash_dkv_win", "flash_dq", "flash_dq_win",
-            "flash_fwd", "flash_fwd_win"]
+        == ["flash_bwd", "flash_bwd_win", "flash_fwd", "flash_fwd_win"]
     assert len(re.findall(r" conditional\(", text)) == 2
 
 
 def test_windowed_flash_kernels_compile_at_trinitys_window(one_chip):
     """The same heads under Trinity-Mini's window of 2,048, four key
-    tiles of 512 and not two: the three windowed kernels pass Mosaic
-    beside the three plain ones under the traced flag."""
+    tiles of 512 and not two: the two windowed kernels pass Mosaic
+    beside the two plain ones under the traced flag."""
     from parallax_tpu.ops.pallas_attention import flash_attention
 
     def sds(*shape):
@@ -269,9 +269,51 @@ def test_windowed_flash_kernels_compile_at_trinitys_window(one_chip):
     names = re.findall(
         r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
     assert sorted(n.rsplit(".", 1)[0] if "." in n else n for n in names) \
-        == ["flash_dkv", "flash_dkv_win", "flash_dq", "flash_dq_win",
-            "flash_fwd", "flash_fwd_win"]
+        == ["flash_bwd", "flash_bwd_win", "flash_fwd", "flash_fwd_win"]
     assert len(re.findall(r" conditional\(", text)) == 2
+
+
+@pytest.mark.parametrize("heads,kv_heads,dim,window", [
+    pytest.param(20, 20, 256, None, id="glm"),
+    pytest.param(32, 4, 128, 1024, id="mellum2"),
+])
+def test_one_backward_kernel_a_kind_within_the_vmem_limit(
+        one_chip, heads, kv_heads, dim, window):
+    """The backward alone, from the forward's kept output and logsumexp,
+    at GLM-4.7-Flash's latent heads (20 on 20 of 256) and at Mellum2's
+    window (32 on 4 of 128, a group of 8, a traced flag) over 8,192
+    causal keys in tiles of 512: ONE Mosaic call a kind of layer,
+    ``flash_bwd`` (and ``flash_bwd_win``), each making dq, dk and dv,
+    and none asking for or using more than 64 MiB of scoped VMEM (a
+    kernel that asked for 100 MiB cost a neighbour its place in VMEM,
+    ``PERF.md`` section 7)."""
+    from parallax_tpu.ops import pallas_attention as pa
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def bwd(q, k, v, out, lse, g, flag):
+        return pa._flash_backward(
+            q, k, v, None, out, lse, g, True, dim ** -0.5, 512, 512, False,
+            window=window, window_on=None if window is None else flag)
+
+    q = sds(1, heads, 8192, dim)
+    kv = sds(1, kv_heads, 8192, dim)
+    text = _compile(bwd, q, kv, kv, q, sds(1, heads, 8192,
+                                           dtype=jnp.float32),
+                    q, sds(dtype=jnp.bool_)).as_text()
+    calls = _outside_fusions(text, 'custom_call_target="tpu_custom_call"')
+    names = [c.split(" = ")[0].split("%")[-1].split(".")[0] for c in calls]
+    assert sorted(names) == (["flash_bwd"] if window is None
+                             else ["flash_bwd", "flash_bwd_win"])
+    mib = 1024 * 1024
+    for call in calls:
+        (asked,) = re.findall(
+            r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', call)
+        (used,) = re.findall(
+            r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', call)
+        assert int(asked) <= 64 * mib and 0 < int(used) <= 64 * mib
+        assert f"bf16[1,{kv_heads},8192,{dim}]" in call
 
 
 def test_zaya_step_compiles_at_published_widths_and_fits(topo):
@@ -326,7 +368,7 @@ def test_zaya_step_compiles_at_published_widths_and_fits(topo):
     kinds = sorted({n.rsplit(".", 1)[0] for n in names})
     # (no `sum_rows`: one choice a token and every pair in one part, so
     # the per-token sums are gathers of each token's own row)
-    assert kinds == ["flash_dkv", "flash_dq", "flash_fwd", "gmm", "tgmm"]
+    assert kinds == ["flash_bwd", "flash_fwd", "gmm", "tgmm"]
     # neither every expert for every token nor whole float32 scores
     assert "[8192,8,2048]" not in text
     assert not re.search(r"f32\[(1,)?8192,8192\]", text)
@@ -340,7 +382,7 @@ def test_mellum2_step_compiles_at_published_widths_and_fits(topo):
     (``peak_memory_in_bytes``) between the driver's floor and 15.5 GB
     of the chip's 16.9, ONE loop over the layers in each direction, and
     in them both kinds' flash kernels once each: the plain and the
-    windowed forward call in the forward loop's body, the four backward
+    windowed forward call in the forward loop's body, the two backward
     calls in the backward loop's, no forward kernel made again by the
     rematerialisation."""
     import numpy as np
@@ -386,12 +428,11 @@ def test_mellum2_step_compiles_at_published_widths_and_fits(topo):
     names = re.findall(
         r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
     flash = sorted(n for n in names if n.startswith("flash_"))
-    # each of the six once in the whole program: the loops' bodies are
+    # each of the four once in the whole program: the loops' bodies are
     # traced once, and the kept output and logsumexp spare the backward
     # pass a second forward call
     assert [n.rsplit(".", 1)[0] if "." in n else n for n in flash] == [
-        "flash_dkv", "flash_dkv_win", "flash_dq", "flash_dq_win",
-        "flash_fwd", "flash_fwd_win"]
+        "flash_bwd", "flash_bwd_win", "flash_fwd", "flash_fwd_win"]
     # (the experts' second part, under its own `cond`, names its calls
     # after the transformation that made them)
     assert all("gmm" in n or "sum_rows" in n for n in names
@@ -399,7 +440,7 @@ def test_mellum2_step_compiles_at_published_widths_and_fits(topo):
     assert any("sum_rows" in n for n in names)
     # ONE loop over the layers a direction: the entry computation holds
     # two, the forward one's body the `conditional` with the two forward
-    # calls and the backward one's the four backward calls
+    # calls and the backward one's the two backward calls
     entry = text[text.index("\nENTRY "):]
     bodies = re.findall(r" while\([^\n]*body=%([\w.\-]+)", entry)
     assert len(bodies) == 2
@@ -421,8 +462,8 @@ def test_mellum2_step_compiles_at_published_widths_and_fits(topo):
 
     assert calls_under_conditionals(bodies[0]) == ["flash_fwd",
                                                    "flash_fwd_win"]
-    assert calls_under_conditionals(bodies[1]) == [
-        "flash_dkv", "flash_dkv_win", "flash_dq", "flash_dq_win"]
+    assert calls_under_conditionals(bodies[1]) == ["flash_bwd",
+                                                   "flash_bwd_win"]
     # neither every expert for every token, nor a row for every (token,
     # choice) pair, nor whole float32 scores
     assert "[8192,16,896]" not in text
@@ -536,8 +577,7 @@ def test_olmo_hybrid_step_compiles_at_published_widths_and_fits(
     names = re.findall(
         r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
     assert sorted(n.split(".")[0] for n in names) == [
-        "adam_rows", "delta_bwd", "delta_fwd", "flash_dkv", "flash_dq",
-        "flash_fwd"]
+        "adam_rows", "delta_bwd", "delta_fwd", "flash_bwd", "flash_fwd"]
     assert not re.search(r"f32\[(1,)?8192,8192\]", text)
 
 
@@ -597,11 +637,10 @@ def test_trinity_step_compiles_at_published_widths_and_fits(topo):
         r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
     flash = sorted(n.rsplit(".", 1)[0] if "." in n else n
                    for n in names if n.startswith("flash_"))
-    # the dense layer's three windowed calls, straight, and each of the
-    # six once in the loops' bodies: the kept output and logsumexp spare
+    # the dense layer's two windowed calls, straight, and each of the
+    # four once in the loops' bodies: the kept output and logsumexp spare
     # the backward pass a second forward call
-    assert flash == ["flash_dkv", "flash_dkv_win", "flash_dkv_win",
-                     "flash_dq", "flash_dq_win", "flash_dq_win",
+    assert flash == ["flash_bwd", "flash_bwd_win", "flash_bwd_win",
                      "flash_fwd", "flash_fwd_win", "flash_fwd_win"]
     assert all("gmm" in n or "sum_rows" in n for n in names
                if not n.startswith("flash_"))
@@ -619,7 +658,7 @@ def test_trinity_step_compiles_at_published_widths_and_fits(topo):
 @pytest.mark.parametrize("tiles", [(512, 512), (256, 512)])
 def test_flash_kernels_compile_at_latent_attentions_heads(one_chip, tiles):
     """GLM-4.7-Flash's latent attention: 20 query heads on 20 key/value
-    heads (group 1) of 256 against 8,192 causal keys: the three dense
+    heads (group 1) of 256 against 8,192 causal keys: the two dense
     flash kernels pass Mosaic at twice the head size of every other
     cell, under the same VMEM limit."""
     from parallax_tpu.ops.pallas_attention import flash_attention
@@ -637,10 +676,10 @@ def test_flash_kernels_compile_at_latent_attentions_heads(one_chip, tiles):
                         sds(1, 8192, 20, 256))
     calls = _outside_fusions(compiled.as_text(),
                              'custom_call_target="tpu_custom_call"')
-    assert [next(n for n in ("flash_fwd", "flash_dq", "flash_dkv")
+    assert [next(n for n in ("flash_fwd", "flash_bwd")
                  if n in c.split(" = ")[0]) for c in calls] \
-        == ["flash_fwd", "flash_dq", "flash_dkv"]
-    assert "bf16[1,20,8192,256]" in calls[2]
+        == ["flash_fwd", "flash_bwd"]
+    assert "bf16[1,20,8192,256]" in calls[1]
 
 
 def test_glm_step_compiles_at_published_widths_and_fits(topo):
@@ -698,7 +737,7 @@ def test_glm_step_compiles_at_published_widths_and_fits(topo):
         r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
     flash = sorted(n.rsplit(".", 1)[0] if "." in n else n
                    for n in names if n.startswith("flash_"))
-    assert flash == ["flash_dkv"] * 3 + ["flash_dq"] * 3 + ["flash_fwd"] * 3
+    assert flash == ["flash_bwd"] * 3 + ["flash_fwd"] * 3
     assert all("gmm" in n or "sum_rows" in n or "adam_rows" in n
                for n in names if not n.startswith("flash_"))
     # the loops that carry the stream: the expert layers' scan, forward

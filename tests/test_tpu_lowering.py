@@ -46,8 +46,8 @@ def test_flash_attention_bwd_lowers_for_tpu():
             *a, causal=True, interpret=False).astype(jnp.float32)),
             argnums=(0, 1, 2))(q, k, v)
     text = _export_tpu(fwd_bwd, _S, _S, _S)
-    # fwd + dq + dkv kernels all present
-    assert text.count("tpu_custom_call") == 3, text.count(
+    # the forward kernel and the one backward kernel (dq, dk, dv)
+    assert text.count("tpu_custom_call") == 2, text.count(
         "tpu_custom_call")
 
 
@@ -74,7 +74,7 @@ def test_flash_attention_masked_bwd_lowers_for_tpu():
             *a, kv_mask=m, interpret=False).astype(jnp.float32)),
             argnums=(0, 1, 2))(q, k, v)
     text = _export_tpu(fwd_bwd, _S, _S, _S, mask)
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 2
 
 
 def test_pallas_lstm_flagship_lowers_for_tpu():
