@@ -249,7 +249,9 @@ class SigmoidRoute(NamedTuple):
 # megablox tiles (rows, contraction, columns): the largest that divides
 # the size, of 512, 256 and 128 rows and of the multiples of 128 up to
 # 1,024 across, so that an expert's 2048 x 768 matrix is two tiles and
-# not ninety-six
+# not ninety-six. megablox evaluates the rule once for each product at
+# that product's own (m, k, n): the forward's, and in its VJP the
+# transposed product's (k and n swapped) and ``tgmm``'s
 def _gmm_tiling(m: int, k: int, n: int):
     def fit(size, choices):
         return next((c for c in choices if size % c == 0), size)
@@ -276,15 +278,15 @@ def fast_rows(num_tokens: int, top_k: int, held: int,
 def _grouped_dot(lhs, rhs, group_sizes, impl, transpose_rhs=False):
     """``lhs[rows of group g] @ rhs[g]`` for rows sorted by group; rows
     past ``sum(group_sizes)`` are nobody's and come back unspecified
-    (the caller masks them). Visits only the rows the groups hold."""
+    (the caller masks them). Visits only the rows the groups hold.
+    megablox is handed the tile rule itself, so that each of the three
+    products, this one and its VJP's two, is tiled for its own shape."""
     if impl == "ragged_dot":
         if transpose_rhs:
             rhs = rhs.swapaxes(1, 2)
         return jax.lax.ragged_dot(lhs, rhs, group_sizes)
     from jax.experimental.pallas.ops.tpu import megablox
-    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype,
-                        _gmm_tiling(lhs.shape[0], lhs.shape[1], n),
+    return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, _gmm_tiling,
                         None, None, transpose_rhs, impl == "gmm_interpret")
 
 

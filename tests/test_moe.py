@@ -578,6 +578,13 @@ def test_gate_cotangent_by_rows_is_plain_ad_of_the_pair_formula(rng, part):
     ((8192, 2048, 2048), (512, 1024, 1024)),
     # Trinity-Mini's (2048 x 1024)
     ((8192, 2048, 1024), (512, 1024, 1024)),
+    # the transposed products of Mellum2's gate and up (k and n
+    # swapped) and of its down product, at the cell's 32,768 rows
+    ((32768, 896, 2304), (512, 896, 768)),
+    ((32768, 2304, 896), (512, 768, 896)),
+    # GLM-4.7-Flash's (2048 x 1536): forward and transposed
+    ((8192, 2048, 1536), (512, 1024, 768)),
+    ((8192, 1536, 2048), (512, 768, 1024)),
     # a width only the rule covers: 640 = 5 x 128, and 1920 = 3 x 640
     ((4096, 1920, 640), (512, 640, 640)),
     # no choice divides: the size itself
@@ -585,6 +592,139 @@ def test_gate_cotangent_by_rows_is_plain_ad_of_the_pair_formula(rng, part):
 ])
 def test_gmm_tiling_takes_the_widest_choice_that_divides(shape, want):
     assert moe._gmm_tiling(*shape) == want
+
+
+def _per_group_dot(lhs, rhs, group_sizes, transpose_rhs):
+    """``_grouped_dot`` written out: each row times its group's matrix
+    in float32 at the highest precision, zeros past the groups."""
+    if transpose_rhs:
+        rhs = rhs.swapaxes(1, 2)
+    rows = jnp.arange(lhs.shape[0])
+    group = jnp.searchsorted(jnp.cumsum(group_sizes), rows, side="right")
+    onehot = jax.nn.one_hot(group, rhs.shape[0], dtype=lhs.dtype)
+    return jnp.einsum("mk,gkn,mg->mn", lhs, rhs, onehot,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+# k 384 and n 256: the forward is tiled (128, 384, 256), the transposed
+# product (128, 256, 384); the forward's tuple would not divide it
+GK, GN, GM = 384, 256, 384
+
+
+@pytest.mark.parametrize("transpose_rhs", [False, True])
+@pytest.mark.parametrize("sizes", [
+    # an empty group, one under a row tile (128), one across two row
+    # tiles, and 64 rows past the groups that are nobody's
+    (0, 50, 200, 70),
+    # every row live, the first group across a tile's edge, the last
+    # group empty
+    (130, 126, 128, 0),
+])
+def test_grouped_dot_and_its_gradients_match_a_per_group_product(
+        rng, transpose_rhs, sizes):
+    """The megablox products, interpreted, at shapes where the
+    transposed product's tiles differ from the forward's: the output,
+    the rows' cotangent (the transposed product) and the matrices'
+    (``tgmm``) against the per-group product and its plain AD."""
+    assert moe._gmm_tiling(GM, GK, GN) != moe._gmm_tiling(GM, GN, GK)
+    groups = len(sizes)
+    lhs = jnp.asarray(rng.standard_normal((GM, GK)).astype(np.float32))
+    shape = (groups, GN, GK) if transpose_rhs else (groups, GK, GN)
+    rhs = jnp.asarray(rng.standard_normal(shape).astype(np.float32)) * 0.1
+    ct = jnp.asarray(rng.standard_normal((GM, GN)).astype(np.float32))
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    live = sum(sizes)
+
+    def run(fn):
+        out, vjp = jax.vjp(lambda a, b: fn(a, b, group_sizes, transpose_rhs),
+                           lhs, rhs)
+        return (out,) + vjp(ct)
+
+    got_out, got_dlhs, got_drhs = run(
+        lambda a, b, g, t: moe._grouped_dot(a, b, g, "gmm_interpret", t))
+    want_out, want_dlhs, want_drhs = run(_per_group_dot)
+    tol = dict(rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got_out)[:live],
+                               np.asarray(want_out)[:live], **tol)
+    np.testing.assert_allclose(np.asarray(got_dlhs)[:live],
+                               np.asarray(want_dlhs)[:live], **tol)
+    # the rows past the groups do not reach the matrices' cotangent,
+    # and an empty group's is zero
+    np.testing.assert_allclose(np.asarray(got_drhs), np.asarray(want_drhs),
+                               **tol)
+    for g, size in enumerate(sizes):
+        assert (float(jnp.abs(got_drhs[g]).max()) == 0) == (size == 0)
+
+
+def _kernel_tiles(fn, *args):
+    """The (rows, contraction, columns) tiles of each megablox kernel
+    that tracing ``fn`` on ``args`` calls, in order, read off the block
+    shapes of its ``pallas_call``: ``[("gmm", (tm, tk, tn)), ...]``."""
+    from jax.extend import core as jcore
+    found = []
+
+    def walk(jaxpr, kernel):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                blocks = [[getattr(b, "block_size", None)
+                           for b in m.block_shape]
+                          for m in eqn.params["grid_mapping"].block_mappings]
+                if kernel == "gmm":       # lhs (tm, tk), out (tm, tn)
+                    tiles = (blocks[0][0], blocks[0][1], blocks[2][1])
+                else:                     # rhs (tm, tn), out (., tk, tn)
+                    tiles = (blocks[1][0], blocks[2][1], blocks[2][2])
+                found.append((kernel, tiles))
+                continue
+            inner = eqn.params.get("name") \
+                if eqn.params.get("name") in ("gmm", "tgmm") else kernel
+            for v in eqn.params.values():
+                for j in v if isinstance(v, (list, tuple)) else [v]:
+                    if isinstance(j, jcore.ClosedJaxpr):
+                        walk(j.jaxpr, inner)
+                    elif isinstance(j, jcore.Jaxpr):
+                        walk(j, inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, None)
+    return found
+
+
+@pytest.mark.parametrize("rows,dim,width,transposed", [
+    # (the cell's fast rows, model width, expert width), and what the
+    # transposed products get on the forward's tiles: Mellum2 2.0 x the
+    # MXU passes they need, Keye and GLM 1.5 x; ZAYA and Trinity-Mini
+    # the same tiles either way
+    pytest.param(32768, 2304, 896, True, id="mellum2"),
+    pytest.param(16384, 2048, 768, True, id="keye"),
+    pytest.param(8192, 2048, 1536, True, id="glm"),
+    pytest.param(8192, 2048, 2048, False, id="zaya"),
+    pytest.param(16384, 2048, 1024, False, id="trinity"),
+])
+@pytest.mark.parametrize("product", ["gate_up", "down"])
+def test_each_grouped_product_is_tiled_for_its_own_shape(
+        rows, dim, width, transposed, product):
+    """At each cell's shapes the forward product and ``tgmm`` keep the
+    tiles the forward's shape gives, and megablox's VJP gives the
+    transposed product (k and n swapped) the tiles of its own shape."""
+    k, n = (dim, width) if product == "gate_up" else (width, dim)
+    groups = 16
+
+    def fwd_bwd(x, w, group_sizes, ct):
+        out, vjp = jax.vjp(
+            lambda a, b: moe._grouped_dot(a, b, group_sizes, "gmm"), x, w)
+        return out, vjp(ct)
+
+    found = _kernel_tiles(
+        fwd_bwd, jax.ShapeDtypeStruct((rows, k), jnp.bfloat16),
+        jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16),
+        jax.ShapeDtypeStruct((groups,), jnp.int32),
+        jax.ShapeDtypeStruct((rows, n), jnp.bfloat16))
+    forward = moe._gmm_tiling(rows, k, n)
+    own = moe._gmm_tiling(rows, n, k)
+    assert found == [("gmm", forward), ("gmm", own), ("tgmm", forward)]
+    # each product's tiles divide it: no tile is partly padding
+    for size, tile in zip((rows, n, k), own):
+        assert size % tile == 0
+    assert (own != forward) == transposed
 
 
 def test_linear_router_under_a_given_choice(tokens, rng):
